@@ -67,8 +67,9 @@ class ExecConfig:
     # Fused Pallas aggregation/join kernels (exec/pallas_kernels.py):
     # dense one-hot agg (int64/DECIMAL sums EXACT via 13-bit f32 limbs),
     # sorted-segment mid-cardinality agg (exact via 8-bit int32 limbs),
-    # and the probe join. Off by default until re-measured on hardware;
-    # bench.py BENCH_PALLAS=ab A/Bs per query and keeps the winner.
+    # and the probe join. Off by default: the TPU compiler refuses all
+    # three today (docs/PALLAS_AB.md); bench.py BENCH_PALLAS=ab|on turns
+    # them on for a run and raises what the compiler raises.
     use_pallas: bool = False
 
 

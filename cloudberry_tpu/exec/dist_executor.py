@@ -337,18 +337,10 @@ def _out_specs_like(plan: N.PlanNode):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (module move + check_rep rename)."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature")
+    """The engine's one shard_map call: replication is tracked by the
+    plan's own locus algebra, so jax's varying-manual-axes check is off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class DistLowerer(X.Lowerer):

@@ -285,7 +285,19 @@ def cmd_serve(args) -> int:
     process owns the session; clients connect over TCP."""
     from cloudberry_tpu.serve import Server
     from cloudberry_tpu.utils import faultinject
+    import jax
 
+    # a restarted server should not pay its statements' compiles again
+    # (minutes per join program on a TPU): persistent compile cache on.
+    # Not on the CPU backend: compiles are fast there, and jaxlib 0.9.0's
+    # XLA:CPU loader logs kilobytes to stderr on every cache hit — enough
+    # to fill the pipe of a supervisor that stopped reading after the
+    # banner (tools/crash_torture.py) and wedge the server.
+    cache_dir = None
+    if jax.default_backend() != "cpu":
+        from cloudberry_tpu.utils.compilecache import enable_compile_cache
+
+        cache_dir = enable_compile_cache()
     # crash-torture arming: the harness launches this very entry point
     # with CBTPU_INJECT set, so the faults land inside the REAL server
     # process it is about to kill (never armed in normal operation)
@@ -306,8 +318,11 @@ def cmd_serve(args) -> int:
         print(f"fault injection armed: {n_armed} seam(s) from "
               "CBTPU_INJECT", flush=True)
     role = "standby (read-only)" if srv.read_only else "primary"
+    from cloudberry_tpu.parallel.mesh import device_line
+
     print(f"serving on {srv.host}:{srv.port} (store {args.store}, "
-          f"{srv.session.config.n_segments} segments, {role})", flush=True)
+          f"{srv.session.config.n_segments} segments, {role}; device "
+          f"{device_line()}; compile cache {cache_dir})", flush=True)
     try:
         srv.serve_forever()
     except KeyboardInterrupt:

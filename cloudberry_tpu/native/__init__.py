@@ -9,6 +9,7 @@ numpy fallbacks so every environment works and tests can diff the two.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import tempfile
@@ -24,32 +25,48 @@ def _repo_root() -> str:
         os.path.abspath(__file__))))
 
 
+def _no_native(why: str):
+    """The numpy fallback keeps every environment working (and lets tests
+    diff the two codecs), but its varint path is a per-value Python loop:
+    say ONCE why the C++ codec is missing instead of degrading in silence
+    (``_tried`` makes this the only call per process)."""
+    logging.getLogger(__name__).warning(
+        "native codec unavailable, using the numpy fallback: %s", why)
+    return None
+
+
 def load_native():
-    """Build (once) and load libcbcodec; None if no toolchain."""
+    """Build (once) and load libcbcodec; None — with the reason logged —
+    when it cannot be built or loaded. The library is never committed:
+    ``native/build/`` is git-ignored and a fresh checkout builds on first
+    use, so what loads was always built from THIS ``codec.cpp``."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
     _tried = True
     src = os.path.join(_repo_root(), "native", "codec.cpp")
     if not os.path.exists(src):
-        return None
+        return _no_native(f"{src} not found")
     try:
         build_dir = os.path.join(_repo_root(), "native", "build")
         os.makedirs(build_dir, exist_ok=True)
         so = os.path.join(build_dir, "libcbcodec.so")
         if not os.path.exists(so) or \
                 os.path.getmtime(so) < os.path.getmtime(src):
-            tmp = tempfile.mktemp(suffix=".so", dir=build_dir)
+            fd, tmp = tempfile.mkstemp(suffix=".so", dir=build_dir)
+            os.close(fd)
             subprocess.run(
                 ["g++", "-O3", "-fwrapv", "-shared", "-fPIC", src, "-o", tmp],
                 check=True, capture_output=True, timeout=120)
             os.replace(tmp, so)
-    except Exception:
-        return None  # read-only fs / no toolchain → numpy fallback
+    except subprocess.CalledProcessError as e:
+        return _no_native(f"g++ failed: {e.stderr.decode(errors='replace')[-400:]}")
+    except Exception as e:  # read-only fs / no toolchain
+        return _no_native(f"{type(e).__name__}: {e}")
     try:
         lib = ctypes.CDLL(so)
-    except OSError:
-        return None
+    except OSError as e:
+        return _no_native(f"cannot load {so}: {e}")
     lib.cb_dvarint_encode.restype = ctypes.c_int64
     lib.cb_dvarint_encode.argtypes = [
         ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]

@@ -85,17 +85,9 @@ def init_distributed(coordinator: str | None = None,
     process_id = int(process_id
                      if process_id is not None
                      else os.environ.get("CBTPU_PROC_ID", "0"))
-    # XLA:CPU only implements cross-process collectives through a
-    # pluggable backend (Gloo in jaxlib) — without this, any program
-    # whose device assignment spans processes dies at dispatch with
-    # "Multiprocess computations aren't implemented on the CPU
-    # backend". Must be set before the CPU client spins up, which is
-    # why it lives here (workers call init_distributed before any jax
-    # op). TPU pods ignore it: their DCN collectives are native.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):  # older/newer jax: best effort
-        pass
+    # (XLA:CPU's cross-process collectives ride Gloo, the installed
+    # jax's default jax_cpu_collectives_implementation; TPU pods' DCN
+    # collectives are native.)
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -126,6 +118,15 @@ def _check_device_ids(device_ids, n_devices: int) -> None:
             f"{n_devices} devices are visible — the ids are stale "
             "(devices lost / cluster shrunk since the restriction was "
             "derived); re-probe and rebuild the survivor list")
+
+
+def device_line() -> str:
+    """``platform device_kind xN`` as JAX reports it — the line every
+    measuring entry point prints, so no number travels without the
+    device it was taken on."""
+    devices = jax.devices()
+    return (f"{devices[0].platform} {devices[0].device_kind} "
+            f"x{len(devices)}")
 
 
 def segment_mesh(n_segments: int, device_ids=None) -> Mesh:

@@ -1,8 +1,10 @@
 """bench.py roofline context (VERDICT r5 item 8): every emitted speedup
-carries a bytes-scanned ÷ HBM-bandwidth denominator, including REPLAY
-mode where the bytes come from the static schema estimate — and, since
-the packed-wire motion PR, an interconnect record (collective launches +
+carries a bytes-scanned ÷ HBM-bandwidth denominator — the peak is the
+caller's, looked up by ``device_kind`` on a chip — and, since the
+packed-wire motion PR, an interconnect record (collective launches +
 bytes-on-wire per query at the 8-segment plan shape)."""
+
+import pytest
 
 import bench
 
@@ -16,21 +18,29 @@ def test_static_scan_bytes_scales_with_sf():
     assert bench.static_scan_bytes("q99", 1.0) is None
 
 
-def test_roofline_context_replay_and_live():
-    # replay shape: denominator only (no wall times)
-    rep = bench.roofline_context(["q1", "q3"], 1.0)
-    assert rep["hbm_gbps_nominal"] > 0
+def test_hbm_peak_is_keyed_by_device_kind():
+    # v5e: 819 GB/s (Google Cloud documentation, "TPU v5e"); a kind with
+    # no published peak in the table is an error, never a default
+    assert bench.hbm_gbps("TPU v5 lite") == 819.0
+    with pytest.raises(KeyError, match="no published HBM bandwidth"):
+        bench.hbm_gbps("cpu")
+
+
+def test_roofline_context_static_and_measured():
+    # static shape: denominator only (no wall times)
+    rep = bench.roofline_context(["q1", "q3"], 1.0, 819.0)
+    assert rep["hbm_gbps_nominal"] == 819.0
     assert set(rep["per_query"]) == {"q1", "q3"}
     for rec in rep["per_query"].values():
         assert rec["bytes_scanned"] > 0
         assert "hbm_frac" not in rec
-    # live shape: measured bytes + wall time → achieved GB/s + HBM frac
+    # measured shape: bytes + wall time → achieved GB/s + HBM fraction
     live = bench.roofline_context(
-        ["q1"], 1.0, bytes_by_q={"q1": 2_000_000_000},
+        ["q1"], 1.0, 819.0, bytes_by_q={"q1": 2_000_000_000},
         wall_by_q={"q1": 0.01})
     rec = live["per_query"]["q1"]
     assert rec["scan_gbps"] == 200.0
-    assert 0 < rec["hbm_frac"] < 1
+    assert rec["hbm_frac"] == round(200.0 / 819.0, 4)
 
 
 def test_interconnect_context_records_shuffle_volume():

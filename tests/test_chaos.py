@@ -57,6 +57,36 @@ def test_device_loss_exhausts_retries():
         s.sql("select sum(v) from t")
 
 
+def test_one_device_loss_surfaces_to_the_client():
+    """One chip, one segment: there is no survivor to degrade to. A
+    device error that persists is re-dispatched ``health.retries`` times
+    ON THE SAME one-device mesh and then reaches the client as an error —
+    never a success from some other backend (ISSUE 22 step 4)."""
+    import jax
+
+    from cloudberry_tpu.serve.client import Client, ServerError
+    from cloudberry_tpu.serve.server import Server
+
+    s = _mk(**{"health.backoff_s": 0.01})
+    _load(s)
+    backend = jax.default_backend()
+    with Server(session=s) as srv, Client(srv.host, srv.port) as c:
+        FI.inject_fault("exec_device_lost", "error")  # every dispatch
+        with pytest.raises(ServerError, match="device_lost") as ei:
+            c.sql("select sum(v) from t")
+        assert ei.value.etype == "InjectedFault"
+        # first attempt + health.retries re-dispatches, all on the one
+        # device: the mesh never shrank below it or moved elsewhere
+        fired = FI.list_faults()["armed"]["exec_device_lost"]["fired"]
+        assert fired == 1 + s.config.health.retries
+        assert s.config.n_segments == 1
+        assert jax.default_backend() == backend
+        assert s.stmt_log.counter("recoveries") == s.config.health.retries
+        FI.reset_fault()
+        assert c.sql("select sum(v) as sv from t")["rows"] == \
+            [[int(((np.arange(64) * 7) % 13).sum())]]
+
+
 def test_non_recoverable_fault_not_retried():
     """dispatch_start is not a device-loss seam: no retry, one hit."""
     s = _mk()

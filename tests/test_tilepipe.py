@@ -278,6 +278,7 @@ def test_cancel_mid_window_no_orphan_inflight():
     _load(s)
     FI.inject_fault("tile_step", "sleep", sleep_s=0.05)
     errs = []
+    before = set(threading.enumerate())
 
     def bg():
         try:
@@ -299,9 +300,16 @@ def test_cancel_mid_window_no_orphan_inflight():
     th.join(timeout=60)
     assert errs and isinstance(errs[0], lifecycle.StatementCancelled)
     # abandoned in-flight launches leave no threads behind (JAX's async
-    # dispatch completes into garbage-collected buffers)
-    assert not any(t.name.startswith("cbtpu-")
-                   and t.is_alive() for t in threading.enumerate())
+    # dispatch completes into garbage-collected buffers). Only threads
+    # THIS statement started count: on a multi-core host an earlier
+    # test's store scan leaves the process-lifetime cbtpu-scan-decode
+    # pool alive by design (exec/scanpipe.py decode pool), and this
+    # statement may be the one that creates it.
+    stray = [t.name for t in threading.enumerate()
+             if t not in before and t.is_alive()
+             and t.name.startswith("cbtpu-")
+             and not t.name.startswith("cbtpu-scan-decode")]
+    assert not stray, stray
 
     FI.reset_fault()
     got = s.sql(AGG_Q).to_pandas()

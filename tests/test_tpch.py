@@ -2,12 +2,10 @@
 result, validated against the pandas oracle (tools/tpch_oracle.py) on the
 same generated data — the regress-suite analog."""
 
-import numpy as np
-import pandas as pd
 import pytest
 
 import cloudberry_tpu as cb
-from tools.tpch_oracle import ORACLES
+from tools.tpch_oracle import ORACLES, assert_frames_match  # noqa: F401
 from tools.tpch_queries import QUERIES
 from tools.tpchgen import load_tpch
 
@@ -18,27 +16,6 @@ def tpch_session():
     load_tpch(s, sf=0.01, seed=7)
     tables = {n: t.to_pandas() for n, t in s.catalog.tables.items()}
     return s, tables
-
-
-def assert_frames_match(got: pd.DataFrame, exp: pd.DataFrame, name: str):
-    assert len(got) == len(exp), \
-        f"{name}: row count {len(got)} != {len(exp)}"
-    assert len(got.columns) == len(exp.columns), \
-        f"{name}: column count {list(got.columns)} vs {list(exp.columns)}"
-    for gcol, ecol in zip(got.columns, exp.columns):
-        g, e = got[gcol].to_numpy(), exp[ecol].to_numpy()
-        if g.dtype.kind == "f" or e.dtype.kind == "f":
-            np.testing.assert_allclose(
-                g.astype(np.float64), e.astype(np.float64),
-                rtol=1e-9, atol=1e-2, err_msg=f"{name}.{gcol}")
-        elif g.dtype == object or e.dtype == object:
-            gn, en = pd.isna(g), pd.isna(e)
-            np.testing.assert_array_equal(
-                gn, en, err_msg=f"{name}.{gcol} (null mask)")
-            np.testing.assert_array_equal(
-                g[~gn], e[~en], err_msg=f"{name}.{gcol}")
-        else:
-            np.testing.assert_array_equal(g, e, err_msg=f"{name}.{gcol}")
 
 
 @pytest.mark.parametrize("qname", sorted(QUERIES))

@@ -1,0 +1,245 @@
+"""Programs of the main path, compiled for a v5e that is described, not
+attached (on-chip-measurement guide §2, third rehearsal).
+
+The TPU's compiler is installed wherever the tests run; it compiles for a
+``v5e:2x2`` topology description without a chip. Nothing here runs, so
+nothing here is a chip result — these tests only say that the compiler
+ACCEPTS what the engine would hand it at TPC-H SF1 shapes (lineitem
+6,001,215 rows), and pin what it refuses today.
+
+Rules this file obeys (the driver runs 6 xdist workers, and only one
+process may hold libtpu): the topology is described inside a module-scoped
+fixture that skips when it cannot be — never at import, never in
+conftest, never autouse — and everything compiles in the test's own
+process, all in this one file. Code under test asks
+``jax.default_backend()`` for its platform branch (executor.py Lowerer,
+tilepipe.step_donation); the ``as_tpu`` fixture steers that here, in the
+test, not through a program option. The persistent compilation cache is
+not on under tests (entry points enable it, utils/compilecache.py), so
+these compiles neither read nor write one.
+
+Q3's one-segment program (5 sorts) takes the TPU compiler 342 s at
+SF0.01 shapes and 1009 s at SF1 shapes (PR 22 sandbox, one thread), so it
+is NOT compiled in tier-1: ``test_q3_compiles_for_tpu`` is marked slow.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+SF1_LINEITEM_ROWS = 6_001_215
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Make the engine's ``jax.default_backend()`` platform branches take
+    their accelerator side while a program is built and traced."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shapes(tree, sharding):
+    """Arrays → ShapeDtypeStructs placed by ``sharding`` (a described
+    device holds no array, so programs lower from shapes)."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=sharding), tree)
+
+
+def _tpch_session(tables, rows=None, **over):
+    """A session holding ``tables`` of TPC-H SF0.01, each encoded column
+    cyclically resized to ``rows[name]`` rows where given: SF1 SHAPES
+    without SF1 generation (the compiler reads shapes, not values)."""
+    import cloudberry_tpu as cb
+    from cloudberry_tpu.catalog.catalog import DistributionPolicy
+    from cloudberry_tpu.columnar.batch import encode_column
+    from cloudberry_tpu.config import Config
+    from tools.tpchgen import DIST_KEYS, SCHEMAS, generate
+
+    raw = generate(0.01, seed=7)
+    s = cb.Session(Config().with_overrides(**over))
+    for name in tables:
+        schema, keys = SCHEMAS[name], DIST_KEYS[name]
+        t = s.catalog.create_table(
+            name, schema, DistributionPolicy.replicated() if keys is None
+            else DistributionPolicy.hashed(*keys))
+        enc = {f.name: encode_column(raw[name][f.name], f, t.dicts)
+               for f in schema.fields}
+        if rows and name in rows:
+            enc = {c: np.resize(v, rows[name]) for c, v in enc.items()}
+        t.set_data(enc, t.dicts)
+    return s
+
+
+@pytest.fixture(scope="module")
+def sf1_lineitem():
+    return _tpch_session(["lineitem"],
+                         rows={"lineitem": SF1_LINEITEM_ROWS})
+
+
+def _plan(session, sql):
+    from cloudberry_tpu.plan.planner import plan_statement
+    from cloudberry_tpu.sql.parser import parse_sql
+
+    return plan_statement(parse_sql(sql), session, {}).plan
+
+
+def _compile_one_shot(session, qname, one_chip):
+    from cloudberry_tpu.exec.executor import compile_plan, prepare_inputs
+    from tools.tpch_queries import QUERIES
+
+    exe = compile_plan(_plan(session, QUERIES[qname]), session,
+                       platform="tpu")
+    shapes = _shapes(prepare_inputs(exe, session), one_chip)
+    return exe.fn.lower(shapes).compile()
+
+
+@pytest.mark.parametrize("qname", ["q1", "q6"])
+def test_scan_agg_programs_compile_for_tpu(sf1_lineitem, one_chip, qname):
+    """Q1 and Q6 — the scan + (grouped) aggregate programs of the served
+    path — in the TPU formulation (dense_strategy="reduce") at SF1 shapes."""
+    compiled = _compile_one_shot(sf1_lineitem, qname, one_chip)
+    mem = compiled.memory_analysis()
+    # fits one 16 GB chip with room: arguments + temporaries
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+def test_tiled_step_compiles_for_tpu_with_donation(sf1_lineitem, one_chip,
+                                                   as_tpu):
+    """One exec/tiled.py tile step of Q6 under the smoke's 256 MiB budget:
+    on an accelerator the step DONATES its accumulator
+    (tilepipe.step_donation) — a path no CPU test takes."""
+    import copy
+
+    from cloudberry_tpu.exec import scanpipe as SP
+    from cloudberry_tpu.exec import tiled
+    from cloudberry_tpu.exec import tilepipe as TP
+    from tools.tpch_queries import QUERIES
+
+    s = copy.copy(sf1_lineitem)
+    s.config = sf1_lineitem.config.with_overrides(
+        **{"resource.query_mem_bytes": 256 << 20})
+    texe = tiled.plan_tiled(_plan(s, QUERIES["q6"]), s)
+    assert texe is not None and texe._platform == "tpu"
+    assert TP.step_donation(texe._platform) == (4,)
+    assert TP.effective_window(s.config, texe._platform) == 4
+    prelude_fn, step_fn, _ = texe._compile()
+    resident = texe._resident_inputs()
+    feed = tiled._tile_feed(texe.shape.stream, s, texe.tile_rows)
+    try:
+        tile, _ = next(iter(feed))
+    finally:
+        SP.close_feed(feed)
+    assert len(next(iter(tile.values()))) == texe.tile_rows
+    prelude = jax.eval_shape(lambda r: prelude_fn(r)[0], resident)
+    args = _shapes((resident, prelude, tile, np.int32(0),
+                    texe._init_acc()), one_chip)
+    compiled = step_fn.lower(*args).compile()
+    # the donated accumulator is aliased in place, not copied
+    assert "input_output_alias" in compiled.as_text()
+
+
+def test_redistribute_compiles_to_all_to_all_on_four_chips(topo, as_tpu,
+                                                           monkeypatch):
+    """One 4-device shard_map program with a hash redistribute. Q3 joins
+    co-located tables and broadcasts customer, so its plan has none (and
+    its compile is minutes): this is ``chip_smoke.py --chips 4``'s
+    statement, the cheapest whose plan redistributes — lineitem
+    (distributed by l_orderkey) grouped by l_suppkey. The compiler must
+    place an all-to-all on the 2x2 mesh."""
+    from chip_smoke import mesh_statements
+    from cloudberry_tpu.exec import dist_executor as DX
+    from cloudberry_tpu.parallel.mesh import SEG_AXIS
+
+    s = _tpch_session(["lineitem"], n_segments=4)
+    sql = mesh_statements(with_join=False)["by_supp"][0]
+    assert "Motion redistribute" in s.explain(sql)
+    plan = _plan(s, sql)
+    mesh = Mesh(np.asarray(topo.devices[:4]), (SEG_AXIS,))
+    monkeypatch.setattr(DX, "segment_mesh", lambda n, ids=None: mesh)
+    fn = DX.compile_distributed(plan, s)
+    inputs, in_specs = DX.prepare_dist_inputs(plan, s)
+    shapes = jax.tree_util.tree_map(
+        lambda x, spec: jax.ShapeDtypeStruct(
+            np.shape(x), x.dtype, sharding=NamedSharding(mesh, spec)),
+        inputs, in_specs)
+    compiled = fn.lower(shapes).compile()
+    assert "all-to-all" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_q3_compiles_for_tpu(one_chip):
+    """The join program (build_sort + searchsorted + group sort + top-N:
+    5 sorts), at SF0.01 shapes. Slow tier: 342 s in the PR 22 sandbox
+    (1009 s at SF1 shapes)."""
+    s = _tpch_session(["lineitem", "orders", "customer"])
+    _compile_one_shot(s, "q3", one_chip)
+
+
+# ---------------------------------------------------------------- Pallas
+# Today's truth (PR 22): the TPU compiler refuses all three kernels of
+# exec/pallas_kernels.py; they have only ever run with interpret=True.
+# They sit behind exec.use_pallas (default off), so the main path does
+# not depend on them. The day someone repairs one, its case here fails
+# and ROADMAP D7 has its evidence. docs/PALLAS_AB.md has the table.
+
+
+def _dense_agg(sh):
+    from cloudberry_tpu.exec import pallas_kernels as PK
+
+    n, k = 1 << 20, 20
+    return PK.dense_agg_tiles_pallas.lower(
+        sh((n,), jnp.int32), sh((k, n), jnp.float32), sh((n,), jnp.bool_),
+        n_cells=6, tile=2048)
+
+
+def _probe_join(sh):
+    from cloudberry_tpu.exec import pallas_kernels as PK
+
+    b, n, p = 1024, 1 << 20, 6
+    return PK.probe_join_pallas.lower(
+        sh((b,), jnp.uint32), sh((b,), jnp.bool_), sh((n,), jnp.uint32),
+        sh((n,), jnp.bool_), sh((p, b), jnp.float32), tile=1024)
+
+
+def _sorted_seg(sh):
+    from cloudberry_tpu.exec import pallas_kernels as PK
+
+    r, n = 8, 1 << 20
+    return PK.sorted_seg_pallas.lower(
+        sh((n,), jnp.int32), sh((r, n), jnp.int32), tile=2048)
+
+
+@pytest.mark.parametrize("lower, error, words", [
+    # index maps yield i64 under jax_enable_x64; Mosaic wants i32
+    (_dense_agg, Exception, r"failed to legalize operation 'func\.return'"),
+    (_probe_join, Exception, r"unsupported shape cast"),
+    (_sorted_seg, RecursionError, r"recursion"),
+], ids=["dense_agg_tiles", "probe_join", "sorted_seg"])
+def test_pallas_kernels_are_refused_by_the_tpu_compiler(one_chip, lower,
+                                                        error, words):
+    def sh(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with pytest.raises(error, match=words):
+        lower(sh).compile()

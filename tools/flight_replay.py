@@ -105,6 +105,13 @@ def main(argv=None) -> int:
 
     with open(args.bundle) as fh:
         bundle = pick_bundle(json.load(fh), args.index)
+    # The replay contract is bit-identity, and float aggregates are only
+    # bit-stable on ONE backend: the replay runs wherever JAX puts it
+    # (set JAX_PLATFORMS to match the engine that captured the bundle)
+    # and says where that was — never a silent CPU default.
+    from cloudberry_tpu.parallel.mesh import device_line
+
+    print(f"replaying on {device_line()}", file=sys.stderr)
     verdict = replay(bundle, root=args.root, n_segments=args.segments)
     if verdict.get("unreplayable"):
         print(f"UNREPLAYABLE: {verdict['unreplayable']}", file=sys.stderr)
@@ -121,5 +128,4 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.exit(main())

@@ -485,5 +485,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from cloudberry_tpu.utils.compilecache import entry_banner
+
+    print(f"# {entry_banner()}", file=sys.stderr)
     sys.exit(main())

@@ -1030,5 +1030,7 @@ def main(argv=None) -> list[dict]:
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from cloudberry_tpu.utils.compilecache import entry_banner
+
+    print(f"# {entry_banner()}", file=sys.stderr)
     main()

@@ -12,6 +12,34 @@ def d(s: str) -> np.datetime64:
     return np.datetime64(s)
 
 
+def assert_frames_match(got: pd.DataFrame, exp: pd.DataFrame, name: str):
+    """An engine answer against an oracle frame, column by position:
+    floats within rtol 1e-9 / atol 1e-2 (money is exact cents; averages
+    are doubles), everything else exactly, NULLs in the same places.
+    Raises AssertionError (never a bare ``assert``: chip_smoke.py's
+    verdict must survive ``python -O``)."""
+    if len(got) != len(exp):
+        raise AssertionError(
+            f"{name}: row count {len(got)} != {len(exp)}")
+    if len(got.columns) != len(exp.columns):
+        raise AssertionError(f"{name}: column count {list(got.columns)} "
+                             f"vs {list(exp.columns)}")
+    for gcol, ecol in zip(got.columns, exp.columns):
+        g, e = got[gcol].to_numpy(), exp[ecol].to_numpy()
+        if g.dtype.kind == "f" or e.dtype.kind == "f":
+            np.testing.assert_allclose(
+                g.astype(np.float64), e.astype(np.float64),
+                rtol=1e-9, atol=1e-2, err_msg=f"{name}.{gcol}")
+        elif g.dtype == object or e.dtype == object:
+            gn, en = pd.isna(g), pd.isna(e)
+            np.testing.assert_array_equal(
+                gn, en, err_msg=f"{name}.{gcol} (null mask)")
+            np.testing.assert_array_equal(
+                g[~gn], e[~en], err_msg=f"{name}.{gcol}")
+        else:
+            np.testing.assert_array_equal(g, e, err_msg=f"{name}.{gcol}")
+
+
 def q1(t):
     li = t["lineitem"]
     m = li[li.l_shipdate <= d("1998-09-02")].copy()
