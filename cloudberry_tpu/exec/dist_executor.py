@@ -301,8 +301,7 @@ def execute_distributed(plan: N.PlanNode, session,
     fault_point("dist_execute_start")
     from cloudberry_tpu.obs import trace as OT
 
-    with OT.span("launch", mode="dist"), \
-            OT.device_annotation("launch-dist"):
+    with OT.stage("dispatch", "launch_seconds", mode="dist"):
         cols, sel, checks, stats = fn(inputs)
     record_motion_stats(plan, stats, session=session)
     X.raise_checks(checks)

@@ -308,16 +308,37 @@ def _read_scan_columns(scan: N.PScan, session, log) -> dict:
 
 
 def run_executable(exe: Executable, tables: dict) -> ColumnBatch:
-    # device launch under the statement's trace span + a jax.profiler
-    # annotation (obs/trace.py): an XLA profile of a traced statement
-    # correlates with the host span names; both are no-ops untraced
+    """One launch of a compiled program, split where the time goes
+    (obs/trace.py, children of ``launch``): ``dispatch`` until the call
+    returns (the first call of a shape traces and compiles inside it),
+    ``device-wait`` until the program is done (the first D2H read, which
+    has always been the sync: now a span, not a second sync, and its
+    value stays on the array for raise_checks / make_batch), ``fetch``
+    for the other D2H reads of the check scalars and result columns."""
     from cloudberry_tpu.obs import trace as OT
 
-    with OT.span("launch", plan=type(exe.plan).__name__), \
-            OT.device_annotation("launch"):
+    with OT.stage("dispatch", "launch_seconds",
+                  plan=type(exe.plan).__name__):
         cols, sel, checks = exe.fn(tables)
-    raise_checks(checks)
-    return make_batch(exe.plan, cols, sel)
+    with OT.stage("device-wait", "launch_seconds"):
+        np.asarray(next(iter(checks.values()), sel))
+    with OT.stage("fetch", "launch_seconds", host=True) as st:
+        raise_checks(checks)
+        batch = make_batch(exe.plan, cols, sel)
+        st.args["columns"] = len(batch.columns)
+        st.args["bytes"] = sum(int(a.nbytes)
+                               for a in batch.columns.values())
+    return batch
+
+
+def run_prepared(exe: Executable, session, segment=None) -> ColumnBatch:
+    """prepare_inputs (the ``inputs`` stage) + run_executable: the
+    non-generic statement runner."""
+    from cloudberry_tpu.obs import trace as OT
+
+    with OT.stage("inputs", "launch_seconds", host=True):
+        tables = prepare_inputs(exe, session, segment=segment)
+    return run_executable(exe, tables)
 
 
 def raise_checks(checks: dict) -> None:

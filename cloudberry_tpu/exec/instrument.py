@@ -74,6 +74,11 @@ class StatementLog:
         self.obs_enabled = True
         self.trace_sample = 1
         self.slow_ms = 5000.0
+        # every program JAX hands to the compiler counts (xla_compiles)
+        # against the statement open on the compiling thread
+        from cloudberry_tpu.obs.trace import listen_for_compiles
+
+        listen_for_compiles()
 
     def configure_obs(self, obs_cfg) -> None:
         """Apply a session's ObsConfig (config.py). Called once at
@@ -124,9 +129,12 @@ class StatementLog:
                      tenant=tenant)
 
     def traces(self, limit: int = 16) -> list[dict]:
-        """Most recent completed trace exports, newest first."""
+        """Most recent completed traces' exports, newest first. The
+        ring holds the traces themselves: the request around a statement
+        adds its last spans (render, wire-out, request) after the
+        statement finished."""
         out = list(self._trace_ring)[-max(1, limit):]
-        return out[::-1]
+        return [t.export() for t in out[::-1]]
 
     # ------------------------------------------------------ flight ring
 
@@ -165,7 +173,10 @@ class StatementLog:
     # server's `cancel <id>` verb — cancels by statement id.
 
     def attach(self, sid: int, handle) -> None:
-        """Register a lifecycle.StatementHandle for an active statement."""
+        """Register a lifecycle.StatementHandle for an active statement.
+        The handle learns its engine's log here: obs.trace.stage finds
+        the registry through the thread's handle."""
+        handle.log = self
         with self._lock:
             entry = self._active.get(sid)
             if entry is not None:
@@ -263,7 +274,7 @@ class StatementLog:
         trace = getattr(handle, "trace", None)
         if trace is not None:
             trace.finish(status)
-            self._trace_ring.append(trace.export())
+            self._trace_ring.append(trace)
             self.registry.bump("trace_statements")
             if trace.dropped:
                 self.registry.bump("trace_spans_dropped", trace.dropped)
@@ -361,44 +372,37 @@ def _timed_compile_run(fn, inputs, log=None):
     the honest compile-vs-execute split. Preferred: the AOT API
     (``fn.lower().compile()``) times compilation ALONE and executes
     once. Fallback (older jax / non-jit callables): two calls — the
-    first pays compile+execute, the second executes warm, and the split
-    is the difference (never negative). Both legs record trace spans
-    and stage histograms when the thread is inside a traced statement."""
+    first pays compile+execute (the ``compile`` stage), the second
+    executes warm (``launch``), and the compile figure returned is the
+    difference (never negative). Each leg is one obs.trace stage: span,
+    stage histogram and profiler annotation from one measurement."""
     import jax
 
-    from cloudberry_tpu.obs import metrics as OM
     from cloudberry_tpu.obs import trace as OT
+
+    def _launch(call):
+        t1 = time.monotonic()
+        with OT.stage("launch", log=log, mode="instrumented"):
+            result = call(inputs)
+            jax.block_until_ready(result)
+        return result, time.monotonic() - t1
 
     t0 = time.monotonic()
     compiled = None
     try:
-        with OT.span("compile"):
+        with OT.stage("compile", log=log):
             compiled = fn.lower(inputs).compile()
     except (AttributeError, TypeError):
         compiled = None
     if compiled is not None:
         compile_s = time.monotonic() - t0
-        OM.observe_stage(log, "compile", compile_s)
-        t1 = time.monotonic()
-        with OT.span("launch", mode="instrumented"), \
-                OT.device_annotation("launch"):
-            result = compiled(inputs)
-            jax.block_until_ready(result)
-        exec_s = time.monotonic() - t1
-        OM.observe_stage(log, "launch", exec_s)
+        result, exec_s = _launch(compiled)
         return result, compile_s, exec_s
-    with OT.span("compile+launch"):
-        result = fn(inputs)
-        jax.block_until_ready(result)
+    t0 = time.monotonic()
+    with OT.stage("compile", log=log, first_call=True):
+        jax.block_until_ready(fn(inputs))
     first_s = time.monotonic() - t0
-    t1 = time.monotonic()
-    with OT.span("launch", mode="instrumented"), \
-            OT.device_annotation("launch"):
-        result = fn(inputs)
-        jax.block_until_ready(result)
-    exec_s = time.monotonic() - t1
-    OM.observe_stage(log, "compile", max(first_s - exec_s, 0.0))
-    OM.observe_stage(log, "launch", exec_s)
+    result, exec_s = _launch(fn)
     return result, max(first_s - exec_s, 0.0), exec_s
 
 

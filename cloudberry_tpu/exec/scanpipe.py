@@ -206,46 +206,59 @@ class ScanPipeline:
 
     def _take(self, block: bool):
         from cloudberry_tpu.lifecycle import check_cancel
+        from cloudberry_tpu.obs import trace as OT
 
-        t0 = None
-        while True:
-            err = None
-            with self._cond:
-                if self._buf:
-                    item = self._buf.popleft()
-                    self._cond.notify_all()
-                    if t0 is not None:
-                        self.stall_s += time.perf_counter() - t0
-                    return item
-                if not block:
-                    # the double-buffer probe must NEVER raise: a
-                    # pending producer error belongs to the NEXT
-                    # blocking take, after the caller consumed the
-                    # tile it already popped
-                    return _EOS if (self._done and self._err is None) \
-                        else _EMPTY
-                if self._err is not None:
-                    # staged tiles drained first: the error surfaces at
-                    # the same stream position the synchronous feed
-                    # would have raised it
-                    err = self._err
-                elif self._done:
-                    return _EOS
-                else:
-                    if t0 is None:
-                        t0 = time.perf_counter()
-                    self._cond.wait(0.05)
-            if err is not None:
-                raise err
-            check_cancel()
+        wait = None  # the ``feed-wait`` stage, open while blocked
+        try:
+            while True:
+                err = None
+                with self._cond:
+                    if self._buf:
+                        item = self._buf.popleft()
+                        self._cond.notify_all()
+                        return item
+                    if not block:
+                        # the double-buffer probe must NEVER raise: a
+                        # pending producer error belongs to the NEXT
+                        # blocking take, after the caller consumed the
+                        # tile it already popped
+                        return _EOS if (self._done and self._err is None) \
+                            else _EMPTY
+                    if self._err is not None:
+                        # staged tiles drained first: the error surfaces
+                        # at the same stream position the synchronous
+                        # feed would have raised it
+                        err = self._err
+                    elif self._done:
+                        return _EOS
+                    elif wait is not None:
+                        self._cond.wait(0.05)
+                if err is not None:
+                    raise err
+                if wait is None:
+                    # first look found the queue empty: open the stage
+                    # (outside the leaf lock), then look again and wait
+                    wait = OT.stage("feed-wait", "launch_seconds")
+                    wait.__enter__()
+                check_cancel()
+        finally:
+            if wait is not None:
+                wait.__exit__(None, None, None)
+                # the run report's stall and the histogram: one reading
+                self.stall_s += wait.dur
 
     def _stage(self, item):
         if not self._device_stage:
             return item
         import jax
 
+        from cloudberry_tpu.obs import trace as OT
+
         tile, n = item
-        return ({k: jax.device_put(v) for k, v in tile.items()}, n)
+        with OT.stage("h2d", "launch_seconds", bytes=sum(
+                int(getattr(v, "nbytes", 0)) for v in tile.values()
+                if not isinstance(v, jax.Array))):
+            return ({k: jax.device_put(v) for k, v in tile.items()}, n)
 
     # ------------------------------------------------------------ teardown
 

@@ -605,21 +605,22 @@ class _TileTimer:
 
     def step(self, idx: int):
         import contextlib
-        import time as _t
 
         from cloudberry_tpu.obs import trace as OT
 
         @contextlib.contextmanager
         def _cm():
-            t0 = _t.perf_counter()
+            st = OT.stage("tile-step", "launch_seconds", log=self._log,
+                          tile=idx)
             try:
-                yield
+                with st:
+                    yield
             finally:
-                dt = _t.perf_counter() - t0
-                self._h.add(dt)
+                # the report's distribution and ``tile_seconds`` take
+                # the stage's own reading: one clock for all three
+                self._h.add(st.dur)
                 if self._log is not None and self._log.obs_enabled:
-                    self._log.registry.observe("tile_seconds", dt)
-                OT.mark("tile-step", t0, tile=idx)
+                    self._log.registry.observe("tile_seconds", st.dur)
 
         return _cm()
 
@@ -801,8 +802,8 @@ class SkewSentinel:
         if log is not None:
             log.bump("tile_replans")
         import time as _t
-        OT.mark("tile-replan", _t.perf_counter(),
-                tile=tiles_local, ratio=round(worst[1], 3))
+        OT.stage_since("tile-replan", _t.perf_counter(), None, None,
+                       tile=tiles_local, ratio=round(worst[1], 3))
         raise R.TileReplan(
             f"[tile {tiles_local}] cumulative redistribute skew "
             f"{worst[1]:.2f}x crossed the adaptive replan alarm "
@@ -1052,6 +1053,9 @@ class TiledExecutable(AdaptiveTiledMixin):
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, sel, low.checks
 
+        # a statement-level program set built: the engine's compile
+        # counter moves here as it does in compile_plan
+        X.count_compile(self.session)
         self._compiled = (jax.jit(prelude_fn),
                           jax.jit(step_fn, donate_argnums=TP.step_donation(
                               self._platform)),
@@ -1097,29 +1101,35 @@ class TiledExecutable(AdaptiveTiledMixin):
 
     def _run_once(self) -> ColumnBatch:
         from cloudberry_tpu.exec import recovery as R
+        from cloudberry_tpu.obs import trace as OT
 
-        prelude_fn, step_fn, finalize_fn = self._compile()
-        resident = self._resident_inputs()
-        prelude, pchecks = prelude_fn(resident)
-        X.raise_checks(pchecks)
+        # children of ``launch`` on the statement thread (obs/trace.py):
+        # prelude | (feed-wait | h2d | tile-step ⊃ drain-stall)* |
+        # finalize
+        with OT.stage("prelude", "launch_seconds"):
+            prelude_fn, step_fn, finalize_fn = self._compile()
+            resident = self._resident_inputs()
+            prelude, pchecks = prelude_fn(resident)
+            X.raise_checks(pchecks)
 
-        # mid-statement recovery (exec/recovery.py): resume from the last
-        # K-tile checkpoint instead of replaying the whole stream
-        ctx = R.begin(self, dist=False)
-        acc = self._init_acc()
-        if ctx is not None:
-            acc = ctx.restore_acc(acc)
-        skip = ctx.skip_rows if ctx is not None else 0
-        n_base = ctx.tiles_base if ctx is not None else 0
-        n_local = 0
-        n_sub = 0
-        timer = _TileTimer(self.session)
-        tracker = _progress_tracker(self, n_base, skip)
-        pipe = TP.TilePipe(self.session, TP.effective_window(
-            self.session.config, self._platform))
-        feed = _tile_feed(self.shape.stream, self.session,
-                          self.tile_rows, skip_rows=skip,
-                          min_depth=pipe.window)
+            # mid-statement recovery (exec/recovery.py): resume from the
+            # last K-tile checkpoint instead of replaying the whole
+            # stream
+            ctx = R.begin(self, dist=False)
+            acc = self._init_acc()
+            if ctx is not None:
+                acc = ctx.restore_acc(acc)
+            skip = ctx.skip_rows if ctx is not None else 0
+            n_base = ctx.tiles_base if ctx is not None else 0
+            n_local = 0
+            n_sub = 0
+            timer = _TileTimer(self.session)
+            tracker = _progress_tracker(self, n_base, skip)
+            pipe = TP.TilePipe(self.session, TP.effective_window(
+                self.session.config, self._platform))
+            feed = _tile_feed(self.shape.stream, self.session,
+                              self.tile_rows, skip_rows=skip,
+                              min_depth=pipe.window)
 
         def _verified(d):
             # host effects for ONE drained-clean tile, in stream order
@@ -1163,28 +1173,29 @@ class TiledExecutable(AdaptiveTiledMixin):
             if pipe.deferred_fail:
                 self._deferred_fail = True
             SP.close_feed(feed)
-        SP.stamp_report(self.report, feed)
-        n_tiles = n_base + n_local
-        timer.stamp(self.report)
-        pipe.stamp(self.report)
-        if n_tiles == 0:  # empty stream: one all-masked tile seeds the acc
-            empty = _empty_tile(self.shape.stream, self.tile_rows)
-            acc, checks = step_fn(resident, prelude, empty,
-                                  jnp.asarray(0, dtype=jnp.int32), acc)
-            _raise_tile_checks(checks, 0)
-            n_tiles = 1
+        with OT.stage("finalize", "launch_seconds"):
+            SP.stamp_report(self.report, feed)
+            n_tiles = n_base + n_local
+            timer.stamp(self.report)
+            pipe.stamp(self.report)
+            if n_tiles == 0:  # empty stream: an all-masked tile seeds acc
+                empty = _empty_tile(self.shape.stream, self.tile_rows)
+                acc, checks = step_fn(resident, prelude, empty,
+                                      jnp.asarray(0, dtype=jnp.int32), acc)
+                _raise_tile_checks(checks, 0)
+                n_tiles = 1
 
-        fault_point("tiled_finalize")
-        from cloudberry_tpu.lifecycle import check_cancel
+            fault_point("tiled_finalize")
+            from cloudberry_tpu.lifecycle import check_cancel
 
-        check_cancel()
-        cols, sel, fchecks = finalize_fn(acc)
-        X.raise_checks(fchecks)
-        self.report["n_tiles"] = n_tiles
-        if ctx is not None:
-            ctx.stamp_report(self.report)
-        self._publish_report()
-        return X.make_batch(self.shape.root, cols, sel)
+            check_cancel()
+            cols, sel, fchecks = finalize_fn(acc)
+            X.raise_checks(fchecks)
+            self.report["n_tiles"] = n_tiles
+            if ctx is not None:
+                ctx.stamp_report(self.report)
+            self._publish_report()
+            return X.make_batch(self.shape.root, cols, sel)
 
 
 class TopNTiledExecutable(TiledExecutable):
@@ -1252,6 +1263,9 @@ class TopNTiledExecutable(TiledExecutable):
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, sel, low.checks
 
+        # a statement-level program set built: the engine's compile
+        # counter moves here as it does in compile_plan
+        X.count_compile(self.session)
         self._compiled = (jax.jit(prelude_fn),
                           jax.jit(step_fn, donate_argnums=TP.step_donation(
                               self._platform)),
@@ -1328,6 +1342,9 @@ class SortTiledExecutable(TiledExecutable):
             out = {nm: X._as_column(pcols[nm], n) for nm in names}
             return (out, psel, keys), low.checks
 
+        # a statement-level program set built: the engine's compile
+        # counter moves here as it does in compile_plan
+        X.count_compile(self.session)
         self._compiled = (jax.jit(prelude_fn), jax.jit(step_fn))
         return self._compiled
 
@@ -1598,9 +1615,14 @@ def _tile_feed(scan: N.PScan, session, tile_rows: int,
     mid-statement resume entry point (exec/recovery.py): single-node
     consumption is always a prefix of the deterministic stream order.
     Callers must close the feed (scanpipe.close_feed) on every exit."""
+    from cloudberry_tpu.obs import trace as OT
+
     stats = SP.ScanStats()
     if hasattr(scan, "_store_parts"):
-        gen = _store_tiles(scan, session, tile_rows, skip_rows, stats)
+        # the reader thread's part-read spans name the stage that
+        # started this feed as their parent
+        gen = _store_tiles(scan, session, tile_rows, skip_rows, stats,
+                           parent=OT.current_stage())
     else:
         gen = _ram_tiles(scan, session, tile_rows, skip_rows)
     # min_depth: the dispatch window (exec/tilepipe.py) keeps up to W
@@ -1736,7 +1758,7 @@ def _pool_chunk(scan: N.PScan, ent: dict) -> dict:
 
 
 def _store_tiles(scan: N.PScan, session, tile_rows: int,
-                 skip_rows: int = 0, stats=None):
+                 skip_rows: int = 0, stats=None, parent=None):
     """Stream a pruned cold scan part-by-part, re-chunked to tile_rows:
     the out-of-core path — peak host memory is one partition + the
     pipeline's bounded staging. A resume's ``skip_rows`` drops whole
@@ -1746,8 +1768,6 @@ def _store_tiles(scan: N.PScan, session, tile_rows: int,
     the HBM buffer pool (exec/bufferpool.py) are served from the device
     copy — no read, no decode, no host→device transfer; only misses go
     to the store (and hot misses are admitted for next time)."""
-    import time as _t
-
     store = session.catalog.store
     needed = _phys_cols(scan)
     stats = stats if stats is not None else SP.ScanStats()
@@ -1779,27 +1799,39 @@ def _store_tiles(scan: N.PScan, session, tile_rows: int,
             take = min(tile_rows, buf.rows)
             yield _pad_tile(buf.take(take), 0, take, tile_rows), take
 
+    from cloudberry_tpu.obs import trace as OT
+
     for part in parts[start:]:
-        key = None
-        if bpool is not None:
-            key = BUF.partition_key(session, scan.table_name, part,
-                                    cols_key)
-            ent = bpool.lookup(key, log)
-            if ent is not None:
-                # HBM hit: the decoded chunk is already on-device —
-                # the host path (read/decode/transfer) is skipped
-                # entirely, like the resume parts_skipped fast path
-                stats.parts_resident += 1
-                buf.append(_pool_chunk(scan, ent))
-                yield from drain(final=False)
-                continue
-        fault_point("scan_decode")
-        dts: list = []  # per-column decode seconds (list.append: atomic)
-        t0 = _t.perf_counter()
-        cols, validity = store.read_partitions(
-            scan.table_name, [part], needed, pool=pool,
-            on_decode=dts.append)
-        stats.read_s += _t.perf_counter() - t0
+        key = ent = None
+        # one partition, on whichever thread runs this generator (the
+        # scan reader when pipelined): the pool's answer, else the wall
+        # of read + checksum + column-parallel decode
+        with OT.stage("part-read", "feed_seconds", log=log, parent=parent,
+                      table=scan.table_name, partition=part["file"],
+                      pool="hit") as st:
+            if bpool is not None:
+                key = BUF.partition_key(session, scan.table_name, part,
+                                        cols_key)
+                ent = bpool.lookup(key, log)
+            if ent is None:
+                st.args["pool"] = "miss"
+                fault_point("scan_decode")
+                dts: list = []  # per-column decode seconds (atomic append)
+                cols, validity = store.read_partitions(
+                    scan.table_name, [part], needed, pool=pool,
+                    on_decode=dts.append)
+                st.args["bytes"] = sum(
+                    int(np.asarray(v).nbytes)
+                    for d in (cols, validity) for v in d.values())
+        if ent is not None:
+            # HBM hit: the decoded chunk is already on-device — the
+            # host path (read/decode/transfer) is skipped entirely,
+            # like the resume parts_skipped fast path
+            stats.parts_resident += 1
+            buf.append(_pool_chunk(scan, ent))
+            yield from drain(final=False)
+            continue
+        stats.read_s += st.dur  # report and histogram: one reading
         stats.parts_read += 1
         stats.decode_s += sum(dts)
         if log is not None:

@@ -664,6 +664,9 @@ class DistTiledExecutable(AdaptiveTiledMixin):
             finalize_seg, mesh, (P(SEG_AXIS),),
             (P(SEG_AXIS), P(SEG_AXIS), P())))
 
+        # a statement-level program set built: the engine's compile
+        # counter moves here as it does in compile_plan
+        X.count_compile(self.session)
         self._compiled = (prelude_fn, step_fn, finalize_fn)
         return self._compiled
 
@@ -1052,6 +1055,9 @@ class DistSortTiledExecutable(DistTiledExecutable):
             step_seg, mesh,
             (res_specs, P(SEG_AXIS), P(SEG_AXIS), P(SEG_AXIS)),
             (P(SEG_AXIS), P())))
+        # a statement-level program set built: the engine's compile
+        # counter moves here as it does in compile_plan
+        X.count_compile(self.session)
         self._compiled = (prelude_fn, step_fn)
         return self._compiled
 

@@ -62,7 +62,6 @@ estimate (``window_charge_bytes`` → est_pipeline_bytes).
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import NamedTuple
 
@@ -211,18 +210,21 @@ class TilePipe:
         adaptive retry replays from the last drained checkpoint."""
         from cloudberry_tpu.exec.tiled import _raise_tile_checks
 
+        from cloudberry_tpu.obs import trace as OT
+
         entry = self._q.popleft()
         fault_point("tile_drain")
-        t0 = time.perf_counter()
         try:
-            _raise_tile_checks(entry.checks, entry.idx)
+            with OT.stage("drain-stall", "launch_seconds", log=self._log,
+                          tile=entry.idx) as st:
+                _raise_tile_checks(entry.checks, entry.idx)
         except Exception:
             if self._q:
                 self.deferred_fail = True
                 if self._log is not None:
                     self._log.bump("tile_deferred_overflows")
             raise
-        self.drain_stall_s += time.perf_counter() - t0
+        self.drain_stall_s += st.dur  # report and histogram: one reading
         self.drained += 1
         return Drained(entry.idx, entry.payload)
 

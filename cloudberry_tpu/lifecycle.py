@@ -211,6 +211,9 @@ class StatementHandle:
         # threads exactly like cancellation does (obs.trace reads it via
         # current_handle())
         self.trace = None
+        # the engine's StatementLog, set by its attach(): where
+        # obs.trace.stage finds the metrics registry
+        self.log = None
         # the statement's live progress gauge (obs/progress.py), set by
         # whoever begins the statement when the telemetry plane is on;
         # the tiled executors' tile loops feed it through the same
@@ -255,6 +258,10 @@ class CompositeHandle:
         self.trace = next((h.trace for h in self.handles
                            if getattr(h, "trace", None) is not None),
                           None)
+        self.statement_id = next((h.statement_id for h in self.handles),
+                                 None)
+        self.log = next((h.log for h in self.handles
+                         if getattr(h, "log", None) is not None), None)
         # batched statements are stacked point reads — no tile loop, so
         # the composite scope carries no progress feed of its own (each
         # member's Progress still completes at its finish)

@@ -10,10 +10,10 @@ one instance of each, so a server's backends write one telemetry plane;
 snapshots over the wire."""
 
 from cloudberry_tpu.obs.metrics import (CounterView,  # noqa: F401
-                                        MetricsRegistry, observe_stage)
+                                        MetricsRegistry)
 from cloudberry_tpu.obs.progress import (Progress,  # noqa: F401
                                          current_progress)
 from cloudberry_tpu.obs.statements import StatementStats  # noqa: F401
-from cloudberry_tpu.obs.trace import (Trace, chrome_trace,  # noqa: F401
-                                      current_trace, device_annotation,
-                                      mark, span)
+from cloudberry_tpu.obs.trace import (Request, Trace,  # noqa: F401
+                                      chrome_trace, current_trace, stage,
+                                      stage_since)

@@ -34,13 +34,6 @@ _HIST_BUCKETS = 48
 _HIST_UNIT = 1e-6
 
 
-def _bucket_of(value: float) -> int:
-    v = int(value / _HIST_UNIT)
-    if v <= 0:
-        return 0
-    return min(v.bit_length(), _HIST_BUCKETS - 1)
-
-
 def bucket_upper(i: int) -> float:
     """Upper bound of bucket ``i`` in base units (seconds/bytes)."""
     return (1 << i) * _HIST_UNIT
@@ -55,7 +48,10 @@ class _Hist:
         self.total = 0.0
 
     def add(self, value: float) -> None:
-        self.counts[_bucket_of(value)] += 1
+        # _bucket_of, inlined: every stage of every statement lands here
+        v = int(value * 1e6)
+        self.counts[min(v.bit_length(), _HIST_BUCKETS - 1)
+                    if v > 0 else 0] += 1
         self.n += 1
         self.total += value
 
@@ -272,14 +268,3 @@ class CounterView:
 
     def values(self):
         return self._reg.counter_snapshot().values()
-
-
-def observe_stage(log, stage: str, dt: float,
-                  tenant: str | None = None) -> None:
-    """One per-stage latency sample (``stage_seconds.<stage>``) on the
-    engine registry — the serve_bench time-share columns read these.
-    ``log`` is a StatementLog (or None); a disabled obs config
-    (log.obs_enabled False) makes this a no-op."""
-    if log is None or not getattr(log, "obs_enabled", False):
-        return
-    log.registry.observe(f"stage_seconds.{stage}", dt, tenant=tenant)

@@ -208,8 +208,8 @@ def run_with_retry(fn: Callable, retries: int = 1,
             # the recovery attempt + its backoff are spans on the
             # statement's trace: a recovery storm reads as exactly that
             # in the exported timeline, not as unexplained dead time
-            with OT.span("recovery-backoff", attempt=attempt + 1,
-                         error=type(e).__name__):
+            with OT.stage("recovery-backoff", None, attempt=attempt + 1,
+                          error=type(e).__name__):
                 if token is not None:
                     rem = h.remaining()
                     if rem is not None:

@@ -434,9 +434,7 @@ class Dispatcher:
             from cloudberry_tpu.parallel.topology import topology_token
 
             topo_tok = topology_token(self.session)
-            now = time.perf_counter()
-            from cloudberry_tpu.obs import metrics as OM
-
+            from cloudberry_tpu.obs import trace as OT
             from cloudberry_tpu.obs.progress import Progress
 
             for sid, h, r in zip(sids, handles, group):
@@ -450,13 +448,13 @@ class Dispatcher:
                 h.trace = log.start_trace(sid, r.sql)
                 if log.obs_enabled:
                     h.progress = Progress()
-                if h.trace is not None:
-                    # ends exactly at the trace's root start, so the
-                    # wait renders as the root's sibling, never a
-                    # partial overlap
-                    h.trace.add("dispatch-queue-wait", r.t_enq,
-                                max(h.trace.t0 - r.t_enq, 0.0))
-                OM.observe_stage(log, "queue_wait", now - r.t_enq)
+                # ends exactly at the trace's root start, so the wait
+                # renders as the root's sibling, never a partial overlap
+                OT.stage_since(
+                    "dispatch-queue-wait", r.t_enq,
+                    h.trace.t0 if h.trace is not None else None,
+                    hist="stage_seconds.queue_wait", log=log,
+                    trace=h.trace, statement_id=sid)
             c0 = log.counter("compiles")
             g0 = log.counter("generic_hits")
             try:
@@ -549,7 +547,7 @@ class Dispatcher:
 
     def _run_sequential(self, group: list[_Request]) -> None:
         """Ordinary dispatch, one statement at a time."""
-        from cloudberry_tpu.obs import metrics as OM
+        from cloudberry_tpu.obs import trace as OT
 
         for r in group:
             if time.monotonic() > r.deadline:
@@ -558,8 +556,9 @@ class Dispatcher:
                     "deadline expired before dispatch"))
                 continue
             self._bump("singles")
-            OM.observe_stage(self.session.stmt_log, "queue_wait",
-                             time.perf_counter() - r.t_enq)
+            OT.stage_since("dispatch-queue-wait", r.t_enq,
+                           hist="stage_seconds.queue_wait",
+                           log=self.session.stmt_log)
             try:
                 with self._exec_scope():
                     # the request's deadline governs EXECUTION too (the

@@ -591,26 +591,19 @@ class GenericPlan:
     def run(self, session, planB, keyedB, bindings):
         """Execute the cached program with one rebind's values — never
         compiles."""
-        import time as _t
-
         from cloudberry_tpu.exec import executor as X
         from cloudberry_tpu.obs import trace as OT
 
         session.stmt_log.bump("param_binds")
-        # the rebind gets a SPAN only — the launch STAGE histogram
-        # (recorded by the session around the whole runner) already
-        # contains this host work, and the serve_bench time shares must
-        # partition wall time, not count the bind twice
-        t_bind = _t.perf_counter()
         if self.kind == "dist":
             from cloudberry_tpu.exec import dist_executor as DX
 
-            inputs, _ = DX.prepare_dist_inputs(planB, session)
-            if bindings:
-                inputs["$params"] = dict(bindings)
-            OT.mark("param-bind", t_bind)
-            with OT.span("launch", mode="dist-generic"), \
-                    OT.device_annotation("launch-dist"):
+            with OT.stage("inputs", "launch_seconds", host=True):
+                inputs, _ = DX.prepare_dist_inputs(planB, session)
+                if bindings:
+                    inputs["$params"] = dict(bindings)
+            with OT.stage("dispatch", "launch_seconds",
+                          mode="dist-generic"):
                 cols, sel, checks, stats = self.fn(inputs)
             # the stats keys embed the TRACED plan's node ids — pin the
             # observed bucket demand there, then copy onto the rebind's
@@ -629,8 +622,8 @@ class GenericPlan:
             fold_plan(session, self.plan)
             host_cols = {k: DX._local_row(v) for k, v in cols.items()}
             return X.make_batch(self.plan, host_cols, DX._local_row(sel))
-        inputs = self.bind_inputs(session, planB, keyedB, bindings)
-        OT.mark("param-bind", t_bind)
+        with OT.stage("inputs", "launch_seconds", host=True):
+            inputs = self.bind_inputs(session, planB, keyedB, bindings)
         return X.run_executable(self.exe, inputs)
 
     # ----------------------------------------------------- stacked launch
@@ -782,17 +775,11 @@ def lookup_or_build(session, query: str, plan) -> Optional[Prep]:
     # literals from $params (slot order identical by the walker contract)
     sig2, bindings2, keyed2, slots2 = analyze(session, plan, rewrite=True)
     assert sig2 == sig and list(bindings2) == list(bindings)
-    import time as _time
-
-    from cloudberry_tpu.obs import metrics as OM
     from cloudberry_tpu.obs import trace as OT
 
-    t_build = _time.perf_counter()
-    with OT.span("compile", skeleton=skeleton[:80]):
+    with OT.stage("compile", skeleton=skeleton[:80]):
         gp = GenericPlan(session, skeleton, plan, names, sig, bindings2,
                          keyed2, slots2)
-    OM.observe_stage(session.stmt_log, "compile",
-                     _time.perf_counter() - t_build)
     gp.fast = _try_fast(session, gp, plan, tok_params, bindings2, keyed2,
                         slots2)
     session.stmt_log.bump("generic_builds")
@@ -923,8 +910,11 @@ def run_batch(session, sqls: list[str]):
 
             check_cancel()
             session.stmt_log.bump("dispatches")
-            cols, sel, checks = fn(stacked)
-            X.raise_checks(checks)
+            from cloudberry_tpu.obs import trace as OT
+
+            with OT.stage("launch", mode="stacked", rung=rung):
+                cols, sel, checks = fn(stacked)
+                X.raise_checks(checks)
     except (ResourceError, X.ExecError):
         # vmapped checks OR across lanes: ONE request's runtime check
         # (subquery cardinality, expansion overflow, ...) must not error

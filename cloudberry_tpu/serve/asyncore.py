@@ -39,6 +39,7 @@ import queue
 import selectors
 import socket
 import threading
+import time
 from collections import deque
 from typing import Optional
 
@@ -89,7 +90,7 @@ class _Conn:
     __slots__ = ("sock", "addr", "loop", "rbuf", "wbuf", "lock",
                  "pending", "busy", "authed", "session",
                  "close_after_flush", "closed", "paused", "ended",
-                 "registered", "scanned")
+                 "registered", "scanned", "flushing")
 
     def __init__(self, sock, addr, loop):
         self.sock = sock
@@ -108,6 +109,9 @@ class _Conn:
         self.ended = False
         self.registered = False
         self.scanned = 0  # rbuf prefix already searched for newlines
+        # requests (obs.trace.Request) whose answers sit in wbuf: the
+        # loop closes them when the last byte is sent (under ``lock``)
+        self.flushing: list = []
 
 
 class _IOLoop:
@@ -286,8 +290,10 @@ class _IOLoop:
             del conn.rbuf[:i + 1]
             conn.scanned = 0
             if line:
+                # stamped on arrival: the request's clock starts here,
+                # not when a worker picks the line up
                 with conn.lock:
-                    conn.pending.append(line)
+                    conn.pending.append((line, time.perf_counter()))
                 new = True
         with conn.lock:
             backlog = len(conn.pending)
@@ -328,6 +334,11 @@ class _IOLoop:
                     break
                 del conn.wbuf[:n]
             empty = not conn.wbuf
+            done = conn.flushing if empty or err else ()
+            if done:
+                conn.flushing = []
+        for rq in done:
+            rq.flushed()
         if err:
             self.close_conn(conn)
             return
@@ -460,28 +471,33 @@ class AsyncFrontEnd:
             if conn.busy or conn.closed or conn.close_after_flush \
                     or not conn.pending:
                 return
-            line = conn.pending.popleft()
+            line, t_recv = conn.pending.popleft()
             conn.busy = True
-        self._pool.submit(self._work, conn, line)
+        self._pool.submit(self._work, conn, line, t_recv)
 
-    def _work(self, conn: _Conn, line: bytes) -> None:
+    def _work(self, conn: _Conn, line: bytes, t_recv: float) -> None:
+        from cloudberry_tpu.obs.trace import Request
+
         srv = self.server
         # in-flight window covers compute AND response enqueue: drain
         # waits until every accepted request has its answer queued
         srv._request_begin()
+        rq = Request(srv.session.stmt_log, t_recv)
         try:
-            if conn.session is None:
-                # lazy backend creation: accept stays cheap; the first
-                # request pays the (store-mode) catalog registration
-                conn.session = srv._connection_session()
-            resp, conn.authed = srv._process_line(
-                line, conn.session, conn.authed, conn.addr,
-                async_cb=lambda r: self._complete(conn, r))
+            with rq:
+                if conn.session is None:
+                    # lazy backend creation: accept stays cheap; the
+                    # first request pays the (store-mode) catalog
+                    # registration
+                    conn.session = srv._connection_session()
+                resp, conn.authed = srv._process_line(
+                    line, conn.session, conn.authed, conn.addr,
+                    async_cb=lambda r: self._complete(conn, r, rq))
         except Exception as e:
             resp = srv._error_resp(e)
         if resp is None:
             return  # async completion owns the response AND _request_end
-        self._complete(conn, resp)
+        self._complete(conn, resp, rq)
 
     def _complete_oversized(self, conn: _Conn) -> None:
         """Refuse a request line past serve.max_line_bytes: write one
@@ -496,19 +512,34 @@ class AsyncFrontEnd:
             conn.close_after_flush = True
         conn.loop.enable_write(conn)
 
-    def _complete(self, conn: _Conn, resp: dict) -> None:
+    def _complete(self, conn: _Conn, resp: dict, rq=None) -> None:
         """Queue one response's bytes, release the in-flight window, and
         pump the next pipelined request. Runs on worker threads and on
-        the dispatcher worker (async completions)."""
-        try:
-            data = json.dumps(resp).encode() + b"\n"
-        except (TypeError, ValueError) as e:
-            data = json.dumps(self.server._error_resp(e)).encode() + b"\n"
-        with conn.lock:
-            conn.wbuf += data
-            if resp.get("fatal"):
-                conn.close_after_flush = True
+        the dispatcher worker (async completions). ``rq`` is the wire
+        request (obs.trace.Request): ``wire-out`` is this thread's part
+        (serialize, queue, wake the loop); the loop thread records
+        ``wire-flush`` and closes the request when the bytes are sent."""
+        from cloudberry_tpu.obs import trace as OT
+
         lp = conn.loop
+        with OT.stage("wire-out", host=True, request=rq):
+            try:
+                data = json.dumps(resp).encode() + b"\n"
+            except (TypeError, ValueError) as e:
+                data = json.dumps(
+                    self.server._error_resp(e)).encode() + b"\n"
+            with conn.lock:
+                conn.wbuf += data
+                if resp.get("fatal"):
+                    conn.close_after_flush = True
+        if rq is not None:
+            rq.queued()
+            with conn.lock:
+                gone = conn.closed
+                if not gone:
+                    conn.flushing.append(rq)
+            if gone:
+                rq.finish()  # nobody is left to flush to
         lp.call(lambda c=conn: lp.enable_write(c))
         self.server._request_end()
         with conn.lock:

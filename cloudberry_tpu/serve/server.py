@@ -294,11 +294,19 @@ class Server:
         transport-independent request core. ``None`` means an async
         completion owns the response (``async_cb`` will fire exactly
         once with it — event-loop transport only)."""
+        from cloudberry_tpu.obs import trace as OT
+
+        wire = OT.current_request()
         try:
-            req = json.loads(line)
-            if not authed:
-                resp, authed = self._authenticate(req, addr)
-            else:
+            # wire-in: the line's arrival (the transport stamped it on
+            # the request) to parsed and authenticated
+            with OT.stage("wire-in", host=True, bytes=len(line),
+                          since=wire.t0 if wire is not None else None):
+                req = json.loads(line)
+                resp = None
+                if not authed:
+                    resp, authed = self._authenticate(req, addr)
+            if resp is None:
                 resp = self._execute(req, sess, async_cb=async_cb)
         except Exception as e:
             # bad client/statement must not kill the connection handler
@@ -725,15 +733,12 @@ class Server:
         (ISSUE 9): render time feeds the stage histogram and the
         response's estimated wire bytes feed the per-skeleton
         statements table (obs/statements.py)."""
-        import time as _t
+        from cloudberry_tpu.obs import trace as OT
 
-        t0 = _t.perf_counter()
-        resp = self._render(result)
         log = self.session.stmt_log
+        with OT.stage("render", host=True, log=log):
+            resp = self._render(result)
         if log.obs_enabled:
-            from cloudberry_tpu.obs.metrics import observe_stage
-
-            observe_stage(log, "render", _t.perf_counter() - t0)
             log.statements.add_wire(sql, _resp_bytes(resp))
             # tenant-labeled served counter: the registry's per-tenant
             # attribution (obs/metrics.py bump tenant=) without a new
@@ -780,6 +785,7 @@ class _ThreadedTransport:
 
         class Handler(socketserver.StreamRequestHandler):
             def handle(self):
+                from cloudberry_tpu.obs import trace as OT
                 from cloudberry_tpu.utils.faultinject import fault_point
 
                 fault_point("serve_handler")
@@ -799,11 +805,16 @@ class _ThreadedTransport:
                         # has its answer on the wire
                         outer._request_begin()
                         try:
-                            resp, authed = outer._process_line(
-                                line, sess, authed, addr)
-                            self.wfile.write(
-                                json.dumps(resp).encode() + b"\n")
-                            self.wfile.flush()
+                            # one thread serves the whole request: the
+                            # root span closes when the answer is flushed
+                            with OT.Request(outer.session.stmt_log) as rq:
+                                resp, authed = outer._process_line(
+                                    line, sess, authed, addr)
+                                with OT.stage("wire-out", host=True):
+                                    self.wfile.write(
+                                        json.dumps(resp).encode() + b"\n")
+                                    self.wfile.flush()
+                                rq.finish()
                         finally:
                             outer._request_end()
                         if resp.get("fatal"):

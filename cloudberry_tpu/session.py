@@ -269,6 +269,11 @@ class Session:
 
             handle.progress = Progress()
         self.stmt_log.attach(log_id, handle)
+        # a served statement joins its wire request (obs/trace.py): the
+        # request's spans land on this trace, under this statement id
+        from cloudberry_tpu.obs import trace as OT
+
+        OT.adopt_statement(handle)
         t_begin = _t.monotonic()
         is_read = _read_only(query)
         # device-loss recoveries THIS statement needed — the circuit
@@ -593,149 +598,145 @@ class Session:
         return query + "\x00" + repr(sorted(params.items()))
 
     def _sql_once(self, query: str, **params: Any):
-        import time as _t
-
         from cloudberry_tpu.exec.resource import check_admission
         from cloudberry_tpu.obs import trace as OT
         from cloudberry_tpu.plan.planner import plan_statement
         from cloudberry_tpu.sql.parser import parse_sql
         from cloudberry_tpu.utils.faultinject import fault_point
 
-        self._sync_store()
-        self.last_tiled_report = None  # set again by a tiled runner
-        ckey = self._stmt_cache_key(query, params)
-        cached = self._cached_statement(ckey)
+        with OT.stage("bind", host=True, lookup=True):
+            self._sync_store()
+            self.last_tiled_report = None  # set again by a tiled runner
+            ckey = self._stmt_cache_key(query, params)
+            cached = self._cached_statement(ckey)
         if cached is not None:
             runner, cost, obs_bytes = cached
-            self.stmt_log.bump("stmt_cache_hits")
-            self.stmt_log.bump("dispatches")
-            # capacity plane (obs/capacity.py): the cached DEVICE-BYTE
-            # estimate — one histogram sample, no plan walk on the hot
-            # path. Kept separate from the admission cost: a tiled
-            # runner admits against the whole per-query budget but its
-            # measured working set is the step estimate, and feeding
-            # the budget constant here would pin the peak gauge at
-            # config forever
-            from cloudberry_tpu.obs import capacity as OC
+            with OT.stage("admit", host=True):
+                self.stmt_log.bump("stmt_cache_hits")
+                self.stmt_log.bump("dispatches")
+                # capacity plane (obs/capacity.py): the cached
+                # DEVICE-BYTE estimate — one histogram sample, no plan
+                # walk on the hot path. Kept separate from the admission
+                # cost: a tiled runner admits against the whole
+                # per-query budget but its measured working set is the
+                # step estimate, and feeding the budget constant here
+                # would pin the peak gauge at config forever
+                from cloudberry_tpu.obs import capacity as OC
 
-            OC.observe_stmt_bytes(self.stmt_log, obs_bytes)
-            self._dispatch_seams(fault_point)
-            t_wait = _t.perf_counter()
-            with self._gate, self._admitted(cost):
-                # the admission wait is the direct path's queue-wait:
-                # span from requesting the slot to holding it
-                self._obs_wait(t_wait)
+                OC.observe_stmt_bytes(self.stmt_log, obs_bytes)
+                self._dispatch_seams(fault_point)
+            with self._slot(cost):
                 return self._obs_launch(runner)
 
-        from cloudberry_tpu.obs import metrics as OM
-
-        t0 = _t.perf_counter()
-        with OT.span("parse"):
+        with OT.stage("parse", host=True):
             stmt = parse_sql(query)
-        t1 = _t.perf_counter()
-        OM.observe_stage(self.stmt_log, "parse", t1 - t0)
         # the config this statement PLANS under: a topology cutover
         # swapping it before execute/cache makes the plan's baked
         # capacities stale — the executors below refuse with the
         # retryable TopologyRaceError instead of tracing (or caching) a
         # mixed-shape program (parallel/topology.py)
         cfg_plan = self.config
-        with OT.span("plan"):
+        with OT.stage("plan", host=True):
             result = plan_statement(stmt, self, params)
-        OM.observe_stage(self.stmt_log, "plan", _t.perf_counter() - t1)
         if result.is_ddl:
             return result.ddl_result
-        # the planck gate (config.debug.verify_plans): every plan the
-        # planner or memo emitted is verified against the derived-vs-
-        # required property rules RIGHT BEFORE compile — a finding is a
-        # refusal, not a silently wrong answer at 8 segments
-        self._verify_plan(result.plan, "session")
-        # admission control: memory budget check + queue slot + vmem
-        # reservation (vmem-tracker / resqueue analogs, exec/resource.py);
-        # an over-budget plan falls back to tiled out-of-core execution
-        # (the workfile manager / spill analog, exec/tiled.py) first
         from cloudberry_tpu.exec.resource import ResourceError
-
-        try:
-            est = check_admission(result.plan, self)
-        except ResourceError:
-            from cloudberry_tpu.exec.tiled import plan_tiled
-
-            texe = plan_tiled(result.plan, self)
-            if texe is None and self.config.planner.enable_memo:
-                # the memo's joint order may have put a big relation on
-                # a BUILD side (cheap in memory, spill-hostile: tiling
-                # streams the probe path only). Re-plan greedy — the
-                # fact side stays the stream — and tile that instead;
-                # the reference likewise re-plans when a hash join
-                # flips to batches (nodeHash.c increase-nbatch)
-                # a shallow session clone carries the greedy config so
-                # concurrent planners (and the mesh-resize path, which
-                # also assigns self.config) never observe the override
-                import copy
-
-                clone = copy.copy(self)
-                clone.config = self.config.with_overrides(
-                    **{"planner.enable_memo": False})
-                result2 = plan_statement(stmt, clone, params)
-                self._verify_plan(result2.plan, "greedy-replan")
-                texe = plan_tiled(result2.plan, clone)
-                if texe is not None:
-                    # the clone only existed to plan greedy: runs must
-                    # report (last_tiled_report) to the REAL session
-                    texe.session = self
-            if texe is None:
-                raise
-            from cloudberry_tpu.obs import capacity as OC
-
-            # a cached executable's report predates the pool's current
-            # residency — re-stamp before charging the capacity plane
-            texe.refresh_bufpool_charge()
-            OC.record_tiled(self.stmt_log, texe.report)
-            self.stmt_log.bump("dispatches")
-            self._dispatch_seams(fault_point)
-            t_wait = _t.perf_counter()
-            with self._gate, self._admitted(
-                    self.config.resource.query_mem_bytes):
-                self._obs_wait(t_wait)
-                return self._run_cached_tiled(ckey, texe, cfg_plan)
         from cloudberry_tpu.obs import capacity as OC
 
-        # capacity plane: itemized device-byte estimate (intermediates
-        # + wire buffers + rung capacities) for every fresh plan
-        OC.record_statement(self.stmt_log, result.plan, self, est=est)
-        self.stmt_log.bump("dispatches")
-        self._dispatch_seams(fault_point)
-        t_wait = _t.perf_counter()
-        with self._gate, self._admitted(est.peak_bytes) as sid:
-            self._obs_wait(t_wait)
+        texe = None
+        with OT.stage("admit", host=True):
+            # the planck gate (config.debug.verify_plans): every plan
+            # the planner or memo emitted is verified against the
+            # derived-vs-required property rules RIGHT BEFORE compile —
+            # a finding is a refusal, not a silently wrong answer at 8
+            # segments
+            self._verify_plan(result.plan, "session")
+            # admission control: memory budget check + queue slot + vmem
+            # reservation (vmem-tracker / resqueue analogs,
+            # exec/resource.py); an over-budget plan falls back to tiled
+            # out-of-core execution (the workfile manager / spill
+            # analog, exec/tiled.py) first
+            try:
+                est = check_admission(result.plan, self)
+            except ResourceError:
+                texe = self._plan_tiled_fallback(stmt, params, result.plan)
+                if texe is None:
+                    raise
+                # a cached executable's report predates the pool's
+                # current residency — re-stamp before charging the
+                # capacity plane
+                texe.refresh_bufpool_charge()
+                OC.record_tiled(self.stmt_log, texe.report)
+            else:
+                # capacity plane: itemized device-byte estimate
+                # (intermediates + wire buffers + rung capacities) for
+                # every fresh plan
+                OC.record_statement(self.stmt_log, result.plan, self,
+                                    est=est)
+            self.stmt_log.bump("dispatches")
+            self._dispatch_seams(fault_point)
+        if texe is not None:
+            with self._slot(self.config.resource.query_mem_bytes):
+                return self._run_cached_tiled(ckey, texe, cfg_plan)
+        with self._slot(est.peak_bytes) as sid:
             return self._run_with_growth(ckey, query, result.plan, sid,
                                          cfg_plan)
 
-    def _obs_wait(self, t0: float) -> None:
-        """Record the admission/queue wait that just ended (span +
-        stage histogram) — called immediately after entering the gate."""
-        import time as _t
+    def _plan_tiled_fallback(self, stmt, params, plan):
+        """The tiled executable for an over-budget plan, or None."""
+        from cloudberry_tpu.exec.tiled import plan_tiled
+        from cloudberry_tpu.plan.planner import plan_statement
 
-        from cloudberry_tpu.obs import metrics as OM
+        texe = plan_tiled(plan, self)
+        if texe is None and self.config.planner.enable_memo:
+            # the memo's joint order may have put a big relation on a
+            # BUILD side (cheap in memory, spill-hostile: tiling streams
+            # the probe path only). Re-plan greedy — the fact side stays
+            # the stream — and tile that instead; the reference likewise
+            # re-plans when a hash join flips to batches (nodeHash.c
+            # increase-nbatch). A shallow session clone carries the
+            # greedy config so concurrent planners (and the mesh-resize
+            # path, which also assigns self.config) never observe the
+            # override
+            import copy
+
+            clone = copy.copy(self)
+            clone.config = self.config.with_overrides(
+                **{"planner.enable_memo": False})
+            result2 = plan_statement(stmt, clone, params)
+            self._verify_plan(result2.plan, "greedy-replan")
+            texe = plan_tiled(result2.plan, clone)
+            if texe is not None:
+                # the clone only existed to plan greedy: runs must
+                # report (last_tiled_report) to the REAL session
+                texe.session = self
+        return texe
+
+    def _slot(self, cost: int):
+        """The admission gate + queue slot for one statement, entered
+        under the ``queue-wait`` stage: from requesting the slot to
+        holding it. Yields the statement id ``_admitted`` yields."""
+        import contextlib
+
         from cloudberry_tpu.obs import trace as OT
 
-        dt = _t.perf_counter() - t0
-        OT.mark("queue-wait", t0)
-        OM.observe_stage(self.stmt_log, "queue_wait", dt)
+        @contextlib.contextmanager
+        def _cm():
+            with contextlib.ExitStack() as held:
+                with OT.stage("queue-wait"):
+                    held.enter_context(self._gate)
+                    sid = held.enter_context(self._admitted(cost))
+                yield sid
+
+        return _cm()
 
     def _obs_launch(self, runner):
-        """Run a compiled statement runner, recording the launch stage
-        (histogram; the precise device span records inside
-        run_executable/execute_distributed)."""
-        import time as _t
+        """Run a compiled statement runner under the ``launch`` stage
+        (its children record inside run_executable / the tiled loop)."""
+        from cloudberry_tpu.obs import trace as OT
 
-        from cloudberry_tpu.obs import metrics as OM
-
-        t0 = _t.perf_counter()
-        out = runner()
-        OM.observe_stage(self.stmt_log, "launch", _t.perf_counter() - t0)
-        return out
+        with OT.stage("launch"):
+            return runner()
 
     def _admitted(self, cost: int):
         """Queue slot (bounded active statements, MAX_COST, priority wake
@@ -817,19 +818,23 @@ class Session:
     def _run_cached_tiled(self, ckey: str, texe, cfg_plan=None):
         from cloudberry_tpu.exec import executor as X
 
-        self._check_topology_race(cfg_plan)
-        names = sorted({s.table_name
-                        for s in X.scans_of(texe._whole_plan())})
-        if not self._any_external(names):
-            report = texe.report
-            self._cache_statement(
-                ckey, names, texe.run,
-                self.config.resource.query_mem_bytes,
-                obs_bytes=max(int(report.get("est_step_bytes", 0)),
-                              int(report.get("est_finalize_bytes", 0))),
-                cfg=cfg_plan)
-        out = self._obs_launch(texe.run)
         from cloudberry_tpu.obs import capacity as OC
+        from cloudberry_tpu.obs import trace as OT
+
+        with OT.stage("bind", host=True):
+            self._check_topology_race(cfg_plan)
+            names = sorted({s.table_name
+                            for s in X.scans_of(texe._whole_plan())})
+            if not self._any_external(names):
+                report = texe.report
+                self._cache_statement(
+                    ckey, names, texe.run,
+                    self.config.resource.query_mem_bytes,
+                    obs_bytes=max(int(report.get("est_step_bytes", 0)),
+                                  int(report.get("est_finalize_bytes",
+                                                 0))),
+                    cfg=cfg_plan)
+        out = self._obs_launch(texe.run)
 
         OC.record_tile_dispatch(self.stmt_log, texe.report)
         return out
@@ -1081,43 +1086,45 @@ class Session:
     def _execute_and_cache(self, ckey: str, query: str, plan,
                            cfg_plan=None):
         from cloudberry_tpu.exec import executor as X
+        from cloudberry_tpu.obs import trace as OT
 
-        self._check_topology_race(cfg_plan)
-        names = sorted({s.table_name for s in X.scans_of(plan)})
-        seg = getattr(plan, "_direct_segment", None)
-        runner = None
-        if self.config.sched.generic_plans:
-            # generic-plan gate (sched/paramplan.py): same-shape
-            # statements share one compiled program with literals bound
-            # as device inputs — zero recompiles on a skeleton hit
-            from cloudberry_tpu.sched import paramplan
+        with OT.stage("bind", host=True):
+            self._check_topology_race(cfg_plan)
+            names = sorted({s.table_name for s in X.scans_of(plan)})
+            seg = getattr(plan, "_direct_segment", None)
+            runner = None
+            if self.config.sched.generic_plans:
+                # generic-plan gate (sched/paramplan.py): same-shape
+                # statements share one compiled program with literals
+                # bound as device inputs — zero recompiles on a skeleton
+                # hit
+                from cloudberry_tpu.sched import paramplan
 
-            runner = paramplan.generic_runner(self, query, plan)
-        if runner is not None:
-            pass
-        elif seg is not None:
-            exe = X.compile_plan(plan, self)
-            runner = lambda: X.run_executable(
-                exe, X.prepare_inputs(exe, self, segment=seg))
-        elif self.config.n_segments > 1:
-            from cloudberry_tpu.exec.dist_executor import \
-                execute_distributed
+                runner = paramplan.generic_runner(self, query, plan)
+            if runner is not None:
+                pass
+            elif seg is not None:
+                exe = X.compile_plan(plan, self)
+                runner = lambda: X.run_prepared(exe, self, segment=seg)
+            elif self.config.n_segments > 1:
+                from cloudberry_tpu.exec.dist_executor import \
+                    execute_distributed
 
-            fn = self._rung_executable(query, plan, names)
-            runner = lambda: execute_distributed(plan, self, fn)
-        else:
-            exe = X.compile_plan(plan, self)
-            runner = lambda: X.run_executable(
-                exe, X.prepare_inputs(exe, self))
-        # external tables re-read their source per statement — a cached
-        # program would replay the previous read
-        if not getattr(plan, "_no_stmt_cache", False) \
-                and not self._any_external(names):
-            from cloudberry_tpu.exec.resource import estimate_plan_memory
+                fn = self._rung_executable(query, plan, names)
+                runner = lambda: execute_distributed(plan, self, fn)
+            else:
+                exe = X.compile_plan(plan, self)
+                runner = lambda: X.run_prepared(exe, self)
+            # external tables re-read their source per statement — a
+            # cached program would replay the previous read
+            if not getattr(plan, "_no_stmt_cache", False) \
+                    and not self._any_external(names):
+                from cloudberry_tpu.exec.resource import \
+                    estimate_plan_memory
 
-            self._cache_statement(ckey, names, runner,
-                                  estimate_plan_memory(plan).peak_bytes,
-                                  cfg=cfg_plan)
+                self._cache_statement(
+                    ckey, names, runner,
+                    estimate_plan_memory(plan).peak_bytes, cfg=cfg_plan)
         return self._obs_launch(runner)
 
     def _cache_statement(self, ckey: str, names, runner,

@@ -406,6 +406,282 @@ def test_dispatcher_batch_trace_spans():
         == s.stmt_log.counter("generic_hits")
 
 
+# ------------------------------------- the request, split where it works
+# ISSUE 26: one primitive (obs.trace.stage) records every stage of a
+# served statement as span + histogram + profiler annotation.
+
+_TOP = ("wire-in", "bind", "parse", "plan", "admit", "queue-wait",
+        "launch", "render", "wire-out")
+_KIDS = {"oneshot": ("inputs", "dispatch", "device-wait", "fetch"),
+         "tiled": ("prelude", "feed-wait", "h2d", "tile-step",
+                   "drain-stall", "finalize")}
+_STAGED_Q = ("select g, sum(v) as sv, count(*) as n from staged "
+             "where v < {} group by g order by g")
+
+
+@pytest.fixture(scope="module")
+def staged_store(tmp_path_factory):
+    """A store-backed table of 15 partitions, and the two deployments
+    that serve it: one-shot (default memory) and tiled (1 MiB)."""
+    root = str(tmp_path_factory.mktemp("staged") / "store")
+    base = {"storage.root": root, "storage.rows_per_partition": 4096}
+    s = cb.Session(Config().with_overrides(**base))
+    s.sql("create table staged (k bigint, v bigint, g bigint) "
+          "distributed by (k)")
+    s.sql("insert into staged values " + ",".join(
+        f"({i},{i % 7},{i % 3})" for i in range(60_000)))
+    return {"oneshot": base,
+            "tiled": dict(base, **{"resource.query_mem_bytes": 1 << 20,
+                                   "bufferpool.max_bytes": 1 << 20})}
+
+
+def _hist_counts(log):
+    return {k: (h["count"], h["sum"]) for k, h in
+            log.registry.snapshot()["histograms"].items()}
+
+
+def _served_trace(log, c, sql, want="request"):
+    """Send ``sql``; its trace once the request's last span landed (the
+    event loop closes the request after the answer's last byte)."""
+    c.sql(sql)
+    deadline = time.monotonic() + 5.0
+    while True:
+        tr = log.traces(1)[0]
+        if tr["sql"] == sql[:200] and any(
+                e["name"] == want for e in tr["events"]):
+            return tr
+        assert time.monotonic() < deadline, tr
+        time.sleep(0.01)
+
+
+@pytest.mark.parametrize("threaded", [False, True],
+                         ids=["async", "threaded"])
+@pytest.mark.parametrize("path", ["oneshot", "tiled"])
+def test_served_statement_spans_where_the_work_happens(
+        staged_store, path, threaded, monkeypatch):
+    from cloudberry_tpu.obs import trace as OT
+    from cloudberry_tpu.serve import Client, Server
+
+    # every request reads its thread's CPU clock here (one in
+    # OT.CPU_SAMPLE does in service: the read is a system call)
+    monkeypatch.setattr(OT, "CPU_SAMPLE", 1)
+
+    cfg = Config().with_overrides(**staged_store[path],
+                                  **{"serve.threaded": threaded})
+    with Server(config=cfg) as srv, Client(srv.host, srv.port) as c:
+        log = srv.session.stmt_log
+        _served_trace(log, c, _STAGED_Q.format(5))      # cold: compiles
+        before = _hist_counts(log)
+        tr = _served_trace(log, c, _STAGED_Q.format(4))
+        after = _hist_counts(log)
+    assert tr["status"] == "ok"
+    events = tr["events"]
+    sid = tr["statement_id"]
+    names = [e["name"] for e in events]
+    top = _TOP + (() if threaded else ("wire-flush",))
+    want = set(top) | set(_KIDS[path]) | {"request", "statement"} \
+        | ({"part-read"} if path == "tiled" else set())
+    assert want <= set(names), sorted(want - set(names))
+    assert not set(_KIDS["tiled" if path == "oneshot" else "oneshot"]) \
+        & set(names)
+    # one statement id on every span of every thread; parents named
+    assert all(e["args"]["statement_id"] == sid for e in events), events
+    launch = next(e for e in events if e["name"] == "launch")
+    eps = 2.0
+    for e in events:
+        if e["name"] in _KIDS[path]:
+            assert e["args"]["parent"] in ("launch", "tile-step"), e
+            assert e["tid"] == launch["tid"]
+            assert e["ts"] >= launch["ts"] - eps and e["ts"] + e["dur"] \
+                <= launch["ts"] + launch["dur"] + eps, (e, launch)
+        elif e["name"] == "part-read":
+            # the reader thread names the stage that started its feed
+            assert e["args"]["parent"] == "prelude", e
+            assert e["tid"] != launch["tid"]
+            assert e["args"]["table"] == "staged" and e["args"]["pool"]
+    assert _span_intervals_nest(events), events
+    request = next(e for e in events if e["name"] == "request")
+    for e in events:
+        assert e["ts"] >= request["ts"] - eps and e["ts"] + e["dur"] \
+            <= request["ts"] + request["dur"] + eps, (e, request)
+
+    # each histogram is fed once per span, from the same measurement
+    def fed(hist):
+        return after.get(hist, (0, 0.0))[0] - before.get(hist, (0, 0.0))[0]
+
+    def secs(hist):
+        return after.get(hist, (0, 0.0))[1] - before.get(hist, (0, 0.0))[1]
+
+    for name in top:
+        assert fed("stage_seconds." + name.replace("-", "_")) \
+            == names.count(name), name
+    for name in _KIDS[path]:
+        assert fed("launch_seconds." + name.replace("-", "_")) \
+            == names.count(name), name
+    assert fed("feed_seconds.part_read") == names.count("part-read")
+    assert fed("request_seconds") == fed("host_offcpu_seconds") == 1
+    # (signed: wall minus thread CPU summed over the host-only stages)
+    assert abs(request["args"]["offcpu_s"]) <= request["dur"] / 1e6
+    # the top-level stages partition the request: their sum fits in it,
+    # and the launch's children fit in the launch
+    stage_sum = sum(secs(h) for h in after if h.startswith("stage_seconds."))
+    assert 0 < stage_sum <= secs("request_seconds") + 1e-4
+    kids_sum = sum(secs(h) for h in after if h.startswith("launch_seconds."))
+    assert 0 < kids_sum <= secs("stage_seconds.launch") + 1e-4
+    if path == "tiled":
+        # the run report and the histograms read one measurement
+        assert fed("tile_seconds") == names.count("tile-step")
+
+
+def test_compiles_name_the_statement_that_paid(staged_store):
+    """The tiled path has no generic plans: a new literal builds its
+    programs again. ``compiles`` moves (the tiled ``_compile`` miss),
+    ``xla_compiles`` moves by every program JAX hands to the compiler,
+    and each leaves a ``compile`` span on the statement that paid; a
+    repeat of the same text pays nothing."""
+    s = cb.Session(Config().with_overrides(**staged_store["tiled"]))
+    log = s.stmt_log
+    s.sql(_STAGED_Q.format(6))
+    c0, x0 = log.counter("compiles"), log.counter("xla_compiles")
+    assert c0 >= 1 and x0 >= 3      # prelude, step, finalize at the least
+    s.sql(_STAGED_Q.format(3))
+    assert log.counter("compiles") == c0 + 1
+    paid = log.counter("xla_compiles") - x0
+    assert paid >= 1
+    tr = s.stmt_log.traces(1)[0]
+    spans = [e for e in tr["events"] if e["name"] == "compile"
+             and e["args"].get("xla")]
+    assert len(spans) == paid
+    assert all(e["args"]["statement_id"] == tr["statement_id"]
+               and e["dur"] > 0 for e in spans)
+    assert log.registry.hist("xla_compile_seconds")["count"] \
+        == log.counter("xla_compiles")
+    s.sql(_STAGED_Q.format(3))
+    assert log.counter("compiles") == c0 + 1
+    assert log.counter("xla_compiles") == x0 + paid
+    assert not [e for e in s.stmt_log.traces(1)[0]["events"]
+                if e["name"] == "compile"]
+
+
+def test_xla_compiles_sees_the_pool_hit_slice(tmp_path):
+    """``compiles`` is silent where no statement-level program is built;
+    the feed's first pool hit still compiles an eager slice (partitions
+    of 5000 rows do not tile 16384 evenly), on the scan reader thread:
+    ``xla_compiles`` counts it, named by statement."""
+    base = {"storage.root": str(tmp_path / "store"),
+            "storage.rows_per_partition": 5000}
+    w = cb.Session(Config().with_overrides(**base))
+    w.sql("create table staged (k bigint, v bigint, g bigint) "
+          "distributed by (k)")
+    w.sql("insert into staged values " + ",".join(
+        f"({i},{i % 7},{i % 3})" for i in range(60_000)))
+    cfg = Config().with_overrides(**dict(base, **{
+        "resource.query_mem_bytes": 1 << 20,
+        "bufferpool.max_bytes": 64 << 20,
+        "bufferpool.admit_min_scans": 1}))
+    s = cb.Session(cfg)
+    log = s.stmt_log
+    q = _STAGED_Q.format(2)
+    for _ in range(6):
+        c0, x0 = log.counter("compiles"), log.counter("xla_compiles")
+        h0 = log.counter("bufpool_hits")
+        s.sql(q)
+        if log.counter("bufpool_hits") > h0:
+            break
+    else:
+        pytest.fail("the pool never served the repeated scan")
+    assert log.counter("compiles") == c0          # statement-cache hit
+    assert log.counter("xla_compiles") > x0
+    tr = s.stmt_log.traces(1)[0]
+    assert any(e["name"] == "compile" and e["args"].get("xla")
+               and e["args"]["statement_id"] == tr["statement_id"]
+               for e in tr["events"]), tr["events"]
+    assert any(e["name"] == "part-read" and e["args"]["pool"] == "hit"
+               for e in tr["events"])
+
+
+def test_host_stages_reach_a_profiler_session(staged_store, tmp_path):
+    """With a profiler session on, the host plane holds the stages as
+    ``cbtpu:`` events carrying the statement id, on the thread that did
+    the work — traced statement or not (``obs.trace_sample`` 1000 here
+    keeps this one's span tree out of the ring)."""
+    import glob
+
+    import jax
+
+    cfg = Config().with_overrides(**staged_store["tiled"],
+                                  **{"obs.trace_sample": 1000})
+    s = cb.Session(cfg)
+    s.sql(_STAGED_Q.format(1))          # sampled in: warms the programs
+    with jax.profiler.trace(str(tmp_path)):
+        s.sql(_STAGED_Q.format(1))
+    assert len(s.stmt_log.traces(10)) == 1
+    sid = s.stmt_log.recent(1)[0]["id"]
+    found = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    prof = jax.profiler.ProfileData.from_file(found[-1])
+    by_thread = {}
+    for plane in prof.planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                for e in line.events:
+                    if e.name.startswith("cbtpu:") and \
+                            dict(e.stats).get("statement_id") == sid:
+                        by_thread.setdefault(i, set()).add(e.name[6:])
+    seen = set().union(*by_thread.values())
+    assert {"bind", "admit", "queue-wait", "launch", "prelude", "h2d",
+            "tile-step", "finalize", "part-read"} <= seen, seen
+    # the reader thread's reads are on a line of their own
+    assert any("part-read" in names and "launch" not in names
+               for names in by_thread.values()), by_thread
+
+
+def test_annotations_are_built_only_under_a_profiler_session(monkeypatch):
+    """No profiler session: a stage tests a flag and builds no
+    annotation object at all; with one on, a served one-shot statement
+    builds one per stage (counts, not timings)."""
+    from cloudberry_tpu.obs import trace as OT
+    from cloudberry_tpu.serve import Client, Server
+
+    built, session_on = [], [False]
+
+    class Counting(OT.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+        @staticmethod
+        def is_enabled():
+            return session_on[0]
+
+    s = cb.Session()
+    s.sql("create table an (k bigint, v bigint) distributed by (k)")
+    s.catalog.table("an").set_data({
+        "k": np.arange(300, dtype=np.int64),
+        "v": np.arange(300, dtype=np.int64)}, {})
+    with Server(session=s) as srv, Client(srv.host, srv.port) as c:
+        c.sql("select v from an where k = 1")
+        monkeypatch.setattr(OT, "TraceAnnotation", Counting)
+        _served_trace(s.stmt_log, c, "select v from an where k = 2")
+        assert built == []
+        session_on[0] = True
+        _served_trace(s.stmt_log, c, "select v from an where k = 3")
+    assert 10 <= len(built) <= 16, built
+    assert len(set(built)) >= 10 and all(
+        n.startswith("cbtpu:") for n in built)
+
+
+def test_obs_disabled_records_no_stage():
+    off = cb.Session(Config().with_overrides(**{"obs.enabled": False}))
+    off.sql("create table od (k bigint)")
+    off.sql("insert into od values (1), (2)")
+    assert off.sql("select count(*) as n from od").num_rows() == 1
+    hists = off.stmt_log.registry.snapshot()["histograms"]
+    assert not [h for h in hists if h.split(".")[0] in (
+        "stage_seconds", "launch_seconds", "feed_seconds",
+        "request_seconds", "host_offcpu_seconds")], hists
+
+
 # ------------------------------------------------------- wire surface
 
 
