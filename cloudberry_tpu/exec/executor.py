@@ -12,6 +12,8 @@ run — the shape-world analog of ereport().
 
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -53,6 +55,9 @@ class Executable:
     # instrumented programs return a 4th output (per-node row counts);
     # EXPLAIN ANALYZE's pipeline path runs them directly (instrument.py)
     instrumented: bool = False
+    # the one-shot launch's program (run_executable): ``fn`` ending in
+    # pack_answer, so the host gets the whole answer in one wait
+    packed_fn: Callable = None  # type: ignore[assignment]
 
 
 def execute(plan: N.PlanNode, session) -> ColumnBatch:
@@ -62,7 +67,8 @@ def execute(plan: N.PlanNode, session) -> ColumnBatch:
 
         return execute_distributed(plan, session)
     exe = compile_plan(plan, session)
-    return run_executable(exe, prepare_inputs(exe, session, segment=seg))
+    return run_executable(exe, prepare_inputs(exe, session, segment=seg),
+                          log=getattr(session, "stmt_log", None))
 
 
 def keyed_scan(s: N.PScan) -> bool:
@@ -122,7 +128,9 @@ def compile_plan(plan: N.PlanNode, session,
         out = {f.name: cols[f.name] for f in plan.fields}
         return out, sel, low.checks
 
-    return Executable(plan, jax.jit(run), table_names, store_scans, run)
+    return Executable(plan, jax.jit(run), table_names, store_scans, run,
+                      packed_fn=jax.jit(
+                          lambda tables: pack_answer(*run(tables))))
 
 
 def prepare_tables(table_names: list[str], session,
@@ -307,27 +315,120 @@ def _read_scan_columns(scan: N.PScan, session, log) -> dict:
     return hit
 
 
-def run_executable(exe: Executable, tables: dict) -> ColumnBatch:
+# A leaf larger than this rides beside the packed buffers as an array of
+# its own: packing is for answers whose cost is the number of reads, and
+# concatenating a large result holds it twice on the device (the TPU
+# compiler's byte relayout of an 8 MiB leaf takes 4x its size in
+# temporaries). 1 MiB still covers a capacity-sized aggregate: 60,000
+# rows of three int64 columns read in 1.40 ms packed against 3.15 ms as
+# four overlapped reads (PERF.md §6, PR 27).
+_PACK_LEAF_MAX = 1 << 20
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["bufs"], meta_fields=["layout"])
+@dataclass
+class PackedAnswer:
+    """What a packed one-shot program returns: ``bufs``, the device
+    arrays the host reads (the byte buffer, the float64 buffer, then
+    every leaf too large to pack), and ``layout``, which says where each
+    of the program's outputs lies in them. The layout is static pytree
+    data: jit keeps it with the output structure of each shape it
+    traced, so it is computed once, when the program is built, from the
+    program's own output avals."""
+
+    bufs: tuple
+    layout: tuple
+
+
+def pack_answer(cols, sel, checks) -> PackedAnswer:
+    """The end of a one-shot program: one flag per check (``any()`` of
+    it, in the dict's order), ``sel``, and every output column and mask,
+    bit for bit in as few device buffers as the chip's compiler allows.
+    Every dtype but float64 is bitcast into ONE uint8 buffer; float64
+    leaves share one float64 buffer, because a TPU holds a float64 as
+    two float32 and its compiler refuses the bitcast (the same rule on
+    every platform, so the CPU tests run what the chip runs). Within a
+    buffer the widest items come first: each host view is then aligned
+    with no padding."""
+    import jax.lax as lax
+
+    leaves = [jnp.asarray(x) for x in (
+        *(jnp.any(bad) for bad in checks.values()), sel, *cols.values())]
+    # 0: the byte buffer, 1: the float64 buffer, 2: beside them
+    kinds = [2 if x.nbytes > _PACK_LEAF_MAX
+             else int(x.dtype == jnp.float64) for x in leaves]
+    bufs, place = [], {}  # place[leaf] = (buffer, offset in its items)
+    for k in (0, 1):
+        mine = sorted((i for i, kind in enumerate(kinds) if kind == k),
+                      key=lambda i: -leaves[i].dtype.itemsize)
+        parts, at = [], 0
+        for i in mine:
+            x = leaves[i]
+            if k == 0:
+                x = x.astype(jnp.uint8) if x.dtype == jnp.bool_ \
+                    else lax.bitcast_convert_type(x, jnp.uint8)
+            place[i] = (len(bufs), at)
+            parts.append(x.reshape(-1))
+            at += parts[-1].size
+        if parts:
+            bufs.append(jnp.concatenate(parts))
+    for i, kind in enumerate(kinds):
+        if kind == 2:
+            place[i] = (len(bufs), -1)
+            bufs.append(leaves[i])
+    layout = (tuple(checks), tuple(cols),
+              tuple((*place[i], x.shape, x.dtype.name)
+                    for i, x in enumerate(leaves)))
+    return PackedAnswer(tuple(bufs), layout)
+
+
+def unpack_answer(layout, host_bufs):
+    """``(cols, sel, checks)`` of host arrays from a PackedAnswer's
+    buffers once they are NumPy arrays: views cut by the layout, with
+    the dtypes and shapes the unpacked program returns."""
+    check_names, col_names, slots = layout
+    leaves = []
+    for b, at, shape, dtype in slots:
+        buf = host_bufs[b]
+        if at >= 0:
+            dtype = np.dtype(dtype)
+            n = math.prod(shape) * dtype.itemsize // buf.itemsize
+            buf = buf[at:at + n].view(dtype).reshape(shape)
+        leaves.append(buf)
+    k = len(check_names)
+    return (dict(zip(col_names, leaves[k + 1:])), leaves[k],
+            dict(zip(check_names, leaves[:k])))
+
+
+def run_executable(exe: Executable, tables: dict, log=None) -> ColumnBatch:
     """One launch of a compiled program, split where the time goes
     (obs/trace.py, children of ``launch``): ``dispatch`` until the call
     returns (the first call of a shape traces and compiles inside it),
-    ``device-wait`` until the program is done (the first D2H read, which
-    has always been the sync: now a span, not a second sync, and its
-    value stays on the array for raise_checks / make_batch), ``fetch``
-    for the other D2H reads of the check scalars and result columns."""
+    ``device-wait`` for the blocking device-to-host read of the packed
+    answer (every buffer's copy is started before the first is waited
+    for: one read, two where the answer holds a float64), ``fetch`` for
+    the host's part: cutting the buffers into views, the check flags —
+    examined before a batch is built — and the batch. ``log`` (the
+    engine's StatementLog) counts the launch and its reads."""
     from cloudberry_tpu.obs import trace as OT
 
     with OT.stage("dispatch", "launch_seconds",
                   plan=type(exe.plan).__name__):
-        cols, sel, checks = exe.fn(tables)
+        packed = exe.packed_fn(tables)
     with OT.stage("device-wait", "launch_seconds"):
-        np.asarray(next(iter(checks.values()), sel))
+        host = jax.device_get(packed.bufs)
+    if log is not None:
+        log.bump("launch_packed")
+        log.bump("launch_d2h_reads", len(host))
     with OT.stage("fetch", "launch_seconds", host=True) as st:
+        cols, sel, checks = unpack_answer(packed.layout, host)
         raise_checks(checks)
         batch = make_batch(exe.plan, cols, sel)
         st.args["columns"] = len(batch.columns)
         st.args["bytes"] = sum(int(a.nbytes)
                                for a in batch.columns.values())
+        st.args["reads"] = len(host)
     return batch
 
 
@@ -338,7 +439,7 @@ def run_prepared(exe: Executable, session, segment=None) -> ColumnBatch:
 
     with OT.stage("inputs", "launch_seconds", host=True):
         tables = prepare_inputs(exe, session, segment=segment)
-    return run_executable(exe, tables)
+    return run_executable(exe, tables, log=session.stmt_log)
 
 
 def raise_checks(checks: dict) -> None:

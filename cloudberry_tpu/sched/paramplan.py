@@ -624,7 +624,7 @@ class GenericPlan:
             return X.make_batch(self.plan, host_cols, DX._local_row(sel))
         with OT.stage("inputs", "launch_seconds", host=True):
             inputs = self.bind_inputs(session, planB, keyedB, bindings)
-        return X.run_executable(self.exe, inputs)
+        return X.run_executable(self.exe, inputs, log=session.stmt_log)
 
     # ----------------------------------------------------- stacked launch
 

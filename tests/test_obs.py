@@ -531,6 +531,15 @@ def test_served_statement_spans_where_the_work_happens(
     if path == "tiled":
         # the run report and the histograms read one measurement
         assert fed("tile_seconds") == names.count("tile-step")
+    else:
+        # the packed answer (ISSUE 27): the one blocking read is
+        # ``device-wait``; ``fetch`` is the host's part and says how many
+        # reads the answer took (three int64 columns at the scan's
+        # capacity, 480 kB each, and ``sel``: still one buffer)
+        fetch = next(e for e in events if e["name"] == "fetch")
+        assert fetch["args"]["reads"] == 1, fetch
+        assert fetch["args"]["columns"] == 3 and fetch["args"]["bytes"] > 0
+        assert names.count("device-wait") == names.count("fetch") == 1
 
 
 def test_compiles_name_the_statement_that_paid(staged_store):

@@ -104,14 +104,14 @@ def _plan(session, sql):
     return plan_statement(parse_sql(sql), session, {}).plan
 
 
-def _compile_one_shot(session, qname, one_chip):
+def _compile_one_shot(session, qname, one_chip, packed=False):
     from cloudberry_tpu.exec.executor import compile_plan, prepare_inputs
     from tools.tpch_queries import QUERIES
 
     exe = compile_plan(_plan(session, QUERIES[qname]), session,
                        platform="tpu")
     shapes = _shapes(prepare_inputs(exe, session), one_chip)
-    return exe.fn.lower(shapes).compile()
+    return (exe.packed_fn if packed else exe.fn).lower(shapes).compile()
 
 
 @pytest.mark.parametrize("qname", ["q1", "q6"])
@@ -122,6 +122,38 @@ def test_scan_agg_programs_compile_for_tpu(sf1_lineitem, one_chip, qname):
     mem = compiled.memory_analysis()
     # fits one 16 GB chip with room: arguments + temporaries
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+
+
+@pytest.mark.parametrize("qname, bufs", [("q1", ["uint8", "float64"]),
+                                         ("q6", ["uint8"])])
+def test_packed_answers_compile_for_tpu(sf1_lineitem, one_chip, qname,
+                                        bufs):
+    """The programs the served one-shot path launches (run_executable):
+    the same plans ending in pack_answer. The TPU compiler takes the
+    bitcast of every integer column to bytes; float64 (two float32 on
+    the chip, no bitcast) rides in a buffer of its own, so Q1's answer
+    is two device buffers and Q6's one."""
+    compiled = _compile_one_shot(sf1_lineitem, qname, one_chip, packed=True)
+    out = jax.tree_util.tree_leaves(compiled.out_info)
+    assert [o.dtype.name for o in out] == bufs
+    assert all(o.ndim == 1 for o in out)
+    # an answer of a few hundred bytes
+    assert compiled.memory_analysis().output_size_in_bytes < 1 << 16
+
+
+def test_float64_bitcast_is_what_the_tpu_compiler_refuses(one_chip):
+    """Why pack_answer keeps float64 out of the byte buffer: pinned, so
+    a compiler that learns the bitcast is noticed (the answer could then
+    be one buffer)."""
+    import jax.lax as lax
+
+    x = jax.ShapeDtypeStruct((8,), jnp.float64, sharding=one_chip)
+    with pytest.raises(Exception, match="X64"):
+        jax.jit(lambda v: lax.bitcast_convert_type(v, jnp.uint8)) \
+            .lower(x).compile()
+    y = jax.ShapeDtypeStruct((8,), jnp.int64, sharding=one_chip)
+    jax.jit(lambda v: lax.bitcast_convert_type(v, jnp.uint8)) \
+        .lower(y).compile()
 
 
 def test_tiled_step_compiles_for_tpu_with_donation(sf1_lineitem, one_chip,
