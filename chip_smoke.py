@@ -271,7 +271,11 @@ class Smoke:
                 got[name, label] = df
                 # the second send may compile again (feedback re-seeds a
                 # redistribute's bucket rung from what the first one saw),
-                # so it takes a third to see a send that compiles nothing
+                # so it takes a third to see a send that compiles nothing.
+                # Both programs are the same text in every process (check
+                # and stats keys name plan nodes by ordinal, PR 28), so in
+                # a later process with the cache kept both are cache hits
+                # and cost a load each, not a compile
                 say("mesh", f"{name} {label}: first {secs[0]:.3f}s, then "
                     f"{secs[1]:.3f}s and {secs[2]:.3f}s; {len(df)} rows; "
                     f"compiles per send {compiles}")
@@ -338,9 +342,12 @@ def mesh_statements(with_join: bool) -> dict:
     ``by_supp`` — lineitem, distributed by l_orderkey, grouped by
     l_suppkey — is the cheapest statement whose plan has a hash
     redistribute (the program tests/test_tpu_compile.py compiles for
-    2x2). Q3 joins co-located orders and lineitem and broadcasts
-    customer, so it has none, and it compiles for minutes at each
-    segment count: ``--with-join`` only."""
+    2x2); the benchmark's cell ``tpch-sf1-4seg.motion`` sends the spec's
+    form of it, the body of Q15's revenue view. Q3 joins co-located
+    orders and lineitem and broadcasts customer, so it has none, and it
+    compiles for minutes at each segment count the FIRST time: the
+    persistent cache serves it to every later process (its module text no
+    longer carries ``id()`` addresses, PR 28). ``--with-join`` only."""
     from tools.tpch_oracle import ORACLES
     from tools.tpch_queries import QUERIES
 

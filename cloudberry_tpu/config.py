@@ -357,6 +357,13 @@ class ServeConfig:
     # bound (the pipelining cap only sees COMPLETE lines). Oversized
     # lines get one fatal error response, then the connection closes.
     max_line_bytes: int = 64 << 20
+    # Event-loop transport only: a request still being served sends its
+    # client one space after this many seconds of silence, and again as
+    # often (0 = never). A first send can compile for minutes, and a
+    # client's socket time limit counts the silence between two bytes,
+    # not the statement; JSON takes white space before a value, so the
+    # answer's line parses as it did.
+    keepalive_s: float = 30.0
 
 
 @dataclass(frozen=True)

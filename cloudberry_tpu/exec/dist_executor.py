@@ -81,6 +81,7 @@ def compile_distributed(plan: N.PlanNode, session, param_keys=None,
     replicated stats channel — partitioned-node counts psum across
     segments, replicated nodes report segment 0's — so the instrumented
     program is this same entry point's program, not a side path's."""
+    from cloudberry_tpu.obs.capacity import motion_wire_bytes
     from cloudberry_tpu.parallel.transport import (hier_topology,
                                                    make_transport)
 
@@ -97,7 +98,7 @@ def compile_distributed(plan: N.PlanNode, session, param_keys=None,
     tx = make_transport(ic.backend, nseg, chunks=ic.ring_chunks,
                         topo=topo)
     packed = ic.packed_wire
-    _, in_specs = prepare_dist_inputs(plan, session)
+    inputs, in_specs = prepare_dist_inputs(plan, session)
     if param_keys:
         in_specs["$params"] = {k: P() for k in param_keys}
     X.count_compile(session)
@@ -119,22 +120,14 @@ def compile_distributed(plan: N.PlanNode, session, param_keys=None,
         # the capacity-ladder promotion reads these host-side
         return out, sel[None], checks, dict(low.stats)
 
-    return jax.jit(_shard_map(seg_fn, mesh, (in_specs,),
-                              _out_specs_like(plan)))
-
-
-def stat_node_ids(plan: N.PlanNode) -> tuple:
-    """Ordered ids of the plan's stats-bearing nodes (redistributes,
-    then runtime filters, document order). The rung-program cache stores
-    the TRACED plan's tuple so that a signature-equal plan reusing the
-    compiled program can alias its own nodes onto the stats keys — the
-    telemetry keys embed trace-time node ids, and without the alias a
-    cache hit would silently drop the feedback loop's observations."""
-    red = tuple(id(n) for n in X.all_nodes(plan)
-                if isinstance(n, N.PMotion) and n.kind == "redistribute")
-    rf = tuple(id(n) for n in X.all_nodes(plan)
-               if isinstance(n, N.PRuntimeFilter))
-    return (red, rf)
+    fn = jax.jit(_shard_map(seg_fn, mesh, (in_specs,),
+                            _out_specs_like(plan)))
+    # what one launch hands the program and puts on its motions' wires
+    # follows from the shapes the program is compiled for: reckoned here,
+    # once, for execute_distributed's counters
+    fn.input_bytes = scanned_input_bytes(plan, inputs)
+    fn.wire_bytes = motion_wire_bytes(plan)
+    return fn
 
 
 def record_motion_stats(plan: N.PlanNode, stats: dict,
@@ -152,49 +145,36 @@ def record_motion_stats(plan: N.PlanNode, stats: dict,
     only once raise_checks passed."""
     import re
 
-    # redistribute-only by construction; the kind filter also guards the
-    # stale-id aliasing hazard when the program came from a rung-cached
-    # executable of an equivalent, since-collected plan (same guard as
-    # grow_expansion's id-match path)
-    motions = {id(n): n for n in X.all_nodes(plan)
-               if isinstance(n, N.PMotion) and n.kind == "redistribute"}
-    filters = {id(n): n for n in X.all_nodes(plan)
-               if isinstance(n, N.PRuntimeFilter)}
-    # program reused from an equivalent traced plan (_rung_executable):
-    # admit the TRACED ids as aliases for this plan's same-ordered nodes.
-    # A live id is never overwritten — if a traced id happens to collide
-    # with a current node's id, the kind filter + first-writer-wins keeps
-    # the pre-existing aliasing guarantee.
-    alias = getattr(plan, "_stat_id_alias", None)
-    if alias:
-        for old, new in alias.items():
-            if new in motions and old not in motions:
-                motions[old] = motions[new]
-            elif new in filters and old not in filters:
-                filters[old] = filters[new]
+    # keys name their node by its ordinal in the plan (X.numbered_nodes):
+    # a program served from the rung cache was traced off a
+    # signature-equal plan, whose ordinals are this plan's. The kind
+    # filters keep an ordinal from another plan shape off the wrong node.
+    nodes = X.numbered_nodes(plan)
+    motions: dict = {}      # id -> redistribute this launch reported on
+
+    def motion_of(key):
+        n = X.keyed_node(nodes, key)
+        if isinstance(n, N.PMotion) and n.kind == "redistribute":
+            return motions.setdefault(id(n), n)
+        return None
+
     for key, v in stats.items():
-        m = re.search(r"required bucket \(node (\d+)\)", key)
-        if m is not None:
-            node = motions.get(int(m.group(1)))
+        if key.startswith("required bucket "):
+            node = motion_of(key)
             if node is not None:
                 node._observed_bucket = int(np.asarray(v))
-            continue
-        m = re.search(r"required host bucket \(node (\d+)\)", key)
-        if m is not None:
-            node = motions.get(int(m.group(1)))
+        elif key.startswith("required host bucket "):
+            node = motion_of(key)
             if node is not None:
                 node._observed_host_bucket = int(np.asarray(v))
-            continue
-        m = re.search(r"seg rows \(node (\d+)\)", key)
-        if m is not None:
-            node = motions.get(int(m.group(1)))
+        elif key.startswith("seg rows "):
+            node = motion_of(key)
             if node is not None:
                 node._seg_rows = np.asarray(v).astype(np.int64)
-            continue
-        m = re.search(r"join_filter (pre|post) \(node (\d+)\)", key)
-        if m is not None:
-            node = filters.get(int(m.group(2)))
-            if node is not None:
+        else:
+            m = re.match(r"join_filter (pre|post) ", key)
+            node = X.keyed_node(nodes, key) if m is not None else None
+            if isinstance(node, N.PRuntimeFilter):
                 which = "_jf_pre" if m.group(1) == "pre" else "_jf_post"
                 setattr(node, which, int(np.asarray(v)))
     _record_skew(motions.values(), session)
@@ -293,28 +273,84 @@ def record_jf_counters(stats: dict, log) -> None:
                      else "jf_rows_out", int(np.asarray(v)))
 
 
-def execute_distributed(plan: N.PlanNode, session,
-                        fn=None) -> ColumnBatch:
-    if fn is None:
-        fn = compile_distributed(plan, session)
-    inputs, _ = prepare_dist_inputs(plan, session)
-    fault_point("dist_execute_start")
+def execute_distributed(plan: N.PlanNode, session, fn=None, *,
+                        inputs_plan: N.PlanNode | None = None,
+                        params: dict | None = None,
+                        mode: str = "dist") -> ColumnBatch:
+    """One launch of a distributed program, split where the time goes
+    (obs/trace.py, children of ``launch``, under the one-shot path's
+    names): ``inputs`` (the scanned tables' shards — HOST arrays, handed
+    to every launch — and the ``$params`` of a generic plan), ``dispatch``
+    until the call returns (the first call of a shape traces and
+    compiles inside it), ``device-wait`` for the first blocking read
+    (the selection mask: it holds the device's own time),
+    ``motion-stats`` for the host's reading of the program's motion
+    statistics and checks (a blocking read a leaf) and the feedback
+    fold, ``fetch`` for the result columns, read leaf by leaf, and the
+    batch. ``plan`` is the plan ``fn`` was traced from; a generic plan's
+    rebind passes its freshly bound ``inputs_plan`` (same shapes, this
+    send's scans) and ``params``."""
     from cloudberry_tpu.obs import trace as OT
-
-    with OT.stage("dispatch", "launch_seconds", mode="dist"):
-        cols, sel, checks, stats = fn(inputs)
-    record_motion_stats(plan, stats, session=session)
-    X.raise_checks(checks)
-    record_jf_counters(stats, getattr(session, "stmt_log", None))
     from cloudberry_tpu.plan.feedback import fold_plan
 
-    fold_plan(session, plan)
-    # every segment computed the (gathered) final result; read the first
-    # shard THIS HOST can address (on a multi-host mesh, segment 0 may
-    # live on another process — any local copy is identical post-gather)
-    host_cols = {k: _local_row(v) for k, v in cols.items()}
-    host_sel = _local_row(sel)
-    return X.make_batch(plan, host_cols, host_sel)
+    if fn is None:
+        fn = compile_distributed(plan, session)
+    log = getattr(session, "stmt_log", None)
+    with OT.stage("inputs", "launch_seconds", host=True, mode=mode):
+        inputs, _ = prepare_dist_inputs(inputs_plan or plan, session)
+        if params:
+            inputs["$params"] = dict(params)
+    fault_point("dist_execute_start")
+    with OT.stage("dispatch", "launch_seconds", mode=mode):
+        cols, sel, checks, stats = fn(inputs)
+    with OT.stage("device-wait", "launch_seconds"):
+        # every segment computed the (gathered) final result; read the
+        # first shard THIS HOST can address (on a multi-host mesh,
+        # segment 0 may live on another process — any local copy is
+        # identical post-gather)
+        host_sel = _local_row(sel)
+    with OT.stage("motion-stats", "launch_seconds"):
+        # one blocking read a leaf, made here and nowhere after
+        stats = {k: np.asarray(v) for k, v in stats.items()}
+        checks = {k: np.asarray(v) for k, v in checks.items()}
+        record_motion_stats(plan, stats, session=session)
+        X.raise_checks(checks)
+        record_jf_counters(stats, log)
+        fold_plan(session, plan)
+    with OT.stage("fetch", "launch_seconds") as st:
+        host_cols = {k: _local_row(v) for k, v in cols.items()}
+        batch = X.make_batch(plan, host_cols, host_sel)
+        reads = 1 + len(stats) + len(checks) + len(cols)
+        st.args["columns"] = len(batch.columns)
+        st.args["bytes"] = sum(int(a.nbytes)
+                               for a in batch.columns.values())
+        st.args["reads"] = reads
+    if log is not None:
+        log.bump("launch_dist")
+        log.bump("launch_d2h_reads", reads)
+        log.bump("dist_input_bytes", fn.input_bytes)
+        log.bump("motion_wire_bytes", fn.wire_bytes)
+    return batch
+
+
+def scanned_input_bytes(plan: N.PlanNode, inputs: dict) -> int:
+    """Host bytes of ``inputs`` the program keeps: the shards of the
+    columns (and validity masks) its scans name, and each table's row
+    counts. ``jax.jit`` drops an argument the program never reads
+    before it is transferred, so a table's other columns cost nothing."""
+    used: dict = {}
+    for s in X.scans_of(plan):
+        used.setdefault(s.table_name, set()).update(
+            list(s.column_map) + [f"$nn:{p}" for p in s.mask_map])
+    total = 0
+    for name, cols in used.items():
+        t = inputs.get(name)
+        if t is None:
+            continue
+        total += int(np.asarray(t["$nrows"]).nbytes)
+        total += sum(int(t["$cols"][c].nbytes) for c in cols
+                     if c in t["$cols"])
+    return total
 
 
 def _local_row(v) -> np.ndarray:
@@ -345,9 +381,9 @@ def _shard_map(f, mesh, in_specs, out_specs):
 class DistLowerer(X.Lowerer):
     def __init__(self, tables, nseg: int, platform: str | None = None,
                  use_pallas: bool = False, tx=None, packed: bool = True,
-                 params=None):
+                 params=None, root=None):
         super().__init__(tables, platform=platform, use_pallas=use_pallas,
-                         params=params)
+                         params=params, root=root)
         self.nseg = nseg
         # motion transport (ic_modules.c vtable analog): XLA-native
         # collectives or ppermute ring compositions
@@ -412,7 +448,7 @@ class DistLowerer(X.Lowerer):
             # stats-proven narrow keys halve the all-gathered bytes too
             kb, kp, big = K.downcast32(kb), K.downcast32(kp), K._U32_MAX
         kb_all = self.tx.all_gather(kb, SEG_AXIS)
-        kb_sorted = jnp.sort(kb_all)
+        kb_sorted = jax.lax.sort(kb_all, is_stable=False)
         pos = jnp.clip(jnp.searchsorted(kb_sorted, kp), 0,
                        kb_sorted.shape[0] - 1)
         hit = (kb_sorted[pos] == kp) & (kp != big)
@@ -487,17 +523,23 @@ class DistLowerer(X.Lowerer):
         filter (psum over segments) — the host pins them on the plan node
         (record_motion_stats) for EXPLAIN ANALYZE consumers, bench.py's
         join_filter record, and ic_bench --join-filter."""
-        self.stats[f"join_filter pre (node {id(node)})"] = self.tx.psum(
+        self.stats[f"join_filter pre (node {self.ref(node)})"] = self.tx.psum(
             jnp.sum(pre.astype(jnp.int32)), SEG_AXIS)
-        self.stats[f"join_filter post (node {id(node)})"] = self.tx.psum(
+        self.stats[f"join_filter post (node {self.ref(node)})"] = self.tx.psum(
             jnp.sum(post.astype(jnp.int32)), SEG_AXIS)
 
     def motion(self, node: N.PMotion):
         cols, sel = self.lower_shared(node.child)
+        # a profile tells a Motion's operations (bucketing, pack, the
+        # collective, unpack) from the rest by this scope in their names
+        with jax.named_scope(f"motion:{node.kind}"):
+            return self._motion(node, cols, sel)
+
+    def _motion(self, node: N.PMotion, cols, sel):
         if node.pre_compact:
             cols, sel, n = K.compact(cols, sel, node.pre_compact)
             self.checks[
-                f"pre-gather compaction truncated rows (node {id(node)}): "
+                f"pre-gather compaction truncated rows {self.label(node)}: "
                 "local top-N emitted more than its limit"] = \
                 n > node.pre_compact
         if node.kind in ("gather", "broadcast"):
@@ -573,37 +615,32 @@ class DistLowerer(X.Lowerer):
                                      num_segments=nseg + 1)[:nseg]
         self.checks[
             f"redistribute overflow: a destination bucket exceeded capacity "
-            f"{B} (node {id(node)}); raise "
+            f"{B} {self.label(node)}; raise "
             f"config.interconnect.capacity_factor"] = (counts > B).any()
         # observed global bucket demand (replicated): the host reads it
         # after the run so an overflow promotes DIRECTLY to the capacity
         # rung that fits — one retry, not a probe up the ladder
-        self.stats[f"required bucket (node {id(node)})"] = \
+        self.stats[f"required bucket (node {self.ref(node)})"] = \
             self.tx.pmax(jnp.max(counts), SEG_AXIS)
         # per-destination GLOBAL demand (replicated vector): the same
         # psum the rung adaptation rides, promoted to skew telemetry —
         # the host derives rows-per-segment / wire-bytes-per-segment
         # skew ratios (max/mean) from it (record_motion_stats)
-        self.stats[f"seg rows (node {id(node)})"] = \
+        self.stats[f"seg rows (node {self.ref(node)})"] = \
             self.tx.psum(counts, SEG_AXIS)
 
-        order = jnp.argsort(dest)
-        sorted_dest = dest[order]
-        start = jnp.searchsorted(sorted_dest, jnp.arange(nseg))
-        rank = jnp.arange(dest.shape[0]) - start[
-            jnp.clip(sorted_dest, 0, nseg - 1)]
-        valid = (sorted_dest < nseg) & (rank < B)
-        slot = jnp.where(valid, sorted_dest * B + rank, nseg * B)
+        # rows sorted by destination, ties in position order; slot (d, r)
+        # of the send buffer takes destination d's r-th row
+        src, filled = K.bucket_slots(K.bucket_argsort(dest, nseg), counts, B)
 
         if self.packed and cols:
-            # pack once, scatter rows into their destination buckets,
-            # ship ONE (nseg, B, W) buffer; unfilled slots stay all-zero,
+            # pack once, gather rows into their destination buckets,
+            # ship ONE (nseg, B, W) buffer; unfilled slots are all-zero,
             # which unpacks as invalid — the validity mask needs no
             # separate collective
             layout = K.wire_layout({n: c.dtype for n, c in cols.items()})
             pbuf = K.pack_wire(cols, sel, layout)
-            buf = jnp.zeros((nseg * B, layout.width), dtype=jnp.uint32)
-            buf = buf.at[slot].set(pbuf[order], mode="drop")
+            buf = jnp.where(filled[:, None], pbuf[src], jnp.uint32(0))
             if self._use_hier(node):
                 # two-level exchange: intra-host re-bucket by dest host,
                 # ONE aggregated DCN hop at the host rung, intra-host
@@ -613,11 +650,11 @@ class DistLowerer(X.Lowerer):
                     buf.reshape(nseg, B, layout.width), SEG_AXIS, HB)
                 self.checks[
                     f"host bucket overflow: a host-pair block exceeded "
-                    f"capacity {HB} (node {id(node)}); the two-level "
+                    f"capacity {HB} {self.label(node)}; the two-level "
                     "retry promotes the host rung"] = (hostdem > HB).any()
                 # observed host-pair demand (replicated): the host rung
                 # ladder's one-retry promotion feed, like bucket_cap's
-                self.stats[f"required host bucket (node {id(node)})"] = \
+                self.stats[f"required host bucket (node {self.ref(node)})"] = \
                     self.tx.pmax(jnp.max(hostdem), SEG_AXIS)
             else:
                 recv = self.tx.all_to_all(
@@ -627,14 +664,11 @@ class DistLowerer(X.Lowerer):
 
         out = {}
         for name, c in cols.items():
-            buf = jnp.zeros((nseg * B,), dtype=c.dtype)
-            buf = buf.at[slot].set(c[order], mode="drop")
+            buf = jnp.where(filled, c[src], jnp.zeros((), dtype=c.dtype))
             shaped = buf.reshape(nseg, B)
             recv = self.tx.all_to_all(shaped, SEG_AXIS)
             out[name] = recv.reshape(nseg * B)
-        selbuf = jnp.zeros((nseg * B,), dtype=jnp.bool_)
-        selbuf = selbuf.at[slot].set(valid, mode="drop")
-        recv_sel = self.tx.all_to_all(selbuf.reshape(nseg, B),
+        recv_sel = self.tx.all_to_all(filled.reshape(nseg, B),
                                       SEG_AXIS)
         return out, recv_sel.reshape(nseg * B)
 
@@ -676,16 +710,18 @@ class _InstrumentedDistLowerer(DistLowerer):
         cols, sel = super().lower(node)
         cnt = jnp.sum(sel.astype(jnp.int64))
         is_seg0 = jnp.equal(jax.lax.axis_index(SEG_AXIS), 0)
-        self.stats[f"node_rows_sum (node {id(node)})"] = \
+        self.stats[f"node_rows_sum (node {self.ref(node)})"] = \
             self.tx.psum(cnt, SEG_AXIS)
-        self.stats[f"node_rows_one (node {id(node)})"] = \
+        self.stats[f"node_rows_one (node {self.ref(node)})"] = \
             self.tx.psum(jnp.where(is_seg0, cnt, 0), SEG_AXIS)
         return cols, sel
 
 
 def instrument_counts(plan: N.PlanNode, stats: dict) -> dict:
-    """Host-side per-node counts from an instrumented program's stats:
-    pick the cross-segment sum for partitioned nodes, segment 0's count
+    """Host-side per-node counts (by ``id(node)``, for this process's
+    renderers) from an instrumented program's stats, whose keys name
+    nodes by ordinal: pick the cross-segment sum for partitioned nodes,
+    segment 0's count
     for replicated ones (the same rule the legacy instrumented path
     applies to its per-seg arrays)."""
     import re
@@ -697,13 +733,12 @@ def instrument_counts(plan: N.PlanNode, stats: dict) -> dict:
             continue
         (sums if m.group(1) == "sum" else ones)[int(m.group(2))] = \
             int(np.asarray(v))
-    nodes = {id(n): n for n in X.all_nodes(plan)}
     out = {}
-    for nid, n in nodes.items():
-        if nid not in sums:
+    for o, n in enumerate(X.numbered_nodes(plan)):
+        if o not in sums:
             continue
         if n.sharding is not None and n.sharding.is_partitioned:
-            out[nid] = sums[nid]
+            out[id(n)] = sums[o]
         else:
-            out[nid] = ones.get(nid, sums[nid])
+            out[id(n)] = ones.get(o, sums[o])
     return out

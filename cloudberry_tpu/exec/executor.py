@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import functools
 import math
+import re
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -558,19 +559,36 @@ def all_nodes(plan: N.PlanNode):
         yield from all_nodes(c)
 
 
+def numbered_nodes(plan: N.PlanNode) -> list:
+    """The plan's nodes in document order (``all_nodes``), each once: a
+    node's index here is its ORDINAL, the name check and stats keys give
+    it. The plan itself fixes it, so the same statement traces to the
+    same program text in every process (the persistent compile cache
+    keys on that text, which carries the keys in ``jax.result_info``),
+    and two signature-equal plans name their nodes alike."""
+    return _dedupe_nodes(all_nodes(plan))
+
+
+_NODE_REF = re.compile(r"\(node (\d+)[):]")
+
+
+def keyed_node(plan_nodes: list, key: str):
+    """The node a check message or stats key names (``(node <ordinal>)``
+    or ``(node <ordinal>: <title>)``) among ``numbered_nodes(plan)``;
+    None when it names none."""
+    m = _NODE_REF.search(key)
+    if m is None or int(m.group(1)) >= len(plan_nodes):
+        return None
+    return plan_nodes[int(m.group(1))]
+
+
 def find_expansion_node(plan: N.PlanNode, message: str):
     """The join a detected expansion-overflow check message points at
-    (messages embed the node id), or None."""
-    import re
-
-    m = re.search(r"\(node (\d+)\)", message)
-    if m is None or "expansion overflow" not in message:
+    (messages name the node by its ordinal), or None."""
+    if "expansion overflow" not in message:
         return None
-    nid = int(m.group(1))
-    for node in all_nodes(plan):
-        if id(node) == nid and isinstance(node, N.PJoin):
-            return node
-    return None
+    node = keyed_node(numbered_nodes(plan), message)
+    return node if isinstance(node, N.PJoin) else None
 
 
 def _dedupe_nodes(nodes) -> list:
@@ -596,14 +614,15 @@ def grow_expansion(plan: N.PlanNode, message: str, factor: int = 4,
     the next CAPACITY RUNG that fits (``factor`` does not apply there —
     rung shapes are what the session's executable cache is keyed on).
 
-    ``allow_fallback``: when the message's node id resolves nowhere in
-    ``plan``, grow every candidate buffer instead of giving up. Only
-    the statement retry loop sets this — there an unresolvable id means
-    the program came from a rung-cached executable of an equivalent,
-    since-collected plan, and blanket growth is padding at worst with
-    guaranteed progress. Tiled callers keep it off: their id miss means
-    the overflowing node is genuinely outside the plan at hand, and the
-    original error must surface, not a mutated retry."""
+    ``allow_fallback``: when the message's node ordinal resolves to no
+    candidate of ``plan``, grow every candidate buffer instead of giving
+    up. Only the statement retry loop sets this — blanket growth is
+    padding at worst with guaranteed progress. (A program served from
+    the rung cache was traced off a signature-equal plan, whose
+    ordinals are this plan's: the fallback is a net, not the route.)
+    Tiled callers keep it off: their miss means the overflowing node is
+    genuinely outside the plan at hand, and the original error must
+    surface, not a mutated retry."""
     from cloudberry_tpu.lifecycle import check_cancel
 
     # cancel seam: each grow-and-retry round recompiles and re-runs the
@@ -625,15 +644,11 @@ def grow_expansion(plan: N.PlanNode, message: str, factor: int = 4,
             # shrink a runtime-grown buffer back below what overflowed
             nd._min_out_cap = nd.out_capacity
         return True
+    named = keyed_node(numbered_nodes(plan), message)
     if "host bucket overflow" in message:
-        import re
-
-        m = re.search(r"\(node (\d+)\)", message)
-        nid = int(m.group(1)) if m is not None else -1
-        hits = _dedupe_nodes(
-            nd for nd in all_nodes(plan)
-            if isinstance(nd, N.PMotion) and nd.kind == "redistribute"
-            and nd.host_bucket_cap > 0 and id(nd) == nid)
+        hits = [named] if isinstance(named, N.PMotion) \
+            and named.kind == "redistribute" \
+            and named.host_bucket_cap > 0 else []
         if not hits and allow_fallback:
             hits = _dedupe_nodes(
                 nd for nd in all_nodes(plan)
@@ -650,22 +665,14 @@ def grow_expansion(plan: N.PlanNode, message: str, factor: int = 4,
             # only), so the promoted rung cannot be shrunk back
         return bool(hits)
     if "redistribute overflow" in message:
-        import re
-
-        m = re.search(r"\(node (\d+)\)", message)
-        nid = int(m.group(1)) if m is not None else -1
-        # kind filter matters: a stale id from a rung-cached executable
-        # (compiled off an equivalent, since-collected plan) could alias
-        # ANY current node's address — never promote a gather/broadcast
-        hits = _dedupe_nodes(
-            nd for nd in all_nodes(plan)
-            if isinstance(nd, N.PMotion)
-            and nd.kind == "redistribute" and id(nd) == nid)
+        # kind filter matters: an ordinal from a program traced off
+        # another plan shape must never promote a gather/broadcast
+        hits = [named] if isinstance(named, N.PMotion) \
+            and named.kind == "redistribute" else []
         if not hits and allow_fallback:
-            # the failing program was compiled from an EQUIVALENT plan
-            # (rung-cache hit across a replan), so the embedded node id
-            # does not resolve here: promote every redistribute — extra
-            # padding at worst, and the retry is guaranteed progress
+            # the ordinal names no redistribute of THIS plan: promote
+            # every one — extra padding at worst, and the retry is
+            # guaranteed progress
             hits = _dedupe_nodes(
                 nd for nd in all_nodes(plan)
                 if isinstance(nd, N.PMotion)
@@ -720,8 +727,15 @@ class Lowerer:
     which overrides scan (per-segment inputs) and motion (collectives)."""
 
     def __init__(self, tables, platform: str | None = None,
-                 use_pallas: bool = False, params=None):
+                 use_pallas: bool = False, params=None, root=None):
         self.tables = tables
+        # id(node) -> ordinal under ``root`` (numbered_nodes): how check
+        # and stats keys name a node. Without a ``root`` the first plan
+        # lowered is it; callers that lower a SUBTREE first (tiled
+        # preludes) and look the node up in the whole plan pass it.
+        self._ordinals: dict[int, int] = {}
+        if root is not None:
+            self._number(root)
         # runtime literal bindings for a generic plan (sched/paramplan.py):
         # "$prm<slot>" -> scalar array, injected next to the columns when
         # an expression carries Param leaves
@@ -741,7 +755,26 @@ class Lowerer:
         self.dense_strategy = "segment" if platform == "cpu" else "reduce"
         self.use_pallas = use_pallas
 
+    def _number(self, plan: N.PlanNode) -> None:
+        for n in all_nodes(plan):
+            self._ordinals.setdefault(id(n), len(self._ordinals))
+
+    def ref(self, node: N.PlanNode) -> int:
+        """The node's ordinal: its name in check and stats keys. A node
+        outside the numbered root (a finalize chain beside a partial
+        plan) is numbered after it, so it names no node of the root."""
+        if id(node) not in self._ordinals:
+            self._number(node)
+        return self._ordinals[id(node)]
+
+    def label(self, node: N.PlanNode) -> str:
+        """``(node <ordinal>: <title>)`` — a check message's reference,
+        for the retry (the ordinal) and for a person (the title)."""
+        return f"(node {self.ref(node)}: {node.title()})"
+
     def lower(self, node: N.PlanNode) -> tuple[dict, jnp.ndarray]:
+        if not self._ordinals:
+            self._number(node)
         if isinstance(node, N.PScan):
             return self.scan(node)
         if isinstance(node, N.PFilter):
@@ -765,7 +798,13 @@ class Lowerer:
                 keys.append(_as_column(_sortable(e, node.child, cols),
                                        sel.shape[0]))
                 desc.append(not asc)
-            perm = K.sort_indices(keys, sel, descending=desc)
+            if node.pack_bits:
+                packed = K.pack_keys(keys, sel, descending=desc)
+                perm = K.sort_indices(
+                    [K.downcast32(packed) if node.pack_bits == 32
+                     else packed], sel)
+            else:
+                perm = K.sort_indices(keys, sel, descending=desc)
             return {n: c[perm] for n, c in cols.items()}, sel[perm]
         if isinstance(node, N.PLimit):
             cols, sel = self.lower(node.child)
@@ -867,7 +906,7 @@ class Lowerer:
                     arr = scols[sq.plan.fields[0].name]
                     self.checks[
                         f"scalar subquery returned more than one row "
-                        f"(node {key})"] = n > 1
+                        f"{self.label(sq.plan)}"] = n > 1
                     # 0 selected rows: argmax lands on row 0, whose value
                     # is arbitrary — the binder's presence term masks the
                     # result NULL, so it is never observed
@@ -951,7 +990,7 @@ class Lowerer:
             # weaker — it fires only when a probe row actually HITS the
             # duplicated key, i.e. exactly when results would be wrong
             self.checks[
-                f"join build side has duplicate keys (node {id(node)}) but "
+                f"join build side has duplicate keys {self.label(node)} but "
                 "the planner assumed a unique (PK) build side"] = has_dup
         cols = {**pcols, **payload}
         if node.match_name:
@@ -990,7 +1029,7 @@ class Lowerer:
         desc = [not asc for _, asc in node.order_keys]
         perm = K.sort_indices(pk + ok, sel,
                               descending=[False] * len(pk) + desc)
-        inv = jnp.argsort(perm)
+        inv = K.inverse_permutation(perm)
         s_sel = sel[perm]
         n_sel = jnp.sum(s_sel.astype(jnp.int32))
         idx = jnp.arange(cap)
@@ -1006,16 +1045,16 @@ class Lowerer:
             (jnp.zeros(cap, dtype=jnp.bool_).at[0].set(True) & s_sel)
         run_flag = (seg_flag | flags(ok)) if ok else seg_flag
 
-        seg_starts_c = jnp.argsort(~seg_flag, stable=True)
-        seg_cum = jnp.cumsum(seg_flag.astype(jnp.int32))
+        seg_starts_c = K.flagged_first(seg_flag)
+        seg_cum = K.prefix_sum(seg_flag.astype(jnp.int32))
         seg_id0 = jnp.clip(seg_cum - 1, 0, cap - 1)
         n_segs = jnp.sum(seg_flag.astype(jnp.int32))
         seg_start = seg_starts_c[seg_id0]
         nxt = seg_starts_c[jnp.clip(seg_id0 + 1, 0, cap - 1)]
         seg_end = jnp.where(seg_id0 + 1 < n_segs, nxt - 1, n_sel - 1)
 
-        run_starts_c = jnp.argsort(~run_flag, stable=True)
-        run_cum = jnp.cumsum(run_flag.astype(jnp.int32))
+        run_starts_c = K.flagged_first(run_flag)
+        run_cum = K.prefix_sum(run_flag.astype(jnp.int32))
         run_id0 = jnp.clip(run_cum - 1, 0, cap - 1)
         n_runs = jnp.sum(run_flag.astype(jnp.int32))
         rnxt = run_starts_c[jnp.clip(run_id0 + 1, 0, cap - 1)]
@@ -1023,7 +1062,7 @@ class Lowerer:
         run_end = jnp.where(run_id0 + 1 < n_runs, rnxt - 1, n_sel - 1)
 
         def pref(vals):
-            csum = jnp.cumsum(vals)
+            csum = K.prefix_sum(vals)
             return jnp.concatenate(
                 [jnp.zeros((1,), dtype=csum.dtype), csum])
 
@@ -1272,7 +1311,7 @@ class Lowerer:
             node, bkeys, bselm, pkeys, pselm, cap)
         self.checks[
             f"semi-join expansion overflow: match pairs exceed capacity "
-            f"{cap} (node {id(node)})"] = total > cap
+            f"{cap} {self.label(node)}"] = total > cap
         paircols = {name: jnp.take(c, pi, axis=0) for name, c in pcols.items()}
         for name in node.build_payload:
             paircols[name] = jnp.take(bcols[name], bi, axis=0)
@@ -1309,7 +1348,7 @@ class Lowerer:
         probe_valid = osel  # rows whose probe columns are real
         if node.kind in ("left", "full"):
             um = psel & ~matched
-            um_rank = jnp.cumsum(um.astype(total.dtype)) - 1
+            um_rank = K.prefix_sum(um.astype(total.dtype)) - 1
             n_um = jnp.sum(um.astype(total.dtype))
             slot = jnp.where(um, total + um_rank, cap)
             pi = pi.at[slot].set(jnp.arange(um.shape[0], dtype=pi.dtype),
@@ -1322,7 +1361,7 @@ class Lowerer:
                 bmatched = jnp.zeros(bsel.shape, dtype=jnp.bool_)
                 bmatched = bmatched.at[bi].max(is_pair, mode="drop")
                 um_b = bsel & ~bmatched
-                umb_rank = jnp.cumsum(um_b.astype(total.dtype)) - 1
+                umb_rank = K.prefix_sum(um_b.astype(total.dtype)) - 1
                 n_umb = jnp.sum(um_b.astype(total.dtype))
                 slot_b = jnp.where(um_b, total + n_um + umb_rank, cap)
                 bi = bi.at[slot_b].set(
@@ -1336,7 +1375,7 @@ class Lowerer:
             raise ExecError(f"expansion join does not support {node.kind}")
         self.checks[
             f"join expansion overflow: match pairs exceed capacity {cap} "
-            f"(node {id(node)})"] = need > cap
+            f"{self.label(node)}"] = need > cap
 
         cols = {}
         for name, c in pcols.items():
@@ -1391,10 +1430,10 @@ class Lowerer:
                     for name, e in node.group_keys}
         out_keys, out_aggs, out_sel, n_groups = merge_group_aggregate(
             key_cols, agg_values, agg_specs, sel, node.capacity,
-            self.use_pallas, self.platform)
+            self.use_pallas, self.platform, pack_bits=node.pack_bits)
         self.checks[
             f"aggregation overflow: more groups than capacity "
-            f"{node.capacity} (node {id(node)})"] = n_groups > node.capacity
+            f"{node.capacity} {self.label(node)}"] = n_groups > node.capacity
         for name, div in post_scale.items():
             out_aggs[name] = out_aggs[name] / div
         return {**out_keys, **out_aggs}, out_sel
@@ -1563,7 +1602,8 @@ class Lowerer:
 
 
 def merge_group_aggregate(key_cols, agg_values, specs, sel, capacity: int,
-                          use_pallas: bool, platform: str):
+                          use_pallas: bool, platform: str,
+                          pack_bits: int = 0):
     """Grouped-aggregation dispatch shared by the one-shot Lowerer and
     the tiled/tiled-dist merge steps: the fused sorted-segment Pallas
     kernel when eligible (sum/avg over integer-carried values + count,
@@ -1579,7 +1619,8 @@ def merge_group_aggregate(key_cols, agg_values, specs, sel, capacity: int,
             return PK.sorted_segment_aggregate(
                 key_cols, agg_values, specs, sel, capacity,
                 interpret=(platform == "cpu"))
-    return K.group_aggregate(key_cols, agg_values, specs, sel, capacity)
+    return K.group_aggregate(key_cols, agg_values, specs, sel, capacity,
+                             pack_bits=pack_bits)
 
 
 def _sortable(e: ex.Expr, child: N.PlanNode, cols) -> jnp.ndarray:

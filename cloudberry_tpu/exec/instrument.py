@@ -344,12 +344,23 @@ class InstrumentingMixin:
     """Mixes into a Lowerer: records post-node selected-row counts."""
 
     def __init_instrument__(self):
+        # by the node's ORDINAL (Lowerer.ref): the counts are a program
+        # output, and an output's key is part of the program's text
         self.node_counts: dict[int, jnp.ndarray] = {}
 
     def lower(self, node):  # type: ignore[override]
         cols, sel = super().lower(node)  # type: ignore[misc]
-        self.node_counts[id(node)] = jnp.sum(sel.astype(jnp.int64))
+        self.node_counts[self.ref(node)] = jnp.sum(sel.astype(jnp.int64))
         return cols, sel
+
+
+def counts_by_node(plan: N.PlanNode, counts: dict) -> dict:
+    """An instrumented program's counts, keyed by ordinal, as this
+    process's renderers key them: by ``id(node)``."""
+    from cloudberry_tpu.exec.executor import numbered_nodes
+
+    nodes = numbered_nodes(plan)
+    return {id(nodes[k]): v for k, v in counts.items() if k < len(nodes)}
 
 
 def plan_nodes_in_order(plan: N.PlanNode) -> list[N.PlanNode]:
@@ -562,7 +573,8 @@ def run_instrumented(plan: N.PlanNode, session, query: str = ""):
     X.raise_checks(checks)
     batch = X.make_batch(plan, cols, sel)
 
-    counts_host = {k: int(np.asarray(v)) for k, v in counts.items()}
+    counts_host = {k: int(np.asarray(v))
+                   for k, v in counts_by_node(plan, counts).items()}
     metrics = _metrics(plan, counts_host, query, wall_s, compile_s,
                        int(np.asarray(sel).sum()))
     _emit(session, metrics)
@@ -634,6 +646,7 @@ def _dist_counts_host(plan, counts) -> dict:
     """Per-seg count arrays → one number per node: partitioned nodes sum
     across segments, replicated nodes count once (segment 0)."""
     counts_host = {}
+    counts = counts_by_node(plan, counts)
     for n in plan_nodes_in_order(plan):
         arr = counts.get(id(n))
         if arr is None:
@@ -783,8 +796,8 @@ def _pipeline_once(plan, session, query):
                 _timed_compile_run(exe.fn, inputs, log=session.stmt_log)
             X.raise_checks(checks)
             batch = X.make_batch(plan, cols, sel)
-            counts_host = {k: int(np.asarray(v))
-                           for k, v in counts.items()}
+            counts_host = {k: int(np.asarray(v)) for k, v in
+                           counts_by_node(plan, counts).items()}
             rows_out = int(np.asarray(sel).sum())
     metrics = _metrics(plan, counts_host, query, wall_s, compile_s,
                        rows_out)

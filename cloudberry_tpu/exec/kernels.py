@@ -80,6 +80,7 @@ def key_ranges(
 def pack_with_ranges(
     keys: Sequence[jnp.ndarray],
     ranges: Sequence[tuple[jnp.ndarray, jnp.ndarray]],
+    descending: Sequence[bool] | None = None,
 ) -> jnp.ndarray:
     """Pack key columns into ONE order-preserving uint64 using given ranges.
 
@@ -90,17 +91,22 @@ def pack_with_ranges(
     """
     packed = jnp.zeros(keys[0].shape, dtype=jnp.uint64)
     oob = jnp.zeros(keys[0].shape, dtype=jnp.bool_)
-    for k, (lo, span) in zip(keys, ranges):
+    desc = descending if descending is not None else [False] * len(keys)
+    for k, (lo, span), d in zip(keys, ranges, desc):
         u = sort_key_u64(k)
         oob = oob | (u < lo) | (u - lo >= span)
-        packed = packed * span + jnp.clip(u - lo, jnp.uint64(0), span - jnp.uint64(1))
+        digit = jnp.clip(u - lo, jnp.uint64(0), span - jnp.uint64(1))
+        # (a descending key counts down from its range's top)
+        packed = packed * span + (span - jnp.uint64(1) - digit if d
+                                  else digit)
     return jnp.where(oob, _U64_MAX, packed)
 
 
-def pack_keys(keys: Sequence[jnp.ndarray], sel: jnp.ndarray) -> jnp.ndarray:
+def pack_keys(keys: Sequence[jnp.ndarray], sel: jnp.ndarray,
+              descending: Sequence[bool] | None = None) -> jnp.ndarray:
     """Pack multiple key columns of one batch into order-preserving uint64
     (selected rows are in-range by construction; others → sentinel)."""
-    return pack_with_ranges(keys, key_ranges(keys, sel))
+    return pack_with_ranges(keys, key_ranges(keys, sel), descending)
 
 
 _U32_MAX = jnp.uint32(0xFFFFFFFF)
@@ -116,23 +122,173 @@ def downcast32(packed: jnp.ndarray) -> jnp.ndarray:
                      packed.astype(jnp.uint32))
 
 
+# --------------------------------------------------------------------------
+# sorts and prefix sums. The TPU compiler's time for ONE sort grows with
+# the words its comparator reads, hardly with the rows: at 1.5M rows an
+# unstable sort of one u32 takes it 4 s, of two key words 19 s, three 37 s,
+# five 100 s, seven 172 s; a STABLE sort pays for a hidden position key
+# besides, and payload operands ~15 s a word (sandbox, PR 28, v5e:2x2 ahead
+# of time; jnp.lexsort of (flag, u64, u64, u64) with its int64 index: 477
+# s). So every argsort here is one UNSTABLE ``lax.sort`` whose operands are
+# all keys, as few 32-bit words as the dtypes allow, the row's position the
+# last of them:
+# the order is the stable one, and the sorted positions are the answer.
+# --------------------------------------------------------------------------
+
+
+def prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """``jnp.cumsum`` of a 1-D integer array, as shifted adds: inside
+    blocks of 128 (seven steps of add-what-lies-s-to-the-left), then the
+    blocks' totals the same way. Integer sums wrap, so the order of the
+    additions does not show. The TPU compiler takes 12 s over one int64
+    cumsum of 1.5M rows and 31 s over an int32 one, one after the other
+    (a join with a grouped aggregate and a limit has four); these adds
+    take it 1.4 s (sandbox, PR 28). Floats keep ``jnp.cumsum``: there
+    the order of the additions is the answer's last bits."""
+    if x.ndim != 1 or not (jnp.issubdtype(x.dtype, jnp.integer)
+                           or x.dtype == jnp.bool_):
+        return jnp.cumsum(x)
+    if x.dtype == jnp.bool_:
+        x = x.astype(int)           # as cumsum counts flags
+
+    def shifted_adds(m, axis):
+        n, s = m.shape[axis], 1
+        while s < n:
+            pad = [(0, 0)] * m.ndim
+            pad[axis] = (s, 0)
+            keep = [slice(None)] * m.ndim
+            keep[axis] = slice(0, n - s)
+            m = m + jnp.pad(m[tuple(keep)], pad)
+            s *= 2
+        return m
+
+    n, block = x.shape[0], 128
+    if n <= block:
+        return shifted_adds(x, 0)
+    rows = jnp.pad(x, (0, -n % block)).reshape(-1, block)
+    inner = shifted_adds(rows, 1)
+    totals = inner[:, -1]
+    before = prefix_sum(totals) - totals
+    return (inner + before[:, None]).reshape(-1)[:n]
+
+
+def sort_key_word(col: jnp.ndarray) -> jnp.ndarray:
+    """``sort_key_u64`` in the narrowest unsigned word that holds the
+    column: u32 for anything up to 32 bits wide."""
+    if col.dtype == jnp.bool_:
+        return col.astype(jnp.uint32)
+    if col.dtype == jnp.float32:
+        bits = col.view(jnp.uint32)
+        mask = jnp.where(bits >> jnp.uint32(31) != 0,
+                         jnp.uint32(0xFFFFFFFF), jnp.uint32(1) << jnp.uint32(31))
+        return bits ^ mask
+    if jnp.issubdtype(col.dtype, jnp.unsignedinteger) \
+            and col.dtype.itemsize <= 4:
+        return col.astype(jnp.uint32)
+    if jnp.issubdtype(col.dtype, jnp.signedinteger) \
+            and col.dtype.itemsize <= 4:
+        return col.astype(jnp.int32).view(jnp.uint32) \
+            ^ (jnp.uint32(1) << jnp.uint32(31))
+    return sort_key_u64(col)
+
+
+def _word_max(dt) -> jnp.ndarray:
+    return _U32_MAX if dt == jnp.uint32 else _U64_MAX
+
+
+def _positions(n: int, spare: int = 1) -> jnp.ndarray:
+    """0..n-1 as one u32 word (``spare`` × n must fit it too)."""
+    if spare * n > 1 << 32:
+        raise ValueError(f"{n} rows: positions no longer fit one word")
+    return jax.lax.iota(jnp.uint32, n)
+
+
+def stable_argsort(*words: jnp.ndarray) -> jnp.ndarray:
+    """Stable ascending argsort by unsigned ``words`` (most significant
+    first)."""
+    pos = _positions(words[0].shape[0])
+    out = jax.lax.sort(tuple(words) + (pos,), num_keys=len(words) + 1,
+                       is_stable=False)
+    return out[-1].astype(jnp.int64)
+
+
+def flagged_first(flag: jnp.ndarray) -> jnp.ndarray:
+    """Positions of the flagged rows in order, then the others' in order
+    (``argsort(~flag, stable=True)``): a sort of one word, the position
+    moved past every flagged row's where the flag is down."""
+    n = flag.shape[0]
+    pos = _positions(n, spare=2)
+    t = jax.lax.sort(pos + jnp.where(flag, jnp.uint32(0), jnp.uint32(n)),
+                     is_stable=False)
+    return (t - jnp.where(t >= n, jnp.uint32(n), jnp.uint32(0))) \
+        .astype(jnp.int64)
+
+
+def bucket_argsort(bucket: jnp.ndarray, n_buckets: int) -> jnp.ndarray:
+    """Stable argsort of bucket numbers in 0..``n_buckets`` (the last one
+    holds what is dropped): one word, bucket * rows + position, where
+    that fits 32 bits."""
+    n = bucket.shape[0]
+    b = bucket.astype(jnp.uint32)
+    if (n_buckets + 1) * n > 1 << 32:
+        return stable_argsort(b)
+    t = jax.lax.sort(b * jnp.uint32(n) + _positions(n), is_stable=False)
+    return (t % jnp.uint32(n)).astype(jnp.int64)
+
+
+def bucket_slots(order: jnp.ndarray, counts: jnp.ndarray,
+                 cap: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """For every slot of an (n_buckets, cap) buffer, the row that fills
+    it and whether one does. ``order`` sorts the rows by bucket, ties in
+    position order (``bucket_argsort``), and ``counts[b]`` rows go to
+    bucket b, so bucket b's rows are sorted rows start[b].. and slot
+    (b, r) takes sorted row start[b] + r while r < counts[b]; rows past
+    ``cap`` find no slot. A gather of n_buckets * cap rows where the
+    scatter it replaces moved every input row: the TPU serializes a
+    scatter, and its compiler takes half a minute over one of 1.5M rows
+    (sandbox, PR 28)."""
+    n_buckets = counts.shape[0]
+    start = jnp.cumsum(counts) - counts
+    j = jnp.arange(n_buckets * cap)
+    b, r = j // cap, j % cap
+    filled = r < counts[b]
+    src = order[jnp.clip(start[b] + r, 0, order.shape[0] - 1)]
+    return src, filled
+
+
+def inverse_permutation(perm: jnp.ndarray) -> jnp.ndarray:
+    """``argsort(perm)`` of a permutation: its values are distinct, so
+    the position rides as a payload and no tie needs an order."""
+    _, pos = jax.lax.sort((perm.astype(jnp.uint32),
+                           _positions(perm.shape[0])),
+                          num_keys=1, is_stable=False)
+    return pos.astype(jnp.int64)
+
+
 def sort_indices(
     keys: Sequence[jnp.ndarray],
     sel: jnp.ndarray,
     descending: Sequence[bool] | None = None,
 ) -> jnp.ndarray:
-    """Permutation putting selected rows first, ordered by keys (lexsort).
+    """Permutation putting selected rows first, ordered by keys, ties in
+    position order; the unselected rows follow in position order.
 
-    keys[0] is the PRIMARY key (SQL ORDER BY first column)."""
+    keys[0] is the PRIMARY key (SQL ORDER BY first column). An unselected
+    row carries the largest word in every key and its position moved past
+    every selected row's, so it sorts behind a selected row even where
+    that row's keys are all the largest too: no flag operand."""
     n = sel.shape[0]
     desc = list(descending) if descending is not None else [False] * len(keys)
-    cols = []
+    words = []
     for k, d in zip(keys, desc):
-        u = sort_key_u64(k)
-        cols.append(~u if d else u)
-    # lexsort: LAST key is primary ⇒ reverse; unselected rows go last.
-    order = jnp.lexsort(tuple(reversed(cols)) + (~sel,))
-    return order
+        u = sort_key_word(k)
+        words.append(jnp.where(sel, ~u if d else u, _word_max(u.dtype)))
+    tie = _positions(n, spare=2) \
+        + jnp.where(sel, jnp.uint32(0), jnp.uint32(n))
+    t = jax.lax.sort(tuple(words) + (tie,), num_keys=len(words) + 1,
+                     is_stable=False)[-1]
+    return (t - jnp.where(t >= n, jnp.uint32(n), jnp.uint32(0))) \
+        .astype(jnp.int64)
 
 
 # --------------------------------------------------------------------------
@@ -170,10 +326,18 @@ class GroupLayout:
 
 
 def group_layout(key_cols: Columns, sel: jnp.ndarray,
-                 out_capacity: int) -> GroupLayout:
+                 out_capacity: int, pack_bits: int = 0) -> GroupLayout:
+    """``pack_bits`` 32 or 64: the planner's proof (PAgg.pack_bits) that
+    the keys of the selected rows pack into one word that wide; the sort
+    then compares that word."""
     names = list(key_cols)
     key_list = [key_cols[n] for n in names]
-    perm = sort_indices(key_list, sel)
+    if pack_bits:
+        packed = pack_keys(key_list, sel)
+        perm = sort_indices(
+            [downcast32(packed) if pack_bits == 32 else packed], sel)
+    else:
+        perm = sort_indices(key_list, sel)
     s_sel = sel[perm]
     s_keys = {n: key_cols[n][perm] for n in names}
 
@@ -188,7 +352,7 @@ def group_layout(key_cols: Columns, sel: jnp.ndarray,
     n_sel = jnp.sum(s_sel.astype(jnp.int32))
 
     # boundary positions compact to the front via a stable bool argsort
-    starts_all = jnp.argsort(~new_grp, stable=True)
+    starts_all = flagged_first(new_grp)
     g = jnp.arange(out_capacity)
     starts = starts_all[jnp.clip(g, 0, starts_all.shape[0] - 1)]
     next_start = starts_all[jnp.clip(g + 1, 0, starts_all.shape[0] - 1)]
@@ -211,6 +375,7 @@ def group_aggregate(
     aggs: Sequence[AggSpec],
     sel: jnp.ndarray,
     out_capacity: int,
+    pack_bits: int = 0,
 ) -> tuple[Columns, Columns, jnp.ndarray, jnp.ndarray]:
     """Sort-based grouped aggregation (nodeAgg.c analog).
 
@@ -226,7 +391,7 @@ def group_aggregate(
     per-group aggregate is a cumulative-sum DIFFERENCE between consecutive
     group boundaries — pure sort/scan/gather, the VPU formulation.
     """
-    lay = group_layout(key_cols, sel, out_capacity)
+    lay = group_layout(key_cols, sel, out_capacity, pack_bits)
     names, key_list = lay.names, [key_cols[n] for n in lay.names]
     perm, s_sel = lay.perm, lay.s_sel
     n_groups, n_sel = lay.n_groups, lay.n_sel
@@ -234,7 +399,7 @@ def group_aggregate(
     out_keys = lay.out_keys
 
     def seg_sum(vals):
-        csum = jnp.cumsum(vals)
+        csum = prefix_sum(vals)
         c0 = jnp.concatenate([jnp.zeros((1,), dtype=csum.dtype), csum])
         return jnp.where(valid, c0[ends + 1] - c0[starts], 0)
 
@@ -443,7 +608,7 @@ def build_sort(
     if bits == 32:
         kb = downcast32(kb)
     kb_masked = jnp.where(build_sel, kb, big)
-    order = jnp.argsort(kb_masked)
+    order = stable_argsort(kb_masked)
     return order, kb_masked[order], ranges
 
 
@@ -565,7 +730,7 @@ def join_expand_sorted(
     cnt = jnp.where(ok, (end - start).astype(jnp.int64), jnp.int64(0))
     matched = cnt > 0
 
-    offsets = jnp.cumsum(cnt)
+    offsets = prefix_sum(cnt)
     total = offsets[-1] if cnt.shape[0] else jnp.asarray(0, jnp.int64)
     j = jnp.arange(out_capacity, dtype=jnp.int64)
     # probe row for output slot j: first i with offsets[i] > j
@@ -799,19 +964,13 @@ def wire_rebucket(rows: jnp.ndarray, key: jnp.ndarray,
     rows past ``cap`` are DROPPED FROM THE BUFFER but counted, so the
     caller's overflow check (demand > cap) fires before any result
     could ship; the capacity-ladder retry then promotes the rung.
-    Same slot-scatter discipline as the redistribute lowering."""
-    n = rows.shape[0]
+    Same slot discipline as the redistribute lowering (``bucket_slots``)."""
     k = jnp.where(valid, key, n_buckets)
     counts = jax.ops.segment_sum(valid.astype(jnp.int32), k,
                                  num_segments=n_buckets + 1)[:n_buckets]
-    order = jnp.argsort(k)          # stable: ties keep position order
-    sorted_k = k[order]
-    start = jnp.searchsorted(sorted_k, jnp.arange(n_buckets))
-    rank = jnp.arange(n) - start[jnp.clip(sorted_k, 0, n_buckets - 1)]
-    ok = (sorted_k < n_buckets) & (rank < cap)
-    slot = jnp.where(ok, sorted_k * cap + rank, n_buckets * cap)
-    out = jnp.zeros((n_buckets * cap, rows.shape[1]), dtype=rows.dtype)
-    out = out.at[slot].set(rows[order], mode="drop")
+    src, filled = bucket_slots(bucket_argsort(k, n_buckets), counts, cap)
+    out = jnp.where(filled[:, None], rows[src],
+                    jnp.zeros((), dtype=rows.dtype))
     return out.reshape(n_buckets, cap, rows.shape[1]), counts
 
 
@@ -825,6 +984,19 @@ def rung_up(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
+def shard_rung_up(n: int) -> int:
+    """Round a shard's row capacity up to its rung: the next multiple of
+    a 64th of the power of two above it (at most 3.1 % of padding, none
+    up to 64 rows). Every shape of a distributed program follows from its
+    scans' shard capacities, and the persistent compile cache keys on the
+    shapes: a table reloaded with a few thousand rows more or fewer (a
+    new ``--seed`` of a benchmark, a day's appends) then meets the program
+    the last process compiled instead of a multi-minute compile."""
+    n = max(int(n), 1)
+    step = 1 << max((n - 1).bit_length() - 6, 0)
+    return -(-n // step) * step
+
+
 # --------------------------------------------------------------------------
 # misc
 # --------------------------------------------------------------------------
@@ -832,7 +1004,7 @@ def rung_up(n: int) -> int:
 
 def limit_mask(sel: jnp.ndarray, k: int, offset: int = 0) -> jnp.ndarray:
     """Keep rows offset..offset+k of the SELECTED sequence (post-sort)."""
-    rank = jnp.cumsum(sel.astype(jnp.int64)) - 1
+    rank = prefix_sum(sel.astype(jnp.int64)) - 1
     return sel & (rank >= offset) & (rank < offset + k)
 
 
@@ -847,7 +1019,6 @@ def compact(
     against ``capacity`` post-run — rows beyond capacity are truncated, which
     is an error to surface, never silence."""
     n_selected = jnp.sum(sel.astype(jnp.int64))
-    idx = sort_indices([jnp.zeros_like(sel, dtype=jnp.int32)], sel)
-    idx = idx[:capacity]
+    idx = flagged_first(sel)[:capacity]
     out = {n: c[idx] for n, c in cols.items()}
     return out, sel[idx], n_selected

@@ -1003,7 +1003,8 @@ class TiledExecutable(AdaptiveTiledMixin):
         plat, pallas = self._platform, self._use_pallas
 
         def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, use_pallas=pallas)
+            low = X.Lowerer(tables, platform=plat, use_pallas=pallas,
+                            root=shape.partial_plan)
             outs = [low.lower_shared(b) for b in shape.builds]
             return outs, low.checks
 
@@ -1048,7 +1049,8 @@ class TiledExecutable(AdaptiveTiledMixin):
             acc_cols, acc_sel = acc
             low = _ReplacingLowerer(
                 {}, {id(_leaf_of(shape.root)): (acc_cols, acc_sel)},
-                platform=plat, use_pallas=pallas)
+                platform=plat, use_pallas=pallas,
+                root=shape.partial_plan)
             cols, sel = low.lower(shape.root)
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, sel, low.checks
@@ -1231,7 +1233,8 @@ class TopNTiledExecutable(TiledExecutable):
         names = [f.name for f in shape.partial_plan.fields]
 
         def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, use_pallas=pallas)
+            low = X.Lowerer(tables, platform=plat, use_pallas=pallas,
+                            root=shape.partial_plan)
             outs = [low.lower_shared(b) for b in shape.builds]
             return outs, low.checks
 
@@ -1249,7 +1252,8 @@ class TopNTiledExecutable(TiledExecutable):
                      for n in names}
             csel = jnp.concatenate([acc_sel, psel])
             low2 = _ReplacingLowerer({}, {id(mleaf): (ccols, csel)},
-                                     platform=plat, use_pallas=pallas)
+                                     platform=plat, use_pallas=pallas,
+                                     root=shape.partial_plan)
             scols, ssel = low2.lower(msort)
             checks.update(low2.checks)
             return ({n: scols[n][:m] for n in names}, ssel[:m]), checks
@@ -1258,7 +1262,8 @@ class TopNTiledExecutable(TiledExecutable):
             acc_cols, acc_sel = acc
             low = _ReplacingLowerer(
                 {}, {id(_leaf_of(shape.root)): (acc_cols, acc_sel)},
-                platform=plat, use_pallas=pallas)
+                platform=plat, use_pallas=pallas,
+                root=shape.partial_plan)
             cols, sel = low.lower(shape.root)
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, sel, low.checks
@@ -1321,7 +1326,8 @@ class SortTiledExecutable(TiledExecutable):
         names = [f.name for f in sort.child.fields]
 
         def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, use_pallas=pallas)
+            low = X.Lowerer(tables, platform=plat, use_pallas=pallas,
+                            root=shape.partial_plan)
             outs = [low.lower_shared(b) for b in shape.builds]
             return outs, low.checks
 
@@ -1473,7 +1479,8 @@ class WindowTiledExecutable(SortTiledExecutable):
             sel = jnp.arange(cap) < n_valid
             low = _ReplacingLowerer(
                 {}, {id(win.child): (chunk_cols, sel)},
-                platform=plat, use_pallas=pallas)
+                platform=plat, use_pallas=pallas,
+                root=shape.partial_plan)
             cols, osel = low.lower(shape.root)
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, osel, low.checks

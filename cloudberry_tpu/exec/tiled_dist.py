@@ -537,9 +537,9 @@ def _motion_stats(low, motions, nseg: int):
     (exec/tiled.py SkewSentinel) accumulates these host-side across
     tiles; the end-of-run fold publishes them to the feedback store."""
     return tuple(
-        (low.stats.get(f"required bucket (node {id(m)})",
+        (low.stats.get(f"required bucket (node {low.ref(m)})",
                        jnp.zeros((), jnp.int32)),
-         low.stats.get(f"seg rows (node {id(m)})",
+         low.stats.get(f"seg rows (node {low.ref(m)})",
                        jnp.zeros((nseg,), jnp.int32)))
         for m in motions)
 
@@ -642,7 +642,8 @@ class DistTiledExecutable(AdaptiveTiledMixin):
 
         def prelude_seg(tables):
             low = DistLowerer(tables, nseg, use_pallas=self._use_pallas,
-                              tx=tx, packed=self._packed)
+                              tx=tx, packed=self._packed,
+                              root=shape.partial_plan)
             outs = [_add_seg(low.lower_shared(b)) for b in shape.builds]
             return outs, _reduce_checks(low.checks)
 
@@ -655,7 +656,8 @@ class DistTiledExecutable(AdaptiveTiledMixin):
             acc_cols, acc_sel = _strip_seg(tuple(acc))
             low = _DistReplacingLowerer(
                 {}, nseg, {id(shape.replace_node): (acc_cols, acc_sel)},
-                use_pallas=self._use_pallas, tx=tx, packed=self._packed)
+                use_pallas=self._use_pallas, tx=tx, packed=self._packed,
+                root=shape.partial_plan)
             cols, sel = low.lower(shape.root)
             out = {f.name: cols[f.name][None] for f in shape.root.fields}
             return out, sel[None], _reduce_checks(low.checks)
@@ -971,7 +973,8 @@ class DistTopNTiledExecutable(DistTiledExecutable):
             csel = jnp.concatenate([acc_sel, psel])
             low2 = _DistReplacingLowerer(
                 {}, nseg, {id(mleaf): (ccols, csel)},
-                use_pallas=self._use_pallas, tx=tx, packed=self._packed)
+                use_pallas=self._use_pallas, tx=tx, packed=self._packed,
+                root=shape.partial_plan)
             scols, ssel = low2.lower(msort)
             checks.update(low2.checks)
             return _add_seg(({n: scols[n][:m] for n in names},
@@ -1020,7 +1023,8 @@ class DistSortTiledExecutable(DistTiledExecutable):
 
         def prelude_seg(tables):
             low = DistLowerer(tables, nseg, use_pallas=self._use_pallas,
-                              tx=tx, packed=self._packed)
+                              tx=tx, packed=self._packed,
+                              root=shape.partial_plan)
             outs = [_add_seg(low.lower_shared(b)) for b in shape.builds]
             return outs, _reduce_checks(low.checks)
 
@@ -1193,7 +1197,8 @@ class DistWindowTiledExecutable(DistSortTiledExecutable):
             sel = jnp.arange(cap) < n_valid
             low = _ReplacingLowerer(
                 {}, {id(shape.replace_node): (chunk_cols, sel)},
-                platform=plat, use_pallas=pallas)
+                platform=plat, use_pallas=pallas,
+                root=shape.partial_plan)
             cols, osel = low.lower(shape.root)
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, osel, low.checks

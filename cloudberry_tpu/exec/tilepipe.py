@@ -213,17 +213,19 @@ class TilePipe:
         from cloudberry_tpu.obs import trace as OT
 
         entry = self._q.popleft()
-        fault_point("tile_drain")
-        try:
-            with OT.stage("drain-stall", "launch_seconds", log=self._log,
-                          tile=entry.idx) as st:
+        # (the seam inside the stage: a delay injected there is a stall
+        # the report shows, however short the wait for the tile itself)
+        with OT.stage("drain-stall", "launch_seconds", log=self._log,
+                      tile=entry.idx) as st:
+            fault_point("tile_drain")
+            try:
                 _raise_tile_checks(entry.checks, entry.idx)
-        except Exception:
-            if self._q:
-                self.deferred_fail = True
-                if self._log is not None:
-                    self._log.bump("tile_deferred_overflows")
-            raise
+            except Exception:
+                if self._q:
+                    self.deferred_fail = True
+                    if self._log is not None:
+                        self._log.bump("tile_deferred_overflows")
+                raise
         self.drain_stall_s += st.dur  # report and histogram: one reading
         self.drained += 1
         return Drained(entry.idx, entry.payload)

@@ -77,6 +77,33 @@ def two_level_staging_bytes(node, row_bytes: int | None = None) -> int:
     return (4 * S * lane_rows + hh * hb) * rb
 
 
+def _motion_wire(plan) -> tuple:
+    """(wire bytes, redistribute rung rows) of a plan's motions: each
+    motion's receive capacity times its wire row, plus the two-level
+    exchange's staging where a redistribute is stamped hierarchical."""
+    from cloudberry_tpu.exec.executor import numbered_nodes
+    from cloudberry_tpu.plan import nodes as N
+
+    wire = rung_rows = 0
+    for node in numbered_nodes(plan):
+        if not isinstance(node, N.PMotion):
+            continue
+        rows = max(int(node.out_capacity or 0), 0)
+        rb = _wire_row_bytes(node)
+        wire += rows * rb
+        if node.kind == "redistribute":
+            rung_rows += rows  # bucket_cap × nseg by construction
+            wire += two_level_staging_bytes(node, rb)
+    return wire, rung_rows
+
+
+def motion_wire_bytes(plan) -> int:
+    """Bytes one launch of ``plan`` puts on its motions' wires, per
+    segment: the ``stmt_wire_bytes`` arithmetic (capacities, not the
+    rows that happened to be valid — the buffers are shape-static)."""
+    return int(_motion_wire(plan)[0])
+
+
 def plan_device_bytes(plan, session=None) -> dict:
     """Itemized device-byte estimate for one compiled statement.
 
@@ -88,25 +115,11 @@ def plan_device_bytes(plan, session=None) -> dict:
     (the floor no fusion removes); rung_rows totals redistribute
     receive capacities (bucket_cap over every destination) — the
     skew-governed share of the peak."""
-    from cloudberry_tpu.exec.executor import all_nodes
     from cloudberry_tpu.exec.resource import estimate_plan_memory
-    from cloudberry_tpu.plan import nodes as N
 
     est = estimate_plan_memory(plan)
     live = max((b for _, b in est.per_node), default=0)
-    wire = 0
-    rung_rows = 0
-    seen: set = set()
-    for node in all_nodes(plan):
-        if not isinstance(node, N.PMotion) or id(node) in seen:
-            continue
-        seen.add(id(node))
-        rows = max(int(node.out_capacity or 0), 0)
-        rb = _wire_row_bytes(node)
-        wire += rows * rb
-        if node.kind == "redistribute":
-            rung_rows += rows  # bucket_cap × nseg by construction
-            wire += two_level_staging_bytes(node, rb)
+    wire, rung_rows = _motion_wire(plan)
     return {
         "peak_bytes": int(est.peak_bytes + wire),
         "live_bytes": int(live),

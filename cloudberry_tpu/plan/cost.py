@@ -177,7 +177,9 @@ def _col_source(plan: N.PlanNode, name: str):
 
 
 def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
-    """Prove 32-bit packed join keys from build-side column statistics.
+    """Prove 32-bit packed join keys from build-side column statistics,
+    and one packed word (32 or 64 bits) for a grouped aggregate's keys
+    and for a sort's.
 
     The kernels pack key tuples into one order-preserving integer using the
     BUILD side's runtime ranges (kernels.pack_with_ranges); probe values
@@ -195,36 +197,61 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
     _AFFINE = (DType.INT32, DType.INT64, DType.DATE, DType.DECIMAL,
                DType.STRING)
 
-    def bits_of(build: N.PlanNode, keys) -> int:
+    def span_product(src_node: N.PlanNode, keys, most: int):
+        """Product of the keys' stats-proven spans, or None where a key is
+        no plain column, has no statistics, or the product passes
+        ``most``."""
         prod = 1
         for k in keys:
             if not isinstance(k, ex.ColumnRef) \
                     or k.dtype.base not in _AFFINE:
-                return 64
-            src = _col_source(build, k.name)
+                return None
+            src = _col_source(src_node, k.name)
             if src is None:
-                return 64
+                return None
             try:
                 mm = catalog.table(src[0]).stats.min_max.get(src[1])
             except KeyError:
-                return 64
+                return None
             if mm is None:
-                return 64
+                return None
             # stats store float64 min/max: beyond 2^53 the rounding could
-            # understate a span that straddles the 32-bit threshold
+            # understate a span that straddles a threshold
             if abs(mm[0]) >= 2 ** 53 or abs(mm[1]) >= 2 ** 53:
-                return 64
+                return None
             span = int(mm[1]) - int(mm[0]) + 1
             if span <= 0:
-                return 64
+                return None
             prod *= span
-            if prod > (1 << 32) - 2:
-                return 64
-        return 32
+            if prod > most:
+                return None
+        return prod
+
+    def bits_of(build: N.PlanNode, keys) -> int:
+        return 64 if span_product(build, keys, (1 << 32) - 2) is None \
+            else 32
+
+    def word_bits(child: N.PlanNode, keys) -> int:
+        """One packed word for a grouping or ordering sort (PAgg.pack_bits,
+        PSort.pack_bits): the all-ones word stays free for the rows that
+        are not selected."""
+        prod = span_product(child, keys, (1 << 64) - 2)
+        if prod is None:
+            return 0
+        return 32 if prod <= (1 << 32) - 2 else 64
 
     def walk(n: N.PlanNode):
         if isinstance(n, (N.PJoin, N.PRuntimeFilter)):
             n.pack_bits = bits_of(n.build, n.build_keys)
+        if isinstance(n, N.PAgg) and n.group_keys:
+            n.pack_bits = word_bits(n.child, [e for _, e in n.group_keys])
+        if isinstance(n, N.PSort):
+            # (a string sorts by its collation rank, not by the code the
+            # statistics are of)
+            keys = [e for e, _ in n.keys]
+            n.pack_bits = 0 if any(
+                e.dtype.base == DType.STRING for e in keys) \
+                else word_bits(n.child, keys)
         from cloudberry_tpu.plan.distribute import _node_exprs
 
         for e in _node_exprs(n):

@@ -186,7 +186,8 @@ class Distributor:
                 srt = node.child
                 inner, icap = self.walk(srt.child)
                 if inner.sharding.is_partitioned and k < icap:
-                    local_sort = N.PSort(inner, list(srt.keys))
+                    local_sort = N.PSort(inner, list(srt.keys),
+                                         pack_bits=srt.pack_bits)
                     local_sort.fields = list(inner.fields)
                     local_sort.sharding = inner.sharding
                     local_top = N.PLimit(local_sort, k)
@@ -757,8 +758,10 @@ class Distributor:
     def _two_stage_group_agg(self, node: N.PAgg, child: N.PlanNode,
                              cap: int) -> tuple[N.PlanNode, int]:
         partial_aggs, final_aggs, finalize = _split_aggs(node.aggs)
+        # (the final stage groups the same columns' values: one proof)
         partial = N.PAgg(child, node.group_keys, partial_aggs,
-                         capacity=min(node.capacity, cap), mode="partial")
+                         capacity=min(node.capacity, cap), mode="partial",
+                         pack_bits=node.pack_bits)
         partial.fields = [N.PlanField(n, e.dtype, _f_dict(child, e))
                           for n, e in node.group_keys] + \
                          [N.PlanField(n, c.dtype, None)
@@ -801,7 +804,8 @@ class Distributor:
 
         final_keys = [(n, _field_ref(motion, n)) for n, _ in node.group_keys]
         final = N.PAgg(motion, final_keys, final_aggs,
-                       capacity=min(node.capacity, mcap), mode="final")
+                       capacity=min(node.capacity, mcap), mode="final",
+                       pack_bits=node.pack_bits)
         final.fields = [N.PlanField(n, e.dtype, _f_dict(motion, e))
                         for n, e in final_keys] + \
                        [N.PlanField(n, c.dtype, None) for n, c in final_aggs]

@@ -226,6 +226,12 @@ class PAgg(PlanNode):
     aggs: list[tuple[str, ex.AggCall]]      # output agg name -> call
     capacity: int                            # max groups (static)
     mode: str = "single"
+    # 32 or 64: the planner proved from table min/max statistics that the
+    # group keys pack into ONE order-preserving word that wide
+    # (cost.annotate_pack_bits), so the grouping sort compares one key and
+    # not a tuple: the TPU compiler's time for a sort grows steeply with
+    # the words its comparator reads (kernels.py, "sorts"). 0: not proven.
+    pack_bits: int = 0
 
     def children(self):
         return [self.child]
@@ -239,6 +245,7 @@ class PAgg(PlanNode):
 class PSort(PlanNode):
     child: PlanNode
     keys: list[tuple[ex.Expr, bool]]  # (expr, ascending)
+    pack_bits: int = 0                # see PAgg.pack_bits
 
     def children(self):
         return [self.child]

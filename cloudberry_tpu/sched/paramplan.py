@@ -337,12 +337,13 @@ class _Walker:
                  self.esig(c.arg, False)[0],
                  self.esig(c.filter, False)[0])
                 for name, c in node.aggs)
-            return (t, node.mode, node.capacity, keys, aggs,
+            return (t, node.mode, node.capacity, node.pack_bits, keys, aggs,
                     self._fieldsig(node), self.nsig(node.child))
         if isinstance(node, N.PSort):
             keys = tuple((self.esig(e, False)[0], asc)
                          for e, asc in node.keys)
-            return (t, keys, self._fieldsig(node), self.nsig(node.child))
+            return (t, keys, node.pack_bits, self._fieldsig(node),
+                    self.nsig(node.child))
         if isinstance(node, N.PLimit):
             return (t, node.limit, node.offset, self.nsig(node.child))
         if isinstance(node, N.PWindow):
@@ -598,30 +599,21 @@ class GenericPlan:
         if self.kind == "dist":
             from cloudberry_tpu.exec import dist_executor as DX
 
-            with OT.stage("inputs", "launch_seconds", host=True):
-                inputs, _ = DX.prepare_dist_inputs(planB, session)
-                if bindings:
-                    inputs["$params"] = dict(bindings)
-            with OT.stage("dispatch", "launch_seconds",
-                          mode="dist-generic"):
-                cols, sel, checks, stats = self.fn(inputs)
-            # the stats keys embed the TRACED plan's node ids — pin the
-            # observed bucket demand there, then copy onto the rebind's
-            # motions (signature-equal plans walk identically), so a skew
-            # overflow still promotes straight to the fitting rung
-            DX.record_motion_stats(self.plan, stats, session=session)
-            for a, b in zip(_redistributes(self.plan),
-                            _redistributes(planB)):
-                ob = getattr(a, "_observed_bucket", None)
-                if ob is not None:
-                    b._observed_bucket = ob
-            X.raise_checks(checks)
-            DX.record_jf_counters(stats, session.stmt_log)
-            from cloudberry_tpu.plan.feedback import fold_plan
-
-            fold_plan(session, self.plan)
-            host_cols = {k: DX._local_row(v) for k, v in cols.items()}
-            return X.make_batch(self.plan, host_cols, DX._local_row(sel))
+            # the program was traced from self.plan and names its nodes
+            # by ordinal; the rebind's plan walks identically, so the
+            # observed bucket demand pinned there is copied onto the
+            # rebind's motions and a skew overflow still promotes
+            # straight to the fitting rung
+            try:
+                return DX.execute_distributed(
+                    self.plan, session, self.fn, inputs_plan=planB,
+                    params=bindings, mode="dist-generic")
+            finally:
+                for a, b in zip(_redistributes(self.plan),
+                                _redistributes(planB)):
+                    ob = getattr(a, "_observed_bucket", None)
+                    if ob is not None:
+                        b._observed_bucket = ob
         with OT.stage("inputs", "launch_seconds", host=True):
             inputs = self.bind_inputs(session, planB, keyedB, bindings)
         return X.run_executable(self.exe, inputs, log=session.stmt_log)

@@ -24,6 +24,11 @@ path stays behind ``config.serve.threaded``):
   read set until its backlog drains), and the dispatcher/tenancy
   backpressure taxonomy (SchedQueueFull / TenantQueueFull).
 
+A request served in silence for ``config.serve.keepalive_s`` sends its
+client a space, and again as often (``_IOLoop._keepalive``), so a socket
+time limit shorter than a first send's compile does not lose the answer;
+the threaded transport does not.
+
 Drain and lifecycle semantics are the Server's, unchanged: every
 accepted request holds the in-flight window until its response bytes are
 queued, so ``Server.stop(drain_s)`` keeps its never-silently-dropped
@@ -90,7 +95,7 @@ class _Conn:
     __slots__ = ("sock", "addr", "loop", "rbuf", "wbuf", "lock",
                  "pending", "busy", "authed", "session",
                  "close_after_flush", "closed", "paused", "ended",
-                 "registered", "scanned", "flushing")
+                 "registered", "scanned", "flushing", "beat")
 
     def __init__(self, sock, addr, loop):
         self.sock = sock
@@ -112,6 +117,8 @@ class _Conn:
         # requests (obs.trace.Request) whose answers sit in wbuf: the
         # loop closes them when the last byte is sent (under ``lock``)
         self.flushing: list = []
+        # when the request in flight began, or its last keep-alive went
+        self.beat = 0.0
 
 
 class _IOLoop:
@@ -132,6 +139,7 @@ class _IOLoop:
         self._tlock = threading.Lock()
         self._stopping = False
         self.conns: set = set()
+        self._next_beat = 0.0
         self._thread: Optional[threading.Thread] = None
 
     # ------------------------------------------------------ thread control
@@ -191,10 +199,34 @@ class _IOLoop:
                     fn()
                 except Exception:
                     pass
+            self._keepalive()
             with self._tlock:
                 if self._stopping:
                     break
         self._shutdown()
+
+    def _keepalive(self) -> None:
+        """One space to every connection whose request has been served
+        in silence for ``serve.keepalive_s`` (the loop wakes every half
+        second).
+        Never while answer bytes are queued: a space inside a line
+        would be inside the JSON."""
+        every = self.fe.keepalive_s
+        now = time.monotonic()
+        if every <= 0 or now < self._next_beat:
+            return
+        # a look at every connection, so not at every wake
+        self._next_beat = now + every / 4
+        for conn in self.conns:
+            with conn.lock:
+                if not conn.busy or conn.wbuf or conn.closed \
+                        or now - conn.beat < every:
+                    continue
+                conn.beat = now
+                try:
+                    conn.sock.send(b" ")
+                except OSError:     # full or gone: the read path closes it
+                    pass
 
     def _shutdown(self) -> None:
         """Final flush: drain queued response bytes with a short blocking
@@ -373,6 +405,7 @@ class AsyncFrontEnd:
         cfg = server._config.serve
         self.pipeline_depth = max(1, cfg.pipeline_depth)
         self.max_line_bytes = max(1 << 16, cfg.max_line_bytes)
+        self.keepalive_s = float(cfg.keepalive_s)
         ls = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         ls.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         ls.bind((host, port))
@@ -473,6 +506,7 @@ class AsyncFrontEnd:
                 return
             line, t_recv = conn.pending.popleft()
             conn.busy = True
+            conn.beat = time.monotonic()
         self._pool.submit(self._work, conn, line, t_recv)
 
     def _work(self, conn: _Conn, line: bytes, t_recv: float) -> None:

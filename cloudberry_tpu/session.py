@@ -35,7 +35,7 @@ class SerializationError(RuntimeError):
 @dataclass
 class ShardedTable:
     """Host-side sharded layout: per-column (n_segments, capacity) arrays
-    padded to the largest shard, plus true per-segment row counts."""
+    padded to the largest shard's rung, plus true per-segment row counts."""
     columns: dict[str, np.ndarray]
     counts: np.ndarray          # (n_segments,) int64
     capacity: int
@@ -778,10 +778,9 @@ class Session:
             except ExecError as e:
                 with self._stmt_lock:  # drop the failed runner
                     self._stmt_cache.pop(ckey, None)
-                # allow_fallback: this loop may be retrying a program
-                # served from the rung cache, whose check messages can
-                # embed node ids from an equivalent, since-collected
-                # plan — blanket growth still guarantees progress here
+                # allow_fallback: should the message's ordinal name no
+                # candidate of this plan, blanket growth still
+                # guarantees progress here
                 if not grow_expansion(plan, str(e), allow_fallback=True):
                     raise
                 self.growth_events += 1
@@ -1212,30 +1211,20 @@ class Session:
         key = (query, self.config.n_segments,
                sharedcache.rung_scope_token(self),
                registry_version(), versions, self._motion_rung_sig(plan))
-        from cloudberry_tpu.exec.dist_executor import stat_node_ids
-
         with self._rung_lock:
-            ent = self._rung_cache.pop(key, None)
-            if ent is not None:
-                self._rung_cache[key] = ent  # LRU touch
-        if ent is not None:
-            fn, traced = ent
-            cur = stat_node_ids(plan)
-            if traced != cur \
-                    and tuple(map(len, traced)) == tuple(map(len, cur)):
-                # the program's telemetry keys embed the TRACED plan's
-                # node ids — alias this signature-equal plan's nodes to
-                # them so motion stats (and the feedback fold behind
-                # them) survive the cache hit
-                plan._stat_id_alias = {
-                    o: n for ts, cs in zip(traced, cur)
-                    for o, n in zip(ts, cs)}
+            fn = self._rung_cache.pop(key, None)
+            if fn is not None:
+                self._rung_cache[key] = fn  # LRU touch
+        if fn is not None:
+            # the program names its plan's nodes by ordinal, and this
+            # signature-equal plan numbers its own alike: motion stats
+            # (and the feedback fold behind them) survive the cache hit
             return fn
         fn = compile_distributed(plan, self)
         with self._rung_lock:
             while len(self._rung_cache) >= self._RUNG_CACHE_MAX:
                 self._rung_cache.pop(next(iter(self._rung_cache)))
-            self._rung_cache[key] = (fn, stat_node_ids(plan))
+            self._rung_cache[key] = fn
         return fn
 
     def _verify_plan(self, plan, context: str) -> None:
@@ -1341,7 +1330,7 @@ class Session:
             # both the planner's capacities and this materialization —
             # reusing this call's assignment so rows hash exactly once
             counts = self.shard_counts(name, _assign=assign)
-            cap = max(int(counts.max()) if len(counts) else 0, 1)
+            cap = self.shard_capacity(name)
             cols = {}
             order = np.argsort(assign, kind="stable") if len(assign) else assign
             starts = np.concatenate([[0], np.cumsum(counts)])
@@ -1390,4 +1379,8 @@ class Session:
         return counts
 
     def shard_capacity(self, name: str) -> int:
-        return max(int(self.shard_counts(name).max()), 1)
+        """The largest shard's rows, rounded up to its rung: the planner's
+        scan capacity and the materialized (nseg, cap) arrays' width."""
+        from cloudberry_tpu.exec.kernels import shard_rung_up
+
+        return shard_rung_up(self.shard_counts(name).max(initial=1))

@@ -1,0 +1,75 @@
+"""Worker for tests/test_program_identity.py: one process that serves one
+statement through ``Session.sql`` (the generic-plan path the server takes)
+and prints, for every program the statement launched, a hash of its
+lowered module text and the ``jax.result_info`` keys it carries.
+
+    python tests/program_identity_worker.py <n_segments> <q15v|q3>
+"""
+
+from __future__ import annotations
+
+import functools
+import hashlib
+import json
+import os
+import re
+import sys
+
+N_SEG = int(sys.argv[1])
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=4")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import cloudberry_tpu as cb                              # noqa: E402
+from cloudberry_tpu.config import Config                 # noqa: E402
+from cloudberry_tpu.exec import dist_executor as DX      # noqa: E402
+from cloudberry_tpu.exec import executor as X            # noqa: E402
+from tools.tpch_queries import QUERIES                   # noqa: E402
+from tools.tpchgen import load_tpch                      # noqa: E402
+
+Q15V = ("select l_suppkey as supplier_no, "
+        "sum(l_extendedprice * (1 - l_discount)) as total_revenue "
+        "from lineitem where l_shipdate >= date '1996-01-01' "
+        "and l_shipdate < date '1996-04-01' "
+        "group by l_suppkey order by supplier_no")
+STATEMENTS = {"q15v": Q15V, "q3": QUERIES["q3"]}
+
+texts: list = []
+
+
+def recording(fn):
+    """``fn`` (a jitted program), its module text kept at every launch."""
+    @functools.wraps(fn)    # the program's byte counts ride on it
+    def call(inputs):
+        texts.append(fn.lower(inputs).as_text())
+        return fn(inputs)
+    return call
+
+
+_compile_distributed = DX.compile_distributed
+_compile_plan = X.compile_plan
+
+
+def compile_distributed(*a, **kw):
+    return recording(_compile_distributed(*a, **kw))
+
+
+def compile_plan(*a, **kw):
+    exe = _compile_plan(*a, **kw)
+    exe.packed_fn = recording(exe.packed_fn)
+    return exe
+
+
+DX.compile_distributed = compile_distributed
+X.compile_plan = compile_plan
+
+s = cb.Session(Config(n_segments=N_SEG))
+load_tpch(s, sf=0.01, seed=7, tables=["lineitem", "orders", "customer"])
+rows = s.sql(STATEMENTS[sys.argv[2]]).num_rows()
+info = [m for t in texts
+        for m in re.findall(r'jax\.result_info = "([^"]*)"', t)]
+print(json.dumps({"rows": rows, "programs": len(texts),
+                  "hashes": [hashlib.sha256(t.encode()).hexdigest()
+                             for t in texts],
+                  "result_info": info}))

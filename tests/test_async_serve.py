@@ -220,3 +220,36 @@ def test_async_auth_and_lockout():
                 Client(srv.host, srv.port, token="nope")
         with pytest.raises(ServerError, match="locked"):
             Client(srv.host, srv.port, token="hunter2")
+
+
+@pytest.mark.parametrize("keepalive_s, answered", [(0.2, True),
+                                                   (30.0, False)])
+def test_a_long_request_keeps_its_connection_alive(monkeypatch, keepalive_s,
+                                                   answered):
+    """A statement served for longer than the client's socket time limit
+    (a first send that compiles for minutes): a space every
+    ``serve.keepalive_s`` holds the connection, the answer's line parses as ever, and the
+    connection goes on; with the silence longer than the limit the
+    client gives up, as it always did."""
+    sound = Server._execute
+
+    def slow(self, req, sess, async_cb=None):
+        if "count(*)" in req.get("sql", ""):
+            time.sleep(2.5)
+        return sound(self, req, sess, async_cb=async_cb)
+
+    monkeypatch.setattr(Server, "_execute", slow)
+    with Server(session=_session(
+            **{"serve.keepalive_s": keepalive_s})) as srv:
+        c = Client(srv.host, srv.port, timeout=1.0)
+        try:
+            if answered:
+                for _ in range(2):
+                    assert c.sql("select count(*) as n from t")["rows"] \
+                        == [[500]]
+                assert c.sql("select a from t where a = 7")["rows"] == [[7]]
+            else:
+                with pytest.raises(OSError):
+                    c.sql("select count(*) as n from t")
+        finally:
+            c._sock.close()

@@ -263,7 +263,7 @@ def test_segment_rung_promotion_lifts_host_rung(hosts4):
     m.host_bucket_cap, m.hier_hosts = 256, 4
     m._observed_bucket = 5000
     m._observed_host_bucket = 9000
-    assert grow_expansion(m, f"redistribute overflow (node {id(m)})")
+    assert grow_expansion(m, "redistribute overflow (node 0: Motion)")
     assert m.bucket_cap == 8192
     assert m.host_bucket_cap >= max(m.bucket_cap, 9000)
 

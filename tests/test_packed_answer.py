@@ -299,12 +299,19 @@ def test_tiled_statement_counts_no_packed_launch(tmp_path):
 
 
 def test_distributed_statement_counts_no_packed_launch():
+    """The distributed launch reads leaf by leaf: it counts every blocking
+    read on the shared counter and itself on ``launch_dist``, never on
+    ``launch_packed``."""
     s = cb.Session(Config(n_segments=4))
     s.sql("create table t (a int, b bigint) distributed by (a)")
     s.sql("insert into t values " + ",".join(
         f"({i},{i * 3})" for i in range(100)))
     before = _counts(s.stmt_log)
+    dist = s.stmt_log.counter("launch_dist")
     out = s.sql("select b % 5 as m, sum(b) sb from t group by b % 5 "
                 "order by m").to_pandas()
     assert len(out) == 5
-    assert _counts(s.stmt_log) == before
+    packed, reads = (a - b for a, b in zip(_counts(s.stmt_log), before))
+    assert packed == 0
+    assert s.stmt_log.counter("launch_dist") - dist == 1
+    assert reads >= 4       # sel, two columns and at least one check

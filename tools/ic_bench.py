@@ -151,7 +151,7 @@ def bench_shuffle(fmt: str, nseg: int, rows: int, n_cols: int,
         bucket_cap = max(int(np.ceil(rows / nseg * capacity_factor)), 8)
         bucket_cap = max(bucket_cap, actual_max)  # complete, not error
 
-    node = N.PMotion(None, "redistribute",
+    node = N.PMotion(N.PScan("$dual", {}, 1), "redistribute",
                      hash_keys=[ex.ColumnRef("c0", INT64)])
     node.bucket_cap = bucket_cap
 
@@ -288,7 +288,7 @@ def bench_two_level(nseg: int, hosts: int, rows: int, n_cols: int,
 
     recs = {}
     for fmt in ("flat", "hier"):
-        node = N.PMotion(None, "redistribute",
+        node = N.PMotion(N.PScan("$dual", {}, 1), "redistribute",
                          hash_keys=[ex.ColumnRef("c0", INT64)])
         node.bucket_cap = B
         if fmt == "hier":
