@@ -1518,7 +1518,7 @@ class Binder:
             return _colref(f)
 
         if isinstance(node, ast.NumberLit):
-            return _bind_number(node.text)
+            return _token_literal("num", node.text, node.pos)
 
         if isinstance(node, ast.StringLit):
             # bare string literal: binds to a code only in comparison context;
@@ -1529,7 +1529,7 @@ class Binder:
             return ex.Literal(node.value, T.BOOL)
 
         if isinstance(node, ast.DateLit):
-            return ex.Literal(T.date_to_days(node.value), T.DATE)
+            return _token_literal("date", node.value, node.pos)
 
         if isinstance(node, ast.IntervalLit):
             raise BindError("interval literal only valid in date arithmetic")
@@ -1544,7 +1544,7 @@ class Binder:
             if node.op == "+":
                 return operand
             if isinstance(operand, ex.Literal):
-                out: ex.Expr = ex.Literal(-operand.value, operand.dtype)
+                out: ex.Expr = _negate_literal(operand)
             else:
                 out = ex.UnaryOp("-", operand, operand.dtype)
             return _set_valid(out, _valid_of(operand))
@@ -2600,9 +2600,7 @@ class Binder:
         iv = node.right
         sign = 1 if node.op == "+" else -1
         if isinstance(base, ex.Literal) and base.dtype.base == DType.DATE:
-            d = T.days_to_date(base.value)
-            d2 = _shift_date(d, sign * iv.n, iv.unit)
-            return ex.Literal(T.date_to_days(d2), T.DATE)
+            return _shift_literal(base, sign * iv.n, iv.unit)
         if iv.unit == "day":
             return ex.BinOp("+" if sign > 0 else "-", base,
                             ex.Literal(iv.n, T.INT32), T.DATE)
@@ -3317,6 +3315,8 @@ def _has_agg(node: ast.ExprNode) -> bool:
 def _ast_key(node: ast.Node) -> str:
     parts = [type(node).__name__]
     for k, v in sorted(vars(node).items()):
+        if k == "pos":
+            continue  # where a literal stood in the text is not what it is
         if isinstance(v, ast.Node):
             parts.append(f"{k}={_ast_key(v)}")
         elif isinstance(v, list):
@@ -3386,34 +3386,80 @@ def _default_name(node: ast.ExprNode) -> Optional[str]:
     return None
 
 
-def _bind_number(text: str) -> ex.Literal:
-    if "e" in text.lower():
-        return ex.Literal(float(text), T.FLOAT64)
-    if "." in text:
-        frac = text.split(".")[1]
-        scale = len(frac)
-        return ex.Literal(int(text.replace(".", "")), T.DECIMAL(scale))
-    return ex.Literal(int(text), T.INT64)
+def _token_literal(kind: str, text: str, pos: int = -1) -> ex.Literal:
+    """The literal a number ('num') or ``date '…'`` ('date') token binds
+    to. ``pos`` (the token's offset in the statement's text) starts the
+    literal's origin; the folds below carry it on."""
+    if kind == "date":
+        v, t = T.date_to_days(text), T.DATE
+    elif "e" in text.lower():
+        v, t = float(text), T.FLOAT64
+    elif "." in text:
+        v, t = int(text.replace(".", "")), T.DECIMAL(len(text.split(".")[1]))
+    else:
+        v, t = int(text), T.INT64
+    return ex.Literal(
+        v, t, ex.LiteralOrigin(pos, kind, t) if pos >= 0 else None)
+
+
+def _folded(e: ex.Literal, *step) -> Optional[ex.LiteralOrigin]:
+    """The origin of a literal folded from ``e`` by ``step`` (a name in
+    ``_REPLAY`` and the fold's other arguments)."""
+    return e.origin.then(*step) if e.origin is not None else None
+
+
+def _negate_literal(e: ex.Literal) -> ex.Literal:
+    return ex.Literal(-e.value, e.dtype, _folded(e, "neg"))
+
+
+def _shift_literal(e: ex.Literal, n: int, unit: str) -> ex.Literal:
+    """``date literal ± interval``, folded at bind time."""
+    d2 = _shift_date(T.days_to_date(e.value), n, unit)
+    return ex.Literal(T.date_to_days(d2), T.DATE,
+                      _folded(e, "shift", n, unit))
 
 
 def _literal_cast(e: ex.Literal, t: SqlType) -> ex.Literal:
     v = e.value
-    if t.base == DType.DECIMAL:
-        if e.dtype.base == DType.DECIMAL:
-            diff = t.scale - e.dtype.scale
-            return ex.Literal(int(v) * 10 ** diff if diff >= 0
-                              else int(round(v / 10 ** (-diff))), t)
-        if e.dtype.base in (DType.INT32, DType.INT64):
-            return ex.Literal(int(v) * 10 ** t.scale, t)
-        if e.dtype.base == DType.FLOAT64:
-            return ex.Literal(int(round(v * 10 ** t.scale)), t)
-    if t.base == DType.FLOAT64:
-        if e.dtype.base == DType.DECIMAL:
-            return ex.Literal(v / 10 ** e.dtype.scale, t)
-        return ex.Literal(float(v), t)
-    if t.base in (DType.INT32, DType.INT64):
-        return ex.Literal(int(v), t)
-    return ex.Literal(v, t)
+    if t.base == DType.DECIMAL and e.dtype.base == DType.DECIMAL:
+        diff = t.scale - e.dtype.scale
+        v = int(v) * 10 ** diff if diff >= 0 \
+            else int(round(v / 10 ** (-diff)))
+    elif t.base == DType.DECIMAL and e.dtype.base in (DType.INT32,
+                                                      DType.INT64):
+        v = int(v) * 10 ** t.scale
+    elif t.base == DType.DECIMAL and e.dtype.base == DType.FLOAT64:
+        v = int(round(v * 10 ** t.scale))
+    elif t.base == DType.FLOAT64:
+        v = v / 10 ** e.dtype.scale if e.dtype.base == DType.DECIMAL \
+            else float(v)
+    elif t.base in (DType.INT32, DType.INT64):
+        v = int(v)
+    return ex.Literal(v, t, _folded(e, "cast", t))
+
+
+# the folds a literal's origin can name, for replay_literal
+_REPLAY = {"neg": _negate_literal, "shift": _shift_literal,
+           "cast": _literal_cast}
+
+
+def replay_literal(origin: ex.LiteralOrigin, text: str
+                   ) -> Optional[ex.Literal]:
+    """What a literal of this origin would be had its token read ``text``:
+    the binder's own conversion and folds, run again. None when the text
+    does not read as the same kind and type of token (``0.5`` where
+    ``0.05`` stood is another decimal scale, and the plan around the
+    literal may differ) or is no valid literal at all: the caller then
+    takes the full path, which says what is wrong with it."""
+    try:
+        lit = _token_literal(origin.kind, text)
+        if lit.dtype != origin.dtype:
+            return None
+        for name, *args in origin.steps:
+            lit = _REPLAY[name](lit, *args)
+    except (ValueError, TypeError, OverflowError):
+        return None
+    return lit
 
 
 def _common_type(ts: list[SqlType]) -> SqlType:

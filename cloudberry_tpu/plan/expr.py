@@ -36,9 +36,34 @@ class ColumnRef(Expr):
 
 
 @dataclass(frozen=True)
+class LiteralOrigin:
+    """How a literal's value follows from ONE literal token of the
+    statement's text: the token (``pos``, its offset in the text), what the
+    binder read it as (``kind`` 'num' | 'date', ``dtype`` the type the
+    token's own text gave it) and the folds applied on the way, in order
+    (``steps``; plan/binder.py ``replay_literal`` runs them again on
+    another text). The literal template of a generic plan
+    (sched/paramplan.py) is built from these."""
+    pos: int
+    kind: str
+    dtype: SqlType
+    steps: tuple = ()
+
+    def then(self, *step) -> "LiteralOrigin":
+        return LiteralOrigin(self.pos, self.kind, self.dtype,
+                             self.steps + (step,))
+
+
+@dataclass(frozen=True)
 class Literal(Expr):
     value: Any
     dtype: SqlType
+    # None for a literal no single token accounts for (a constant the
+    # binder made, a fold that does not track it). Beside the value, never
+    # part of it: not in equality, hash, repr, a plan's signature or the
+    # traced program
+    origin: Optional[LiteralOrigin] = field(default=None, compare=False,
+                                            repr=False)
 
 
 @dataclass(frozen=True)

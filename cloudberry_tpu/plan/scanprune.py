@@ -66,33 +66,40 @@ def _exprs_of(node: N.PlanNode):
     yield from _node_exprs(node)
 
 
-def _bind_scan(node: N.PScan, preds: tuple, t, store) -> None:
-    rev = {out: phys for phys, out in node.column_map.items()}
+def scan_bounds(cmps) -> tuple[dict, dict]:
+    """(ranges, eqs) for ``TableStore.select_partitions`` from a scan's
+    ``(physical column, op, value)`` comparisons."""
     ranges: dict[str, tuple] = {}
     eqs: dict[str, object] = {}
-    for p in preds:
-        for c in _conjuncts(p):
-            got = _simple_cmp(c, rev)
-            if got is None:
-                continue
-            col, op, val = got
-            if op == "=":
-                eqs[col] = val
-                continue
-            lo, hi = ranges.get(col, (None, None))
-            if op in (">", ">="):
-                # strict bounds tighten by 1 on integral literals (exact
-                # partition elimination); floats stay conservative
-                v = val + 1 if op == ">" and isinstance(val, int) else val
-                lo = v if lo is None else max(lo, v)
-            else:
-                v = val - 1 if op == "<" and isinstance(val, int) else val
-                hi = v if hi is None else min(hi, v)
-            ranges[col] = (lo, hi)
+    for col, op, val in cmps:
+        if op == "=":
+            eqs[col] = val
+            continue
+        lo, hi = ranges.get(col, (None, None))
+        if op in (">", ">="):
+            # strict bounds tighten by 1 on integral literals (exact
+            # partition elimination); floats stay conservative
+            v = val + 1 if op == ">" and isinstance(val, int) else val
+            lo = v if lo is None else max(lo, v)
+        else:
+            v = val - 1 if op == "<" and isinstance(val, int) else val
+            hi = v if hi is None else min(hi, v)
+        ranges[col] = (lo, hi)
+    return ranges, eqs
+
+
+def _bind_scan(node: N.PScan, preds: tuple, t, store) -> None:
+    rev = {out: phys for phys, out in node.column_map.items()}
+    cmps = [got for p in preds for c in _conjuncts(p)
+            if (got := _simple_cmp(c, rev)) is not None]
+    ranges, eqs = scan_bounds((col, op, lit.value) for col, op, lit in cmps)
     parts, report = store.select_partitions(t.name, ranges, eqs)
     rows = sum(p["num_rows"] - len(p["deleted"]) for p in parts)
     node._store_parts = parts
     node._prune_report = report
+    # the comparisons that chose the partitions, literals as bound: a
+    # literal template (sched/paramplan.py) re-decides the list from them
+    node._prune_cmps = cmps
     node._input_key = f"{node.table_name}#{id(node)}"
     node.capacity = max(rows, 1)
     node.num_rows = rows
@@ -204,7 +211,7 @@ def _conjuncts(e: ex.Expr):
 
 def _simple_cmp(e: ex.Expr, rev: dict):
     """column <op> literal over a range-comparable physical type, in either
-    orientation; returns (phys_col, op, value) or None."""
+    orientation; returns (phys_col, op, the literal) or None."""
     if not isinstance(e, ex.BinOp) or e.op not in ("=", "<", "<=", ">", ">="):
         return None
     l, r = e.left, e.right
@@ -219,4 +226,4 @@ def _simple_cmp(e: ex.Expr, rev: dict):
         return None
     if not isinstance(r.value, (int, float)):
         return None
-    return phys, op, r.value
+    return phys, op, r

@@ -60,16 +60,22 @@ _PARAM_HEADS = ("select", "with", "(")
 _KEEP_AFTER = ("limit", "offset", "interval")
 
 
-def normalize(sql: str):
-    """(skeleton, literal texts) for a parameterizable statement, else
-    None. The skeleton is the token stream with number/string literals
-    replaced by kind-tagged placeholders — same-shape statements collide
-    on it regardless of their literal values."""
+def param_head(sql: str) -> bool:
+    """Does the statement open as one that can have a skeleton?"""
     head = sql.lstrip()[:1]
     if not head:
-        return None
+        return False
     first = sql.split(None, 1)[0].lower() if head != "(" else "("
-    if first not in _PARAM_HEADS:
+    return first in _PARAM_HEADS
+
+
+def _lex(sql: str):
+    """(skeleton, literal texts, their offsets in ``sql``) for a
+    parameterizable statement, else None. The skeleton is the token
+    stream with number/string literals replaced by kind-tagged
+    placeholders — same-shape statements collide on it regardless of
+    their literal values."""
+    if not param_head(sql):
         return None
     try:
         toks = tokenize(sql)
@@ -77,20 +83,29 @@ def normalize(sql: str):
         return None
     parts: list[str] = []
     params: list[str] = []
+    offsets: list[int] = []
     prev = ""
     for t in toks:
         if t.kind == "number" and prev not in _KEEP_AFTER:
             params.append(t.text)
+            offsets.append(t.pos)
             parts.append("?n")
         elif t.kind == "string" and prev not in _KEEP_AFTER:
             params.append(t.text)
+            offsets.append(t.pos)
             parts.append("?s")
         elif t.kind == "string":
             parts.append(f"'{t.text}'")
         elif t.kind != "eof":
             parts.append(t.text)
         prev = t.text if t.kind == "ident" else ""
-    return " ".join(parts), tuple(params)
+    return " ".join(parts), tuple(params), tuple(offsets)
+
+
+def normalize(sql: str):
+    """(skeleton, literal texts) of ``_lex``, else None."""
+    lexed = _lex(sql)
+    return lexed and lexed[:2]
 
 
 # ------------------------------------------------------- plan signatures
@@ -136,6 +151,12 @@ class _Walker:
         self.slots: list[SqlType] = []
         self.bindings: dict[str, np.ndarray] = {}
         self.keyed: list[N.PScan] = []
+        # for the literal template: the literal behind each $prm slot
+        # (None where a rewritten plan is walked again), and the literals
+        # of a known origin that stay baked into the program
+        self.slot_lits: list[Optional[ex.Literal]] = []
+        self.baked: list[ex.Literal] = []
+        self.subplans = 0
         self._nrw = 0  # scan row-count parameter slots ($nrw<i>)
         self._memo: dict[int, int] = {}
         # table-owned dictionaries are version-pinned (any content change
@@ -156,6 +177,7 @@ class _Walker:
             if paramable and _param_scalar(e):
                 slot = len(self.slots)
                 self.slots.append(e.dtype)
+                self.slot_lits.append(e)
                 key = f"$prm{slot}"
                 self.bindings[key] = np.asarray(e.value,
                                                 dtype=e.dtype.np_dtype)
@@ -165,6 +187,8 @@ class _Walker:
                 new = ex.Param(slot, e.dtype, e.value) if self.rewrite \
                     else e
                 return ("P", _tsig(e.dtype)), new
+            if e.origin is not None:
+                self.baked.append(e)
             return ("L", _tsig(e.dtype), _pyval(e.value)), e
         if isinstance(e, ex.Param):
             # re-analysis of an already-rewritten plan (the expansion-growth
@@ -174,6 +198,7 @@ class _Walker:
                 raise UnsupportedPlan("Param at a non-parameter site")
             slot = len(self.slots)
             self.slots.append(e.dtype)
+            self.slot_lits.append(None)
             key = f"$prm{slot}"
             self.bindings[key] = np.asarray(e.value,
                                             dtype=e.dtype.np_dtype)
@@ -235,6 +260,7 @@ class _Walker:
         if isinstance(e, ex.SubqueryScalar):
             # the subplan lowers inside the same program — recurse; its
             # filter/project literals are param sites like any other
+            self.subplans += 1
             psig = self.nsig(e.plan)
             return ("SQ", e.mode, _tsig(e.dtype), psig), e
         raise UnsupportedPlan(f"expression {type(e).__name__}")
@@ -384,14 +410,20 @@ class _Walker:
         raise UnsupportedPlan(f"node {t}")
 
 
-def analyze(session, plan: N.PlanNode, rewrite: bool = False):
-    """(signature, bindings, keyed scans, slot types) for a bound plan.
-    ``rewrite=True`` (generic-plan build only) additionally replaces every
-    parameter-site literal with its ``expr.Param`` slot IN PLACE."""
+def _walk(session, plan: N.PlanNode, rewrite: bool = False):
+    """(signature, the walker that made it) for a bound plan."""
     w = _Walker(session, rewrite=rewrite)
     root = ("root", w.nsig(plan),
             getattr(plan, "_direct_segment", None) is not None,
             w._fieldsig(plan))
+    return root, w
+
+
+def analyze(session, plan: N.PlanNode, rewrite: bool = False):
+    """(signature, bindings, keyed scans, slot types) for a bound plan.
+    ``rewrite=True`` (generic-plan build only) additionally replaces every
+    parameter-site literal with its ``expr.Param`` slot IN PLACE."""
+    root, w = _walk(session, plan, rewrite)
     return root, w.bindings, w.keyed, w.slots
 
 
@@ -514,9 +546,11 @@ class GenericPlan:
         # shared-tier guards (sched/sharedcache.py): content-stable table
         # version tokens + the plan epoch — store-scope entries match
         # across sessions, everything else stays private by construction
+        self.names = names
         self.versions = sharedcache.table_versions(session, names)
         self.ddlv = sharedcache.plan_epoch(session)
         self.plan = plan
+        self.keyed = keyed
         self.param_keys = sorted(bindings, key=lambda k: (k[:4],
                                                           int(k[4:])))
         self.keyed_keys = [s._input_key for s in keyed]
@@ -557,6 +591,9 @@ class GenericPlan:
         else:
             self.stack_mode = None
         self.fast: Optional[FastRebind] = None
+        # the literal template (below), or why this variant has none
+        self.template: Optional[LiteralTemplate] = None
+        self.template_refused: Optional[str] = None
         self._rungs: dict[int, Any] = {}
         self._rung_lock = __import__("threading").Lock()
 
@@ -647,6 +684,292 @@ class GenericPlan:
         return fn
 
 
+# --------------------------------------------------- the literal template
+#
+# A statement whose skeleton is known need not be parsed and planned to
+# find the variant its plan would match: where every literal token either
+# reaches the program as $prm values alone, by folds that can be run again
+# on another text, or is repeated verbatim, the variant and its bindings
+# follow from the tokens. The template is that derivation. It is SOUND
+# when a hit runs the program, inputs and bindings the full path would
+# for the same text; what it cannot show it refuses, each under a reason
+# ``template_refused.<reason>`` counts:
+#
+#   dist, direct_segment   the plan is placed by a literal or spans segments
+#   point_lookup           the literal chooses the scan's ROWS
+#   plan_shape             a node outside _TEMPLATE_NODES (a join, a motion,
+#                          a window, a subplan: capacities the planner
+#                          estimates from a predicate's selectivity)
+#   untracked_literal      a $prm slot, or a comparison that pruned a scan,
+#                          whose literal names no token of the text
+#   replay_mismatch        run again on the build's own text, the derivation
+#                          does not give the build's bindings and partitions
+#   arm_mismatch           a later send through the full path matched the
+#                          variant with bindings the derivation does not give
+#   no_stmt_cache, external, user_params
+#                          (counted where those statements leave the gate)
+
+_TEMPLATE_NODES = (N.PScan, N.PFilter, N.PProject, N.PAgg, N.PSort,
+                   N.PLimit)
+
+
+def _scan_files(scan) -> tuple:
+    return tuple(p["file"] for p in scan._store_parts)
+
+
+def _same_bindings(a: dict, b: dict) -> bool:
+    """Bit for bit, dtype included."""
+    return a.keys() == b.keys() and all(
+        a[k].dtype == b[k].dtype and a[k].shape == b[k].shape
+        and a[k].tobytes() == b[k].tobytes() for k in a)
+
+
+class LiteralTemplate:
+    """One variant's bindings as a function of the statement's literal
+    tokens. ``pinned`` tokens must read as the build's did (they are baked
+    into the program, or nothing accounts for them); each ``$prm`` slot
+    replays its literal's origin on one of the others; a store scan's
+    partition list is decided again from the comparisons that pruned it
+    and must come out as the variant's. ``unseen`` holds the free tokens
+    no full-path send has yet shown under another text: the template
+    binds nothing until it is empty."""
+
+    def __init__(self, session, names, tokens, free, slots, fixed, scans,
+                 files):
+        import weakref
+
+        from cloudberry_tpu.plan.feedback import feedback_gen
+
+        self.tokens = tokens        # the build's literal texts
+        self.pinned = tuple((i, t) for i, t in enumerate(tokens)
+                            if i not in free)
+        self.slots = slots          # ((key, token index, origin, type), ...)
+        self.fixed = fixed          # $nrw bindings: the table versions' own
+        # per keyed scan: (table, ((col, op, origin, token index), ...),
+        # the manifest's partitions to choose from), and the files the
+        # variant reads of each
+        self.scans = scans
+        self.files = files
+        self.unseen = frozenset(free)
+        # which of the variant's tables were cold (scanned from the store,
+        # not from RAM): a catalog's own state, and no version moves when a
+        # table is materialized, yet the planner scans it otherwise
+        self.cold = self._cold(session, names)
+        self.fbgen = feedback_gen(session)
+        # a shared scope's plan epoch leaves DDL to the signature, which
+        # a hit never computes. What DDL can change under a text whose
+        # tables are at the guarded versions is a view: catalogs without
+        # views read the text alike (sharedcache.rung_scope_token's rule),
+        # any other is held to the DDL version of its own last full-path
+        # match
+        self.viewless = not session.catalog.views
+        self.ddl = weakref.WeakKeyDictionary()
+        self.ddl[session.catalog] = session.catalog.ddl_version
+
+    @staticmethod
+    def _cold(session, names) -> tuple:
+        tables = session.catalog.tables
+        return tuple(getattr(tables.get(n), "cold", None) for n in names)
+
+    def derive(self, session, tokens):
+        """(bindings, each keyed scan's partition files) as this text's
+        literal tokens give them, or None when the text is not one the
+        template speaks for."""
+        from cloudberry_tpu.plan.binder import replay_literal
+        from cloudberry_tpu.plan.scanprune import scan_bounds
+
+        if len(tokens) != len(self.tokens):
+            return None
+        for i, text in self.pinned:
+            if tokens[i] != text:
+                return None
+        out = dict(self.fixed)
+        for key, i, origin, t in self.slots:
+            lit = replay_literal(origin, tokens[i])
+            if lit is None or lit.dtype != t:
+                return None
+            try:
+                out[key] = np.asarray(lit.value, dtype=t.np_dtype)
+            except (TypeError, ValueError, OverflowError):
+                return None
+        files = []
+        store = session.catalog.store
+        for (table, cmps, candidates), own in zip(self.scans, self.files):
+            if not cmps:
+                files.append(own)  # no literal chose them
+                continue
+            vals = []
+            for col, op, origin, i in cmps:
+                lit = replay_literal(origin, tokens[i])
+                if lit is None or not isinstance(lit.value, (int, float)):
+                    return None
+                vals.append((col, op, lit.value))
+            # (the table-version guard holds: the manifest is the build's)
+            parts, _ = store.select_partitions(
+                table, *scan_bounds(vals), candidates=candidates)
+            files.append(tuple(p["file"] for p in parts))
+        return out, tuple(files)
+
+    def bind(self, session, tokens) -> Optional[dict]:
+        """The variant's bindings for this text, or None: not this
+        template's text, or its literals prune other partitions than the
+        variant reads."""
+        got = self.derive(session, tokens)
+        if got is None or got[1] != self.files:
+            return None
+        return got[0]
+
+    def guards_hold(self, session, gp: "GenericPlan") -> bool:
+        """What Session._cached_statement and GenericPlan.matches hold an
+        entry to, short of the signature: config identity, plan epoch
+        (UDF registry, topology), the feedback generation, this catalog's
+        DDL, table versions, and which tables are cold."""
+        from cloudberry_tpu.plan.feedback import feedback_gen
+        from cloudberry_tpu.sched import sharedcache
+
+        cat = session.catalog
+        if gp.config is not session.config \
+                or gp.ddlv != sharedcache.plan_epoch(session) \
+                or self.fbgen != feedback_gen(session) \
+                or not (self.viewless and not cat.views
+                        or self.ddl.get(cat) == cat.ddl_version):
+            return False
+        try:
+            return gp.versions == sharedcache.table_versions(
+                session, gp.names) \
+                and self.cold == self._cold(session, gp.names)
+        except KeyError:
+            return False
+
+    def observe(self, session, tokens, bindings, keyed) -> bool:
+        """A send that went the full path matched this variant: hold the
+        derivation to what the planner bound. False: it differs."""
+        from cloudberry_tpu.plan.feedback import feedback_gen
+
+        got = self.derive(session, tokens)
+        if got is None:
+            # another pinned text or token type: the signature matched
+            # all the same, so the template says nothing of this text
+            return True
+        if not _same_bindings(got[0], bindings) \
+                or got[1] != tuple(_scan_files(s) for s in keyed):
+            return False
+        self.unseen = self.unseen - {
+            i for i in self.unseen if tokens[i] != self.tokens[i]}
+        self.fbgen = feedback_gen(session)
+        self.viewless = self.viewless and not session.catalog.views
+        self.ddl[session.catalog] = session.catalog.ddl_version
+        return True
+
+
+def _derive_template(session, gp: GenericPlan, plan, w: _Walker,
+                     tokens, offsets):
+    """The variant's LiteralTemplate, or the reason it has none."""
+    from cloudberry_tpu.exec import executor as X
+
+    if gp.kind != "single":
+        return "dist" if gp.kind == "dist" else "direct_segment"
+    if any(hasattr(s, "_point_rows") for s in w.keyed):
+        return "point_lookup"
+    if w.subplans or not all(isinstance(n, _TEMPLATE_NODES)
+                             for n in X.all_nodes(plan)):
+        return "plan_shape"
+    index = {pos: i for i, pos in enumerate(offsets)}
+    store = session.catalog.store
+
+    def token_of(lit) -> Optional[int]:
+        o = lit.origin if lit is not None else None
+        return index.get(o.pos) if o is not None else None
+
+    slots = []
+    for k, lit in enumerate(w.slot_lits):
+        i = token_of(lit)
+        if i is None:
+            return "untracked_literal"
+        slots.append((f"$prm{k}", i, lit.origin, w.slots[k]))
+    scans = []
+    for s in w.keyed:
+        cmps = []
+        for col, op, lit in getattr(s, "_prune_cmps", None) or ():
+            i = token_of(lit)
+            if i is None:
+                return "untracked_literal"
+            cmps.append((col, op, lit.origin, i))
+        scans.append((s.table_name, tuple(cmps),
+                      store.read_manifest(s.table_name)["partitions"]))
+    # a token is free to change only where $prm slots are all it feeds
+    free = {i for _, i, _, _ in slots} - {token_of(b) for b in w.baked}
+    fixed = {k: v for k, v in w.bindings.items() if k.startswith("$nrw")}
+    t = LiteralTemplate(session, gp.names, tokens, free, tuple(slots), fixed,
+                        tuple(scans), tuple(_scan_files(s) for s in w.keyed))
+    got = t.bind(session, tokens)
+    if got is None or not _same_bindings(got, w.bindings):
+        return "replay_mismatch"
+    return t
+
+
+def _refuse_template(session, reason: str, gp: Optional[GenericPlan] = None
+                     ) -> None:
+    if gp is not None:
+        gp.template = None
+        gp.template_refused = reason
+    session.stmt_log.bump(f"template_refused.{reason}")
+
+
+def _note_template(session, gp: GenericPlan, plan, w: _Walker,
+                   tokens, offsets) -> None:
+    """Every full-path send that built or matched ``gp`` passes here:
+    derive the variant's template once, then hold it to each such send
+    (``observe``), which is also what arms it."""
+    t = gp.template
+    if t is None:
+        if gp.template_refused is None:
+            got = _derive_template(session, gp, plan, w, tokens, offsets)
+            if isinstance(got, str):
+                _refuse_template(session, got, gp)
+            else:
+                gp.template = got
+                session.stmt_log.bump("template_builds")
+    elif not t.observe(session, tokens, w.bindings, w.keyed):
+        _refuse_template(session, "arm_mismatch", gp)
+
+
+def template_bind(session, query: str):
+    """(GenericPlan, bindings) where an armed template of the statement's
+    skeleton speaks for this text and its guards hold, else None: one
+    tokenisation, no parse, no plan. The caller launches the variant over
+    its own plan and scans (``gp.run(session, gp.plan, gp.keyed,
+    bindings)``)."""
+    lexed = _lex(query)
+    if lexed is None or not lexed[1]:
+        return None
+    skeleton, tokens, _ = lexed
+    cache = session._generic_cache
+    with session._generic_lock:
+        bucket = cache.pop(skeleton, None)
+        if bucket is None:
+            return None
+        cache[skeleton] = bucket  # LRU touch
+        bucket = tuple(bucket)
+    armed = False
+    for gp in reversed(bucket):
+        t = gp.template
+        if t is None or t.unseen:
+            continue
+        armed = True
+        if t.guards_hold(session, gp):
+            bindings = t.bind(session, tokens)
+            if bindings is not None:
+                session.stmt_log.bump("template_binds")
+                # a template hit IS a generic-plan reuse (as a fast
+                # rebind is): the hit counter agrees with the full path
+                session.stmt_log.bump("generic_hits")
+                return gp, bindings
+    if armed:
+        session.stmt_log.bump("template_fallbacks")
+    return None
+
+
 # ----------------------------------------------------- session-side cache
 
 
@@ -705,14 +1028,6 @@ def _try_fast(session, gp: GenericPlan, plan, tok_params, bindings,
                       dist_dtype)
 
 
-def _eligible(session, query, plan) -> bool:
-    if not session.config.sched.generic_plans:
-        return False
-    if getattr(plan, "_no_stmt_cache", False):
-        return False
-    return True
-
-
 @dataclass
 class Prep:
     """One statement's rebinding package: the shared program plus this
@@ -733,14 +1048,18 @@ def lookup_or_build(session, query: str, plan) -> Optional[Prep]:
     keeps the non-generic path."""
     from cloudberry_tpu.exec import executor as X
 
-    if not _eligible(session, query, plan):
+    if not session.config.sched.generic_plans:
         return None
-    norm = normalize(query)
-    if norm is None or not norm[1]:
+    if getattr(plan, "_no_stmt_cache", False):
+        _refuse_template(session, "no_stmt_cache")
         return None
-    skeleton, tok_params = norm
+    lexed = _lex(query)
+    if lexed is None or not lexed[1]:
+        return None
+    skeleton, tokens, offsets = lexed
     names = sorted({s.table_name for s in X.scans_of(plan)})
     if session._any_external(names):
+        _refuse_template(session, "external")
         return None
     from cloudberry_tpu.sched import sharedcache
 
@@ -750,7 +1069,7 @@ def lookup_or_build(session, query: str, plan) -> Optional[Prep]:
         return None
     ddlv = sharedcache.plan_epoch(session)
     try:
-        sig, bindings, keyed, slots = analyze(session, plan)
+        sig, w = _walk(session, plan)
     except UnsupportedPlan:
         return None
     lock = session._generic_lock
@@ -759,21 +1078,25 @@ def lookup_or_build(session, query: str, plan) -> Optional[Prep]:
         bucket = cache.pop(skeleton, None)
         if bucket is not None:
             cache[skeleton] = bucket  # LRU touch
-            for gp in bucket:
-                if gp.matches(session, sig, versions, ddlv):
-                    session.stmt_log.bump("generic_hits")
-                    return Prep(gp, plan, keyed, bindings)
+            bucket = tuple(bucket)
+    for gp in bucket or ():
+        if gp.matches(session, sig, versions, ddlv):
+            session.stmt_log.bump("generic_hits")
+            _note_template(session, gp, plan, w, tokens, offsets)
+            return Prep(gp, plan, w.keyed, w.bindings)
     # build: re-walk with rewrite=True so the compiled program reads its
     # literals from $params (slot order identical by the walker contract)
-    sig2, bindings2, keyed2, slots2 = analyze(session, plan, rewrite=True)
-    assert sig2 == sig and list(bindings2) == list(bindings)
+    sig2, w2 = _walk(session, plan, rewrite=True)
+    assert sig2 == sig and list(w2.bindings) == list(w.bindings)
     from cloudberry_tpu.obs import trace as OT
 
     with OT.stage("compile", skeleton=skeleton[:80]):
-        gp = GenericPlan(session, skeleton, plan, names, sig, bindings2,
-                         keyed2, slots2)
-    gp.fast = _try_fast(session, gp, plan, tok_params, bindings2, keyed2,
-                        slots2)
+        gp = GenericPlan(session, skeleton, plan, names, sig, w2.bindings,
+                         w2.keyed, w2.slots)
+    gp.fast = _try_fast(session, gp, plan, tokens, w2.bindings, w2.keyed,
+                        w2.slots)
+    # the first walk saw the literals the rewrite has since replaced
+    _note_template(session, gp, plan, w, tokens, offsets)
     session.stmt_log.bump("generic_builds")
     with lock:
         bucket = cache.setdefault(skeleton, [])
@@ -781,7 +1104,7 @@ def lookup_or_build(session, query: str, plan) -> Optional[Prep]:
         del bucket[:-session.config.sched.max_variants]
         while len(cache) > _GENERIC_CACHE_MAX:
             cache.pop(next(iter(cache)))
-    return Prep(gp, plan, keyed2, bindings2, built=True)
+    return Prep(gp, plan, w2.keyed, w2.bindings, built=True)
 
 
 def generic_runner(session, query: str, plan):

@@ -604,29 +604,39 @@ class Session:
         from cloudberry_tpu.sql.parser import parse_sql
         from cloudberry_tpu.utils.faultinject import fault_point
 
-        with OT.stage("bind", host=True, lookup=True):
+        hit = None
+        with OT.stage("bind", host=True, lookup=True) as lookup:
             self._sync_store()
             self.last_tiled_report = None  # set again by a tiled runner
             ckey = self._stmt_cache_key(query, params)
             cached = self._cached_statement(ckey)
-        if cached is not None:
-            runner, cost, obs_bytes = cached
-            with OT.stage("admit", host=True):
-                self.stmt_log.bump("stmt_cache_hits")
-                self.stmt_log.bump("dispatches")
-                # capacity plane (obs/capacity.py): the cached
-                # DEVICE-BYTE estimate — one histogram sample, no plan
-                # walk on the hot path. Kept separate from the admission
-                # cost: a tiled runner admits against the whole
-                # per-query budget but its measured working set is the
-                # step estimate, and feeding the budget constant here
-                # would pin the peak gauge at config forever
-                from cloudberry_tpu.obs import capacity as OC
+            if cached is None and self.config.sched.generic_plans:
+                # literal template (sched/paramplan.py): a text whose
+                # skeleton has an armed template binds its literals
+                # here, without parse or plan
+                from cloudberry_tpu.sched import paramplan
 
-                OC.observe_stmt_bytes(self.stmt_log, obs_bytes)
-                self._dispatch_seams(fault_point)
-            with self._slot(cost):
-                return self._obs_launch(runner)
+                if not params:
+                    hit = paramplan.template_bind(self, query)
+                    if hit is not None:
+                        lookup.args["template"] = True
+                elif paramplan.param_head(query):
+                    self.stmt_log.bump("template_refused.user_params")
+        if hit is not None:
+            from cloudberry_tpu.exec.executor import ExecError
+
+            gp, bindings = hit
+            try:
+                return self._dispatch_cached(
+                    lambda: gp.run(self, gp.plan, gp.keyed, bindings),
+                    gp.est_bytes, gp.est_bytes)
+            except ExecError:
+                # what _run_with_growth answers by growing the plan: the
+                # full path below owns that, once
+                self.stmt_log.bump("template_fallbacks")
+        if cached is not None:
+            self.stmt_log.bump("stmt_cache_hits")
+            return self._dispatch_cached(*cached)
 
         with OT.stage("parse", host=True):
             stmt = parse_sql(query)
@@ -681,6 +691,29 @@ class Session:
         with self._slot(est.peak_bytes) as sid:
             return self._run_with_growth(ckey, query, result.plan, sid,
                                          cfg_plan)
+
+    def _dispatch_cached(self, runner, cost: int, obs_bytes: int):
+        """Admit and launch a runner whose plan is already known (a
+        statement-cache hit, a literal-template hit): the dispatch count,
+        the capacity sample, the fault seams and the cancel check, the
+        queue slot, the launch."""
+        from cloudberry_tpu.obs import capacity as OC
+        from cloudberry_tpu.obs import trace as OT
+        from cloudberry_tpu.utils.faultinject import fault_point
+
+        with OT.stage("admit", host=True):
+            self.stmt_log.bump("dispatches")
+            # capacity plane (obs/capacity.py): the cached DEVICE-BYTE
+            # estimate — one histogram sample, no plan walk on the hot
+            # path. Kept separate from the admission cost: a tiled
+            # runner admits against the whole per-query budget but its
+            # measured working set is the step estimate, and feeding the
+            # budget constant here would pin the peak gauge at config
+            # forever
+            OC.observe_stmt_bytes(self.stmt_log, obs_bytes)
+            self._dispatch_seams(fault_point)
+        with self._slot(cost):
+            return self._obs_launch(runner)
 
     def _plan_tiled_fallback(self, stmt, params, plan):
         """The tiled executable for an over-budget plan, or None."""

@@ -40,6 +40,10 @@ class Star(ExprNode):
 @dataclass
 class NumberLit(ExprNode):
     text: str  # keep literal text; binder decides int vs decimal + scale
+    # where the token stands in the statement's text (-1: made by a
+    # rewrite, not read from the text). Not the expression's identity:
+    # `x + 1` in the select list and in GROUP BY are the same expression
+    pos: int = field(default=-1, compare=False, repr=False)
 
 
 @dataclass
@@ -50,6 +54,7 @@ class StringLit(ExprNode):
 @dataclass
 class DateLit(ExprNode):
     value: str  # ISO yyyy-mm-dd
+    pos: int = field(default=-1, compare=False, repr=False)  # as NumberLit
 
 
 @dataclass

@@ -855,7 +855,7 @@ class Parser:
         t = self.cur
         if t.kind == "number":
             self.advance()
-            return ast.NumberLit(t.text)
+            return ast.NumberLit(t.text, t.pos)
         if t.kind == "string":
             self.advance()
             return ast.StringLit(t.text)
@@ -876,7 +876,8 @@ class Parser:
         word = self.cur.text
         if word == "date" and self.toks[self.i + 1].kind == "string":
             self.advance()
-            return ast.DateLit(self.advance().text)
+            t = self.advance()
+            return ast.DateLit(t.text, t.pos)
         if word == "interval" and self.toks[self.i + 1].kind == "string":
             n, unit = self._parse_interval_literal()
             if unit not in ("year", "month", "day"):

@@ -541,17 +541,21 @@ class TableStore:
 
     def select_partitions(self, table: str, ranges: dict | None = None,
                           eqs: dict | None = None,
-                          version: Optional[int] = None
+                          version: Optional[int] = None,
+                          candidates: Optional[list] = None
                           ) -> tuple[list[dict], dict]:
         """Pick the partitions a predicate can touch, without reading any
         column data. ``ranges``: {col: (lo, hi)}; ``eqs``: {col: value}.
         Manifest min/max prunes first (no file IO); equality predicates then
         check footer bloom filters (footer-only IO). Returns (surviving
         partition entries, report) — the report counts candidates and
-        skips per mechanism (for EXPLAIN and the file-skip tests)."""
-        man = self.read_manifest(table, version)
-        tdir = os.path.join(self.root, table)
-        report = {"candidates": len(man["partitions"]),
+        skips per mechanism (for EXPLAIN and the file-skip tests).
+        ``candidates``: the manifest's partition entries, from a caller
+        that holds them under a guard on the table's version (manifests
+        are immutable) — the manifest is then not read again."""
+        if candidates is None:
+            candidates = self.read_manifest(table, version)["partitions"]
+        report = {"candidates": len(candidates),
                   "skipped_minmax": 0, "skipped_bloom": 0}
         ranges = dict(ranges or {})
         for c, v in (eqs or {}).items():
@@ -560,7 +564,7 @@ class TableStore:
             hi = v if hi is None else min(hi, v)
             ranges[c] = (lo, hi)
         out = []
-        for part in man["partitions"]:
+        for part in candidates:
             if ranges and not all(_part_may_match(part, c, lo, hi)
                                   for c, (lo, hi) in ranges.items()):
                 report["skipped_minmax"] += 1

@@ -3,7 +3,12 @@ statement through ``Session.sql`` (the generic-plan path the server takes)
 and prints, for every program the statement launched, a hash of its
 lowered module text and the ``jax.result_info`` keys it carries.
 
-    python tests/program_identity_worker.py <n_segments> <q15v|q3>
+    python tests/program_identity_worker.py <n_segments> <q15v|q3|q1|q6> \
+        [--no-origin]
+
+``--no-origin`` binds every literal without its origin (``expr.Literal``'s
+``origin``, ISSUE 29), as the tree before it did: the witness that the
+origin reaches no program.
 """
 
 from __future__ import annotations
@@ -33,7 +38,15 @@ Q15V = ("select l_suppkey as supplier_no, "
         "from lineitem where l_shipdate >= date '1996-01-01' "
         "and l_shipdate < date '1996-04-01' "
         "group by l_suppkey order by supplier_no")
-STATEMENTS = {"q15v": Q15V, "q3": QUERIES["q3"]}
+STATEMENTS = {"q15v": Q15V, "q3": QUERIES["q3"], "q1": QUERIES["q1"],
+              "q6": QUERIES["q6"]}
+
+if "--no-origin" in sys.argv[3:]:
+    from cloudberry_tpu.plan import binder
+
+    _token_literal = binder._token_literal
+    binder._token_literal = lambda kind, text, pos=-1: _token_literal(
+        kind, text)
 
 texts: list = []
 

@@ -22,9 +22,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 WORKER = os.path.join(HERE, "program_identity_worker.py")
 
 
-def _start(nseg: int, stmt: str):
+def _start(nseg: int, stmt: str, *flags: str):
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    return subprocess.Popen([sys.executable, WORKER, str(nseg), stmt],
+    return subprocess.Popen([sys.executable, WORKER, str(nseg), stmt, *flags],
                             stdout=subprocess.PIPE, stderr=subprocess.PIPE,
                             env=env, text=True)
 
@@ -52,6 +52,26 @@ def test_two_processes_lower_the_same_module(nseg, stmt):
     if nseg > 1 and stmt == "q15v":
         assert any(k.startswith("result[3]['required bucket (node ")
                    for k in a["result_info"])
+
+
+@pytest.mark.parametrize("nseg,stmt", [(1, "q1"), (1, "q6"), (4, "q15v"),
+                                       (4, "q3")])
+def test_a_literals_origin_reaches_no_program(nseg, stmt):
+    """The origin a literal carries for the literal template (ISSUE 29)
+    is beside its value, never in the program: bound with it and without
+    it (as the tree before it bound), a statement lowers to the same
+    module text, so the compile cache of the benchmark's cells is met."""
+    procs = [_start(nseg, stmt), _start(nseg, stmt, "--no-origin")]
+    outs = []
+    for p in procs:
+        out, err = p.communicate(timeout=600)
+        assert p.returncode == 0, err[-2000:]
+        outs.append(json.loads(out.strip().splitlines()[-1]))
+    a, b = outs
+    assert a["programs"] == b["programs"] >= 1
+    assert a["rows"] == b["rows"] > 0
+    assert a["hashes"] == b["hashes"]
+    assert a["result_info"] == b["result_info"]
 
 
 def test_no_program_key_embeds_an_address():
