@@ -19,9 +19,7 @@ accelerator it exits non-zero and prints no number; an engine error is a
 traceback and a non-zero exit, never a record.
 
 Env knobs: BENCH_SF (default 1.0), BENCH_REPS (default 3), BENCH_QUERIES
-(default "q1,q3,q9"), BENCH_PALLAS (off|on|ab, default off: no Pallas
-kernel compiles for the chip yet, docs/PALLAS_AB.md; "on" and "ab" raise
-what the compiler raises).
+(default "q1,q3,q9").
 """
 
 from __future__ import annotations
@@ -831,24 +829,17 @@ def measure() -> None:
     log(f"generated sf={sf}: lineitem {n_rows} rows "
         f"in {time.time()-t0:.1f}s")
 
-    def bench_on(plan, device, use_pallas: bool = False) -> tuple:
+    def bench_on(plan, device) -> float:
         # compile per executing platform so each backend gets its best
         # kernel formulation (honest baseline: best-CPU vs best-TPU)
-        sess = session
-        if use_pallas:
-            import copy
-
-            sess = copy.copy(session)
-            sess.config = session.config.with_overrides(
-                **{"exec.use_pallas": True})
-        exe = compile_plan(plan, sess, platform=device.platform)
+        exe = compile_plan(plan, session, platform=device.platform)
         from cloudberry_tpu.exec.executor import prepare_inputs
 
         with jax.default_device(device):
             tables = {
                 key: {c: jax.device_put(v, device)
                       for c, v in cols.items()}
-                for key, cols in prepare_inputs(exe, sess).items()
+                for key, cols in prepare_inputs(exe, session).items()
             }
             out = exe.fn(tables)  # warmup/compile
             jax.block_until_ready(out)
@@ -858,28 +849,7 @@ def measure() -> None:
                 out = exe.fn(tables)
                 jax.block_until_ready(out)
                 best = min(best, time.time() - t)
-        return best, out
-
-    def outputs_match(a, b) -> bool:
-        # selected lanes only: unselected lanes legitimately hold
-        # path-dependent garbage
-        import numpy as np
-
-        acols, asel, _ = a
-        bcols, bsel, _ = b
-        m = np.asarray(asel)
-        if set(acols) != set(bcols)                 or not np.array_equal(m, np.asarray(bsel)):
-            return False
-        for k in acols:
-            x, y = np.asarray(acols[k])[m], np.asarray(bcols[k])[m]
-            if x.dtype.kind == "f" or y.dtype.kind == "f":
-                if not np.allclose(x.astype(np.float64),
-                                   y.astype(np.float64),
-                                   rtol=1e-5, atol=1e-6, equal_nan=True):
-                    return False
-            elif not np.array_equal(x, y):
-                return False
-        return True
+        return best
 
     def plan_scan_bytes(plan) -> int:
         """Bytes the plan's projected scans read — the roofline numerator,
@@ -896,12 +866,6 @@ def measure() -> None:
                     total += np.asarray(arr).nbytes
         return total
 
-    # BENCH_PALLAS=ab A/Bs each query's TPU run with the fused kernels
-    # (dense agg + probe join) and keeps whichever is faster; =on forces
-    # them; the default, off, skips them (the compiler refuses all three
-    # today, docs/PALLAS_AB.md) — a kernel that fails raises
-    pallas_mode = os.environ.get("BENCH_PALLAS", "off")
-    pallas_won = []
     speedups = {}
     rows_s = {}
     scan_bytes = {}
@@ -911,22 +875,10 @@ def measure() -> None:
         # plan a session would execute, minus admission/dispatch
         plan = plan_statement(parse_sql(QUERIES[qn]), session, {}).plan
         scan_bytes[qn] = plan_scan_bytes(plan)
-        cpu_t, _ = bench_on(plan, cpu)
+        cpu_t = bench_on(plan, cpu)
         log(f"{qn} cpu executor: {cpu_t*1000:.1f} ms")
-        tpu_t, tpu_out = bench_on(plan, dev,
-                                  use_pallas=(pallas_mode == "on"))
+        tpu_t = bench_on(plan, dev)
         log(f"{qn} tpu executor: {tpu_t*1000:.1f} ms")
-        if pallas_mode == "ab":
-            tp, p_out = bench_on(plan, dev, use_pallas=True)
-            log(f"{qn} tpu executor (pallas): {tp*1000:.1f} ms")
-            # a fast-but-wrong kernel must never win: only a
-            # result-identical Pallas run can replace the XLA time
-            if not outputs_match(tpu_out, p_out):
-                log(f"{qn} PALLAS PARITY FAILURE — results differ "
-                    "from the XLA path; pallas time discarded")
-            elif tp < tpu_t:
-                tpu_t = tp
-                pallas_won.append(qn)
         speedups[qn] = cpu_t / tpu_t
         tpu_wall[qn] = tpu_t
         # rows/sec/chip (BASELINE.md's second metric): the biggest
@@ -983,8 +935,6 @@ def measure() -> None:
         f"{q}={s:.2f}x/{rows_s[q]/1e6:.0f}Mrows_s_chip"
         f"/{roofline['per_query'].get(q, {}).get('hbm_frac', 0):.3f}HBM"
         for q, s in speedups.items())
-    if pallas_won:
-        per_q += f"; pallas won: {','.join(pallas_won)}"
     emit({
         "metric": metric_name(),
         "value": round(geo, 3),

@@ -58,22 +58,6 @@ class InterconnectConfig:
 
 
 @dataclass(frozen=True)
-class ExecConfig:
-    """Executor shape/dtype discipline (XLA: static shapes only).
-
-    Planned-but-unwired knobs live in docs/DESIGN.md's gap list, not here —
-    every field below is read by the engine."""
-
-    # Fused Pallas aggregation/join kernels (exec/pallas_kernels.py):
-    # dense one-hot agg (int64/DECIMAL sums EXACT via 13-bit f32 limbs),
-    # sorted-segment mid-cardinality agg (exact via 8-bit int32 limbs),
-    # and the probe join. Off by default: the TPU compiler refuses all
-    # three today (docs/PALLAS_AB.md); bench.py BENCH_PALLAS=ab|on turns
-    # them on for a run and raises what the compiler raises.
-    use_pallas: bool = False
-
-
-@dataclass(frozen=True)
 class JoinFilterConfig:
     """Runtime join-filter digests + the join-index cache (the
     semijoin-reduction / runtime-filter-pushdown pair: ORCA's semijoin
@@ -658,7 +642,6 @@ class Config:
     # tightens but never loosens this.
     statement_timeout_s: float = 0.0
     interconnect: InterconnectConfig = field(default_factory=InterconnectConfig)
-    exec: ExecConfig = field(default_factory=ExecConfig)
     planner: PlannerConfig = field(default_factory=PlannerConfig)
     join_filter: JoinFilterConfig = field(default_factory=JoinFilterConfig)
     resource: ResourceConfig = field(default_factory=ResourceConfig)
@@ -682,7 +665,7 @@ class Config:
 
     def with_overrides(self, **kv: Any) -> "Config":
         """Return a copy with dotted-path overrides, e.g.
-        ``cfg.with_overrides(**{"exec.use_pallas": True})``."""
+        ``cfg.with_overrides(**{"interconnect.packed_wire": False})``."""
         out = self
         for path, value in kv.items():
             parts = path.split(".")

@@ -18,6 +18,8 @@ all — the planner relies on that (plan/distribute.py).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -69,6 +71,37 @@ def prepare_dist_inputs(plan: N.PlanNode, session, names=None):
     return inputs, in_specs
 
 
+def wire_packed(session) -> bool:
+    """Whether a Motion ships ONE packed buffer (kernels.wire_layout) or
+    one collective per column: what the lowerer is built with, and what
+    EXPLAIN ANALYZE's launch counts describe."""
+    return session.config.interconnect.packed_wire
+
+
+def dist_lowering(session):
+    """``(mesh, lowerer)``: what every distributed program of ``session``
+    is lowered with — the segment mesh over the live devices, and the
+    DistLowerer constructor with the segment count, the Motion transport
+    and the wire format bound. The host topology re-derives from the
+    LIVE device list here, so an epoch flip (expand/shrink/failover)
+    re-splits collectives the moment the new epoch's first program
+    compiles (the shared cache tier keys programs by topology epoch, so
+    a stale split never serves post-cutover), and the one-shot, tiled
+    and EXPLAIN ANALYZE programs lower a Motion to the same collectives:
+    a plan whose motions carry two-level stamps would otherwise pay
+    their padding while shipping flat."""
+    from cloudberry_tpu.parallel.transport import (hier_topology,
+                                                   make_transport)
+
+    nseg = session.config.n_segments
+    live_ids = getattr(session, "_live_device_ids", None)
+    ic = session.config.interconnect
+    tx = make_transport(ic.backend, nseg, chunks=ic.ring_chunks,
+                        topo=hier_topology(session.config, nseg, live_ids))
+    return segment_mesh(nseg, live_ids), functools.partial(
+        DistLowerer, nseg=nseg, tx=tx, packed=wire_packed(session))
+
+
 def compile_distributed(plan: N.PlanNode, session, param_keys=None,
                         instrument=False):
     """Build the jitted SPMD program once; reusable across calls (the
@@ -82,38 +115,23 @@ def compile_distributed(plan: N.PlanNode, session, param_keys=None,
     segments, replicated nodes report segment 0's — so the instrumented
     program is this same entry point's program, not a side path's."""
     from cloudberry_tpu.obs.capacity import motion_wire_bytes
-    from cloudberry_tpu.parallel.transport import (hier_topology,
-                                                   make_transport)
 
-    nseg = session.config.n_segments
-    live_ids = getattr(session, "_live_device_ids", None)
-    mesh = segment_mesh(nseg, live_ids)
-    ic = session.config.interconnect
-    # topology-aware two-level motion: the host topology re-derives from
-    # the LIVE device list here, so an epoch flip (expand/shrink/
-    # failover) re-splits collectives the moment the new epoch's first
-    # program compiles — and the shared cache tier keys programs by
-    # topology epoch, so a stale split can never serve post-cutover
-    topo = hier_topology(session.config, nseg, live_ids)
-    tx = make_transport(ic.backend, nseg, chunks=ic.ring_chunks,
-                        topo=topo)
-    packed = ic.packed_wire
+    mesh, lowerer = dist_lowering(session)
     inputs, in_specs = prepare_dist_inputs(plan, session)
     if param_keys:
         in_specs["$params"] = {k: P() for k in param_keys}
     X.count_compile(session)
-    lowerer_cls = _InstrumentedDistLowerer if instrument else DistLowerer
 
     def seg_fn(tables):
-        low = lowerer_cls(tables, nseg, tx=tx, packed=packed,
-                          params=tables.get("$params"))
+        low = lowerer(tables, params=tables.get("$params"),
+                      count_rows=instrument)
         cols, sel = low.lower(plan)
         out = {f.name: cols[f.name][None] for f in plan.fields}
         # reduce checks to replicated scalars (any segment tripped) so
         # every HOST can read them — per-seg shards are not addressable
         # across processes on a multi-host mesh
         checks = {
-            k: tx.psum(jnp.asarray(v).astype(jnp.int32), SEG_AXIS) > 0
+            k: low.tx.psum(jnp.asarray(v).astype(jnp.int32), SEG_AXIS) > 0
             for k, v in low.checks.items()}
         # motion stats (already pmax-reduced, replicated): the observed
         # per-destination bucket demand each redistribute actually saw —
@@ -379,11 +397,9 @@ def _shard_map(f, mesh, in_specs, out_specs):
 
 
 class DistLowerer(X.Lowerer):
-    def __init__(self, tables, nseg: int, platform: str | None = None,
-                 use_pallas: bool = False, tx=None, packed: bool = True,
-                 params=None, root=None):
-        super().__init__(tables, platform=platform, use_pallas=use_pallas,
-                         params=params, root=root)
+    def __init__(self, tables, nseg: int, tx=None, packed: bool = True,
+                 **kw):
+        super().__init__(tables, **kw)
         self.nseg = nseg
         # motion transport (ic_modules.c vtable analog): XLA-native
         # collectives or ppermute ring compositions
@@ -395,6 +411,17 @@ class DistLowerer(X.Lowerer):
         # packed wire format (kernels.wire_layout): one collective per
         # motion; False = legacy one-collective-per-column (parity path)
         self.packed = packed
+
+    def record_rows(self, node: N.PlanNode, n) -> None:
+        """Counts ride the replicated stats channel: the global sum for
+        partitioned nodes and segment 0's count for replicated ones
+        (post-gather nodes must count once, not nseg times) —
+        instrument_counts picks between them host-side."""
+        is_seg0 = jnp.equal(jax.lax.axis_index(SEG_AXIS), 0)
+        self.stats[f"node_rows_sum (node {self.ref(node)})"] = \
+            self.tx.psum(n, SEG_AXIS)
+        self.stats[f"node_rows_one (node {self.ref(node)})"] = \
+            self.tx.psum(jnp.where(is_seg0, n, 0), SEG_AXIS)
 
     def scan(self, node: N.PScan):
         if node.table_name == "$dual":
@@ -699,31 +726,12 @@ def _digest_fold(rows: "jnp.ndarray", nkeys: int) -> "jnp.ndarray":
     return jnp.concatenate(parts)
 
 
-class _InstrumentedDistLowerer(DistLowerer):
-    """EXPLAIN ANALYZE's per-node row counts over the SAME distributed
-    lowering (instrument.py run_pipeline): each node's selected-row
-    count rides the existing replicated stats channel — the global sum
-    for partitioned nodes and segment 0's count for replicated ones
-    (post-gather nodes must count once, not nseg times)."""
-
-    def lower(self, node):
-        cols, sel = super().lower(node)
-        cnt = jnp.sum(sel.astype(jnp.int64))
-        is_seg0 = jnp.equal(jax.lax.axis_index(SEG_AXIS), 0)
-        self.stats[f"node_rows_sum (node {self.ref(node)})"] = \
-            self.tx.psum(cnt, SEG_AXIS)
-        self.stats[f"node_rows_one (node {self.ref(node)})"] = \
-            self.tx.psum(jnp.where(is_seg0, cnt, 0), SEG_AXIS)
-        return cols, sel
-
-
 def instrument_counts(plan: N.PlanNode, stats: dict) -> dict:
     """Host-side per-node counts (by ``id(node)``, for this process's
     renderers) from an instrumented program's stats, whose keys name
     nodes by ordinal: pick the cross-segment sum for partitioned nodes,
     segment 0's count
-    for replicated ones (the same rule the legacy instrumented path
-    applies to its per-seg arrays)."""
+    for replicated ones."""
     import re
 
     sums, ones = {}, {}

@@ -100,35 +100,20 @@ def compile_plan(plan: N.PlanNode, session,
     table_names = sorted({s.table_name for s in scans
                           if not keyed_scan(s)})
     platform = platform or jax.default_backend()
-    use_pallas = session.config.exec.use_pallas
     count_compile(session)
 
-    if instrument:
-        from cloudberry_tpu.exec.instrument import InstrumentingMixin
-
-        class _InstrLowerer(InstrumentingMixin, Lowerer):
-            def __init__(self, *a, **kw):
-                Lowerer.__init__(self, *a, **kw)
-                self.__init_instrument__()
-
-        def run(tables):
-            low = _InstrLowerer(tables, platform=platform,
-                                use_pallas=use_pallas,
-                                params=tables.get("$params"))
-            cols, sel = low.lower(plan)
-            out = {f.name: cols[f.name] for f in plan.fields}
-            return out, sel, low.checks, low.node_counts
-
-        return Executable(plan, jax.jit(run), table_names, store_scans,
-                          run, instrumented=True)
-
     def run(tables):
-        low = Lowerer(tables, platform=platform, use_pallas=use_pallas,
-                      params=tables.get("$params"))
+        low = Lowerer(tables, platform=platform,
+                      params=tables.get("$params"), count_rows=instrument)
         cols, sel = low.lower(plan)
         out = {f.name: cols[f.name] for f in plan.fields}
+        if instrument:
+            return out, sel, low.checks, low.node_counts
         return out, sel, low.checks
 
+    if instrument:
+        return Executable(plan, jax.jit(run), table_names, store_scans,
+                          run, instrumented=True)
     return Executable(plan, jax.jit(run), table_names, store_scans, run,
                       packed_fn=jax.jit(
                           lambda tables: pack_answer(*run(tables))))
@@ -724,10 +709,16 @@ def scans_of(plan: N.PlanNode):
 
 class Lowerer:
     """Traces a plan into jax ops. Subclassed by the distributed executor,
-    which overrides scan (per-segment inputs) and motion (collectives)."""
+    which overrides scan (per-segment inputs) and motion (collectives).
+    Three pieces of state vary what a lowering does, for both classes:
+    ``replace`` (nodes already computed), ``stream``/``tile_n`` (the scan
+    a tiled step feeds a tile at a time) and ``count_rows`` (EXPLAIN
+    ANALYZE's per-node row counts)."""
 
-    def __init__(self, tables, platform: str | None = None,
-                 use_pallas: bool = False, params=None, root=None):
+    def __init__(self, tables, platform: str | None = None, params=None,
+                 root=None, replace: dict | None = None,
+                 stream: N.PScan | None = None, tile_n=None,
+                 count_rows: bool = False):
         self.tables = tables
         # id(node) -> ordinal under ``root`` (numbered_nodes): how check
         # and stats keys name a node. Without a ``root`` the first plan
@@ -740,6 +731,21 @@ class Lowerer:
         # "$prm<slot>" -> scalar array, injected next to the columns when
         # an expression carries Param leaves
         self.params = params
+        # id(node) -> (cols, sel): such a node lowers to what it is given
+        # and its subtree is never traced (a tiled step's prelude-computed
+        # builds, a finalize's accumulator leaf)
+        self.replace = replace or {}
+        # the streamed scan of a tiled step reads ``tables["$tile"]``,
+        # ``tile_n`` rows of it; every other scan, of the same table too,
+        # reads its table
+        self.stream = stream
+        self.tile_n = tile_n
+        # EXPLAIN ANALYZE: every lowered node's selected-row count goes
+        # through record_rows
+        self.count_rows = count_rows
+        # by the node's ORDINAL (ref): the counts are a program output,
+        # and an output's key is part of the program's text
+        self.node_counts: dict[int, jnp.ndarray] = {}
         self.checks: dict[str, jnp.ndarray] = {}
         # replicated observability scalars (e.g. each redistribute's
         # observed bucket demand) — the distributed executor returns
@@ -753,7 +759,6 @@ class Lowerer:
         platform = platform or jax.default_backend()
         self.platform = platform
         self.dense_strategy = "segment" if platform == "cpu" else "reduce"
-        self.use_pallas = use_pallas
 
     def _number(self, plan: N.PlanNode) -> None:
         for n in all_nodes(plan):
@@ -773,8 +778,33 @@ class Lowerer:
         return f"(node {self.ref(node)}: {node.title()})"
 
     def lower(self, node: N.PlanNode) -> tuple[dict, jnp.ndarray]:
+        hit = self.replace.get(id(node))
+        if hit is not None:
+            return hit
         if not self._ordinals:
             self._number(node)
+        cols, sel = self.scan_tile(node) if node is self.stream \
+            else self.lower_node(node)
+        if self.count_rows:
+            self.record_rows(node, jnp.sum(sel.astype(jnp.int64)))
+        return cols, sel
+
+    def record_rows(self, node: N.PlanNode, n) -> None:
+        """Where ``count_rows`` puts a lowered node's selected-row count:
+        here a program output keyed by ordinal, in the distributed
+        lowerer the replicated stats channel."""
+        self.node_counts[self.ref(node)] = n
+
+    def scan_tile(self, node: N.PScan):
+        tile = self.tables["$tile"]
+        cols = {}
+        for phys, out in node.column_map.items():
+            cols[out] = tile[phys]
+        for phys, out in node.mask_map.items():
+            cols[out] = tile[f"$nn:{phys}"]
+        return cols, jnp.arange(node.capacity) < self.tile_n
+
+    def lower_node(self, node: N.PlanNode) -> tuple[dict, jnp.ndarray]:
         if isinstance(node, N.PScan):
             return self.scan(node)
         if isinstance(node, N.PFilter):
@@ -967,28 +997,21 @@ class Lowerer:
             return self._join_expand(node, bcols, bsel, bselm, bkeys,
                                      pcols, psel, pselm, pkeys)
 
-        fused = self._probe_join_pallas(node, bcols, bselm, bkeys,
-                                        pselm, pkeys)
-        if fused is not None:
-            matched, payload, has_dup = fused
+        jix = self._join_index(node)
+        if jix is not None:
+            idx, matched, has_dup = K.join_lookup_sorted(
+                jix[0], jix[1], jix[2], pkeys, pselm,
+                bits=node.pack_bits)
         else:
-            jix = self._join_index(node)
-            if jix is not None:
-                idx, matched, has_dup = K.join_lookup_sorted(
-                    jix[0], jix[1], jix[2], pkeys, pselm,
-                    bits=node.pack_bits)
-            else:
-                idx, matched, has_dup = K.join_lookup(
-                    bkeys, bselm, pkeys, pselm, bits=node.pack_bits)
-            payload = K.gather_payload(
-                {n: bcols[n] for n in node.build_payload}, idx, matched)
+            idx, matched, has_dup = K.join_lookup(
+                bkeys, bselm, pkeys, pselm, bits=node.pack_bits)
+        payload = K.gather_payload(
+            {n: bcols[n] for n in node.build_payload}, idx, matched)
         if node.kind in ("inner", "left"):
             # semi/anti only test membership; inner/left rely on the
-            # planner's uniqueness proof — verify it at runtime. The XLA
-            # path checks the build side itself (adjacent-equal on its
-            # sorted keys); the fused path's >1 one-hot column sum is
-            # weaker — it fires only when a probe row actually HITS the
-            # duplicated key, i.e. exactly when results would be wrong
+            # planner's uniqueness proof — verify it at runtime: the
+            # lookup checks the build side itself (adjacent-equal on its
+            # sorted keys)
             self.checks[
                 f"join build side has duplicate keys {self.label(node)} but "
                 "the planner assumed a unique (PK) build side"] = has_dup
@@ -1428,120 +1451,15 @@ class Lowerer:
 
         key_cols = {name: self.expr(e, cols)
                     for name, e in node.group_keys}
-        out_keys, out_aggs, out_sel, n_groups = merge_group_aggregate(
+        out_keys, out_aggs, out_sel, n_groups = K.group_aggregate(
             key_cols, agg_values, agg_specs, sel, node.capacity,
-            self.use_pallas, self.platform, pack_bits=node.pack_bits)
+            pack_bits=node.pack_bits)
         self.checks[
             f"aggregation overflow: more groups than capacity "
             f"{node.capacity} {self.label(node)}"] = n_groups > node.capacity
         for name, div in post_scale.items():
             out_aggs[name] = out_aggs[name] / div
         return {**out_keys, **out_aggs}, out_sel
-
-
-    def _dense_agg_pallas(self, gid, n_cells, agg_specs, agg_values, sel):
-        """Fused one-pass Pallas path (config.exec.use_pallas) for
-        sum/count/avg over a small cell domain. Integer-carried values
-        (BIGINT, DECIMAL cents) ride 13-bit f32 limbs through the MXU
-        one-hot matmul and recombine EXACTLY in int64 — bit-identical to
-        the XLA path, so Q1's money sums are A/B-eligible. Float values
-        keep the single-f32-row transport (approximate analytics).
-        Returns None when ineligible (min/max) → XLA path."""
-        if not self.use_pallas:
-            return None
-        if any(s.func not in ("sum", "count", "avg") for s in agg_specs):
-            return None
-        from cloudberry_tpu.exec import pallas_kernels as PK
-
-        tile = 2048
-        sum_specs = [s for s in agg_specs if s.func in ("sum", "avg")]
-        rows: list = []
-        layout = []  # (spec, first row, "int"|"float", value dtype)
-        for s in sum_specs:
-            v = agg_values[s.out_name]
-            if jnp.issubdtype(v.dtype, jnp.integer):
-                layout.append((s, len(rows), "int", v.dtype))
-                rows.extend(PK.int64_to_agg_limbs(v))
-            else:
-                layout.append((s, len(rows), "float", v.dtype))
-                rows.append(v.astype(jnp.float32))
-        stacked = jnp.stack(rows) if rows else \
-            jnp.zeros((0, gid.shape[0]), jnp.float32)
-        tiles = PK.dense_agg_tiles_pallas(
-            _pallas_pad(gid.astype(jnp.int32), tile),
-            _pallas_pad(stacked, tile),
-            _pallas_pad(sel, tile),
-            n_cells=n_cells, tile=tile,
-            interpret=(self.platform == "cpu"))
-        # per-tile counts are exact integers in f32 (≤ tile < 2^24);
-        # the cross-tile combine runs in int64, exact for any N
-        counts = jnp.sum(jnp.round(tiles[:, 0]).astype(jnp.int64), axis=0)
-        out = {}
-        n_limbs = len(PK.AGG_LIMB_BITS)
-        for s, row0, kind, dt in layout:
-            if kind == "int":
-                totals = [jnp.sum(jnp.round(tiles[:, 1 + row0 + i])
-                                  .astype(jnp.int64), axis=0)
-                          for i in range(n_limbs)]
-                ssum = PK.agg_limbs_to_int64(totals)
-                out[s.out_name] = ssum.astype(jnp.float64) \
-                    / jnp.maximum(counts, 1) if s.func == "avg" \
-                    else ssum.astype(dt)
-            else:
-                ssum = jnp.sum(tiles[:, 1 + row0].astype(jnp.float64),
-                               axis=0)
-                out[s.out_name] = ssum / jnp.maximum(counts, 1) \
-                    if s.func == "avg" else ssum.astype(dt)
-        for s in agg_specs:
-            if s.func == "count":
-                out[s.out_name] = counts
-        return out, counts > 0
-
-    _PALLAS_PROBE_MAX_BUILD = 2048
-
-    def _probe_join_pallas(self, node: N.PJoin, bcols, bselm, bkeys,
-                           pselm, pkeys):
-        """Fused probe join (config.exec.use_pallas): for a SMALL unique
-        build whose keys pack to 32 bits, stream probe tiles once —
-        compare-all match on the VPU, payload gather as ONE one-hot
-        matmul on the MXU, integer payloads transported exactly through
-        21/21/22-bit f32 limbs (pallas_kernels.probe_join_pallas).
-        Returns (matched, payload cols, has_dup) or None → XLA path."""
-        if not self.use_pallas or node.pack_bits != 32:
-            return None
-        b = int(bselm.shape[0])
-        if b > self._PALLAS_PROBE_MAX_BUILD:
-            return None
-        for nm in node.build_payload:
-            if not (jnp.issubdtype(bcols[nm].dtype, jnp.integer)
-                    or bcols[nm].dtype == jnp.bool_):
-                return None  # float payload: exactness needs the XLA path
-        from cloudberry_tpu.exec import pallas_kernels as PK
-
-        ranges = K.key_ranges(bkeys, bselm)
-        bp = K.downcast32(K.pack_with_ranges(bkeys, ranges))
-        pp = K.downcast32(K.pack_with_ranges(pkeys, ranges))
-        rows = []
-        for nm in node.build_payload:
-            rows.extend(PK.int64_to_limbs(bcols[nm]))
-        if not rows:  # membership-only joins still fuse the match
-            rows = [jnp.zeros((b,), jnp.float32)]
-        tile = 1024
-        n = int(pselm.shape[0])
-        match_f, gathered = PK.probe_join_pallas(
-            _pallas_pad(bp, 256), _pallas_pad(bselm, 256),
-            _pallas_pad(pp, tile), _pallas_pad(pselm, tile),
-            _pallas_pad(jnp.stack(rows), 256), tile=tile,
-            interpret=(self.platform == "cpu"))
-        matched = match_f[:n] > 0.5
-        has_dup = jnp.any(match_f > 1.5)
-        payload = {}
-        for i, nm in enumerate(node.build_payload):
-            v = PK.limbs_to_int64(gathered[3 * i, :n],
-                                  gathered[3 * i + 1, :n],
-                                  gathered[3 * i + 2, :n])
-            payload[nm] = v.astype(bcols[nm].dtype)
-        return matched, payload, has_dup
 
     def _dense_agg(self, node: N.PAgg, cols, sel, agg_specs, agg_values,
                    post_scale):
@@ -1576,14 +1494,9 @@ class Lowerer:
         for (name, e), stride in zip(node.group_keys, strides):
             gid = gid + self.expr(e, cols).astype(jnp.int32) \
                 * np.int32(stride)
-        pallas_out = self._dense_agg_pallas(gid, prod, agg_specs,
-                                            agg_values, sel)
-        if pallas_out is not None:
-            out_aggs, occupied = pallas_out
-        else:
-            out_aggs, occupied = K.group_aggregate_dense(
-                gid, prod, agg_values, agg_specs, sel,
-                strategy=self.dense_strategy)
+        out_aggs, occupied = K.group_aggregate_dense(
+            gid, prod, agg_values, agg_specs, sel,
+            strategy=self.dense_strategy)
         for name, div in post_scale.items():
             out_aggs[name] = out_aggs[name] / div
 
@@ -1599,28 +1512,6 @@ class Lowerer:
             out_aggs = {n: jnp.pad(c, (0, pad)) for n, c in out_aggs.items()}
             occupied = jnp.pad(occupied, (0, pad))
         return {**out_keys, **out_aggs}, occupied
-
-
-def merge_group_aggregate(key_cols, agg_values, specs, sel, capacity: int,
-                          use_pallas: bool, platform: str,
-                          pack_bits: int = 0):
-    """Grouped-aggregation dispatch shared by the one-shot Lowerer and
-    the tiled/tiled-dist merge steps: the fused sorted-segment Pallas
-    kernel when eligible (sum/avg over integer-carried values + count,
-    ≤ 2^23 rows — pallas_kernels.sorted_segment_eligible), else the XLA
-    sort path. The two produce BIT-IDENTICAL results for eligible aggs
-    (int sums exact in both), so per-tile partials and one-shot runs
-    agree exactly whichever side fires."""
-    if use_pallas:
-        from cloudberry_tpu.exec import pallas_kernels as PK
-
-        if PK.sorted_segment_eligible(specs, agg_values,
-                                      int(sel.shape[0])):
-            return PK.sorted_segment_aggregate(
-                key_cols, agg_values, specs, sel, capacity,
-                interpret=(platform == "cpu"))
-    return K.group_aggregate(key_cols, agg_values, specs, sel, capacity,
-                             pack_bits=pack_bits)
 
 
 def _sortable(e: ex.Expr, child: N.PlanNode, cols) -> jnp.ndarray:
@@ -1671,15 +1562,6 @@ def _shift_months_days(days, n_months: int):
     dim = jnp.where((m2 == 2) & leap, 29, dim)
     d2 = jnp.minimum(d.astype(jnp.int64), dim)
     return _days_from_civil(y2, m2, d2)
-
-
-def _pallas_pad(a, tile):
-    n = a.shape[-1]
-    pad = (-n) % tile
-    if pad == 0:
-        return a
-    widths = [(0, 0)] * (a.ndim - 1) + [(0, pad)]
-    return jnp.pad(a, widths)
 
 
 def _substitute_subqueries(e: ex.Expr, mapping: dict[int, str]) -> ex.Expr:

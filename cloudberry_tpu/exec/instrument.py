@@ -30,7 +30,6 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field
 
-import jax.numpy as jnp
 import numpy as np
 
 from cloudberry_tpu.plan import nodes as N
@@ -340,20 +339,6 @@ class QueryMetrics:
     compiles: int = 0
 
 
-class InstrumentingMixin:
-    """Mixes into a Lowerer: records post-node selected-row counts."""
-
-    def __init_instrument__(self):
-        # by the node's ORDINAL (Lowerer.ref): the counts are a program
-        # output, and an output's key is part of the program's text
-        self.node_counts: dict[int, jnp.ndarray] = {}
-
-    def lower(self, node):  # type: ignore[override]
-        cols, sel = super().lower(node)  # type: ignore[misc]
-        self.node_counts[self.ref(node)] = jnp.sum(sel.astype(jnp.int64))
-        return cols, sel
-
-
 def counts_by_node(plan: N.PlanNode, counts: dict) -> dict:
     """An instrumented program's counts, keyed by ordinal, as this
     process's renderers key them: by ``id(node)``."""
@@ -537,128 +522,6 @@ def explain_analyze_text(plan: N.PlanNode, counts: dict[int, int],
     return "\n".join(lines)
 
 
-# --------------------------------------------------- the instrumented runs
-
-
-def run_instrumented(plan: N.PlanNode, session, query: str = ""):
-    """Execute with instrumentation; returns (ColumnBatch, QueryMetrics).
-
-    The LEGACY side path: a private jitted program outside the statement
-    pipeline (no lifecycle handle, no admission, no generic-plan form).
-    Kept as the parity oracle for run_pipeline and for library callers
-    that want counts without pipeline semantics.
-    """
-    from cloudberry_tpu.exec import executor as X
-
-    if session.config.n_segments > 1:
-        return _run_instrumented_dist(plan, session, query)
-
-    import jax
-
-    class InstrLowerer(InstrumentingMixin, X.Lowerer):
-        def __init__(self, tables, platform=None):
-            X.Lowerer.__init__(self, tables, platform)
-            self.__init_instrument__()
-
-    def run(tables):
-        low = InstrLowerer(tables)
-        cols, sel = low.lower(plan)
-        out = {f.name: cols[f.name] for f in plan.fields}
-        return out, sel, low.checks, low.node_counts
-
-    fn = jax.jit(run)
-    tables = X.prepare_plan_inputs(plan, session)
-    (cols, sel, checks, counts), compile_s, wall_s = \
-        _timed_compile_run(fn, tables)
-    X.raise_checks(checks)
-    batch = X.make_batch(plan, cols, sel)
-
-    counts_host = {k: int(np.asarray(v))
-                   for k, v in counts_by_node(plan, counts).items()}
-    metrics = _metrics(plan, counts_host, query, wall_s, compile_s,
-                       int(np.asarray(sel).sum()))
-    _emit(session, metrics)
-    return batch, metrics
-
-
-def _run_instrumented_dist(plan: N.PlanNode, session, query: str):
-    """Distributed: per-node counts summed over segments (post-gather nodes
-    count once via segment 0 — they are replicated)."""
-    import jax
-
-    from cloudberry_tpu.exec import dist_executor as DX
-    from cloudberry_tpu.exec import executor as X
-    from jax.sharding import PartitionSpec as P
-
-    # reuse the dist executor wiring but with an instrumenting lowerer
-    nseg = session.config.n_segments
-    mesh = DX.segment_mesh(nseg,
-                           getattr(session, "_live_device_ids", None))
-    inputs, in_specs = DX.prepare_dist_inputs(plan, session)
-
-    from cloudberry_tpu.parallel.transport import (hier_topology,
-                                                   make_transport)
-
-    ic = session.config.interconnect
-    # instrument the program the engine actually runs: on a two-level
-    # session the real path is hierarchical, and EXPLAIN ANALYZE's
-    # counts/annotations must describe THAT program, not a flat side
-    # path (compile_distributed's same-entry-point contract)
-    topo = hier_topology(session.config, nseg,
-                         getattr(session, "_live_device_ids", None))
-    tx = make_transport(ic.backend, nseg, chunks=ic.ring_chunks,
-                        topo=topo)
-    packed = ic.packed_wire
-
-    class InstrDistLowerer(InstrumentingMixin, DX.DistLowerer):
-        def __init__(self, tables, nseg):
-            DX.DistLowerer.__init__(self, tables, nseg, tx=tx,
-                                    packed=packed)
-            self.__init_instrument__()
-
-    def seg_fn(tables):
-        low = InstrDistLowerer(tables, nseg)
-        cols, sel = low.lower(plan)
-        out = {f.name: cols[f.name][None] for f in plan.fields}
-        checks = {k: jnp.asarray(v).reshape(1) for k, v in low.checks.items()}
-        counts = {k: jnp.asarray(v).reshape(1)
-                  for k, v in low.node_counts.items()}
-        return out, sel[None], checks, counts
-
-    out_specs = ({f.name: P(DX.SEG_AXIS) for f in plan.fields},
-                 P(DX.SEG_AXIS), P(DX.SEG_AXIS), P(DX.SEG_AXIS))
-    fn = jax.jit(DX._shard_map(seg_fn, mesh, (in_specs,), out_specs))
-    (cols, sel, checks, counts), compile_s, wall_s = \
-        _timed_compile_run(fn, inputs)
-    X.raise_checks(checks)
-    host_cols = {k: np.asarray(v)[0] for k, v in cols.items()}
-    host_sel = np.asarray(sel)[0]
-    batch = X.make_batch(plan, host_cols, host_sel)
-
-    counts_host = _dist_counts_host(plan, counts)
-    metrics = _metrics(plan, counts_host, query, wall_s, compile_s,
-                       int(host_sel.sum()))
-    _emit(session, metrics)
-    return batch, metrics
-
-
-def _dist_counts_host(plan, counts) -> dict:
-    """Per-seg count arrays → one number per node: partitioned nodes sum
-    across segments, replicated nodes count once (segment 0)."""
-    counts_host = {}
-    counts = counts_by_node(plan, counts)
-    for n in plan_nodes_in_order(plan):
-        arr = counts.get(id(n))
-        if arr is None:
-            continue
-        per_seg = np.asarray(arr)
-        if n.sharding is not None and n.sharding.is_partitioned:
-            counts_host[id(n)] = int(per_seg.sum())
-        else:
-            counts_host[id(n)] = int(per_seg[0])  # replicated: count once
-    return counts_host
-
-
 # --------------------------------------- EXPLAIN ANALYZE via the pipeline
 
 
@@ -729,10 +592,11 @@ def _generic_form(session, plan):
 
 def _pipeline_once(plan, session, query):
     from cloudberry_tpu.exec import executor as X
+    from cloudberry_tpu.exec.dist_executor import wire_packed
     from cloudberry_tpu.exec.resource import ResourceError, check_admission
 
     session.last_tiled_report = None  # set again by the tiled fallback
-    packed = session.config.interconnect.packed_wire
+    packed = wire_packed(session)
     try:
         est = check_admission(plan, session)
     except ResourceError:

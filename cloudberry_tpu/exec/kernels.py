@@ -306,11 +306,8 @@ class AggSpec:
 
 @dataclass
 class GroupLayout:
-    """Sorted-group scaffolding shared by the XLA sort-based aggregation
-    and the fused Pallas sorted-segment kernel — ONE implementation of
-    the sort, boundary detection, and start compaction, so the two paths
-    cannot diverge on a grouping rule (their bit-identity is a contract:
-    the bench A/B gate and the tiled-merge parity both rely on it)."""
+    """Sorted-group scaffolding of the sort-based aggregation: the sort,
+    boundary detection and start compaction."""
 
     names: list
     perm: jnp.ndarray        # sort permutation (selected rows first)
@@ -437,10 +434,9 @@ def group_aggregate(
         elif spec.func == "avg":
             # integer-carried values (BIGINT, DECIMAL cents) sum EXACTLY
             # in int64 before the f64 division — an f64 cumsum rounds
-            # once prefixes pass 2^53, and the fused Pallas path (which
-            # divides the exact int64 sum) must stay bit-identical. The
-            # widen matters for INT32/DATE too: cumsum keeps the input
-            # dtype, so an un-widened int32 numerator would wrap at 2^31.
+            # once prefixes pass 2^53. The widen matters for INT32/DATE
+            # too: cumsum keeps the input dtype, so an un-widened int32
+            # numerator would wrap at 2^31.
             masked = jnp.where(s_sel, v[perm], 0).astype(
                 jnp.int64 if jnp.issubdtype(v.dtype, jnp.integer)
                 else jnp.float64)

@@ -543,48 +543,6 @@ def _choose_tile(shape: _TileShape, budget: int) -> Optional[int]:
     return None
 
 
-# --------------------------------------------------------------- lowerers
-
-
-class _ReplacingLowerer(X.Lowerer):
-    """Lowerer with a node-identity substitution table: nodes whose ids
-    appear in ``replace`` lower to the given (cols, sel) instead of being
-    traced (prelude-computed builds, the finalize accumulator leaf)."""
-
-    def __init__(self, tables, replace: dict, **kw):
-        super().__init__(tables, **kw)
-        self._replace = replace
-
-    def lower(self, node: N.PlanNode):
-        hit = self._replace.get(id(node))
-        if hit is not None:
-            return hit
-        return super().lower(node)
-
-
-class _TileLowerer(_ReplacingLowerer):
-    """Step-program lowerer: the stream scan reads the tile input; spine
-    builds read their prelude-computed arrays."""
-
-    def __init__(self, tables, stream: N.PScan, tile_n, replace: dict,
-                 **kw):
-        super().__init__(tables, replace, **kw)
-        self._stream = stream
-        self._tile_n = tile_n
-
-    def scan(self, node: N.PScan):
-        if node is not self._stream:
-            return super().scan(node)
-        tile = self.tables["$tile"]
-        cols = {}
-        for phys, out in node.column_map.items():
-            cols[out] = tile[phys]
-        for phys, out in node.mask_map.items():
-            cols[out] = tile[f"$nn:{phys}"]
-        sel = jnp.arange(node.capacity) < self._tile_n
-        return cols, sel
-
-
 # --------------------------------------------------------------- execution
 
 
@@ -941,7 +899,6 @@ class TiledExecutable(AdaptiveTiledMixin):
         self.tile_rows = tile_rows
         self.budget = budget
         self._platform = jax.default_backend()
-        self._use_pallas = session.config.exec.use_pallas
         self._compiled = None
         # server handler threads may hit the cached runner concurrently;
         # retries mutate shared plan capacities, so runs serialize (the
@@ -1000,11 +957,10 @@ class TiledExecutable(AdaptiveTiledMixin):
         if self._compiled is not None:
             return self._compiled
         shape = self.shape
-        plat, pallas = self._platform, self._use_pallas
+        plat = self._platform
 
         def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, use_pallas=pallas,
-                            root=shape.partial_plan)
+            low = X.Lowerer(tables, platform=plat, root=shape.partial_plan)
             outs = [low.lower_shared(b) for b in shape.builds]
             return outs, low.checks
 
@@ -1017,8 +973,8 @@ class TiledExecutable(AdaptiveTiledMixin):
             tables["$tile"] = tile
             replace = {id(b): prelude[i]
                        for i, b in enumerate(shape.builds)}
-            low = _TileLowerer(tables, shape.stream, tile_n, replace,
-                               platform=plat, use_pallas=pallas)
+            low = X.Lowerer(tables, platform=plat, replace=replace,
+                            stream=shape.stream, tile_n=tile_n)
             pcols, psel = low.lower(shape.partial_plan)
             checks = dict(low.checks)
             acc_cols, acc_sel = acc
@@ -1029,11 +985,10 @@ class TiledExecutable(AdaptiveTiledMixin):
                     [acc_cols[s.out_name], pcols[s.out_name]])
                     for s in specs}
                 sel = jnp.concatenate([acc_sel, psel])
-                # the same fused-or-XLA dispatch the one-shot executor
-                # uses: eligible int sums are bit-identical either way,
-                # so tiled and one-shot results cannot diverge
-                ok, oa, osel, n_groups = X.merge_group_aggregate(
-                    key_cols, agg_vals, specs, sel, g_cap, pallas, plat)
+                # the one-shot executor's grouped aggregation: tiled
+                # and one-shot results cannot diverge
+                ok, oa, osel, n_groups = K.group_aggregate(
+                    key_cols, agg_vals, specs, sel, g_cap)
                 checks["tile merge overflow: more groups than capacity "
                        f"{g_cap}; raise the aggregation capacity"] = \
                     n_groups > g_cap
@@ -1047,10 +1002,9 @@ class TiledExecutable(AdaptiveTiledMixin):
 
         def finalize_fn(acc):
             acc_cols, acc_sel = acc
-            low = _ReplacingLowerer(
-                {}, {id(_leaf_of(shape.root)): (acc_cols, acc_sel)},
-                platform=plat, use_pallas=pallas,
-                root=shape.partial_plan)
+            low = X.Lowerer(
+                {}, platform=plat, root=shape.partial_plan,
+                replace={id(_leaf_of(shape.root)): (acc_cols, acc_sel)})
             cols, sel = low.lower(shape.root)
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, sel, low.checks
@@ -1227,14 +1181,13 @@ class TopNTiledExecutable(TiledExecutable):
         if self._compiled is not None:
             return self._compiled
         shape = self.shape
-        plat, pallas = self._platform, self._use_pallas
+        plat = self._platform
         m = shape.g_cap
         mleaf, msort = shape.finalize["mleaf"], shape.finalize["msort"]
         names = [f.name for f in shape.partial_plan.fields]
 
         def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, use_pallas=pallas,
-                            root=shape.partial_plan)
+            low = X.Lowerer(tables, platform=plat, root=shape.partial_plan)
             outs = [low.lower_shared(b) for b in shape.builds]
             return outs, low.checks
 
@@ -1243,27 +1196,25 @@ class TopNTiledExecutable(TiledExecutable):
             tables["$tile"] = tile
             replace = {id(b): prelude[i]
                        for i, b in enumerate(shape.builds)}
-            low = _TileLowerer(tables, shape.stream, tile_n, replace,
-                               platform=plat, use_pallas=pallas)
+            low = X.Lowerer(tables, platform=plat, replace=replace,
+                            stream=shape.stream, tile_n=tile_n)
             pcols, psel = low.lower(shape.partial_plan)
             checks = dict(low.checks)
             acc_cols, acc_sel = acc
             ccols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
                      for n in names}
             csel = jnp.concatenate([acc_sel, psel])
-            low2 = _ReplacingLowerer({}, {id(mleaf): (ccols, csel)},
-                                     platform=plat, use_pallas=pallas,
-                                     root=shape.partial_plan)
+            low2 = X.Lowerer({}, platform=plat, root=shape.partial_plan,
+                             replace={id(mleaf): (ccols, csel)})
             scols, ssel = low2.lower(msort)
             checks.update(low2.checks)
             return ({n: scols[n][:m] for n in names}, ssel[:m]), checks
 
         def finalize_fn(acc):
             acc_cols, acc_sel = acc
-            low = _ReplacingLowerer(
-                {}, {id(_leaf_of(shape.root)): (acc_cols, acc_sel)},
-                platform=plat, use_pallas=pallas,
-                root=shape.partial_plan)
+            low = X.Lowerer(
+                {}, platform=plat, root=shape.partial_plan,
+                replace={id(_leaf_of(shape.root)): (acc_cols, acc_sel)})
             cols, sel = low.lower(shape.root)
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, sel, low.checks
@@ -1321,13 +1272,12 @@ class SortTiledExecutable(TiledExecutable):
         if self._compiled is not None:
             return self._compiled
         shape = self.shape
-        plat, pallas = self._platform, self._use_pallas
+        plat = self._platform
         sort = shape.sortnode
         names = [f.name for f in sort.child.fields]
 
         def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, use_pallas=pallas,
-                            root=shape.partial_plan)
+            low = X.Lowerer(tables, platform=plat, root=shape.partial_plan)
             outs = [low.lower_shared(b) for b in shape.builds]
             return outs, low.checks
 
@@ -1336,8 +1286,8 @@ class SortTiledExecutable(TiledExecutable):
             tables["$tile"] = tile
             replace = {id(b): prelude[i]
                        for i, b in enumerate(shape.builds)}
-            low = _TileLowerer(tables, shape.stream, tile_n, replace,
-                               platform=plat, use_pallas=pallas)
+            low = X.Lowerer(tables, platform=plat, replace=replace,
+                            stream=shape.stream, tile_n=tile_n)
             pcols, psel = low.lower(shape.partial_plan)
             n = psel.shape[0]
             keys = []
@@ -1472,15 +1422,14 @@ class WindowTiledExecutable(SortTiledExecutable):
             return self._chunk_compiled
         shape = self.shape
         win = shape.winnode
-        plat, pallas = self._platform, self._use_pallas
+        plat = self._platform
         cap = self.tile_rows
 
         def run_chunk(chunk_cols, n_valid):
             sel = jnp.arange(cap) < n_valid
-            low = _ReplacingLowerer(
-                {}, {id(win.child): (chunk_cols, sel)},
-                platform=plat, use_pallas=pallas,
-                root=shape.partial_plan)
+            low = X.Lowerer(
+                {}, platform=plat, root=shape.partial_plan,
+                replace={id(win.child): (chunk_cols, sel)})
             cols, osel = low.lower(shape.root)
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, osel, low.checks

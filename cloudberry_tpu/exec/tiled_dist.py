@@ -47,14 +47,14 @@ from cloudberry_tpu.exec import executor as X
 from cloudberry_tpu.exec import kernels as K
 from cloudberry_tpu.exec import scanpipe as SP
 from cloudberry_tpu.exec import tilepipe as TP
-from cloudberry_tpu.exec.dist_executor import (DistLowerer, _local_row,
-                                               _shard_map,
+from cloudberry_tpu.exec.dist_executor import (_local_row, _shard_map,
+                                               dist_lowering,
                                                prepare_dist_inputs)
 from cloudberry_tpu.exec.resource import estimate_plan_memory
 from cloudberry_tpu.exec.tiled import (_MAX_TILE, _MIN_TILE, _acc_width,
                                        _expr_dict, _merge_bytes, _out_cap,
                                        _raise_tile_checks, AdaptiveTiledMixin)
-from cloudberry_tpu.parallel.mesh import SEG_AXIS, segment_mesh
+from cloudberry_tpu.parallel.mesh import SEG_AXIS
 from cloudberry_tpu.parallel.topology import \
     topology_token as _topology_token
 from cloudberry_tpu.plan import expr as ex
@@ -470,46 +470,6 @@ def _choose_tile_dist(shape: _DistTileShape, budget: int,
     return None
 
 
-# --------------------------------------------------------------- lowerers
-
-
-class _DistReplacingLowerer(DistLowerer):
-    """DistLowerer with a node-identity substitution table (prelude-computed
-    builds; the finalize accumulator)."""
-
-    def __init__(self, tables, nseg: int, replace: dict, **kw):
-        super().__init__(tables, nseg, **kw)
-        self._replace = replace
-
-    def lower(self, node: N.PlanNode):
-        hit = self._replace.get(id(node))
-        if hit is not None:
-            return hit
-        return super().lower(node)
-
-
-class _DistTileLowerer(_DistReplacingLowerer):
-    """Step-program lowerer: the stream scan reads this segment's tile."""
-
-    def __init__(self, tables, nseg: int, stream: N.PScan, tile_n,
-                 replace: dict, **kw):
-        super().__init__(tables, nseg, replace, **kw)
-        self._stream = stream
-        self._tile_n = tile_n
-
-    def scan(self, node: N.PScan):
-        if node is not self._stream:
-            return super().scan(node)
-        tile = self.tables["$tile"]
-        cols = {}
-        for phys, out in node.column_map.items():
-            cols[out] = tile[phys]
-        for phys, out in node.mask_map.items():
-            cols[out] = tile[f"$nn:{phys}"]
-        sel = jnp.arange(node.capacity) < self._tile_n
-        return cols, sel
-
-
 # --------------------------------------------------------------- execution
 
 
@@ -558,11 +518,6 @@ class DistTiledExecutable(AdaptiveTiledMixin):
         self.nseg = session.config.n_segments
         self.tile_rows = tile_rows
         self.budget = budget
-        self._use_pallas = session.config.exec.use_pallas
-        # the step programs' spine motions AND the finalize merge motion
-        # share the packed wire format (kernels.wire_layout) — per-tile
-        # redistributes are one collective each too
-        self._packed = session.config.interconnect.packed_wire
         self._compiled = None
         self._run_lock = threading.Lock()
         self._refresh_report()
@@ -623,41 +578,27 @@ class DistTiledExecutable(AdaptiveTiledMixin):
         if self._compiled is not None:
             return self._compiled
         shape = self.shape
-        nseg = self.nseg
-        live_ids = getattr(self.session, "_live_device_ids", None)
-        mesh = segment_mesh(nseg, live_ids)
-        from cloudberry_tpu.parallel.transport import (hier_topology,
-                                                       make_transport)
-
-        ic = self.session.config.interconnect
-        # the tiled program must run the SAME motion semantics as the
-        # in-memory dist path: a plan whose motions carry two-level
-        # stamps (host_combine grew the rungs) would otherwise pay the
-        # padding while shipping flat — the regression, not the win
-        topo = hier_topology(self.session.config, nseg, live_ids)
-        tx = make_transport(ic.backend, nseg, chunks=ic.ring_chunks,
-                            topo=topo)
+        # the step programs' spine motions AND the finalize merge motion
+        # lower as the in-memory dist path's do (dist_lowering)
+        mesh, lowerer = dist_lowering(self.session)
         names = self._resident_names()
         _, res_specs = prepare_dist_inputs(None, self.session, names=names)
 
         def prelude_seg(tables):
-            low = DistLowerer(tables, nseg, use_pallas=self._use_pallas,
-                              tx=tx, packed=self._packed,
-                              root=shape.partial_plan)
+            low = lowerer(tables, root=shape.partial_plan)
             outs = [_add_seg(low.lower_shared(b)) for b in shape.builds]
             return outs, _reduce_checks(low.checks)
 
         prelude_fn = jax.jit(_shard_map(
             prelude_seg, mesh, (res_specs,), (P(SEG_AXIS), P())))
 
-        step_fn = self._make_step(mesh, tx, res_specs)
+        step_fn = self._make_step(mesh, lowerer, res_specs)
 
         def finalize_seg(acc):
             acc_cols, acc_sel = _strip_seg(tuple(acc))
-            low = _DistReplacingLowerer(
-                {}, nseg, {id(shape.replace_node): (acc_cols, acc_sel)},
-                use_pallas=self._use_pallas, tx=tx, packed=self._packed,
-                root=shape.partial_plan)
+            low = lowerer(
+                {}, root=shape.partial_plan,
+                replace={id(shape.replace_node): (acc_cols, acc_sel)})
             cols, sel = low.lower(shape.root)
             out = {f.name: cols[f.name][None] for f in shape.root.fields}
             return out, sel[None], _reduce_checks(low.checks)
@@ -682,12 +623,11 @@ class DistTiledExecutable(AdaptiveTiledMixin):
                      if isinstance(n, N.PMotion)
                      and n.kind == "redistribute")
 
-    def _make_step(self, mesh, tx, res_specs):
+    def _make_step(self, mesh, lowerer, res_specs):
         shape = self.shape
         nseg = self.nseg
         group_names = list(shape.group_names)
         specs = shape.merge_specs
-        pallas, plat = self._use_pallas, jax.default_backend()
         stat_motions = self._stat_motions()
 
         def step_seg(resident, prelude, tile, tile_n, acc):
@@ -696,10 +636,8 @@ class DistTiledExecutable(AdaptiveTiledMixin):
             plocal = _strip_seg(prelude)
             replace = {id(b): tuple(plocal[i])
                        for i, b in enumerate(shape.builds)}
-            low = _DistTileLowerer(tables, nseg, shape.stream,
-                                   tile_n.reshape(()), replace,
-                                   use_pallas=self._use_pallas, tx=tx,
-                                   packed=self._packed)
+            low = lowerer(tables, replace=replace, stream=shape.stream,
+                          tile_n=tile_n.reshape(()))
             pcols, psel = low.lower(shape.partial_plan)
             checks = dict(low.checks)
             srows = _motion_stats(low, stat_motions, nseg)
@@ -712,10 +650,9 @@ class DistTiledExecutable(AdaptiveTiledMixin):
                     [acc_cols[s.out_name], pcols[s.out_name]])
                     for s in specs}
                 sel = jnp.concatenate([acc_sel, psel])
-                # same fused-or-XLA dispatch as the one-shot executor:
-                # eligible int sums are bit-identical on either side
-                ok, oa, osel, n_groups = X.merge_group_aggregate(
-                    key_cols, agg_vals, specs, sel, g_cap, pallas, plat)
+                # the one-shot executor's grouped aggregation
+                ok, oa, osel, n_groups = K.group_aggregate(
+                    key_cols, agg_vals, specs, sel, g_cap)
                 checks["tile merge overflow: more groups than capacity "
                        f"{g_cap}; raise the aggregation capacity"] = \
                     n_groups > g_cap
@@ -941,7 +878,7 @@ class DistTopNTiledExecutable(DistTiledExecutable):
                 for f in shape.partial_plan.fields}
         return cols, np.zeros((self.nseg, shape.g_cap), dtype=np.bool_)
 
-    def _make_step(self, mesh, tx, res_specs):
+    def _make_step(self, mesh, lowerer, res_specs):
         from cloudberry_tpu.exec.tiled import _AccLeaf
 
         shape = self.shape
@@ -960,10 +897,8 @@ class DistTopNTiledExecutable(DistTiledExecutable):
             plocal = _strip_seg(prelude)
             replace = {id(b): tuple(plocal[i])
                        for i, b in enumerate(shape.builds)}
-            low = _DistTileLowerer(tables, nseg, shape.stream,
-                                   tile_n.reshape(()), replace,
-                                   use_pallas=self._use_pallas, tx=tx,
-                                   packed=self._packed)
+            low = lowerer(tables, replace=replace, stream=shape.stream,
+                          tile_n=tile_n.reshape(()))
             pcols, psel = low.lower(shape.partial_plan)
             checks = dict(low.checks)
             srows = _motion_stats(low, stat_motions, nseg)
@@ -971,10 +906,8 @@ class DistTopNTiledExecutable(DistTiledExecutable):
             ccols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
                      for n in names}
             csel = jnp.concatenate([acc_sel, psel])
-            low2 = _DistReplacingLowerer(
-                {}, nseg, {id(mleaf): (ccols, csel)},
-                use_pallas=self._use_pallas, tx=tx, packed=self._packed,
-                root=shape.partial_plan)
+            low2 = lowerer({}, root=shape.partial_plan,
+                           replace={id(mleaf): (ccols, csel)})
             scols, ssel = low2.lower(msort)
             checks.update(low2.checks)
             return _add_seg(({n: scols[n][:m] for n in names},
@@ -1005,26 +938,13 @@ class DistSortTiledExecutable(DistTiledExecutable):
         if self._compiled is not None:
             return self._compiled
         shape = self.shape
-        nseg = self.nseg
-        live_ids = getattr(self.session, "_live_device_ids", None)
-        mesh = segment_mesh(nseg, live_ids)
-        from cloudberry_tpu.parallel.transport import (hier_topology,
-                                                       make_transport)
-
-        ic = self.session.config.interconnect
-        # same two-level selection as the in-memory dist path (see the
-        # agg-mode _compile above): stamped motions keep their semantics
-        topo = hier_topology(self.session.config, nseg, live_ids)
-        tx = make_transport(ic.backend, nseg, chunks=ic.ring_chunks,
-                            topo=topo)
+        mesh, lowerer = dist_lowering(self.session)
         rnames = self._resident_names()
         _, res_specs = prepare_dist_inputs(None, self.session,
                                            names=rnames)
 
         def prelude_seg(tables):
-            low = DistLowerer(tables, nseg, use_pallas=self._use_pallas,
-                              tx=tx, packed=self._packed,
-                              root=shape.partial_plan)
+            low = lowerer(tables, root=shape.partial_plan)
             outs = [_add_seg(low.lower_shared(b)) for b in shape.builds]
             return outs, _reduce_checks(low.checks)
 
@@ -1041,10 +961,8 @@ class DistSortTiledExecutable(DistTiledExecutable):
             plocal = _strip_seg(prelude)
             replace = {id(b): tuple(plocal[i])
                        for i, b in enumerate(shape.builds)}
-            low = _DistTileLowerer(tables, nseg, shape.stream,
-                                   tile_n.reshape(()), replace,
-                                   use_pallas=self._use_pallas, tx=tx,
-                                   packed=self._packed)
+            low = lowerer(tables, replace=replace, stream=shape.stream,
+                          tile_n=tile_n.reshape(()))
             pcols, psel = low.lower(shape.partial_plan)
             n = psel.shape[0]
             keys = []
@@ -1186,19 +1104,15 @@ class DistWindowTiledExecutable(DistSortTiledExecutable):
     def _chunk_fn(self):
         if getattr(self, "_chunk_compiled", None) is not None:
             return self._chunk_compiled
-        from cloudberry_tpu.exec.tiled import _ReplacingLowerer
-
         shape = self.shape
         cap = self.tile_rows
-        pallas = self._use_pallas
         plat = jax.default_backend()
 
         def run_chunk(chunk_cols, n_valid):
             sel = jnp.arange(cap) < n_valid
-            low = _ReplacingLowerer(
-                {}, {id(shape.replace_node): (chunk_cols, sel)},
-                platform=plat, use_pallas=pallas,
-                root=shape.partial_plan)
+            low = X.Lowerer(
+                {}, platform=plat, root=shape.partial_plan,
+                replace={id(shape.replace_node): (chunk_cols, sel)})
             cols, osel = low.lower(shape.root)
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, osel, low.checks

@@ -12,7 +12,7 @@ SURVEY §4.2/§5.2):
   calls made while holding a lock, and cycles (potential deadlock), calls
   that re-acquire a held non-reentrant lock, and writes to mixed-guard
   shared attributes outside any lock are findings;
-- **trace purity** (``purity-*``) — inside jitted/Pallas-kernel functions,
+- **trace purity** (``purity-*``) — inside jitted/kernel functions,
   host-side escapes are findings: ``np.*`` on traced values,
   ``.item()``/``float()``/``int()`` coercions, Python branching on tracer
   values, f32 accumulation of int64/DECIMAL values outside the limb

@@ -91,7 +91,6 @@ ATTR_CLASS_HINTS = {
 # the trace-purity pass audits
 KERNEL_MODULES = (
     "exec/kernels.py",
-    "exec/pallas_kernels.py",
     "exec/expr_compile.py",
     "exec/executor.py",
     "exec/dist_executor.py",

@@ -164,7 +164,7 @@ def config_uid(cfg) -> int:
     """Process-unique token for a Config OBJECT (frozen dataclasses
     reject attribute stamping, and a bare id() could be reused after
     GC): the config-epoch component for shared cache keys — programs
-    bake config knobs (packed wire, pallas, ...), so entries built
+    bake config knobs (packed wire, ...), so entries built
     under different Config objects must never collide."""
     with _tier_lock:
         ent = _config_uids.get(id(cfg))
@@ -239,7 +239,7 @@ def rung_scope_token(session) -> tuple:
     cross-session sharing is only sound when the plan is a pure function
     of store content AND config: any catalog with session-local views
     keeps its entries scoped to its own ddl generation, and the shared
-    branch carries the config uid (programs bake packed-wire/pallas/...
+    branch carries the config uid (programs bake packed-wire/...
     knobs — the config-epoch guard the sibling caches get from object
     identity)."""
     scope = scope_for(session)

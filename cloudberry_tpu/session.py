@@ -1093,7 +1093,7 @@ class Session:
         # version: re-registering a function must drop plans that baked
         # its OLD results in at bind time. The config IDENTITY check is
         # the config-epoch guard: any with_overrides/degrade_mesh swap
-        # (n_segments, pallas, packed wire, ...) replaces the frozen tree
+        # (n_segments, packed wire, ...) replaces the frozen tree
         # wholesale, so `is` catches every knob a program may have baked.
         # fbgen is the feedback-store generation the plan was built
         # against: a MATERIAL sketch fold (plan/feedback.py — new

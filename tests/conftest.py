@@ -76,7 +76,6 @@ _SLOW_TIER = (
     "test_spill_sort_window.py::test_external_sort_matches_in_memory"
     "[dist8]",
     "test_spill_dist.py::test_dist_tiled_join_group_matches_in_memory",
-    "test_pallas.py::test_tiled_dist_matches_xla_fused",
     "test_cte.py::test_basic_cte[dist8]",
     "test_grouping_sets.py::test_cube[dist8]",
     "test_setop_all.py::test_running_extreme_null_never_beats_dtype_extreme"

@@ -4,16 +4,23 @@ and prints, for every program the statement launched, a hash of its
 lowered module text and the ``jax.result_info`` keys it carries.
 
     python tests/program_identity_worker.py <n_segments> <q15v|q3|q1|q6> \
-        [--no-origin]
+        [--no-origin] [--tiled]
 
 ``--no-origin`` binds every literal without its origin (``expr.Literal``'s
 ``origin``, ISSUE 29), as the tree before it did: the witness that the
 origin reaches no program.
+
+``--tiled`` holds the statement to a memory budget that sends it through
+``exec/tiled.py`` (``exec/tiled_dist.py`` on several segments) as the
+out-of-core cell's 125 MiB does at SF1
+(``resource.query_mem_bytes``: 4 MiB at this worker's SF 0.01 — 1.25 MiB,
+the cell's budget cut with the data, is under the least tile Q6 runs at),
+and keeps the text of each tiled program (prelude, step, finalize) at its
+first launch.
 """
 
 from __future__ import annotations
 
-import functools
 import hashlib
 import json
 import os
@@ -30,6 +37,10 @@ import cloudberry_tpu as cb                              # noqa: E402
 from cloudberry_tpu.config import Config                 # noqa: E402
 from cloudberry_tpu.exec import dist_executor as DX      # noqa: E402
 from cloudberry_tpu.exec import executor as X            # noqa: E402
+from cloudberry_tpu.exec import tiled as T               # noqa: E402
+from cloudberry_tpu.exec import tiled_dist as TD         # noqa: E402
+from program_texts import (record_tiled_programs,        # noqa: E402
+                           recording)
 from tools.tpch_queries import QUERIES                   # noqa: E402
 from tools.tpchgen import load_tpch                      # noqa: E402
 
@@ -48,41 +59,37 @@ if "--no-origin" in sys.argv[3:]:
     binder._token_literal = lambda kind, text, pos=-1: _token_literal(
         kind, text)
 
-texts: list = []
-
-
-def recording(fn):
-    """``fn`` (a jitted program), its module text kept at every launch."""
-    @functools.wraps(fn)    # the program's byte counts ride on it
-    def call(inputs):
-        texts.append(fn.lower(inputs).as_text())
-        return fn(inputs)
-    return call
-
+programs: list = []     # (function name, module text) of every launch
 
 _compile_distributed = DX.compile_distributed
 _compile_plan = X.compile_plan
 
 
 def compile_distributed(*a, **kw):
-    return recording(_compile_distributed(*a, **kw))
+    return recording(_compile_distributed(*a, **kw), programs)
 
 
 def compile_plan(*a, **kw):
     exe = _compile_plan(*a, **kw)
-    exe.packed_fn = recording(exe.packed_fn)
+    exe.packed_fn = recording(exe.packed_fn, programs)
     return exe
 
 
 DX.compile_distributed = compile_distributed
 X.compile_plan = compile_plan
 
-s = cb.Session(Config(n_segments=N_SEG))
+overrides = {}
+if "--tiled" in sys.argv[3:]:
+    overrides["resource.query_mem_bytes"] = 4 << 20
+    record_tiled_programs((T, TD), programs)
+
+s = cb.Session(Config(n_segments=N_SEG).with_overrides(**overrides))
 load_tpch(s, sf=0.01, seed=7, tables=["lineitem", "orders", "customer"])
 rows = s.sql(STATEMENTS[sys.argv[2]]).num_rows()
-info = [m for t in texts
+info = [m for _, t in programs
         for m in re.findall(r'jax\.result_info = "([^"]*)"', t)]
-print(json.dumps({"rows": rows, "programs": len(texts),
+print(json.dumps({"rows": rows, "programs": len(programs),
+                  "names": [name for name, _ in programs],
                   "hashes": [hashlib.sha256(t.encode()).hexdigest()
-                             for t in texts],
+                             for _, t in programs],
                   "result_info": info}))

@@ -88,6 +88,83 @@ def test_group_aggregate_vs_pandas(jit):
     np.testing.assert_allclose(got["a"], expect["a"].to_numpy(), rtol=1e-12)
 
 
+def _assert_group_aggregate_exact(keys, v, sel, cap, pack_bits=0):
+    """K.group_aggregate against numpy over the selected rows: keys in
+    ascending order, int64 sums and counts exact, avg within an ulp,
+    and the true group count."""
+    specs = [K.AggSpec("sum", "s"), K.AggSpec("count", "c"),
+             K.AggSpec("avg", "a")]
+    av = {"s": jnp.asarray(v), "c": None, "a": jnp.asarray(v)}
+    ok, oa, osel, ng = K.group_aggregate(
+        {"k": jnp.asarray(keys)}, av, specs, jnp.asarray(sel), cap,
+        pack_bits=pack_bits)
+    uk, inv = np.unique(keys[sel], return_inverse=True)
+    sums = np.zeros(len(uk), np.int64)
+    np.add.at(sums, inv, v[sel])
+    counts = np.bincount(inv, minlength=len(uk))
+    m = np.asarray(osel)
+    assert int(ng) == len(uk) == int(m.sum())
+    assert m[:len(uk)].all()      # groups sit at the front, in key order
+    np.testing.assert_array_equal(np.asarray(ok["k"])[m], uk)
+    got_s = np.asarray(oa["s"])[m]
+    assert got_s.dtype == np.int64
+    np.testing.assert_array_equal(got_s, sums)
+    np.testing.assert_array_equal(np.asarray(oa["c"])[m], counts)
+    np.testing.assert_allclose(np.asarray(oa["a"])[m], sums / counts,
+                               rtol=1e-15)
+    return int(ng)
+
+
+def _boundary_case(shape):
+    """Shapes that stress group boundaries, all (1536 rows, capacity
+    512): the sorted-segment shapes of the kernel this path outlived."""
+    rng = np.random.default_rng(6)
+    n, cap = 1536, 512
+    if shape == "groups_equal_capacity":
+        keys = np.concatenate([np.repeat(np.arange(cap, dtype=np.int64), 2),
+                               np.zeros(n - 2 * cap, np.int64)])
+        sel = np.arange(n) < 2 * cap
+        expect = cap
+    elif shape == "one_group":
+        keys, sel, expect = np.zeros(n, np.int64), np.ones(n, bool), 1
+    elif shape == "all_filtered":
+        keys = rng.integers(0, 50, n).astype(np.int64)
+        sel, expect = np.zeros(n, bool), 0
+    else:  # one hot group between smaller ones
+        keys = np.concatenate([rng.integers(0, 40, 300), np.full(900, 40),
+                               rng.integers(41, 80, 336)]).astype(np.int64)
+        rng.shuffle(keys)
+        sel, expect = rng.random(n) > 0.2, None
+    return keys, rng.integers(-10**12, 10**12, n), sel, cap, expect
+
+
+@pytest.mark.parametrize("shape", ["groups_equal_capacity", "one_group",
+                                   "all_filtered", "hot_group"])
+def test_group_aggregate_boundary_shapes(shape):
+    keys, v, sel, cap, expect = _boundary_case(shape)
+    ng = _assert_group_aggregate_exact(keys, v, sel, cap)
+    assert expect is None or ng == expect
+
+
+def test_group_aggregate_beyond_dense_domain():
+    """2^16 groups — far beyond any dense cell domain — over 2^17 rows,
+    values past 2^53 in sum: every group id appears, so the group count
+    is exactly 2^16, and the packed one-word sort gives the same."""
+    rng = np.random.default_rng(10)
+    groups = 1 << 16
+    keys0 = np.concatenate([np.arange(groups, dtype=np.int64),
+                            rng.integers(0, groups, groups)])
+    sel0 = np.ones(keys0.shape[0], bool)
+    # filter only duplicate-half rows: every group keeps one survivor
+    sel0[groups:] = rng.random(groups) > 0.25
+    perm = rng.permutation(keys0.shape[0])
+    keys, sel = keys0[perm], sel0[perm]
+    v = rng.integers(-10**17, 10**17, keys.shape[0])
+    for bits in (0, 32):
+        assert _assert_group_aggregate_exact(keys, v, sel, 1 << 17,
+                                             pack_bits=bits) == groups
+
+
 def test_global_aggregate():
     v = jnp.asarray(np.array([1.0, 2.0, 3.0, 100.0]))
     sel = jnp.asarray(np.array([True, True, True, False]))
@@ -370,3 +447,24 @@ def test_join_expand_presorted_parity():
                               [jnp.asarray(pvals)], psel, cap)
     for a, b in zip(r0, r1):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_kernel_bench_grouped_agg_smoke():
+    """The grouped-agg cardinality sweep of tools/kernel_bench.py runs on
+    the CPU and emits one record per ladder point."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    out = subprocess.run(
+        [sys.executable, "-m", "tools.kernel_bench", "grouped-agg",
+         "--rows", "4096", "--ladder", "4,6", "--reps", "1"],
+        capture_output=True, text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.returncode == 0, out.stderr[-2000:]
+    recs = [json.loads(ln) for ln in out.stdout.splitlines()
+            if ln.startswith("{")]
+    assert [(r["strategy"], r["groups"]) for r in recs] == \
+        [("xla_sort", 16), ("xla_sort", 64)]
+    assert all(r["rows"] == 4096 and r["mrows_per_s"] > 0 for r in recs)

@@ -360,7 +360,8 @@ def test_a_config_swap_takes_the_full_path():
     s = _mem_session()
     text = "select sum(b) as x from t where a < {}"
     _armed(s, text)
-    s.config = s.config.with_overrides(**{"exec.use_pallas": True})
+    s.config = s.config.with_overrides(
+        **{"interconnect.packed_wire": False})
     binds = _counter(s, "template_binds")
     assert paramplan.template_bind(s, text.format(9)) is None
     assert s.sql(text.format(9)).to_pandas().x[0] == \

@@ -157,17 +157,14 @@ def dist_session():
     return s
 
 
-def _node_rows(metrics):
-    return [r for _, _, r in metrics.node_rows]
-
-
 @pytest.mark.parametrize("nseg", [1, 8])
-def test_pipeline_counts_match_legacy(nseg, dist_session):
-    """Row counts from the pipeline path (generic-plan form, shared
-    compile entry points) are identical to the legacy private-lowerer
-    path at 1 and 8 segments."""
-    from cloudberry_tpu.exec.instrument import (run_instrumented,
-                                                run_pipeline)
+def test_pipeline_counts_match_the_data(nseg, dist_session):
+    """Per-node row counts from the pipeline path (generic-plan form,
+    shared compile entry points) against counts reckoned from the
+    inserted rows, at 1 and 8 segments: 64 rows, ``k < 32`` keeps 32,
+    7 groups; a partial aggregate emits one row per (segment, group)
+    present on that segment, and the gather above it counts them once."""
+    from cloudberry_tpu.exec.instrument import run_pipeline
     from cloudberry_tpu.plan.planner import plan_statement
     from cloudberry_tpu.sql.parser import parse_sql
 
@@ -177,15 +174,35 @@ def test_pipeline_counts_match_legacy(nseg, dist_session):
         s.sql("insert into d1 values "
               + ",".join(f"({i},{i % 7})" for i in range(64)))
         q = "select v, count(*) as n from d1 where k < 32 group by v"
+        partials = None
     else:
         s = dist_session
         q = "select v, count(*) as n from d8 where k < 32 group by v"
-    p1 = plan_statement(parse_sql(q), s, {}).plan
-    _, legacy = run_instrumented(p1, s, q)
-    p2 = plan_statement(parse_sql(q), s, {}).plan
-    batch, pipe, _ann = run_pipeline(p2, s, q)
-    assert _node_rows(legacy) == _node_rows(pipe)
-    assert batch.num_rows() == pipe.rows_out
+        st = s.sharded_table("d8")
+        partials = 0
+        for seg in range(8):
+            k = np.asarray(st.columns["k"][seg])[:st.counts[seg]]
+            v = np.asarray(st.columns["v"][seg])[:st.counts[seg]]
+            partials += len(np.unique(v[k < 32]))
+    kk = np.arange(64)
+    n_filtered = int((kk < 32).sum())
+    n_groups = len(np.unique((kk % 7)[kk < 32]))
+
+    def expected(title):
+        if title.startswith("Scan"):
+            return 64
+        if title.startswith("Filter"):
+            return n_filtered
+        if "partial" in title or title.startswith("Motion"):
+            return partials
+        return n_groups     # the (final) aggregate and what sits on it
+
+    plan = plan_statement(parse_sql(q), s, {}).plan
+    batch, pipe, _ann = run_pipeline(plan, s, q)
+    assert len(pipe.node_rows) == (4 if nseg == 1 else 7)
+    assert [(t, r) for t, _, r in pipe.node_rows] == \
+        [(t, expected(t)) for t, _, _ in pipe.node_rows]
+    assert batch.num_rows() == pipe.rows_out == n_groups
     # pipeline semantics: the run is a real statement — logged, counted
     recent = s.stmt_log.recent(5)
     assert recent[0]["sql"] == q and recent[0]["status"] == "ok"

@@ -221,7 +221,8 @@ def test_stmt_cache_config_epoch_invalidates():
     q = "select count(*) as n from pts"
     s.sql(q)
     assert s._cached_statement(q) is not None
-    s.config = s.config.with_overrides(**{"exec.use_pallas": True})
+    s.config = s.config.with_overrides(
+        **{"interconnect.packed_wire": False})
     assert s._cached_statement(q) is None  # stale under the new epoch
 
 

@@ -542,3 +542,68 @@ def test_string_coalesce_cross_dict(sess):
     sess.sql("create table ovf (v bigint)")
     with pytest.raises(BindError):
         sess.sql("insert into ovf values (99999999999999999999)")
+
+
+def test_mid_cardinality_group_by_sums_exact(sess):
+    """GROUP BY over thousands of groups (beyond any dense cell domain:
+    the sort path): BIGINT and DECIMAL sums equal numpy's int64 sums bit
+    for bit, counts exactly, averages to the last ulp or so."""
+    rng = np.random.default_rng(11)
+    n, groups = 30_000, 8_000
+    data = {"k": rng.integers(0, groups, n),
+            "v": rng.integers(-10**12, 10**12, n),
+            "amt": rng.integers(0, 10**8, n)}
+    sess.sql("create table f (k bigint, v bigint, amt decimal(12,2))")
+    sess.catalog.table("f").set_data(dict(data))
+    batch = sess.sql("select k, sum(v) as sv, sum(amt) as sa, avg(v) as av, "
+                     "count(*) as n from f group by k order by k")
+    uk, inv = np.unique(data["k"], return_inverse=True)
+    sv, sa = np.zeros(len(uk), np.int64), np.zeros(len(uk), np.int64)
+    np.add.at(sv, inv, data["v"])
+    np.add.at(sa, inv, data["amt"])
+    counts = np.bincount(inv)
+    sel = np.asarray(batch.sel)
+    got = {c: np.asarray(v)[sel] for c, v in batch.columns.items()}
+    assert got["k"].tolist() == uk.tolist()
+    assert got["sv"].tolist() == sv.tolist()
+    assert got["sa"].tolist() == sa.tolist()      # cents, scale 2
+    assert got["n"].tolist() == counts.tolist()
+    np.testing.assert_allclose(got["av"], sv / counts, rtol=1e-15)
+
+
+def test_small_unique_build_join_carries_int64_payload_exactly(sess):
+    """A star join against a small unique build (500 rows, some probe
+    keys missing from it), grouped by two payload columns of the build:
+    every sum and count equals the pandas join's, int64 for int64."""
+    import pandas as pd
+
+    rng = np.random.default_rng(4)
+    nd, nf = 500, 40_000
+    dim = pd.DataFrame({"k": np.arange(nd),
+                        "name": [f"n{i % 37}" for i in range(nd)],
+                        "grp": rng.integers(0, 9, nd)})
+    fact = pd.DataFrame({"k": rng.integers(0, nd + 50, nf),
+                         "v": rng.integers(0, 1000, nf),
+                         "amt": rng.integers(0, 10**6, nf)})
+    sess.sql("create table dim (k bigint, name text, grp bigint) "
+             "distributed by (k)")
+    sess.sql("create table fact (k bigint, v bigint, amt decimal(12,2)) "
+             "distributed by (k)")
+    sess.sql("insert into dim values " + ", ".join(
+        f"({r.k}, '{r.name}', {r.grp})" for r in dim.itertuples()))
+    sess.catalog.table("fact").set_data(
+        {c: fact[c].to_numpy() for c in fact})
+    batch = sess.sql(
+        "select grp, name, sum(v) as sv, sum(amt) as sa, count(*) as n "
+        "from fact join dim on fact.k = dim.k "
+        "group by grp, name order by grp, name")
+    want = (fact.merge(dim, on="k").groupby(["grp", "name"])
+            .agg(sv=("v", "sum"), sa=("amt", "sum"), n=("v", "size"))
+            .reset_index().sort_values(["grp", "name"]))
+    got = batch.to_pandas()
+    assert got["grp"].tolist() == want["grp"].tolist()
+    assert got["name"].tolist() == want["name"].tolist()
+    sel = np.asarray(batch.sel)
+    for c in ("sv", "sa", "n"):     # raw: sa in cents, as stored
+        assert np.asarray(batch.columns[c])[sel].tolist() == \
+            want[c].tolist(), c

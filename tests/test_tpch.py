@@ -32,3 +32,37 @@ def test_explain_q3(tpch_session):
     session, _ = tpch_session
     text = session.explain(QUERIES["q3"])
     assert "Join" in text and "Scan lineitem" in text and "GroupAgg" in text
+
+
+def test_q1_money_sums_exact_in_int64(tpch_session):
+    """Q1's four money sums are DECIMAL sums carried as scaled int64
+    (scales 2, 2, 4, 6): bit for bit numpy's int64 arithmetic over the
+    stored cents, where a float sum would round; the counts alike."""
+    import datetime
+
+    import numpy as np
+
+    session, _ = tpch_session
+    batch = session.sql(QUERIES["q1"])
+    li = {c: np.asarray(v) for c, v in
+          session.catalog.table("lineitem").data.items()}
+    cutoff = (datetime.date(1998, 12, 1) - datetime.timedelta(days=90)
+              - datetime.date(1970, 1, 1)).days
+    keep = li["l_shipdate"] <= cutoff
+    disc = li["l_extendedprice"] * (100 - li["l_discount"])
+    exact = {"sum_qty": li["l_quantity"],
+             "sum_base_price": li["l_extendedprice"],
+             "sum_disc_price": disc,
+             "sum_charge": disc * (100 + li["l_tax"]),
+             "count_order": np.ones_like(li["l_quantity"])}
+    sel = np.asarray(batch.sel)
+    flags = np.asarray(batch.columns["l_returnflag"])[sel]
+    status = np.asarray(batch.columns["l_linestatus"])[sel]
+    assert len(flags) == 4
+    for name, v in exact.items():
+        got = np.asarray(batch.columns[name])[sel]
+        assert got.dtype == np.int64
+        want = [int(v[keep & (li["l_returnflag"] == f)
+                      & (li["l_linestatus"] == st)].sum())
+                for f, st in zip(flags, status)]
+        assert got.tolist() == want, name

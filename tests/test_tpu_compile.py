@@ -5,7 +5,7 @@ The TPU's compiler is installed wherever the tests run; it compiles for a
 ``v5e:2x2`` topology description without a chip. Nothing here runs, so
 nothing here is a chip result — these tests only say that the compiler
 ACCEPTS what the engine would hand it at TPC-H SF1 shapes (lineitem
-6,001,215 rows), and pin what it refuses today.
+6,001,215 rows), and pin what it refuses today (a float64 bitcast).
 
 Rules this file obeys (the driver runs 6 xdist workers, and only one
 process may hold libtpu): the topology is described inside a module-scoped
@@ -228,50 +228,69 @@ def test_q3_compiles_for_tpu(one_chip):
     _compile_one_shot(s, "q3", one_chip)
 
 
-# ---------------------------------------------------------------- Pallas
-# Today's truth (PR 22): the TPU compiler refuses all three kernels of
-# exec/pallas_kernels.py; they have only ever run with interpret=True.
-# They sit behind exec.use_pallas (default off), so the main path does
-# not depend on them. The day someone repairs one, its case here fails
-# and ROADMAP D7 has its evidence. docs/PALLAS_AB.md has the table.
+# ------------------------------------------- the three kernel shapes, as XLA
+# The shapes three Pallas kernels were written for and the TPU compiler
+# refused (docs/DESIGN.md "Known limitations" has the three refusals): what
+# is true of them on the path that runs is that the XLA formulations
+# compile. One-word sorts and 2^16 rows: a sort's compile time grows with
+# the words its comparator reads (PERF.md section 6, PR 28).
+
+_N = 1 << 16
 
 
-def _dense_agg(sh):
-    from cloudberry_tpu.exec import pallas_kernels as PK
+def _mid_cardinality_agg(sh):
+    """K.group_aggregate at 2^16 groups: far beyond a dense cell domain."""
+    from cloudberry_tpu.exec import kernels as K
 
-    n, k = 1 << 20, 20
-    return PK.dense_agg_tiles_pallas.lower(
-        sh((n,), jnp.int32), sh((k, n), jnp.float32), sh((n,), jnp.bool_),
-        n_cells=6, tile=2048)
+    specs = [K.AggSpec("sum", "s"), K.AggSpec("count", "c"),
+             K.AggSpec("avg", "a")]
 
-
-def _probe_join(sh):
-    from cloudberry_tpu.exec import pallas_kernels as PK
-
-    b, n, p = 1024, 1 << 20, 6
-    return PK.probe_join_pallas.lower(
-        sh((b,), jnp.uint32), sh((b,), jnp.bool_), sh((n,), jnp.uint32),
-        sh((n,), jnp.bool_), sh((p, b), jnp.float32), tile=1024)
+    def f(k, v, sel):
+        return K.group_aggregate({"k": k}, {"s": v, "c": None, "a": v},
+                                 specs, sel, _N, pack_bits=32)
+    return jax.jit(f).lower(sh((_N,), jnp.int64), sh((_N,), jnp.int64),
+                            sh((_N,), jnp.bool_))
 
 
-def _sorted_seg(sh):
-    from cloudberry_tpu.exec import pallas_kernels as PK
+def _small_build_probe_join(sh):
+    """K.join_lookup + gather_payload against a 1,024-row unique build,
+    an int64 payload gathered to every probe row."""
+    from cloudberry_tpu.exec import kernels as K
 
-    r, n = 8, 1 << 20
-    return PK.sorted_seg_pallas.lower(
-        sh((n,), jnp.int32), sh((r, n), jnp.int32), tile=2048)
+    def f(bk, bsel, pk, psel, pay):
+        idx, matched, has_dup = K.join_lookup([bk], bsel, [pk], psel,
+                                              bits=32)
+        return K.gather_payload({"p": pay}, idx, matched), matched, has_dup
+    return jax.jit(f).lower(sh((1024,), jnp.int64), sh((1024,), jnp.bool_),
+                            sh((_N,), jnp.int64), sh((_N,), jnp.bool_),
+                            sh((1024,), jnp.int64))
 
 
-@pytest.mark.parametrize("lower, error, words", [
-    # index maps yield i64 under jax_enable_x64; Mosaic wants i32
-    (_dense_agg, Exception, r"failed to legalize operation 'func\.return'"),
-    (_probe_join, Exception, r"unsupported shape cast"),
-    (_sorted_seg, RecursionError, r"recursion"),
-], ids=["dense_agg_tiles", "probe_join", "sorted_seg"])
-def test_pallas_kernels_are_refused_by_the_tpu_compiler(one_chip, lower,
-                                                        error, words):
+def _dense_agg_q1(sh):
+    """Q1's aggregate: 6 cells (returnflag x linestatus), four exact
+    int64 money sums, three averages and a count, chip strategy."""
+    from cloudberry_tpu.exec import kernels as K
+
+    sums, avgs = ["s0", "s1", "s2", "s3"], ["a0", "a1", "a2"]
+    specs = [K.AggSpec("sum", n) for n in sums] \
+        + [K.AggSpec("avg", n) for n in avgs] + [K.AggSpec("count", "c")]
+
+    def f(gid, v, sel):
+        vals = {n: v for n in sums + avgs}
+        return K.group_aggregate_dense(gid, 6, {**vals, "c": None}, specs,
+                                       sel, strategy="reduce")
+    return jax.jit(f).lower(sh((_N,), jnp.int32), sh((_N,), jnp.int64),
+                            sh((_N,), jnp.bool_))
+
+
+@pytest.mark.parametrize("lower", [_mid_cardinality_agg,
+                                   _small_build_probe_join, _dense_agg_q1],
+                         ids=["group_aggregate_2e16", "join_lookup_1024",
+                              "dense_agg_q1"])
+def test_xla_formulations_of_the_kernel_shapes_compile_for_tpu(one_chip,
+                                                               lower):
     def sh(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    with pytest.raises(error, match=words):
-        lower(sh).compile()
+    compiled = lower(sh).compile()
+    assert "tpu_custom_call" not in compiled.as_text()

@@ -10,14 +10,12 @@ backend JAX selects (it prints which), one JSON line per measurement.
 Usage:
   python -m tools.kernel_bench [--build N] [--probe N] [--reps R]
   python -m tools.kernel_bench grouped-agg [--rows N] [--ladder LO,HI]
-      [--reps R] [--interpret] [--csv PATH]
+      [--reps R] [--csv PATH]
 
 ``grouped-agg`` sweeps a group-cardinality ladder (2^LO … 2^HI, default
-2^4 … 2^20) through BOTH grouped-aggregation strategies — the XLA sort
-path (kernels.group_aggregate) and the fused sorted-segment Pallas
-kernel (pallas_kernels.sorted_segment_aggregate) — so the XLA-vs-Pallas
-crossover is measured, not guessed. ``--interpret`` runs the Pallas side
-in interpreter mode so the sweep smoke-runs on CPU without hardware.
+2^4 … 2^20) through the grouped aggregation (kernels.group_aggregate),
+one record per ladder point under the strategy name ``xla_sort``: a
+second strategy adds its own records beside it.
 """
 
 from __future__ import annotations
@@ -58,7 +56,6 @@ def grouped_agg_sweep(args) -> None:
     import numpy as np
 
     from cloudberry_tpu.exec import kernels as K
-    from cloudberry_tpu.exec import pallas_kernels as PK
 
     try:
         lo, hi = (int(x) for x in args.ladder.split(","))
@@ -89,16 +86,12 @@ def grouped_agg_sweep(args) -> None:
         strategies = {
             "xla_sort": make_fn(functools.partial(
                 K.group_aggregate, out_capacity=cap)),
-            "pallas_sorted_segment": make_fn(functools.partial(
-                PK.sorted_segment_aggregate, out_capacity=cap,
-                interpret=args.interpret)),
         }
         for name, fn in strategies.items():
             best, _ = _bench_loop(jax, fn, keys, v, sel, reps=args.reps)
             rec = {
                 "kernel": "grouped_agg", "strategy": name,
                 "groups": groups, "rows": n, "device": str(dev),
-                "interpret": bool(args.interpret),
                 "wall_ms": round(best * 1e3, 2),
                 "mrows_per_s": round(n / best / 1e6, 1),
             }
@@ -128,8 +121,6 @@ def main() -> None:
                     help="grouped-agg: log2 group-count range LO,HI")
     ap.add_argument("--step", type=int, default=2,
                     help="grouped-agg: log2 ladder stride")
-    ap.add_argument("--interpret", action="store_true",
-                    help="grouped-agg: Pallas interpret mode (CPU smoke)")
     ap.add_argument("--csv", default=None,
                     help="grouped-agg: also write a CSV table here")
     args = ap.parse_args()
