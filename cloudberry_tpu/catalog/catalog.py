@@ -172,26 +172,12 @@ class Table:
                 return
             if appended is not None and appended < n:
                 k = appended
-                # refresh persisted uniqueness incrementally: a previously
-                # unique column stays unique iff the appended tail has no
-                # internal dups and no overlap with the head (O(N) isin,
-                # not a full O(N log N) re-sort per statement)
-                prev = self.backing.read_manifest(self.name) \
-                    .get("unique", {})
-                unique = dict(prev)
-                for c, flag in prev.items():
-                    arr = data.get(c)
-                    if arr is None or not flag:
-                        continue
-                    tail, head = arr[n - k:], arr[:n - k]
-                    unique[c] = bool(
-                        len(np.unique(tail)) == len(tail)
-                        and not np.isin(tail, head).any())
+                # the store keeps the manifest's uniqueness flags current
+                # on every append (TableStore._unique_flags)
                 self._store_version = self.backing.append(
                     self.name, {c: v[-k:] for c, v in data.items()},
                     self.schema, self.dicts,
                     validity={c: v[-k:] for c, v in self.validity.items()},
-                    unique=unique,
                     policy=self.policy,
                     rows_per_partition=self.backing.rows_per_partition)
             else:

@@ -145,6 +145,7 @@ def compile_distributed(plan: N.PlanNode, session, param_keys=None,
     # once, for execute_distributed's counters
     fn.input_bytes = scanned_input_bytes(plan, inputs)
     fn.wire_bytes = motion_wire_bytes(plan)
+    fn.join_shapes = X.join_shapes(plan)
     return fn
 
 
@@ -348,6 +349,7 @@ def execute_distributed(plan: N.PlanNode, session, fn=None, *,
         log.bump("launch_d2h_reads", reads)
         log.bump("dist_input_bytes", fn.input_bytes)
         log.bump("motion_wire_bytes", fn.wire_bytes)
+        X.count_join_shapes(log, fn.join_shapes)
     return batch
 
 

@@ -59,6 +59,9 @@ class Executable:
     # the one-shot launch's program (run_executable): ``fn`` ending in
     # pack_answer, so the host gets the whole answer in one wait
     packed_fn: Callable = None  # type: ignore[assignment]
+    # (lookups, expansions) among the plan's joins (join_shapes), fixed
+    # when the plan is lowered and counted on every launch
+    join_shapes: tuple = (0, 0)
 
 
 def execute(plan: N.PlanNode, session) -> ColumnBatch:
@@ -116,7 +119,8 @@ def compile_plan(plan: N.PlanNode, session,
                           run, instrumented=True)
     return Executable(plan, jax.jit(run), table_names, store_scans, run,
                       packed_fn=jax.jit(
-                          lambda tables: pack_answer(*run(tables))))
+                          lambda tables: pack_answer(*run(tables))),
+                      join_shapes=join_shapes(plan))
 
 
 def prepare_tables(table_names: list[str], session,
@@ -407,6 +411,7 @@ def run_executable(exe: Executable, tables: dict, log=None) -> ColumnBatch:
     if log is not None:
         log.bump("launch_packed")
         log.bump("launch_d2h_reads", len(host))
+        count_join_shapes(log, exe.join_shapes)
     with OT.stage("fetch", "launch_seconds", host=True) as st:
         cols, sel, checks = unpack_answer(packed.layout, host)
         raise_checks(checks)
@@ -576,6 +581,27 @@ def find_expansion_node(plan: N.PlanNode, message: str):
     return node if isinstance(node, N.PJoin) else None
 
 
+def join_shapes(plan: N.PlanNode) -> tuple:
+    """(sorted-build lookups, pair expansions) among ``plan``'s joins, by
+    the shape ``Lowerer.join`` takes for each (``PJoin.expands``)."""
+    joins = _dedupe_nodes(nd for nd in all_nodes(plan)
+                          if isinstance(nd, N.PJoin))
+    expand = sum(nd.expands for nd in joins)
+    return len(joins) - expand, expand
+
+
+def count_join_shapes(log, shapes: tuple) -> None:
+    """One launch's joins on the engine's counters: ``launch_joins_lookup``
+    and ``launch_joins_expand`` say which join the planner chose in the
+    programs that ran (a program that joins nothing bumps neither)."""
+    if log is None:
+        return
+    if shapes[0]:
+        log.bump("launch_joins_lookup", shapes[0])
+    if shapes[1]:
+        log.bump("launch_joins_expand", shapes[1])
+
+
 def _dedupe_nodes(nodes) -> list:
     """Unique by identity, preserving order — all_nodes re-walks shared
     (PShare) subtrees once per reference, and a buffer must be grown
@@ -620,8 +646,7 @@ def grow_expansion(plan: N.PlanNode, message: str, factor: int = 4,
             and "expansion overflow" in message:
         join_hits = _dedupe_nodes(
             nd for nd in all_nodes(plan)
-            if isinstance(nd, N.PJoin)
-            and (not nd.unique_build or nd.residual is not None))
+            if isinstance(nd, N.PJoin) and nd.expands)
     if join_hits:
         for nd in join_hits:
             nd.out_capacity = max(nd.out_capacity * factor, 64)

@@ -806,7 +806,11 @@ class AdaptiveTiledMixin:
             # rounds instead of re-streaming the table
             check_cancel()
             try:
-                return self._run_once()
+                batch = self._run_once()
+                X.count_join_shapes(
+                    getattr(self.session, "stmt_log", None),
+                    X.join_shapes(self._whole_plan()))
+                return batch
             except X.ExecError as e:
                 msg = str(e)
                 shape = self.shape

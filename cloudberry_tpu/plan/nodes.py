@@ -212,6 +212,12 @@ class PJoin(PlanNode):
     def children(self):
         return [self.build, self.probe]
 
+    @property
+    def expands(self) -> bool:
+        """Whether the lowerer takes the pair-expansion shape for this
+        join (``Lowerer.join``); otherwise the sorted-build lookup."""
+        return not self.unique_build or self.residual is not None
+
     def title(self):
         return f"Join {self.kind}"
 

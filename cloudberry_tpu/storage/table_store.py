@@ -177,10 +177,9 @@ class TableStore:
         """Append transaction ``t``'s last ``k`` rows onto the CURRENT
         snapshot (which another session committed after this transaction
         began). String codes re-encode against the stored dictionary (the
-        two sessions may have extended the base dictionary differently),
-        and stored uniqueness flags are re-verified against the merged
-        data — a column stays unique only if the tail neither overlaps the
-        stored values nor repeats internally."""
+        two sessions may have extended the base dictionary differently);
+        ``append`` re-verifies the stored uniqueness flags against the
+        merged data (``_unique_flags``)."""
         name = t.name
         tail = {c: np.asarray(v)[-k:] for c, v in t.data.items()}
         validity = {c: np.asarray(v)[-k:] for c, v in t.validity.items()
@@ -197,20 +196,9 @@ class TableStore:
             vals = d.decode(tail[c])
             tail[c] = sd.encode(np.asarray(vals, dtype=object))
             dicts[c] = sd
-        unique = dict(man.get("unique", {}))
-        for c, was in list(unique.items()):
-            if not was or c not in tail:
-                continue
-            tc = tail[c]
-            if len(np.unique(tc)) != len(tc):
-                unique[c] = False
-                continue
-            stored, _ = self.read_partitions(name, man["partitions"], [c])
-            unique[c] = not bool(np.isin(tc, stored[c]).any())
-        v = self.append(name, tail, t.schema, dicts, replace=False,
-                        validity=validity, unique=unique,
-                        rows_per_partition=self.rows_per_partition)
-        return v
+        return self.append(name, tail, t.schema, dicts, replace=False,
+                           validity=validity,
+                           rows_per_partition=self.rows_per_partition)
 
     # ----------------------------------------------------------- manifests
 
@@ -391,6 +379,8 @@ class TableStore:
         snapshot contains ONLY these rows — still one atomic commit, so a
         crash mid-write never publishes an empty intermediate).
         ``validity`` masks persist as extra "$nn:<col>" bool columns.
+        The manifest's ``unique`` flags are current after every append:
+        a caller's ``unique`` wins, else ``_unique_flags`` derives them.
         Returns the new snapshot version."""
         tdir = os.path.join(self.root, table)
         os.makedirs(tdir, exist_ok=True)
@@ -445,8 +435,8 @@ class TableStore:
         elif validity:
             man["nullable"] = sorted(set(man.get("nullable", []))
                                      | set(validity))
-        if unique is not None:
-            man["unique"] = unique
+        man["unique"] = unique if unique is not None else \
+            self._unique_flags(table, man, data)
         if policy is not None:
             man["policy"] = {"kind": policy.kind, "keys": list(policy.keys)}
         if spec is not None:
@@ -464,6 +454,47 @@ class TableStore:
         man["dicts"] = new_dicts
         man["partitions"] = man["partitions"] + new_parts
         return self._commit(table, man)
+
+    def _unique_flags(self, table: str, man: dict,
+                      data: dict[str, np.ndarray]) -> dict[str, bool]:
+        """The manifest's ``unique`` flags once ``data`` has joined the
+        partitions ``man`` still lists (none on ``replace`` or a first
+        snapshot) — what lets a COLD table's joins plan as PK lookups
+        (catalog.Table.is_unique). The columns that count are
+        save_table's: integer-kind arrays, not nullable. Over no stored
+        rows a column is unique iff its values are distinct. On a later
+        append it stays unique iff it was flagged, the tail has no
+        repeat, and the tail does not meet the stored values: a column
+        once not unique is never looked at again."""
+        nullable = set(man.get("nullable", []))
+        tails = {c: v for c, v in ((c, np.asarray(v))
+                                   for c, v in data.items())
+                 if v.dtype.kind in "iu" and c not in nullable}
+        stored = man["partitions"]
+        if not stored:
+            return {c: _distinct(v) for c, v in tails.items()}
+        prev = man.get("unique", {})
+        flags = {c: bool(u) and c not in nullable for c, u in prev.items()}
+        flags.update({
+            c: bool(prev.get(c)) and _distinct(v)
+            and not self._meets_stored(table, stored, c, v)
+            for c, v in tails.items()})
+        return flags
+
+    def _meets_stored(self, table: str, parts: list[dict], col: str,
+                      tail: np.ndarray) -> bool:
+        """Whether any of ``tail``'s values is already in ``col`` of
+        ``parts``. Partitions whose min/max ``stats`` lie apart from the
+        tail's range are settled without a read (a bulk load's ascending
+        keys read nothing); of the others that one column is read."""
+        if not len(tail):
+            return False
+        lo, hi = tail.min(), tail.max()
+        near = [p for p in parts if _part_may_match(p, col, lo, hi)]
+        if not near:
+            return False
+        stored, _ = self.read_partitions(table, near, [col])
+        return bool(np.isin(tail, stored[col]).any())
 
     _QUOTA_TTL_S = 5.0
 
@@ -924,6 +955,20 @@ def _partition_rows(spec, phys_data: dict, n: int):
         for val in np.unique(v):
             idx = np.nonzero(v == val)[0]
             yield f"l{val}", idx
+
+
+def _distinct(arr: np.ndarray) -> bool:
+    """Whether an integer array holds no value twice. A span narrower
+    than the row count settles it without a sort, and so does an
+    ascending run (a generated key)."""
+    n = len(arr)
+    if n < 2:
+        return True
+    if int(arr.max()) - int(arr.min()) + 1 < n:
+        return False
+    if bool((arr[1:] > arr[:-1]).all()):
+        return True
+    return len(np.unique(arr)) == n
 
 
 def _part_may_match(part: dict, col: str, lo, hi) -> bool:

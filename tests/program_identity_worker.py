@@ -4,7 +4,7 @@ and prints, for every program the statement launched, a hash of its
 lowered module text and the ``jax.result_info`` keys it carries.
 
     python tests/program_identity_worker.py <n_segments> <q15v|q3|q1|q6> \
-        [--no-origin] [--tiled]
+        [--no-origin] [--tiled] [--store [--warm]]
 
 ``--no-origin`` binds every literal without its origin (``expr.Literal``'s
 ``origin``, ISSUE 29), as the tree before it did: the witness that the
@@ -17,6 +17,13 @@ out-of-core cell's 125 MiB does at SF1
 the cell's budget cut with the data, is under the least tile Q6 runs at),
 and keeps the text of each tiled program (prelude, step, finalize) at its
 first launch.
+
+``--store`` writes the tables through a micro-partition store in several
+appends (``tools/tpchgen.stream_load_tpch``, as the benchmark's loader
+and ``chip_smoke.py`` do) and serves the statement from a fresh session,
+whose tables are COLD: the planner knows of them what the manifests say
+(ISSUE 31). ``--warm`` loads them into RAM before the statement is
+planned. The line also carries the joins the launches counted, by shape.
 """
 
 from __future__ import annotations
@@ -83,13 +90,30 @@ if "--tiled" in sys.argv[3:]:
     overrides["resource.query_mem_bytes"] = 4 << 20
     record_tiled_programs((T, TD), programs)
 
-s = cb.Session(Config(n_segments=N_SEG).with_overrides(**overrides))
-load_tpch(s, sf=0.01, seed=7, tables=["lineitem", "orders", "customer"])
+TABLES = ["lineitem", "orders", "customer"]
+if "--store" in sys.argv[3:]:
+    import tempfile
+
+    from tools.tpchgen import stream_load_tpch
+
+    overrides["storage.root"] = tempfile.mkdtemp(prefix="cbtpu_identity_")
+    config = Config(n_segments=N_SEG).with_overrides(**overrides)
+    stream_load_tpch(cb.Session(config), sf=0.01, seed=7, tables=TABLES,
+                     chunk_rows=4000)
+    s = cb.Session(config)
+    if "--warm" in sys.argv[3:]:
+        for name in TABLES:
+            s.catalog.table(name).ensure_loaded()
+else:
+    s = cb.Session(Config(n_segments=N_SEG).with_overrides(**overrides))
+    load_tpch(s, sf=0.01, seed=7, tables=TABLES)
 rows = s.sql(STATEMENTS[sys.argv[2]]).num_rows()
 info = [m for _, t in programs
         for m in re.findall(r'jax\.result_info = "([^"]*)"', t)]
 print(json.dumps({"rows": rows, "programs": len(programs),
                   "names": [name for name, _ in programs],
+                  "joins": [s.stmt_log.counter("launch_joins_lookup"),
+                            s.stmt_log.counter("launch_joins_expand")],
                   "hashes": [hashlib.sha256(t.encode()).hexdigest()
                              for _, t in programs],
                   "result_info": info}))

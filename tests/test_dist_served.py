@@ -5,6 +5,11 @@ clients. The benchmark's plain references (``benchmarks/reference/q3.py``,
 four-segment answer is the one-segment answer. The distributed launch
 records its five stages and four counters, and two backend sessions
 launching four-device programs from two threads do not hang.
+
+ISSUE 31: the loader's appends leave the keys' uniqueness in the
+manifests, so Q3's two joins are lookups on a COLD table as on a loaded
+one, the served Q3 counts two lookup joins and no expansion, and a
+manifest that lies ends in ``DuplicateBuildKeyError``.
 """
 
 from __future__ import annotations
@@ -13,6 +18,7 @@ import os
 import sys
 import threading
 
+import numpy as np
 import pytest
 
 import cloudberry_tpu as cb
@@ -141,6 +147,133 @@ def test_distributed_launch_records_its_stages_and_counters(deployment):
     # gather of <= capacity partial groups and the redistribute's
     # buckets: kilobytes
     assert 0 < added["motion_wire_bytes"] < 4 << 20
+
+
+def test_the_load_leaves_the_keys_uniqueness_in_the_manifests(deployment):
+    _, root, _ = deployment
+    store = cb.Session(_config(root, 1)).catalog.store
+    flags = {t: store.read_manifest(t)["unique"]
+             for t in ("lineitem", "orders", "customer")}
+    assert flags["orders"]["o_orderkey"] and flags["customer"]["c_custkey"]
+    assert not flags["lineitem"]["l_orderkey"]
+    assert not flags["orders"]["o_custkey"]
+
+
+def _q3_plan(session, cell):
+    from cloudberry_tpu.plan.planner import plan_statement
+    from cloudberry_tpu.sql.parser import parse_sql
+
+    return plan_statement(parse_sql(_text(cell, "q3")), session, {},
+                          explain_only=True).plan
+
+
+def _shape(plan) -> list:
+    """Every node of the plan: its kind, its capacities and what decides
+    a join's lowering; then the plan's text, less the one thing that
+    follows from where the rows are (a cold table's one-segment scan is
+    bound to its store partitions: ``parts n/m``)."""
+    import re
+
+    return [(type(nd).__name__, getattr(nd, "unique_build", None),
+             getattr(nd, "out_capacity", None),
+             getattr(nd, "capacity", None)) for nd in _all(plan)] \
+        + [re.sub(r" parts \d+/\d+", "", plan.explain())]
+
+
+@pytest.mark.parametrize("nseg", [1, 4])
+def test_a_cold_session_plans_q3_as_a_loaded_one_does(deployment, nseg):
+    """A plan must not depend on whether a table happens to be in RAM:
+    cold, ``is_unique`` answers from the manifest; loaded, from the data."""
+    from cloudberry_tpu.plan import nodes as N
+
+    cell, root, _ = deployment
+    cold = cb.Session(_config(root, nseg))
+    cold._sync_store()
+    tables = [cold.catalog.table(t) for t in cell.tables()]
+    assert all(t.cold for t in tables)
+    plan = _q3_plan(cold, cell)
+    if nseg == 1:       # (at four, sizing the shards loads the tables)
+        assert all(t.cold for t in tables)      # planning loaded nothing
+    joins = [nd for nd in _all(plan) if isinstance(nd, N.PJoin)]
+    assert len(joins) == 2
+    assert all(j.unique_build and not j.expands for j in joins)
+    warm = cb.Session(_config(root, nseg))
+    warm._sync_store()
+    for t in cell.tables():
+        warm.catalog.table(t).ensure_loaded()
+    assert not any(warm.catalog.table(t).cold for t in cell.tables())
+    assert _shape(_q3_plan(warm, cell)) == _shape(plan)
+
+
+def _all(plan):
+    from cloudberry_tpu.exec.executor import all_nodes
+
+    return list(all_nodes(plan))
+
+
+@pytest.mark.parametrize("nseg", [1, 4])
+def test_served_q3_launches_two_lookup_joins(deployment, nseg):
+    """``launch_joins_lookup`` / ``launch_joins_expand``: the joins of each
+    shape in every program launched. A session over the cold store, as
+    the server's backend is: Q3 counts two lookups a send and the view,
+    which joins nothing, counts none."""
+    cell, root, truth = deployment
+    with Server(config=_config(root, nseg)) as srv:
+        c = Client(srv.host, srv.port, timeout=300.0)
+        try:
+            def counters():     # the engine's log, shared by backends
+                log = srv.session.stmt_log
+                return (log.counter("launch_joins_lookup"),
+                        log.counter("launch_joins_expand"))
+            assert counters() == (0, 0)
+            got = c.sql(_text(cell, "q3"))
+            assert counters() == (2, 0)
+            c.sql(_text(cell, "q15v"))
+            assert counters() == (2, 0)
+            c.sql(_text(cell, "q3"))
+            assert counters() == (4, 0)
+        finally:
+            c.close()
+    ref = cell.statements["q3"][1].answer(truth, DRAWS["q3"])
+    assert compare.gap(got, ref)[0] == 0
+
+
+@pytest.mark.parametrize("nseg", [1, 4])
+def test_a_manifest_that_lies_ends_in_an_error_not_an_answer(tmp_path,
+                                                             nseg):
+    """The flag is a claim the program re-verifies: a build side flagged
+    unique whose data repeats a key trips the lookup's duplicate check."""
+    from cloudberry_tpu import types as T
+    from cloudberry_tpu.catalog.catalog import DistributionPolicy
+    from cloudberry_tpu.exec.executor import DuplicateBuildKeyError
+    from cloudberry_tpu.types import Schema
+
+    root = str(tmp_path)
+    store = cb.Session(_config(root, 1)).catalog.store
+    dim = Schema.of(d_key=T.INT64, d_val=T.INT64)
+    fact = Schema.of(f_key=T.INT64, f_val=T.INT64)
+    store.append("dim", {"d_key": np.asarray([1, 2, 2, 3], dtype=np.int64),
+                         "d_val": np.arange(4, dtype=np.int64)}, dim,
+                 policy=DistributionPolicy.hashed("d_key"),
+                 unique={"d_key": True, "d_val": True})
+    store.append("fact", {"f_key": np.asarray([1, 2, 3, 3], dtype=np.int64),
+                          "f_val": np.arange(4, dtype=np.int64)}, fact,
+                 policy=DistributionPolicy.hashed("f_key"))
+    s = cb.Session(_config(root, nseg))
+    sql = ("select f_val, d_val from fact join dim on f_key = d_key "
+           "order by f_val, d_val")
+    with pytest.raises(DuplicateBuildKeyError):
+        s.sql(sql)
+    assert s.stmt_log.counter("duplicate_build_key_errors") == 1
+    # the honest flags: an expansion, and the answer
+    store.append("dim", {"d_key": np.asarray([1, 2, 2, 3], dtype=np.int64),
+                         "d_val": np.arange(4, dtype=np.int64)}, dim,
+                 policy=DistributionPolicy.hashed("d_key"), replace=True)
+    s = cb.Session(_config(root, nseg))
+    got = s.sql(sql).to_pandas()
+    assert got.values.tolist() == [[0, 0], [1, 1], [1, 2], [2, 3], [3, 3]]
+    assert s.stmt_log.counter("launch_joins_expand") == 1
+    assert s.stmt_log.counter("launch_joins_lookup") == 0
 
 
 def test_two_backends_launch_four_device_programs_at_once(deployment):

@@ -74,6 +74,27 @@ def test_a_literals_origin_reaches_no_program(nseg, stmt):
     assert a["result_info"] == b["result_info"]
 
 
+def test_a_cold_table_lowers_to_the_program_of_a_loaded_one():
+    """Q3 at four segments over a store written in several appends
+    (ISSUE 31): served from a session whose tables are cold, where the
+    planner has the manifests' word for the keys' uniqueness, and from
+    one that loaded them first, where it has the data, the statement is
+    the same module text, and both its joins are lookups."""
+    procs = [_start(4, "q3", "--store"), _start(4, "q3", "--store", "--warm")]
+    outs = []
+    for p in procs:
+        out, err = p.communicate(timeout=600)
+        assert p.returncode == 0, err[-2000:]
+        outs.append(json.loads(out.strip().splitlines()[-1]))
+    cold, warm = outs
+    assert cold["programs"] == warm["programs"] == 1
+    assert cold["rows"] == warm["rows"] == 10
+    assert cold["hashes"] == warm["hashes"]
+    assert cold["result_info"] == warm["result_info"]
+    assert cold["joins"] == warm["joins"] == [2, 0]
+    assert not any("expansion overflow" in k for k in cold["result_info"])
+
+
 def test_no_program_key_embeds_an_address():
     """No check or stats key anywhere in the engine is built from
     ``id()``: the only ``(node ...)`` references are ordinals
