@@ -22,8 +22,9 @@ import numpy as np
 class StringDictionary:
     """Immutable-ish ordered dictionary: values[code] == string.
 
-    Sorted insertion is NOT guaranteed; ordering comparisons on strings use
-    a rank table (see ``rank_table``).
+    The codes are NOT in the values' order (``encode`` sorts only the
+    values one call adds); ordering comparisons on strings use a rank
+    table (see ``rank_table``).
     """
 
     __slots__ = ("values", "_index")
@@ -48,7 +49,18 @@ class StringDictionary:
         return code
 
     def encode(self, arr: Iterable[str]) -> np.ndarray:
-        return np.fromiter((self.add(v) for v in arr), dtype=np.int32)
+        """Codes of ``arr``'s values. The values the dictionary has not
+        seen take their codes in SORTED order, not in the order of the
+        rows: the codes a column ends with then follow from the sets of
+        values its loads held, and two loads of data drawn alike (a
+        flag, a mode, a priority: every value in the first chunk) plan
+        to the same constants and compile to the same program."""
+        arr = arr if isinstance(arr, (list, np.ndarray)) else list(arr)
+        for v in sorted(set(arr) - self._index.keys(), key=str):
+            self.add(v)
+        index = self._index
+        return np.fromiter((index[v] for v in arr), dtype=np.int32,
+                           count=len(arr))
 
     def decode(self, codes: np.ndarray) -> np.ndarray:
         vals = np.asarray(self.values, dtype=object)

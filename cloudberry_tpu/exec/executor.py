@@ -75,6 +75,10 @@ def execute(plan: N.PlanNode, session) -> ColumnBatch:
                           log=getattr(session, "stmt_log", None))
 
 
+# a scan's row count where it rides beside the scan's columns as data
+NROWS = "$nrows"
+
+
 def keyed_scan(s: N.PScan) -> bool:
     """Scans whose input rides under a per-scan key instead of the
     table name: pruned store reads and point-lookup slices."""
@@ -105,6 +109,8 @@ def compile_plan(plan: N.PlanNode, session,
     platform = platform or jax.default_backend()
     count_compile(session)
 
+    dicts = stamp_dict_tables(plan)
+
     def run(tables):
         low = Lowerer(tables, platform=platform,
                       params=tables.get("$params"), count_rows=instrument)
@@ -114,13 +120,68 @@ def compile_plan(plan: N.PlanNode, session,
             return out, sel, low.checks, low.node_counts
         return out, sel, low.checks
 
+    def jit(fn):
+        return _WithDictTables(jax.jit(fn), dicts) if dicts \
+            else jax.jit(fn)
+
+    raw = run if not dicts else \
+        (lambda tables: run({**tables, DICT_TABLES: dicts}))
     if instrument:
-        return Executable(plan, jax.jit(run), table_names, store_scans,
-                          run, instrumented=True)
-    return Executable(plan, jax.jit(run), table_names, store_scans, run,
-                      packed_fn=jax.jit(
+        return Executable(plan, jit(run), table_names, store_scans,
+                          raw, instrumented=True)
+    return Executable(plan, jit(run), table_names, store_scans, raw,
+                      packed_fn=jit(
                           lambda tables: pack_answer(*run(tables))),
                       join_shapes=join_shapes(plan))
+
+
+# A string predicate is decided once over its column's dictionary, on the
+# host, and the program gathers the verdicts by code (ex.DictLookup). A
+# table longer than this rides into the program as an INPUT at the rung
+# above its length, not as a constant: a large dictionary's size and
+# contents follow from the data (Q13's LIKE over some 560,000 distinct
+# order comments), and a constant would make every load of the table a
+# program of its own. Shorter tables (a flag, a mode, a segment: under
+# the ladder's first step) stay constants.
+DICT_TABLES = "$dicts"
+_DICT_TABLE_MIN = 64
+
+
+def stamp_dict_tables(plan: N.PlanNode) -> dict:
+    """{input key: device array} of the plan's long dictionary tables,
+    each padded to its rung; every such ``DictLookup`` is stamped with
+    its key (``_table_input``), which ``compile_expr`` reads the table
+    by when the lowerer offers it. Keys follow the plan's document order,
+    so two plans of one shape name their tables alike."""
+    from cloudberry_tpu.plan.distribute import _node_exprs
+
+    out = {}
+    for node in numbered_nodes(plan):
+        for e in _node_exprs(node):
+            for sub in ex.walk(e):
+                if isinstance(sub, ex.DictLookup) \
+                        and len(sub.table) > _DICT_TABLE_MIN:
+                    key = f"$dict{len(out)}"
+                    object.__setattr__(sub, "_table_input", key)
+                    table = np.asarray(sub.table)
+                    out[key] = jnp.asarray(K.pad_rows(
+                        table, K.row_rung_up(len(table))))
+    return out
+
+
+class _WithDictTables:
+    """A jitted program called with its dictionary tables beside the
+    inputs it is handed."""
+
+    def __init__(self, jitted, dicts: dict):
+        self.jitted, self.dicts = jitted, dicts
+        self.__name__ = getattr(jitted, "__name__", "run")
+
+    def __call__(self, tables):
+        return self.jitted({**tables, DICT_TABLES: self.dicts})
+
+    def lower(self, tables):
+        return self.jitted.lower({**tables, DICT_TABLES: self.dicts})
 
 
 def prepare_tables(table_names: list[str], session,
@@ -132,10 +193,17 @@ def prepare_tables(table_names: list[str], session,
         t = session.catalog.table(name)
         t.ensure_loaded()  # safety: a cold table on the RAM path loads whole
         if segment is None or t.policy.kind == "replicated":
-            tables[name] = {c: jnp.asarray(v) for c, v in t.data.items()}
+            # one segment: the columns at the scan's capacity rung, the
+            # row count beside them as data (Lowerer.scan); a replicated
+            # table under direct dispatch is read whole, at its length
+            cap = K.row_rung_up(t.num_rows) if segment is None else 0
+            tables[name] = {c: jnp.asarray(K.pad_rows(np.asarray(v), cap))
+                            for c, v in t.data.items()}
             for c, vm in t.validity.items():
-                tables[name][f"$nn:{c}"] = jnp.asarray(
-                    np.asarray(vm, dtype=np.bool_))
+                tables[name][f"$nn:{c}"] = jnp.asarray(K.pad_rows(
+                    np.asarray(vm, dtype=np.bool_), cap))
+            if segment is None:
+                tables[name][NROWS] = np.int64(t.num_rows)
         else:
             st = session.sharded_table(name)
             tables[name] = {c: jnp.asarray(v[segment])
@@ -265,14 +333,19 @@ def _read_scan_columns(scan: N.PScan, session, log) -> dict:
     needed = sorted(set(scan.column_map) | set(scan.mask_map))
     parts = list(scan._store_parts)
     bpool = BUF.pool_for(session)
+    # the columns cross to the device ONCE, at the scan's capacity rung
+    # (zeros past the partitions' rows), the row count beside them
     if bpool is None or not parts:
         cols, validity = store.read_partitions(scan.table_name, parts,
                                                needed)
         if log is not None and parts:
             log.bump("host_decodes", len(parts))
-        hit = {c: jnp.asarray(v) for c, v in cols.items()}
+        hit = {c: jnp.asarray(K.pad_rows(np.asarray(v), scan.capacity))
+               for c, v in cols.items()}
         for c, v in validity.items():
-            hit[f"$nn:{c}"] = jnp.asarray(np.asarray(v, dtype=np.bool_))
+            hit[f"$nn:{c}"] = jnp.asarray(K.pad_rows(
+                np.asarray(v, dtype=np.bool_), scan.capacity))
+        hit[NROWS] = np.int64(scan.num_rows)
         return hit
     cols_key = tuple(needed)
     col_chunks: dict[str, list] = {}
@@ -293,6 +366,10 @@ def _read_scan_columns(scan: N.PScan, session, log) -> dict:
             col_chunks.setdefault(c, []).append(v)
         for c, v in ent["validity"].items():
             val_chunks.setdefault(c, []).append(v)
+    short = scan.capacity - scan.num_rows
+    if short > 0:
+        for vs in (*col_chunks.values(), *val_chunks.values()):
+            vs.append(np.zeros((short,), dtype=vs[0].dtype))
     hit = {c: (jnp.asarray(vs[0]) if len(vs) == 1
                else jnp.concatenate([jnp.asarray(v) for v in vs]))
            for c, vs in col_chunks.items()}
@@ -302,6 +379,7 @@ def _read_scan_columns(scan: N.PScan, session, log) -> dict:
         hit[f"$nn:{c}"] = (jnp.asarray(vs[0]) if len(vs) == 1
                            else jnp.concatenate(
                                [jnp.asarray(v) for v in vs]))
+    hit[NROWS] = np.int64(scan.num_rows)
     return hit
 
 
@@ -412,6 +490,7 @@ def run_executable(exe: Executable, tables: dict, log=None) -> ColumnBatch:
         log.bump("launch_packed")
         log.bump("launch_d2h_reads", len(host))
         count_join_shapes(log, exe.join_shapes)
+        count_scan_rows(log, tables)
     with OT.stage("fetch", "launch_seconds", host=True) as st:
         cols, sel, checks = unpack_answer(packed.layout, host)
         raise_checks(checks)
@@ -602,6 +681,24 @@ def count_join_shapes(log, shapes: tuple) -> None:
         log.bump("launch_joins_expand", shapes[1])
 
 
+def count_scan_rows(log, tables: dict) -> None:
+    """Bump ``scan_rows`` and ``scan_capacity_rows`` by the rows the
+    launch's scanned inputs hold and the rows they are padded to (their
+    capacity rungs); a point slice, which is all rows, counts in
+    neither."""
+    rows = capacity = 0
+    for data in tables.values():
+        n = data.get(NROWS)
+        if n is not None:
+            rows += int(n)
+            # (every column of a scan's input has the capacity's length)
+            capacity += next((v.shape[0] for k, v in data.items()
+                              if k != NROWS), 0)
+    if capacity:
+        log.bump("scan_rows", rows)
+        log.bump("scan_capacity_rows", capacity)
+
+
 def _dedupe_nodes(nodes) -> list:
     """Unique by identity, preserving order — all_nodes re-walks shared
     (PShare) subtrees once per reference, and a buffer must be grown
@@ -756,6 +853,9 @@ class Lowerer:
         # "$prm<slot>" -> scalar array, injected next to the columns when
         # an expression carries Param leaves
         self.params = params
+        # long dictionary tables riding as inputs (stamp_dict_tables):
+        # injected next to the columns of an expression that gathers one
+        self.dict_tables = tables.get(DICT_TABLES) or {}
         # id(node) -> (cols, sel): such a node lowers to what it is given
         # and its subtree is never traced (a tiled step's prelude-computed
         # builds, a finalize's accumulator leaf)
@@ -887,24 +987,33 @@ class Lowerer:
             return {}, jnp.ones((1,), dtype=jnp.bool_)
         data = self.tables[getattr(node, "_input_key", node.table_name)]
         cols = {}
-        for phys, out in node.column_map.items():
-            arr = data[phys]
-            if arr.shape[0] < node.capacity:  # empty table: 0 rows, cap 1
+        for name, out in [*node.column_map.items(),
+                          *((f"$nn:{p}", o)
+                            for p, o in node.mask_map.items())]:
+            arr = data[name]
+            if arr.shape[0] == 0:   # an empty table: no rows, capacity 1
                 arr = jnp.zeros((node.capacity,), dtype=arr.dtype)
+            elif arr.shape[0] != node.capacity:
+                # a short column would be read past its rows: the inputs
+                # are padded to the capacity where they cross to the
+                # device (prepare_tables, _read_scan_columns)
+                raise ExecError(
+                    f"scan of {node.table_name}: column {name} has "
+                    f"{arr.shape[0]} rows, the plan's capacity is "
+                    f"{node.capacity}")
             cols[out] = arr
-        for phys, out in node.mask_map.items():
-            arr = data[f"$nn:{phys}"]
-            if arr.shape[0] < node.capacity:
-                arr = jnp.zeros((node.capacity,), dtype=jnp.bool_)
-            cols[out] = arr
-        n = node.num_rows if node.num_rows >= 0 else node.capacity
+        # the row count is data, the CAPACITY is the shape: a whole-table
+        # or store scan finds its count beside its columns, so that every
+        # table on one capacity rung is one program; a point slice is all
+        # rows
+        n = data.get(NROWS)
+        if n is None:
+            n = node.num_rows if node.num_rows >= 0 else node.capacity
         key = getattr(node, "_nrows_key", None)
         if key is not None and self.params is not None \
                 and key in self.params:
-            # generic plan: the row count rides the $params input, so one
-            # compiled program serves every direct-dispatch segment (and
-            # every table version at unchanged capacity) — the count is
-            # data, the CAPACITY is the shape
+            # generic plan: the count rides the $params input, so one
+            # compiled program serves every direct-dispatch segment
             n = self.params[key]
         sel = jnp.arange(node.capacity) < n
         return cols, sel
@@ -944,6 +1053,10 @@ class Lowerer:
         if self.params is not None \
                 and any(isinstance(n, ex.Param) for n in ex.walk(e)):
             cols = {**cols, **self.params}
+        if self.dict_tables and any(
+                getattr(n, "_table_input", None) in self.dict_tables
+                for n in ex.walk(e)):
+            cols = {**cols, **self.dict_tables}
         if not subs:
             return compile_expr(e)(cols)
         aug = dict(cols)
@@ -1002,6 +1115,14 @@ class Lowerer:
         # subtree — it must trace once
         bcols, bsel = self.lower_shared(node.build)
         pcols, psel = self.lower(node.probe)
+        # a profile tells a join's own operations (key packing, the
+        # search or the expansion, the payload gathers) from its inputs'
+        # and the rest by this scope in their names, by the shape taken
+        with jax.named_scope(
+                "join:expand" if node.expands else "join:lookup"):
+            return self._join(node, bcols, bsel, pcols, psel)
+
+    def _join(self, node: N.PJoin, bcols, bsel, pcols, psel):
         bkeys = [self.expr(k, bcols) for k in node.build_keys]
         pkeys = [self.expr(k, pcols) for k in node.probe_keys]
 

@@ -96,9 +96,15 @@ def compile_expr(e: ex.Expr) -> Callable[[Columns], jnp.ndarray]:
 
     if isinstance(e, ex.DictLookup):
         f = compile_expr(e.column)
-        table = jnp.asarray(e.table)
+        # a long table rides into the program as an input, padded to
+        # its rung (exec/executor.py stamp_dict_tables); where the
+        # lowerer offers none, the table is the constant it always was
+        key = getattr(e, "_table_input", None)
 
         def lookup(cols):
+            table = cols.get(key) if key is not None else None
+            if table is None:
+                table = jnp.asarray(e.table)
             codes = f(cols)
             # code -1 (value absent from dictionary) must not match predicates
             safe = jnp.clip(codes, 0, table.shape[0] - 1)

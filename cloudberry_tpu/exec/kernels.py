@@ -980,17 +980,31 @@ def rung_up(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def shard_rung_up(n: int) -> int:
-    """Round a shard's row capacity up to its rung: the next multiple of
+def row_rung_up(n: int) -> int:
+    """Round a scan's row capacity up to its rung: the next multiple of
     a 64th of the power of two above it (at most 3.1 % of padding, none
-    up to 64 rows). Every shape of a distributed program follows from its
-    scans' shard capacities, and the persistent compile cache keys on the
-    shapes: a table reloaded with a few thousand rows more or fewer (a
-    new ``--seed`` of a benchmark, a day's appends) then meets the program
-    the last process compiled instead of a multi-minute compile."""
+    up to 64 rows). Every shape of a program follows from its scans'
+    capacities, and the persistent compile cache keys on the shapes: a
+    table reloaded with a few thousand rows more or fewer (a new
+    ``--seed`` of a benchmark, a day's appends) then meets the program
+    the last process compiled instead of a multi-minute compile. One
+    ladder for a one-segment scan (its table's rows, or those of the
+    micro-partitions it reads: ``plan/binder.py Binder._scan``,
+    ``plan/scanprune.py``) and for a shard (``Session.shard_capacity``);
+    the row count itself reaches the program as data."""
     n = max(int(n), 1)
     step = 1 << max((n - 1).bit_length() - 6, 0)
     return -(-n // step) * step
+
+
+def pad_rows(arr: np.ndarray, capacity: int) -> np.ndarray:
+    """A scanned host column (or ``$nn:`` mask) at its scan's capacity,
+    padded before it crosses to the device: zeros past the table's rows,
+    which ``sel = arange(capacity) < rows`` never selects."""
+    short = capacity - arr.shape[0]
+    if short <= 0:
+        return arr
+    return np.concatenate([arr, np.zeros((short,), dtype=arr.dtype)])
 
 
 # --------------------------------------------------------------------------

@@ -539,7 +539,7 @@ class Binder:
                     self._ctes = saved
             table = self._lookup_table(ref.name)
             alias = ref.alias or ref.name
-            plan = _scan_node(table, alias)
+            plan = self._scan(table, alias)
             scope.entries.append(RangeEntry(alias, plan))
             return alias, plan
         if isinstance(ref, ast.DerivedTable):
@@ -591,9 +591,28 @@ class Binder:
             raise BindError(f"table function {ref.name}: {e}")
         table = self._lookup_table(tname)
         alias = ref.alias or ref.name
-        plan = _scan_node(table, alias)
+        plan = self._scan(table, alias)
         scope.entries.append(RangeEntry(alias, plan))
         return alias, plan
+
+    def _scan(self, table: Table, alias: str) -> N.PScan:
+        """At one segment a scan's capacity is the rung above its table's
+        rows (exec/kernels.py row_rung_up): tables of nearly one size
+        plan to one set of shapes, and the count reaches the program as
+        data. Across segments plan/distribute.py sizes the scan by its
+        shards, which sit on the same ladder."""
+        return _scan_node(table, alias, self._rung(table.num_rows))
+
+    def _rung(self, rows: int) -> int:
+        """A capacity for ``rows`` rows that follow from the data (a
+        table's count, an expansion's estimated pairs): at one segment
+        the rung above them, so that another load of nearly the same
+        data plans to the same shapes."""
+        if self.config is not None and self.config.n_segments != 1:
+            return max(rows, 1)
+        from cloudberry_tpu.exec.kernels import row_rung_up
+
+        return row_rung_up(rows)
 
     def _requalify(self, sub: N.PlanNode, alias: str) -> N.PProject:
         """Re-qualify a subplan's output names under a derived/CTE alias
@@ -993,9 +1012,9 @@ class Binder:
 
             est = estimate_rows(j, self.catalog)
             j._est_pairs = est  # distribution/tiling re-derive from this
-            j.out_capacity = max(
+            j.out_capacity = self._rung(max(
                 _plan_capacity(build) + _plan_capacity(probe),
-                int(2 * est) + 8)
+                int(2 * est) + 8))
         nm = match_name if kind in ("left", "full") else None
         pm = self.gensym("pmatch") if kind == "full" else None
         j.probe_match_name = pm
@@ -2156,9 +2175,9 @@ class Binder:
                             list(build_keys), list(probe_keys), [])
             est = estimate_rows(pairs, self.catalog)
             j._est_pairs = est  # distribution/tiling re-derive from this
-            j.out_capacity = max(
+            j.out_capacity = self._rung(max(
                 _plan_capacity(subplan) + _plan_capacity(plan),
-                int(2 * est) + 8)
+                int(2 * est) + 8))
         return j
 
     def _apply_in_subquery(self, node: ast.InSubquery, plan: N.PlanNode,
@@ -2746,14 +2765,14 @@ def _dtype_extreme(t: SqlType, want_max: bool):
     return (1 << bits) - 1 if want_max else -(1 << bits)
 
 
-def _scan_node(table: Table, alias: str) -> N.PScan:
+def _scan_node(table: Table, alias: str, capacity: int) -> N.PScan:
     cmap = {f.name: f"{alias}.{f.name}" for f in table.schema.fields}
     validity = getattr(table, "validity", {})
     # mask output names keep the "<alias>.$..." shape so the hidden-column
     # convention (last dotted component starts with "$") holds
     mask_map = {f.name: f"{alias}.$nn:{f.name}"
                 for f in table.schema.fields if f.name in validity}
-    scan = N.PScan(table.name, cmap, capacity=max(table.num_rows, 1),
+    scan = N.PScan(table.name, cmap, capacity=capacity,
                    num_rows=table.num_rows, mask_map=mask_map)
     scan.fields = [
         N.PlanField(f"{alias}.{f.name}", f.type, table.dicts.get(f.name),

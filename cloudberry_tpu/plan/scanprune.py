@@ -101,8 +101,16 @@ def _bind_scan(node: N.PScan, preds: tuple, t, store) -> None:
     # literal template (sched/paramplan.py) re-decides the list from them
     node._prune_cmps = cmps
     node._input_key = f"{node.table_name}#{id(node)}"
-    node.capacity = max(rows, 1)
-    node.num_rows = rows
+    _size_scan(node, rows)
+
+
+def _size_scan(scan: N.PScan, rows: int) -> None:
+    """The scan reads ``rows`` rows at the capacity rung above them (the
+    count is data, the capacity the shape: exec/kernels.py row_rung_up)."""
+    from cloudberry_tpu.exec.kernels import row_rung_up
+
+    scan.capacity = row_rung_up(rows)
+    scan.num_rows = rows
 
 
 def _dynamic_eliminate(join: N.PJoin, session, store) -> None:
@@ -155,9 +163,7 @@ def _dynamic_eliminate(join: N.PJoin, session, store) -> None:
     scan._store_parts = kept
     scan._prune_report["skipped_dynamic"] = \
         scan._prune_report.get("skipped_dynamic", 0) + n_dropped
-    rows = sum(p["num_rows"] - len(p["deleted"]) for p in kept)
-    scan.capacity = max(rows, 1)
-    scan.num_rows = rows
+    _size_scan(scan, sum(p["num_rows"] - len(p["deleted"]) for p in kept))
 
 
 def _eval_build_keys(build: N.PlanNode, key_expr: ex.Expr, session):

@@ -1414,6 +1414,6 @@ class Session:
     def shard_capacity(self, name: str) -> int:
         """The largest shard's rows, rounded up to its rung: the planner's
         scan capacity and the materialized (nseg, cap) arrays' width."""
-        from cloudberry_tpu.exec.kernels import shard_rung_up
+        from cloudberry_tpu.exec.kernels import row_rung_up
 
-        return shard_rung_up(self.shard_counts(name).max(initial=1))
+        return row_rung_up(self.shard_counts(name).max(initial=1))

@@ -11,7 +11,7 @@ import pytest
 import cloudberry_tpu as cb
 from cloudberry_tpu.config import Config
 from cloudberry_tpu.exec import dist_executor as DX
-from cloudberry_tpu.exec.kernels import shard_rung_up
+from cloudberry_tpu.exec.kernels import row_rung_up
 
 
 @pytest.mark.parametrize("n,rung", [
@@ -21,14 +21,14 @@ from cloudberry_tpu.exec.kernels import shard_rung_up
     (1_505_420, 1_507_328), (1_503_424, 1_507_328), (1_501_806, 1_507_328),
     (376_100, 376_832), (37_717, 37_888)])
 def test_the_ladder(n, rung):
-    assert shard_rung_up(n) == rung
+    assert row_rung_up(n) == rung
 
 
 def test_a_rung_is_never_under_and_at_most_a_32nd_over():
     for n in list(range(1, 5000)) + [10**k + 7 for k in range(4, 10)]:
-        r = shard_rung_up(n)
+        r = row_rung_up(n)
         assert n <= r <= n + max(n // 32, 0) + 1, n
-        assert shard_rung_up(r) == r
+        assert row_rung_up(r) == r
 
 
 def _session(rows: int):
