@@ -59,9 +59,10 @@ class Executable:
     # the one-shot launch's program (run_executable): ``fn`` ending in
     # pack_answer, so the host gets the whole answer in one wait
     packed_fn: Callable = None  # type: ignore[assignment]
-    # (lookups, expansions) among the plan's joins (join_shapes), fixed
-    # when the plan is lowered and counted on every launch
-    join_shapes: tuple = (0, 0)
+    # (lookups, expansions, compacted lookups) among the plan's joins
+    # (join_shapes), fixed when the plan is lowered and counted on every
+    # launch
+    join_shapes: tuple = (0, 0, 0)
 
 
 def execute(plan: N.PlanNode, session) -> ColumnBatch:
@@ -409,6 +410,15 @@ class PackedAnswer:
     layout: tuple
 
 
+def _check_flag(bad):
+    """A check as one scalar of the packed answer: whether any lane of it
+    is set; a check that COUNTS (an integer: the rows that overflowed a
+    join's own capacity, 0 where they fit) keeps its count, which the
+    host grows the capacity by (``raise_checks``)."""
+    return jnp.max(bad) if jnp.issubdtype(bad.dtype, jnp.integer) \
+        else jnp.any(bad)
+
+
 def pack_answer(cols, sel, checks) -> PackedAnswer:
     """The end of a one-shot program: one flag per check (``any()`` of
     it, in the dict's order), ``sel``, and every output column and mask,
@@ -422,7 +432,8 @@ def pack_answer(cols, sel, checks) -> PackedAnswer:
     import jax.lax as lax
 
     leaves = [jnp.asarray(x) for x in (
-        *(jnp.any(bad) for bad in checks.values()), sel, *cols.values())]
+        *(_check_flag(bad) for bad in checks.values()), sel,
+        *cols.values())]
     # 0: the byte buffer, 1: the float64 buffer, 2: beside them
     kinds = [2 if x.nbytes > _PACK_LEAF_MAX
              else int(x.dtype == jnp.float64) for x in leaves]
@@ -514,9 +525,14 @@ def run_prepared(exe: Executable, session, segment=None) -> ColumnBatch:
 
 def raise_checks(checks: dict) -> None:
     for msg, bad in checks.items():
-        if bool(np.asarray(bad).any()):
+        bad = np.asarray(bad)
+        if bool(bad.any()):
             if "duplicate keys" in msg:
                 raise DuplicateBuildKeyError(msg)
+            if bad.dtype.kind in "iu":
+                # a counting check (Lowerer._compact_rows): the rows
+                # that came, for grow_expansion to size the retry by
+                msg = f"{msg}: {int(bad.max())} rows"
             raise ExecError(msg)
 
 
@@ -652,33 +668,41 @@ def keyed_node(plan_nodes: list, key: str):
 
 
 def find_expansion_node(plan: N.PlanNode, message: str):
-    """The join a detected expansion-overflow check message points at
-    (messages name the node by its ordinal), or None."""
-    if "expansion overflow" not in message:
+    """The join a detected overflow check message points at (an
+    expansion's pair buffer, a lookup join's compaction: messages name
+    the node by its ordinal), or None."""
+    if "expansion overflow" not in message \
+            and "compaction overflow" not in message:
         return None
     node = keyed_node(numbered_nodes(plan), message)
     return node if isinstance(node, N.PJoin) else None
 
 
 def join_shapes(plan: N.PlanNode) -> tuple:
-    """(sorted-build lookups, pair expansions) among ``plan``'s joins, by
-    the shape ``Lowerer.join`` takes for each (``PJoin.expands``)."""
+    """(sorted-build lookups, pair expansions, lookups at capacities of
+    their own) among ``plan``'s joins, by the shape ``Lowerer.join``
+    takes for each (``PJoin.expands``, plan/joincap.py)."""
     joins = _dedupe_nodes(nd for nd in all_nodes(plan)
                           if isinstance(nd, N.PJoin))
     expand = sum(nd.expands for nd in joins)
-    return len(joins) - expand, expand
+    probe_rows = [(nd, N.capacity_of(nd.probe)) for nd in joins
+                  if nd.compacts]
+    compacted = sum(nd.out_rows(rows) < rows for nd, rows in probe_rows)
+    return len(joins) - expand, expand, compacted
 
 
 def count_join_shapes(log, shapes: tuple) -> None:
     """One launch's joins on the engine's counters: ``launch_joins_lookup``
     and ``launch_joins_expand`` say which join the planner chose in the
-    programs that ran (a program that joins nothing bumps neither)."""
+    programs that ran, ``launch_joins_compacted`` how many of the lookups
+    ran at a capacity of their own (a program that joins nothing bumps
+    none)."""
     if log is None:
         return
-    if shapes[0]:
-        log.bump("launch_joins_lookup", shapes[0])
-    if shapes[1]:
-        log.bump("launch_joins_expand", shapes[1])
+    for name, n in zip(("launch_joins_lookup", "launch_joins_expand",
+                        "launch_joins_compacted"), shapes):
+        if n:
+            log.bump(name, n)
 
 
 def count_scan_rows(log, tables: dict) -> None:
@@ -738,6 +762,17 @@ def grow_expansion(plan: N.PlanNode, message: str, factor: int = 4,
     # capacity ladder, not ride it to the ceiling first
     check_cancel()
     node = find_expansion_node(plan, message)
+    if "compaction overflow" in message:
+        # a lookup join's own capacity (plan/joincap.py): the next one,
+        # and the aggregates above follow
+        if node is None or not node.compacts:
+            return False
+        from cloudberry_tpu.plan import joincap
+
+        rows = re.search(r": (\d+) rows$", message)
+        joincap.grow(plan, node, "probe" if "join probe " in message
+                     else "match", int(rows.group(1)) if rows else 0)
+        return True
     join_hits = [node] if node is not None else []
     if not join_hits and allow_fallback \
             and "expansion overflow" in message:
@@ -1122,6 +1157,20 @@ class Lowerer:
                 "join:expand" if node.expands else "join:lookup"):
             return self._join(node, bcols, bsel, pcols, psel)
 
+    def _compact_rows(self, node: N.PJoin, what: str, cols, sel,
+                      capacity: int):
+        """``cols`` at the ``capacity`` rows the planner stamped for the
+        rows ``sel`` keeps, in order (``K.compact_sparse``); more of them
+        than ``capacity`` is a check that carries their count, which the
+        session answers by growing that capacity to hold them
+        (``grow_expansion``), never a cut row."""
+        out, osel, n = K.compact_sparse(cols, sel, capacity)
+        self.checks[
+            f"join {what} compaction overflow: rows exceed capacity "
+            f"{capacity} {self.label(node)}"] = jnp.where(
+                n > capacity, n, 0)
+        return out, osel
+
     def _join(self, node: N.PJoin, bcols, bsel, pcols, psel):
         bkeys = [self.expr(k, bcols) for k in node.build_keys]
         pkeys = [self.expr(k, pcols) for k in node.probe_keys]
@@ -1136,6 +1185,16 @@ class Lowerer:
         bselm = bsel & bkv if bkv is not None else bsel
         pselm = psel & pkv if pkv is not None else psel
 
+        if node.search_rows(psel.shape[0]) < psel.shape[0]:
+            # a sparse probe (plan/joincap.py): the rows that can match,
+            # and no others, reach the search, the match test and the
+            # payload gathers (an inner or semi join emits matched rows
+            # only, so the rest are nobody's)
+            pcols, pselm = self._compact_rows(node, "probe", pcols, pselm,
+                                              node.probe_capacity)
+            psel = pselm
+            pkeys = [self.expr(k, pcols) for k in node.probe_keys]
+
         if node.kind in ("semi", "anti") and node.residual is not None:
             return self._join_semi_residual(node, bcols, bselm, bkeys,
                                             pcols, psel, pselm, pkeys)
@@ -1143,14 +1202,22 @@ class Lowerer:
             return self._join_expand(node, bcols, bsel, bselm, bkeys,
                                      pcols, psel, pselm, pkeys)
 
-        jix = self._join_index(node)
-        if jix is not None:
-            idx, matched, has_dup = K.join_lookup_sorted(
-                jix[0], jix[1], jix[2], pkeys, pselm,
-                bits=node.pack_bits)
-        else:
-            idx, matched, has_dup = K.join_lookup(
-                bkeys, bselm, pkeys, pselm, bits=node.pack_bits)
+        order, kb_sorted, ranges = self._join_index(node) \
+            or K.build_sort(bkeys, bselm, node.pack_bits)
+        pos, matched = K.join_probe_sorted(kb_sorted, ranges, pkeys, pselm,
+                                           bits=node.pack_bits)
+        if node.out_rows(psel.shape[0]) < psel.shape[0]:
+            # sparse matches: the probe's columns and the positions found
+            # are compacted to the matched rows, so the build rows and
+            # the payload words are gathered for those alone and every
+            # node above runs at this capacity
+            out, matched = self._compact_rows(
+                node, "match", {**pcols, "$joinpos": pos}, matched,
+                node.out_capacity)
+            pos = out.pop("$joinpos")
+            pcols, psel = out, matched
+        idx = order[pos].astype(jnp.int32)
+        has_dup = K.dup_check(kb_sorted, node.pack_bits)
         payload = K.gather_payload(
             {n: bcols[n] for n in node.build_payload}, idx, matched)
         if node.kind in ("inner", "left"):

@@ -94,25 +94,9 @@ def estimate_plan_memory(plan: N.PlanNode) -> MemoryEstimate:
             w += f.type.np_dtype.itemsize
         return w
 
-    def cap_of(node: N.PlanNode) -> int:
-        if isinstance(node, N.PScan):
-            return node.capacity
-        if isinstance(node, N.PAgg):
-            return node.capacity
-        if isinstance(node, N.PMotion):
-            return node.out_capacity or cap_of(node.child)
-        if isinstance(node, N.PJoin):
-            if not node.unique_build:
-                return node.out_capacity
-            return cap_of(node.probe)
-        if isinstance(node, N.PConcat):
-            return sum(cap_of(c) for c in node.inputs)
-        kids = node.children()
-        return max((cap_of(c) for c in kids), default=1)
-
     def rec(node: N.PlanNode):
         nonlocal total
-        b = cap_of(node) * width(node)
+        b = N.capacity_of(node) * width(node)
         per_node.append((node.title(), b))
         total += b
         for c in node.children():

@@ -496,19 +496,8 @@ def _retile(shape: _TileShape, tile_rows: int) -> None:
         shape.partial_plan.capacity = min(shape.g_cap, max(cap, 1))
 
 
-def _out_cap(node: N.PlanNode) -> int:
-    if isinstance(node, (N.PScan, N.PAgg)):
-        return node.capacity
-    if isinstance(node, N.PJoin):
-        if not node.unique_build:
-            return node.out_capacity
-        return _out_cap(node.probe)
-    if isinstance(node, N.PMotion):
-        return node.out_capacity or _out_cap(node.child)
-    if isinstance(node, N.PConcat):
-        return sum(_out_cap(c) for c in node.inputs)
-    kids = node.children()
-    return max((_out_cap(c) for c in kids), default=1)
+# (the one capacity walk: plan/nodes.py)
+_out_cap = N.capacity_of
 
 
 def _acc_width(shape: _TileShape) -> int:

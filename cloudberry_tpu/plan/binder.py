@@ -2826,25 +2826,8 @@ def _plan_contains(root: N.PlanNode, target: N.PlanNode) -> bool:
     return any(_plan_contains(c, target) for c in root.children())
 
 
-def _plan_capacity(p: N.PlanNode) -> int:
-    if isinstance(p, N.PScan):
-        return p.capacity
-    if isinstance(p, (N.PAgg,)):
-        return p.capacity
-    if isinstance(p, N.PConcat):
-        return sum(_plan_capacity(c) for c in p.inputs)
-    if isinstance(p, N.PWindow):
-        return _plan_capacity(p.child)
-    if isinstance(p, N.PMotion):
-        return p.out_capacity or _plan_capacity(p.child)
-    kids = p.children()
-    if not kids:
-        return 1
-    if isinstance(p, N.PJoin):
-        if not p.unique_build:
-            return p.out_capacity
-        return _plan_capacity(p.probe)
-    return max(_plan_capacity(c) for c in kids)
+# (the one capacity walk: plan/nodes.py)
+_plan_capacity = N.capacity_of
 
 
 def _agg_capacity(child: N.PlanNode, group_keys) -> int:

@@ -23,11 +23,14 @@ DEFAULT_SEL = 0.25
 
 
 def estimate_rows(node: N.PlanNode, catalog) -> float:
-    cached = getattr(node, "_est_rows", None)
+    # kept on the node, under the slot of the statistics it came from (a
+    # view of the catalog names its own: plan/joincap.py)
+    slot = getattr(catalog, "est_slot", "_est_rows")
+    cached = getattr(node, slot, None)
     if cached is not None:
         return cached
     est = max(_estimate(node, catalog), 0.0)
-    node._est_rows = est
+    setattr(node, slot, est)
     return est
 
 

@@ -174,7 +174,9 @@ class PProject(PlanNode):
 class PJoin(PlanNode):
     """Join (nodeHashjoin analog). Two execution shapes:
     - unique_build=True: sorted-build lookup, output rides the probe's
-      capacity; build uniqueness verified at runtime (dup detection);
+      capacity, or the join's own where the planner stamped one
+      (``probe_capacity``, ``out_capacity``: plan/joincap.py); build
+      uniqueness verified at runtime (dup detection);
     - unique_build=False: many-to-many expansion (one output row per match
       pair) at ``out_capacity`` with overflow detection."""
 
@@ -191,7 +193,15 @@ class PJoin(PlanNode):
     # unmatched build rows have NULL probe columns)
     probe_match_name: Optional[str] = None
     unique_build: bool = True
-    out_capacity: int = 0  # expansion joins only
+    # rows the join emits: an expansion's pair buffer; on a lookup join
+    # (``compacts``) the matched rows are compacted to it before the
+    # payload gathers, 0 = the output rides the probe's capacity
+    out_capacity: int = 0
+    # lookup joins (``compacts``): the probe's selected rows are
+    # compacted to it before the search, 0 = the search runs at the
+    # probe's capacity. Both are stamped from estimates and checked at
+    # run time: an overflow is retried at the next capacity, never cut
+    probe_capacity: int = 0
     # semi/anti residual predicate over (probe cols + build cols) — the
     # correlated-EXISTS extra conditions (e.g. Q21's l2.l_suppkey <>
     # l1.l_suppkey); forces pair-expansion evaluation
@@ -218,8 +228,38 @@ class PJoin(PlanNode):
         join (``Lowerer.join``); otherwise the sorted-build lookup."""
         return not self.unique_build or self.residual is not None
 
+    @property
+    def compacts(self) -> bool:
+        """Whether this join's shape can run at capacities of its own: a
+        sorted-build lookup that emits matched probe rows only. Left,
+        full and anti joins keep unmatched rows, residual joins and
+        expansions size a pair buffer: those keep the probe's capacity."""
+        return not self.expands and self.kind in ("inner", "semi")
+
+    def search_rows(self, probe_cap: int) -> int:
+        """Rows the search runs at, given the probe's capacity."""
+        if self.compacts and 0 < self.probe_capacity < probe_cap:
+            return self.probe_capacity
+        return probe_cap
+
+    def out_rows(self, probe_cap: int) -> int:
+        """The join's output capacity, given its probe's: the one
+        derivation every capacity walk follows (``capacity_of``, the
+        verifier's row rule, ``Lowerer._join``). A stamped capacity at
+        or above its input's is not engaged."""
+        if not self.unique_build:
+            return self.out_capacity
+        rows = self.search_rows(probe_cap)
+        if self.compacts and 0 < self.out_capacity < rows:
+            return self.out_capacity
+        return rows
+
     def title(self):
-        return f"Join {self.kind}"
+        caps = "".join(f" [{what} {cap}]" for what, cap in
+                       (("probe", self.probe_capacity),
+                        ("out", self.out_capacity))
+                       if cap and self.compacts)
+        return f"Join {self.kind}{caps}"
 
 
 @dataclass
@@ -430,3 +470,19 @@ class PMotion(PlanNode):
 
     def title(self):
         return f"Motion {self.kind}"
+
+
+def capacity_of(node: PlanNode) -> int:
+    """The static row capacity of ``node``'s output: what its arrays are
+    lowered at. Scans, aggregates, motions and expansion joins carry
+    theirs; a lookup join follows its probe (``PJoin.out_rows``); every
+    other node its widest child."""
+    if isinstance(node, (PScan, PAgg)):
+        return node.capacity
+    if isinstance(node, PConcat):
+        return sum(capacity_of(c) for c in node.inputs)
+    if isinstance(node, PMotion):
+        return node.out_capacity or capacity_of(node.child)
+    if isinstance(node, PJoin):
+        return node.out_rows(capacity_of(node.probe))
+    return max((capacity_of(c) for c in node.children()), default=1)

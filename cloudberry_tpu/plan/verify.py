@@ -616,6 +616,12 @@ def _r_agg(v: Verifier, node: N.PAgg, kids, path) -> Props:
     if node.capacity < 1:
         v.fail("agg-capacity", path,
                f"agg capacity {node.capacity} < 1")
+    if node.group_keys and node.capacity > kids[0].rows \
+            and _compacted_below(node.child):
+        v.fail("agg-capacity", path,
+               f"agg capacity {node.capacity} > the {kids[0].rows} rows "
+               "of its compacted child: the aggregate did not follow "
+               "the join's capacity (plan/joincap.py settle)")
     csh = kids[0].dist
     key_src = {e.name for _, e in node.group_keys
                if isinstance(e, ex.ColumnRef)}
@@ -718,6 +724,7 @@ def _r_join(v: Verifier, node: N.PJoin, kids, path) -> Props:
                "expansion join (unique_build=False) with no "
                "out_capacity — the pair buffer would be empty")
     _check_join_index(v, node, path)
+    _check_join_capacities(v, node, path, pprops.rows)
     rows = _join_rows(node, bprops.rows, pprops.rows)
     if v.local:
         return Props(None, rows)
@@ -773,9 +780,43 @@ def _r_join(v: Verifier, node: N.PJoin, kids, path) -> Props:
 def _join_rows(node: N.PJoin, brows: int, prows: int) -> int:
     if node.residual is not None:
         return prows
-    if not node.unique_build:
-        return max(node.out_capacity, 1)
-    return prows
+    return max(node.out_rows(prows), 1)
+
+
+def _check_join_capacities(v: Verifier, node: N.PJoin, path: str,
+                           prows: int) -> None:
+    """A lookup join's own capacities (plan/joincap.py): only on the
+    shapes that emit matched probe rows alone, and never above the
+    capacity their rows arrive at — the lowering would not engage them
+    while every capacity walk above had sized by them."""
+    if not node.unique_build or node.residual is not None:
+        return      # out_capacity is a pair buffer there
+    if not node.compacts:
+        if node.probe_capacity or node.out_capacity:
+            v.fail("join-capacity", path,
+                   f"{node.kind} join carries a capacity of its own "
+                   f"(probe {node.probe_capacity}, out "
+                   f"{node.out_capacity}): only inner and semi lookup "
+                   "joins drop their unmatched rows")
+        return
+    if node.probe_capacity > prows:
+        v.fail("join-capacity", path,
+               f"probe_capacity {node.probe_capacity} is above the "
+               f"probe's {prows} rows")
+    rows = node.search_rows(prows)
+    if node.out_capacity > rows:
+        v.fail("join-capacity", path,
+               f"out_capacity {node.out_capacity} is above the "
+               f"{rows} rows the search runs at")
+
+
+def _compacted_below(node: N.PlanNode) -> bool:
+    """Whether ``node``'s rows come, through row-preserving nodes, from a
+    lookup join running at a capacity of its own."""
+    while isinstance(node, (N.PFilter, N.PProject, N.PSort, N.PLimit)):
+        node = node.child
+    return isinstance(node, N.PJoin) and node.compacts \
+        and bool(node.probe_capacity or node.out_capacity)
 
 
 def _check_join_index(v: Verifier, node: N.PJoin, path: str) -> None:

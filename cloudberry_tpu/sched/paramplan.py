@@ -348,7 +348,8 @@ class _Walker:
             jix = getattr(node, "_jix", None)
             return (t, node.kind, tuple(node.build_payload),
                     node.match_name, node.probe_match_name,
-                    node.unique_build, node.out_capacity, node.null_aware,
+                    node.unique_build, node.out_capacity,
+                    node.probe_capacity, node.null_aware,
                     node.pack_bits, jix.key if jix is not None else None,
                     bk, pk,
                     self._site(node, "residual", False),

@@ -795,20 +795,40 @@ class Session:
 
     def _run_with_growth(self, ckey: str, query: str, plan,
                          stmt_id: int = 0, cfg_plan=None):
-        """Execute; on a detected join-expansion overflow, grow the pair
-        buffer (re-checking admission) and retry — adaptive capacity, never
-        truncation (exec/executor.py:grow_expansion). Growth that blows the
+        """Execute; on a detected join-expansion overflow (or a lookup
+        join's compaction overflow), grow that capacity (re-checking
+        admission) and retry — adaptive capacity, never truncation
+        (exec/executor.py:grow_expansion). Growth that blows the
         per-query budget falls back to tiled execution; growth that would
         cross the ENGINE-WIDE vmem red line terminates this statement (the
-        runaway_cleaner.c decision)."""
+        runaway_cleaner.c decision).
+
+        This loop is what makes a capacity taken from an estimate safe,
+        so the lookup joins' own capacities are stamped here, on the way
+        in (plan/joincap.py), and on no plan that runs elsewhere. Their
+        overflows do not draw on the six growths of the other buffers:
+        each reports the rows that came and is grown to hold them, so a
+        join overflows each of its two capacities at most once and the
+        plan's joins bound the retries."""
         from cloudberry_tpu.exec.executor import ExecError, grow_expansion
         from cloudberry_tpu.exec.resource import ResourceError, check_admission
+        from cloudberry_tpu.plan import joincap
 
-        for _ in range(6):
+        if self.config.n_segments <= 1:
+            joincap.stamp_join_capacities(plan, self.catalog)
+            if self.config.debug.verify_plans:
+                from cloudberry_tpu.plan.verify import check_plan
+
+                check_plan(plan, self, "join-capacities")
+        growths = 0
+        while True:
             try:
                 return self._execute_and_cache(ckey, query, plan,
                                                cfg_plan)
             except ExecError as e:
+                compaction = "compaction overflow" in str(e)
+                if not compaction and growths >= 6:
+                    raise
                 with self._stmt_lock:  # drop the failed runner
                     self._stmt_cache.pop(ckey, None)
                 # allow_fallback: should the message's ordinal name no
@@ -817,6 +837,10 @@ class Session:
                 if not grow_expansion(plan, str(e), allow_fallback=True):
                     raise
                 self.growth_events += 1
+                if compaction:
+                    self.stmt_log.bump("join_compact_retries")
+                else:
+                    growths += 1
                 from cloudberry_tpu.exec.resource import RunawayError
 
                 try:
@@ -827,11 +851,11 @@ class Session:
                 except ResourceError:
                     from cloudberry_tpu.exec.tiled import plan_tiled
 
+                    joincap.drop(plan)  # a tile is its own capacity
                     texe = plan_tiled(plan, self)  # …or the plan spills
                     if texe is None:
                         raise
                     return self._run_cached_tiled(ckey, texe, cfg_plan)
-        return self._execute_and_cache(ckey, query, plan, cfg_plan)
 
     def _check_topology_race(self, cfg_plan) -> None:
         """Refuse to execute (or cache) a plan whose epoch moved under
@@ -1214,7 +1238,7 @@ class Session:
                             n.pre_compact, n.host_bucket_cap,
                             n.hier_hosts, n.host_combine))
             elif isinstance(n, N.PJoin):
-                sig.append(("join", n.out_capacity))
+                sig.append(("join", n.out_capacity, n.probe_capacity))
         return tuple(sig)
 
     def _rung_executable(self, query: str, plan, names):
@@ -1302,6 +1326,12 @@ class Session:
             if findings and self.config.debug.verify_plans:
                 raise PlanVerifyError(findings, "explain")
         else:
+            if self.config.n_segments <= 1:
+                # the capacities a send of it would run at: stamped
+                # where a statement's retry loop starts, here for display
+                from cloudberry_tpu.plan import joincap
+
+                joincap.stamp_join_capacities(result.plan, self.catalog)
             self._verify_plan(result.plan, "explain")
         return result.plan.explain()
 
@@ -1327,6 +1357,14 @@ class Session:
         result = plan_statement(stmt, self, {})
         if result.is_ddl:
             return str(result.ddl_result)
+        if self.config.n_segments <= 1:
+            # the program a send of it runs, at the lookup joins' own
+            # capacities (plan/joincap.py). This run has no retry loop:
+            # like an expansion's, a capacity's overflow is its error,
+            # which names the join and the rows that came
+            from cloudberry_tpu.plan import joincap
+
+            joincap.stamp_join_capacities(result.plan, self.catalog)
         self._verify_plan(result.plan, "explain-analyze")
         _, metrics, annotations = run_pipeline(result.plan, self, query)
         counts = {id(n): r for n, (_, _, r) in

@@ -160,11 +160,18 @@ def test_the_load_leaves_the_keys_uniqueness_in_the_manifests(deployment):
 
 
 def _q3_plan(session, cell):
+    """Q3's plan as a send of it runs: at one segment with the lookup
+    joins' own capacities, which the session stamps where the
+    statement's retry loop starts (plan/joincap.py)."""
+    from cloudberry_tpu.plan import joincap
     from cloudberry_tpu.plan.planner import plan_statement
     from cloudberry_tpu.sql.parser import parse_sql
 
-    return plan_statement(parse_sql(_text(cell, "q3")), session, {},
+    plan = plan_statement(parse_sql(_text(cell, "q3")), session, {},
                           explain_only=True).plan
+    if session.config.n_segments == 1:
+        joincap.stamp_join_capacities(plan, session.catalog)
+    return plan
 
 
 def _shape(plan) -> list:
@@ -176,6 +183,7 @@ def _shape(plan) -> list:
 
     return [(type(nd).__name__, getattr(nd, "unique_build", None),
              getattr(nd, "out_capacity", None),
+             getattr(nd, "probe_capacity", None),
              getattr(nd, "capacity", None)) for nd in _all(plan)] \
         + [re.sub(r" parts \d+/\d+", "", plan.explain())]
 
@@ -197,6 +205,9 @@ def test_a_cold_session_plans_q3_as_a_loaded_one_does(deployment, nseg):
     joins = [nd for nd in _all(plan) if isinstance(nd, N.PJoin)]
     assert len(joins) == 2
     assert all(j.unique_build and not j.expands for j in joins)
+    if nseg == 1:       # both joins at capacities of their own (ISSUE 33)
+        assert all(0 < j.out_capacity < N.capacity_of(j.probe)
+                   for j in joins)
     warm = cb.Session(_config(root, nseg))
     warm._sync_store()
     for t in cell.tables():

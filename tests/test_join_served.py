@@ -49,7 +49,12 @@ SEED, SCALE = 2147486231, 0.01
 DRAWS = {"q3": {"segment": 1, "day": 15},
          "q12": {"shipmode1": 5, "shipmode2": 3, "year": 1994},
          "q13": {"word1": 0, "word2": 1}}
-JOINS = {"q3": (2, 0), "q12": (1, 0), "q13": (0, 1)}    # lookups, expansions
+# lookups, expansions, lookups at a capacity of their own (ISSUE 33: Q3's
+# two joins match few of their probe rows, Q12's filter keeps few)
+JOINS = {"q3": (2, 0, 2), "q12": (1, 0, 1), "q13": (0, 1, 0)}
+COUNTERS = ("launch_joins_lookup", "launch_joins_expand",
+            "launch_joins_compacted", "join_compact_retries", "scan_rows",
+            "scan_capacity_rows", "launch_packed", "compiles")
 
 
 def _config(root: str):
@@ -63,29 +68,45 @@ def _text(cell, stmt: str) -> str:
 
 
 @pytest.fixture(scope="module")
-def served(tmp_path_factory):
-    """(cell, rows, the generator's arrays, {statement: (wire answer,
-    counters the send added)}), each statement sent twice over TCP."""
+def loaded(tmp_path_factory):
+    """(cell, the store's root, rows, the generator's arrays)."""
     cell = C.Cell(CELL)
     root = str(tmp_path_factory.mktemp("store"))
     rows, truth = load.load(cb.Session(_config(root)), cell.tables(),
                             _columns(cell), SCALE, SEED, 2500)
-    names = ("launch_joins_lookup", "launch_joins_expand", "scan_rows",
-             "scan_capacity_rows", "launch_packed", "compiles")
+    return cell, root, rows, truth
+
+
+def _send_twice(root: str, cell, stmts) -> dict:
+    """{statement: (the second send's wire answer, counters it added,
+    counters both sends added, the first send's answer)} from a server
+    of its own over the store, each statement sent twice over TCP."""
     out = {}
     with Server(config=_config(root)) as srv:
         log = srv.session.stmt_log
         c = Client(srv.host, srv.port, timeout=300.0)
         try:
-            for stmt in sorted(DRAWS):
-                c.sql(_text(cell, stmt))
-                before = {n: log.counter(n) for n in names}
+            for stmt in stmts:
+                start = {n: log.counter(n) for n in COUNTERS}
+                first = c.sql(_text(cell, stmt))
+                before = {n: log.counter(n) for n in COUNTERS}
                 got = c.sql(_text(cell, stmt))
-                out[stmt] = got, {n: log.counter(n) - before[n]
-                                  for n in names}
+                out[stmt] = (got,
+                             {n: log.counter(n) - before[n]
+                              for n in COUNTERS},
+                             {n: log.counter(n) - start[n]
+                              for n in COUNTERS}, first)
         finally:
             c.close()
-    return cell, rows, truth, out
+    return out
+
+
+@pytest.fixture(scope="module")
+def served(loaded):
+    """(cell, rows, the generator's arrays, what ``_send_twice`` gives
+    for every statement)."""
+    cell, root, rows, truth = loaded
+    return cell, rows, truth, _send_twice(root, cell, sorted(DRAWS))
 
 
 @pytest.mark.parametrize("stmt", sorted(DRAWS))
@@ -114,9 +135,102 @@ def test_a_repeat_send_launches_the_joins_and_compiles_nothing(served,
                                                                stmt):
     _, _, _, out = served
     added = out[stmt][1]
-    assert (added["launch_joins_lookup"],
-            added["launch_joins_expand"]) == JOINS[stmt]
+    assert (added["launch_joins_lookup"], added["launch_joins_expand"],
+            added["launch_joins_compacted"]) == JOINS[stmt]
     assert added["launch_packed"] == 1 and added["compiles"] == 0
+
+
+def test_the_pair_runs_three_joins_at_capacities_of_their_own(served):
+    """Q3 and Q12 as the cell sends them: three lookup joins a pair, each
+    at a capacity the planner stamped, and the estimates' slack holds
+    the rows that came: no send was retried."""
+    _, _, _, out = served
+    assert sum(out[s][1]["launch_joins_compacted"]
+               for s in ("q3", "q12")) == 3
+    assert all(out[s][2]["join_compact_retries"] == 0 for s in out)
+
+
+def test_an_estimate_too_small_is_retried_to_the_exact_answer(
+        loaded, monkeypatch):
+    """No slack on the estimates: Q3's customer join is stamped for the
+    729 orders the planner expects and 1,340 match. The overflow is a
+    check, the join runs again at the next capacity (once), the repeat
+    send meets the grown plan, and both answers are the reference's."""
+    from cloudberry_tpu.plan import joincap
+
+    monkeypatch.setattr(joincap, "SLACK", 1)
+    monkeypatch.setattr(joincap, "FLOOR", 64)
+    cell, root, _, truth = loaded
+    got, second, both, first = _send_twice(root, cell, ["q3"])["q3"]
+    ref = _statement(cell, "q3")[1].answer(truth, DRAWS["q3"])
+    assert compare.gap(first, ref)[0] == 0
+    assert compare.gap(got, ref)[0] == 0
+    assert both["join_compact_retries"] == 1
+    assert second["join_compact_retries"] == 0 and second["compiles"] == 0
+    assert second["launch_joins_compacted"] == 2
+
+
+@pytest.mark.parametrize("counted", [True, False])
+def test_a_capacity_climbs_its_ladder_to_the_exact_answer(
+        loaded, monkeypatch, counted):
+    """A capacity of eight rows where some three thousand orders match. The
+    overflow's check reports the rows that came and the join is grown to
+    the power of two that holds them: one retry. With the count withheld
+    it doubles, nine retries where the loop allows the other
+    buffers six together: the ladder ends the retries (at the probe's
+    capacity, by then without compaction), no budget cuts them short,
+    and the count is the generator's every time."""
+    import numpy as np
+
+    from cloudberry_tpu.plan import joincap
+
+    monkeypatch.setattr(joincap, "SHARE", 2)
+    monkeypatch.setattr(joincap, "SLACK", 0)
+    monkeypatch.setattr(joincap, "FLOOR", 1)
+    if not counted:
+        grow = joincap.grow
+        monkeypatch.setattr(
+            joincap, "grow",
+            lambda plan, join, what, rows: grow(plan, join, what, 0))
+    _, root, _, truth = loaded
+    cu, od = truth["customer"], truth["orders"]
+    building = cu["c_custkey"][cu["c_mktsegment"] == "BUILDING"]
+    want = int(np.isin(od["o_custkey"], building).sum())
+    assert want > 2048
+    s = cb.Session(_config(root))
+    q = ("select count(*) as n from orders, customer "
+         "where o_custkey = c_custkey and c_mktsegment = 'BUILDING'")
+    assert "Join inner [out 8]" in s.explain(q)   # the ladder's first
+    got = s.sql(q).to_pandas()
+    assert int(got["n"][0]) == want
+    retries = s.stmt_log.counter("join_compact_retries")
+    assert retries == (1 if counted else 9), retries
+    assert int(s.sql(q).to_pandas()["n"][0]) == want
+    assert s.stmt_log.counter("join_compact_retries") == retries
+
+
+def test_explain_analyze_runs_at_the_capacities_and_has_no_retry(
+        loaded, monkeypatch):
+    """EXPLAIN ANALYZE times the program a send runs: the rows stand
+    beside the capacities. It has no retry loop, so, as with an
+    expansion's buffer, an overflow is its error, and that names the
+    join and the rows that came."""
+    import re
+
+    from cloudberry_tpu.exec.executor import ExecError
+    from cloudberry_tpu.plan import joincap
+
+    cell, root, _, _ = loaded
+    s = cb.Session(_config(root))
+    text = s.explain_analyze(_text(cell, "q12"))
+    cap, rows = map(int, re.search(
+        r"Join inner \[probe (\d+)\]  rows=(\d+)", text).groups())
+    assert 0 < rows <= cap < 15104, text
+    monkeypatch.setattr(joincap, "SLACK", 0)
+    monkeypatch.setattr(joincap, "FLOOR", 1)
+    with pytest.raises(ExecError, match=rf"join probe compaction "
+                       rf"overflow.*\[probe 8\]\): {rows} rows"):
+        s.explain_analyze(_text(cell, "q12"))
 
 
 @pytest.mark.parametrize("stmt", sorted(DRAWS))
@@ -128,3 +242,22 @@ def test_the_launch_counts_its_scans_rows_and_their_rungs(served, stmt):
     assert added["scan_capacity_rows"] == sum(row_rung_up(rows[t])
                                               for t in tables)
     assert added["scan_capacity_rows"] > added["scan_rows"]
+
+
+def test_q3s_dates_plan_to_one_set_of_capacities(loaded):
+    """The capacities follow from estimates, the estimates from the
+    literals, and a capacity is a program to compile: on the coarse
+    ladder (``joincap.LADDER``) every day of March 1995 that Q3's DATE
+    draws (spec 2.4.3.3) plans both joins to the capacities of the
+    validation value's plan."""
+    import re
+
+    cell, root, _, _ = loaded
+    s = cb.Session(_config(root))
+    text, ref = _statement(cell, "q3")
+    seen = set()
+    for day in range(1, 32):
+        plan = s.explain(text.format(**ref.bind({"segment": 1,
+                                                 "day": day})))
+        seen.add(tuple(re.findall(r"Join inner \[out (\d+)\]", plan)))
+    assert len(seen) == 1 and len(next(iter(seen))) == 2, seen

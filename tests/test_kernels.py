@@ -245,6 +245,93 @@ def test_compact_overflow_reported():
     assert int(n) == 4  # caller sees 4 > capacity 2 and errors
 
 
+def _mask(n: int, k: int, seed: int) -> np.ndarray:
+    """``k`` of ``n`` rows selected, at random places."""
+    sel = np.zeros(n, dtype=bool)
+    sel[np.random.default_rng(seed).choice(n, k, replace=False)] = True
+    return sel
+
+
+@pytest.mark.parametrize("n,k,capacity", [
+    (1000, 0, 8),          # nothing selected
+    (1000, 1000, 1000),    # everything, at the input's own capacity
+    (777, 64, 64),         # exactly at capacity
+    (777, 65, 64),         # one over: reported, the first 64 kept
+    (5000, 93, 256),       # sparse
+    (130, 7, 16),          # one block of the prefix sum and a bit
+    (128, 128, 16),        # far over
+])
+@pytest.mark.parametrize("jit", [False, True])
+def test_compact_sparse_is_compact(jit, n, k, capacity):
+    """The sparse form (a prefix sum and ``capacity`` searches) against
+    ``K.compact`` (a sort of the whole input) and numpy: the selected
+    rows in their order, the mask of the slots they fill, and the TRUE
+    count, which the caller checks against the capacity."""
+    sel = _mask(n, k, seed=n + k)
+    rng = np.random.default_rng(k)
+    cols = {"pos": jnp.arange(n, dtype=jnp.int64),
+            "val": jnp.asarray(rng.integers(-99, 99, n).astype(np.int32)),
+            "flag": jnp.asarray(rng.random(n) < 0.5)}
+    fn = jax.jit(K.compact_sparse, static_argnums=2) if jit \
+        else K.compact_sparse
+    out, osel, count = fn(cols, jnp.asarray(sel), capacity)
+    ref, rsel, rcount = K.compact(cols, jnp.asarray(sel), capacity)
+    assert int(count) == int(rcount) == k
+    kept = min(k, capacity)
+    np.testing.assert_array_equal(np.asarray(osel),
+                                  np.arange(capacity) < kept)
+    np.testing.assert_array_equal(np.asarray(osel), np.asarray(rsel))
+    want = np.flatnonzero(sel)[:kept]
+    np.testing.assert_array_equal(np.asarray(out["pos"])[:kept], want)
+    for name in cols:
+        assert out[name].shape == (capacity,)
+        assert out[name].dtype == cols[name].dtype
+        np.testing.assert_array_equal(np.asarray(out[name])[:kept],
+                                      np.asarray(ref[name])[:kept])
+        np.testing.assert_array_equal(np.asarray(out[name])[:kept],
+                                      np.asarray(cols[name])[want])
+
+
+@pytest.mark.parametrize("ladder, pad", [("row_rung_up", 1 / 32),
+                                         ("rung_up", 1.0)])
+def test_row_rungs_at_both_ladders(ladder, pad):
+    """A scan's ladder (64 rungs an octave) and the powers of two that
+    capacities following from an estimate sit on (plan/joincap.py):
+    never under the rows, padding under a rung's step, a rung is its own
+    rung and so is twice a rung."""
+    up = getattr(K, ladder)
+    for n in (1, 7, 64, 65, 729, 1360, 59_877, 233_128, 5_998_031):
+        r = up(n)
+        assert n <= r <= max(n * (1 + pad) + 1, 8)
+        assert up(r) == r
+        assert up(2 * r) == 2 * r
+    assert K.row_rung_up(5_998_031) == 6_029_312
+    assert K.rung_up(233_128) == 262_144
+
+
+def test_join_probe_sorted_is_the_lookups_search():
+    """``join_lookup_sorted`` is ``join_probe_sorted`` and one gather of
+    the build's order: a caller that compacts the matched rows in
+    between takes the build rows for those alone."""
+    rng = np.random.default_rng(3)
+    bk = rng.permutation(500)[:200].astype(np.int64)
+    pk = rng.integers(0, 500, 900).astype(np.int64)
+    bsel = jnp.asarray(rng.random(200) < 0.9)
+    psel = jnp.asarray(rng.random(900) < 0.7)
+    order, kb_sorted, ranges = K.build_sort([jnp.asarray(bk)], bsel)
+    row, matched, _ = K.join_lookup_sorted(order, kb_sorted, ranges,
+                                           [jnp.asarray(pk)], psel)
+    pos, matched2 = K.join_probe_sorted(kb_sorted, ranges,
+                                        [jnp.asarray(pk)], psel)
+    np.testing.assert_array_equal(np.asarray(matched), np.asarray(matched2))
+    np.testing.assert_array_equal(np.asarray(row),
+                                  np.asarray(order)[np.asarray(pos)])
+    hit = np.asarray(matched)
+    np.testing.assert_array_equal(bk[np.asarray(row)[hit]], pk[hit])
+    assert hit.sum() == (np.isin(pk, bk[np.asarray(bsel)])
+                         & np.asarray(psel)).sum()
+
+
 def test_group_overflow_reported():
     cols = {"k": jnp.asarray(np.arange(8, dtype=np.int64))}
     sel = jnp.ones(8, dtype=bool)

@@ -20,6 +20,7 @@ import pytest
 
 import cloudberry_tpu as cb
 from cloudberry_tpu.config import Config
+from cloudberry_tpu.plan import nodes as N
 from cloudberry_tpu.plan.mutate import MUTATIONS
 from cloudberry_tpu.plan.planner import plan_statement
 from cloudberry_tpu.plan.verify import (PlanVerifyError, Verifier,
@@ -301,3 +302,86 @@ def test_verifier_local_mode_skips_distribution(single_session):
     sc.num_rows = sc.capacity + 1
     findings = verify_plan(plan, single_session)
     assert any(f.rule == "scan-rows" for f in findings)
+
+
+# ---------------------------------------- join capacities (plan/joincap.py)
+
+
+def _nodes(plan, cls):
+    from cloudberry_tpu.exec.executor import all_nodes
+
+    return [n for n in all_nodes(plan) if isinstance(n, cls)]
+
+
+def _stamped(session, sql):
+    """The plan with the capacities a send of it runs at: the session
+    stamps them where the statement's retry loop starts."""
+    from cloudberry_tpu.plan import joincap
+
+    plan = _plan(session, sql)
+    joincap.stamp_join_capacities(plan, session.catalog)
+    return plan
+
+
+def _over_the_probe(plan):
+    j = next(n for n in _nodes(plan, N.PJoin) if n.probe_capacity)
+    j.probe_capacity = N.capacity_of(j.probe) + 64
+
+
+def _over_the_search(plan):
+    j = next(n for n in _nodes(plan, N.PJoin) if n.probe_capacity)
+    j.out_capacity = j.probe_capacity + 64
+
+
+def _on_an_outer_join(plan):
+    j = next(n for n in _nodes(plan, N.PJoin) if n.probe_capacity)
+    j.kind = "left"
+
+
+def _agg_above_its_child(plan):
+    a = next(n for n in _nodes(plan, N.PAgg)
+             if n.group_keys and isinstance(n.child, N.PJoin)
+             and n.child.out_capacity)
+    a.capacity = N.capacity_of(a.child) + 64
+
+
+@pytest.mark.parametrize("qname, corrupt, rule", [
+    ("q12", _over_the_probe, "join-capacity"),
+    ("q12", _over_the_search, "join-capacity"),
+    ("q12", _on_an_outer_join, "join-capacity"),
+    ("q11", _agg_above_its_child, "agg-capacity"),
+], ids=["probe_above_its_input", "out_above_the_search",
+        "outer_join_compacted", "agg_above_compacted_child"])
+def test_a_join_capacity_out_of_place_is_a_finding(single_session, qname,
+                                                   corrupt, rule):
+    """A lookup join's own capacities (ISSUE 33) hold only below the
+    capacity their rows arrive at and on joins that drop unmatched rows;
+    the aggregate above follows its compacted child. The planner's own
+    stamps verify clean first."""
+    plan = _stamped(single_session, QUERIES[qname])
+    assert any(n.probe_capacity or (n.compacts and n.out_capacity)
+               for n in _nodes(plan, N.PJoin)), plan.explain()
+    assert verify_plan(plan, single_session) == []
+    corrupt(plan)
+    findings = verify_plan(plan, single_session)
+    assert rule in {f.rule for f in findings}, \
+        [f.render() for f in findings]
+
+
+def test_every_capacity_walk_follows_the_joins_own(single_session):
+    """One derivation: the binder's, the memory estimate's, the tiled
+    planner's and the verifier's row rule give a compacted join the
+    capacity its lowering emits."""
+    from cloudberry_tpu.exec import tiled
+    from cloudberry_tpu.plan import binder
+
+    plan = _stamped(single_session, QUERIES["q12"])
+    j = next(n for n in _nodes(plan, N.PJoin) if n.probe_capacity)
+    want = j.probe_capacity
+    assert 0 < want < N.capacity_of(j.probe)
+    assert N.capacity_of(j) == binder._plan_capacity(j) \
+        == tiled._out_cap(j) == want
+    from cloudberry_tpu.plan.verify import _join_rows
+
+    assert _join_rows(j, N.capacity_of(j.build),
+                      N.capacity_of(j.probe)) == want

@@ -283,10 +283,27 @@ def _dense_agg_q1(sh):
                             sh((_N,), jnp.bool_))
 
 
+def _sparse_compaction_q12(sh):
+    """K.compact_sparse at the width SF1's Q12 runs it (ISSUE 33): the
+    lines its filter keeps, of lineitem's 6,029,312 rows of capacity, to
+    the 262,144 the planner stamps: the mask packed 32 rows a word,
+    262,144 searches through the words' running popcount, five popcount
+    halvings inside the word found, two columns gathered."""
+    from cloudberry_tpu.exec import kernels as K
+
+    n, cap = 6_029_312, 262_144
+
+    def f(key, mode, sel):
+        return K.compact_sparse({"k": key, "m": mode}, sel, cap)
+    return jax.jit(f).lower(sh((n,), jnp.int64), sh((n,), jnp.int32),
+                            sh((n,), jnp.bool_))
+
+
 @pytest.mark.parametrize("lower", [_mid_cardinality_agg,
-                                   _small_build_probe_join, _dense_agg_q1],
+                                   _small_build_probe_join, _dense_agg_q1,
+                                   _sparse_compaction_q12],
                          ids=["group_aggregate_2e16", "join_lookup_1024",
-                              "dense_agg_q1"])
+                              "dense_agg_q1", "compact_sparse_q12"])
 def test_xla_formulations_of_the_kernel_shapes_compile_for_tpu(one_chip,
                                                                lower):
     def sh(shape, dtype):
