@@ -336,6 +336,14 @@ class Table:
 _VERSION_COUNTER = itertools.count(1)
 
 
+def unversioned(table) -> bool:
+    """Whether ``table``'s rows change outside this engine's versioning
+    (external, foreign and directory tables, table functions): nothing
+    cached or proven from one read of it holds for the next."""
+    return any(getattr(table, a, None) for a in (
+        "external", "foreign", "directory", "_tablefunc"))
+
+
 class Catalog:
     def __init__(self):
         self.tables: dict[str, Table] = {}

@@ -63,6 +63,9 @@ class Executable:
     # (join_shapes), fixed when the plan is lowered and counted on every
     # launch
     join_shapes: tuple = (0, 0, 0)
+    # (rows in, capacity) summed over the plan's grouped aggregates
+    # (agg_shapes), fixed and counted likewise
+    agg_shapes: tuple = (0, 0)
 
 
 def execute(plan: N.PlanNode, session) -> ColumnBatch:
@@ -133,7 +136,8 @@ def compile_plan(plan: N.PlanNode, session,
     return Executable(plan, jit(run), table_names, store_scans, raw,
                       packed_fn=jit(
                           lambda tables: pack_answer(*run(tables))),
-                      join_shapes=join_shapes(plan))
+                      join_shapes=join_shapes(plan),
+                      agg_shapes=agg_shapes(plan))
 
 
 # A string predicate is decided once over its column's dictionary, on the
@@ -501,6 +505,7 @@ def run_executable(exe: Executable, tables: dict, log=None) -> ColumnBatch:
         log.bump("launch_packed")
         log.bump("launch_d2h_reads", len(host))
         count_join_shapes(log, exe.join_shapes)
+        count_agg_shapes(log, exe.agg_shapes)
         count_scan_rows(log, tables)
     with OT.stage("fetch", "launch_seconds", host=True) as st:
         cols, sel, checks = unpack_answer(packed.layout, host)
@@ -703,6 +708,27 @@ def count_join_shapes(log, shapes: tuple) -> None:
                         "launch_joins_compacted"), shapes):
         if n:
             log.bump(name, n)
+
+
+def agg_shapes(plan: N.PlanNode) -> tuple:
+    """(the capacity their rows arrive at, their own capacity) summed
+    over ``plan``'s grouped aggregates: how far below its input the
+    planner could hold an aggregate's output, and with it whatever runs
+    above (plan/joincap.py, the proven ceilings)."""
+    aggs = _dedupe_nodes(nd for nd in all_nodes(plan)
+                         if isinstance(nd, N.PAgg) and nd.group_keys)
+    return (sum(N.capacity_of(nd.child) for nd in aggs),
+            sum(nd.capacity for nd in aggs))
+
+
+def count_agg_shapes(log, shapes: tuple) -> None:
+    """One launch's grouped aggregates on the engine's counters:
+    ``launch_agg_rows_in`` and ``launch_agg_capacity`` (a program that
+    groups nothing bumps neither)."""
+    rows_in, capacity = shapes
+    if log is not None and rows_in:
+        log.bump("launch_agg_rows_in", rows_in)
+        log.bump("launch_agg_capacity", capacity)
 
 
 def count_scan_rows(log, tables: dict) -> None:

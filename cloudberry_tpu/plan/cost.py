@@ -148,34 +148,48 @@ def _expr_ndv(plan: N.PlanNode, e: ex.Expr, catalog) -> Optional[int]:
 def _col_source(plan: N.PlanNode, name: str):
     """Trace an output column back to (table, physical column) through
     renames; None when it crosses a computation."""
+    src = col_origin(plan, name)
+    return src and (src[0].table_name, src[1])
+
+
+def col_origin(plan: N.PlanNode, name: str, unions: bool = True,
+               route: tuple = ()):
+    """(scan, physical column, route) of an output column that is its
+    scan's column carried up through renames, else None. ``route``: the
+    side taken at each join on the way down, so two columns with one
+    route are columns of the same rows, and two references to one
+    shared subplan (a CTE's ``PShare``: every reference holds the same
+    scan object) have two. ``unions``: a union's column is traced
+    through its first input, which serves an estimate; a proof passes
+    False (the column holds every input's values)."""
     if isinstance(plan, N.PScan):
         for phys, out in plan.column_map.items():
             if out == name:
-                return (plan.table_name, phys)
+                return plan, phys, route
         return None
     if isinstance(plan, (N.PFilter, N.PRuntimeFilter, N.PSort, N.PLimit,
                          N.PMotion, N.PWindow, N.PShare)):
-        return _col_source(plan.children()[0], name)
+        return col_origin(plan.children()[0], name, unions, route)
     if isinstance(plan, N.PProject):
         for out, e in plan.exprs:
             if out == name:
                 if isinstance(e, ex.ColumnRef):
-                    return _col_source(plan.child, e.name)
+                    return col_origin(plan.child, e.name, unions, route)
                 return None
         return None
     if isinstance(plan, N.PJoin):
         if name in set(plan.probe.names):
-            return _col_source(plan.probe, name)
+            return col_origin(plan.probe, name, unions, route + ("probe",))
         if name in set(plan.build.names):
-            return _col_source(plan.build, name)
+            return col_origin(plan.build, name, unions, route + ("build",))
         return None
     if isinstance(plan, N.PAgg):
         for out, e in plan.group_keys:
             if out == name and isinstance(e, ex.ColumnRef):
-                return _col_source(plan.child, e.name)
+                return col_origin(plan.child, e.name, unions, route)
         return None
-    if isinstance(plan, N.PConcat) and plan.inputs:
-        return _col_source(plan.inputs[0], name)
+    if isinstance(plan, N.PConcat) and plan.inputs and unions:
+        return col_origin(plan.inputs[0], name, unions, route)
     return None
 
 

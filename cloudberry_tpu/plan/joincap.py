@@ -41,6 +41,33 @@ grouped aggregate's capacity to its child's (more groups than rows
 cannot be), remembering the capacity the binder gave it, so that a
 grown join's aggregate grows back with it.
 
+A grouped aggregate is also held to a ceiling that is a PROOF
+(``_group_ceiling``), never an estimate: an aggregation overflow is a
+"cannot happen" check that no retry answers. Where every group key is a
+plain column reference that traces back to a scan (``cost.col_origin``)
+and can hold no NULL (a key an outer join can null-extend carries a
+null mask, and has no ceiling), the groups number at most the product,
+over the REFERENCES the keys come from, of the lesser of
+
+- the scan's capacity: a column's values all come from its rows, and a
+  scan emits no more rows than its capacity, by construction;
+- the product, over the scan's integer keys, of their span (max - min
+  + 1) by the zone maps of the micro-partitions the scan reads, or, for
+  a table in RAM, by the range ``Table.set_data`` computes on every
+  change of its data (a plan is keyed on the table's version).
+
+The capacity is the rung (``kernels.row_rung_up``) above the least of
+these and never above the child's. Q13's ``GROUP BY c_custkey`` over an
+expansion of 1,671,168 pairs emits at customer's 151,552 rows, and its
+second aggregate and sort run there; Q18's ``GROUP BY l_orderkey`` at
+the key's span. An NDV, a histogram or a row estimate is never a
+ceiling; a key that is an expression, a union's column or a table
+whose rows change outside the engine's versions keeps the child's
+capacity. A reference is a route from the aggregate down to a scan (the
+side taken at each join), not a scan object: a CTE named twice is one
+scan under two ``PShare`` nodes, and its two references multiply as two
+scans of one table do.
+
 One segment only: a distributed plan's capacities are per segment and
 its aggregates and Motions are sized by ``plan/distribute.py`` (ROADMAP
 S11).
@@ -48,8 +75,11 @@ S11).
 
 from __future__ import annotations
 
+import math
+
 from cloudberry_tpu.plan import expr as ex
 from cloudberry_tpu.plan import nodes as N
+from cloudberry_tpu.types import DType
 
 SHARE = 16      # stamp where estimate × SHARE < the input's capacity
 SLACK = 4       # ... at the power of two above SLACK × the estimate
@@ -152,31 +182,116 @@ def _matches_estimate(join: N.PJoin, catalog) -> float:
     return est
 
 
+_INTEGERS = (DType.INT32, DType.INT64, DType.DATE)
+
+
+def _key_span(scan: N.PScan, phys: str, catalog):
+    """How many values the scan's integer column can hold (max - min + 1),
+    from a range that cannot be narrower than the rows the scan reads,
+    else None. A store scan: the zone maps of the micro-partitions it is
+    bound to, each written with its file. A table in RAM: the range
+    ``Table.set_data`` recomputes with every change of the data."""
+    from cloudberry_tpu.catalog.catalog import unversioned
+
+    parts = getattr(scan, "_store_parts", None)
+    if parts is not None:
+        ranges = [p.get("stats", {}).get(phys) for p in parts
+                  if p["num_rows"]]
+        if not ranges or any(r is None for r in ranges):
+            return None
+        lo, hi = min(r[0] for r in ranges), max(r[1] for r in ranges)
+    else:
+        try:
+            t = catalog.table(scan.table_name)
+        except KeyError:
+            return None
+        if t.cold or unversioned(t):
+            return None
+        mm = t.stats.min_max.get(phys)
+        if mm is None:
+            return None
+        lo, hi = mm
+    # (a RAM table's range is kept in float64: exact under 2**53)
+    if not (-2 ** 53 < lo <= hi < 2 ** 53):
+        return None
+    return int(hi) - int(lo) + 1
+
+
+def _group_ceiling(agg: N.PAgg, catalog):
+    """The most groups ``agg`` can emit, by proof (module docstring), or
+    None where a key is no plain non-null column of a scan."""
+    from cloudberry_tpu.plan.cost import col_origin
+
+    keys_of: dict[tuple, list] = {}     # a reference's route -> its keys
+    for _, e in agg.group_keys:
+        if not isinstance(e, ex.ColumnRef):
+            return None
+        try:
+            if agg.child.field(e.name).null_mask is not None:
+                return None
+        except KeyError:
+            return None
+        src = col_origin(agg.child, e.name, unions=False)
+        if src is None:
+            return None
+        scan, phys, route = src
+        keys_of.setdefault(route, []).append((scan, phys, e.dtype.base))
+    ceiling = 1
+    for keys in keys_of.values():
+        scan = keys[0][0]
+        most = max(scan.capacity, 1)
+        spans = [_key_span(scan, phys, catalog) if base in _INTEGERS
+                 else None for _, phys, base in keys]
+        # (a key without a proven span: its scan's rows alone bound it)
+        if None not in spans:
+            most = min(most, math.prod(spans))
+        ceiling *= most
+    return ceiling
+
+
+def _settle(node: N.PAgg) -> None:
+    from cloudberry_tpu.exec.kernels import row_rung_up
+
+    bound = getattr(node, "_cap_bound", None)
+    if bound is None:
+        bound = node._cap_bound = node.capacity
+    ceiling = getattr(node, "_cap_ceiling", None)
+    if ceiling is not None:
+        bound = min(bound, row_rung_up(ceiling))
+    node.capacity = min(bound, max(N.capacity_of(node.child), 1))
+
+
 def settle(plan: N.PlanNode) -> None:
-    """Hold every grouped aggregate to its child's capacity (children
-    first, so a chain of them settles in one pass). ``_cap_bound`` keeps
-    what the binder gave it: a join grown after an overflow takes its
-    aggregate back up with it."""
+    """Hold every grouped aggregate to its child's capacity and to its
+    proven ceiling (children first, so a chain of them settles in one
+    pass). ``_cap_bound`` keeps what the binder gave it: a join grown
+    after an overflow takes its aggregate back up with it."""
     for node in _post_order(plan):
         if isinstance(node, N.PAgg) and node.group_keys:
-            bound = getattr(node, "_cap_bound", None)
-            if bound is None:
-                bound = node._cap_bound = node.capacity
-            node.capacity = min(bound, max(N.capacity_of(node.child), 1))
+            _settle(node)
 
 
 def stamp_join_capacities(plan: N.PlanNode, catalog) -> None:
     """Stamp every lookup join of ``plan`` whose probe or matches the
-    estimates call sparse (module docstring), then ``settle``."""
+    estimates call sparse, and every grouped aggregate's proven ceiling
+    (module docstring), then ``settle``."""
     from cloudberry_tpu.plan.cost import estimate_rows
 
     stats = _Persisted(catalog)
     for node in _post_order(plan):
+        if isinstance(node, N.PAgg) and node.group_keys:
+            # (settled at once: a join above sizes by this capacity)
+            node._cap_ceiling = _group_ceiling(node, catalog)
+            _settle(node)
         if not (isinstance(node, N.PJoin) and node.compacts):
             continue
         rows_in = N.capacity_of(node.probe)
-        node.probe_capacity = _capacity_for(
-            estimate_rows(node.probe, stats), rows_in)
+        # a probe that is a join this pass has sized arrives with that
+        # size (key containment), not the planner's min(build, probe)
+        reach = getattr(node.probe, "_est_matches", None)
+        if reach is None:
+            reach = estimate_rows(node.probe, stats)
+        node.probe_capacity = _capacity_for(reach, rows_in)
         node.out_capacity = _capacity_for(
             _matches_estimate(node, stats),
             node.probe_capacity or rows_in)

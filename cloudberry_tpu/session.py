@@ -899,14 +899,9 @@ class Session:
         # foreign (FDW) and directory tables count: their rows change
         # outside this engine's versioning, so cached programs would
         # replay stale reads
-        def _t(n):
-            return self.catalog.tables.get(n)
+        from cloudberry_tpu.catalog.catalog import unversioned
 
-        return any(getattr(_t(n), "external", None)
-                   or getattr(_t(n), "foreign", None)
-                   or getattr(_t(n), "directory", None)
-                   or getattr(_t(n), "_tablefunc", None)
-                   for n in names)
+        return any(unversioned(self.catalog.tables.get(n)) for n in names)
 
     def _sync_store(self) -> None:
         """Pick up OTHER sessions' committed changes at statement start

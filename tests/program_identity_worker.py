@@ -3,7 +3,8 @@ statement through ``Session.sql`` (the generic-plan path the server takes)
 and prints, for every program the statement launched, a hash of its
 lowered module text and the ``jax.result_info`` keys it carries.
 
-    python tests/program_identity_worker.py <n_segments> <q15v|q3|q1|q6> \
+    python tests/program_identity_worker.py <n_segments> \
+        <q15v|q3|q12|q13|q1|q6> \
         [--no-origin] [--tiled] [--store [--warm]]
 
 ``--no-origin`` binds every literal without its origin (``expr.Literal``'s
@@ -56,8 +57,8 @@ Q15V = ("select l_suppkey as supplier_no, "
         "from lineitem where l_shipdate >= date '1996-01-01' "
         "and l_shipdate < date '1996-04-01' "
         "group by l_suppkey order by supplier_no")
-STATEMENTS = {"q15v": Q15V, "q3": QUERIES["q3"], "q1": QUERIES["q1"],
-              "q6": QUERIES["q6"]}
+STATEMENTS = {"q15v": Q15V, **{q: QUERIES[q] for q in
+                               ("q3", "q12", "q13", "q1", "q6")}}
 
 if "--no-origin" in sys.argv[3:]:
     from cloudberry_tpu.plan import binder

@@ -5,7 +5,10 @@ a COLD store, one TCP client. The benchmark's plain references
 (``benchmarks/reference/q3.py``, ``q12.py``, ``q13.py``) hold the
 answers: rows and order exactly. Q3 and Q12 are sorted-build lookups,
 Q13's outer join on ``o_custkey`` an expansion; the launch counts the
-rows its scans hold and the rows they are padded to."""
+rows its scans hold and the rows they are padded to. ISSUE 34: Q18
+(``reference/q18.py``; three lookups, one of them a semi-join whose probe
+is sized by key containment) compiles ONE program cold, and Q13's
+aggregates emit under the capacity their rows arrive at."""
 
 from __future__ import annotations
 
@@ -29,9 +32,12 @@ from benchmarks.harness import cell as C, compare, load       # noqa: E402
 CELL = "tpch-sf1-joins.join-streams"
 
 
+EXTRA = ("q13", "q18")      # served here, in no mix of this cell's
+
+
 def _statement(cell, stmt: str) -> tuple:
-    """(text, reference) of a statement: the cell's own, or, for Q13 (which
-    left the cell's mix: the cold run's room), the benchmark's files."""
+    """(text, reference) of a statement: the cell's own, or, for Q13 and
+    Q18 (another configuration's), the benchmark's files."""
     if stmt in cell.statements:
         return cell.statements[stmt]
     with open(os.path.join(C.BENCH, "statements", stmt + ".sql"),
@@ -40,21 +46,31 @@ def _statement(cell, stmt: str) -> tuple:
 
 
 def _columns(cell) -> dict:
-    """What the loader keeps for the references: the cell's and Q13's."""
+    """What the loader keeps for the references: the cell's, Q13's and
+    Q18's."""
     keep = cell.reference_columns()
-    for table, cols in C.load_module("reference", "q13").COLUMNS.items():
-        keep.setdefault(table, set()).update(cols)
+    for stmt in EXTRA:
+        for table, cols in C.load_module("reference", stmt).COLUMNS.items():
+            keep.setdefault(table, set()).update(cols)
     return keep
 SEED, SCALE = 2147486231, 0.01
 DRAWS = {"q3": {"segment": 1, "day": 15},
          "q12": {"shipmode1": 5, "shipmode2": 3, "year": 1994},
-         "q13": {"word1": 0, "word2": 1}}
+         "q13": {"word1": 0, "word2": 1},
+         # (300, the validation value, keeps one order in a hundred
+         # thousand: none at this scale)
+         "q18": {"quantity": 250}}
 # lookups, expansions, lookups at a capacity of their own (ISSUE 33: Q3's
-# two joins match few of their probe rows, Q12's filter keeps few)
-JOINS = {"q3": (2, 0, 2), "q12": (1, 0, 1), "q13": (0, 1, 0)}
+# two joins match few of their probe rows, Q12's filter keeps few; Q18's
+# semi-join keeps the lines of a few large orders)
+JOINS = {"q3": (2, 0, 2), "q12": (1, 0, 1), "q13": (0, 1, 0),
+         "q18": (3, 0, 1)}
+# the tables a statement scans, one entry a scan
+SCANS = {"q18": ("customer", "orders", "lineitem", "lineitem")}
 COUNTERS = ("launch_joins_lookup", "launch_joins_expand",
             "launch_joins_compacted", "join_compact_retries", "scan_rows",
-            "scan_capacity_rows", "launch_packed", "compiles")
+            "scan_capacity_rows", "launch_packed", "compiles",
+            "launch_agg_rows_in", "launch_agg_capacity")
 
 
 def _config(root: str):
@@ -113,7 +129,7 @@ def served(loaded):
 def test_served_answer_equals_the_plain_reference(served, stmt):
     cell, _, truth, out = served
     ref = _statement(cell, stmt)[1].answer(truth, DRAWS[stmt])
-    assert len(ref["rows"]) >= (10 if stmt == "q3" else 2)
+    assert len(ref["rows"]) >= (10 if stmt in ("q3", "q18") else 2)
     wrong, ulps = compare.gap(out[stmt][0], ref)
     assert wrong == 0, (out[stmt][0]["rows"][:3], ref["rows"][:3])
     assert max(ulps.values(), default=0.0) <= \
@@ -148,6 +164,29 @@ def test_the_pair_runs_three_joins_at_capacities_of_their_own(served):
     assert sum(out[s][1]["launch_joins_compacted"]
                for s in ("q3", "q12")) == 3
     assert all(out[s][2]["join_compact_retries"] == 0 for s in out)
+
+
+def test_q18_compiles_one_program_cold(served):
+    """Q18's semi-join probes with lineitem JOIN orders JOIN customer,
+    which every line survives: sized by the planner's min(build, probe)
+    it overflowed a stamped ``[probe n]`` on every cold backend, which
+    then compiled a second program (ISSUE 34). Sized as a build is, by
+    key containment, the first send compiles one and retries nothing."""
+    _, _, _, out = served
+    both = out["q18"][2]
+    assert both["join_compact_retries"] == 0 and both["compiles"] == 1
+    assert both["launch_packed"] == 2
+
+
+def test_q13s_aggregates_emit_under_the_expansions_capacity(served):
+    """One expansion a launch, and both grouped aggregates at customer's
+    rows (``plan/joincap.py``, the proven ceilings): the first one's
+    rows arrive at the pair buffer's capacity, many times that."""
+    _, rows, _, out = served
+    added = out["q13"][1]
+    assert added["launch_joins_expand"] == 1
+    assert added["launch_agg_capacity"] == 2 * row_rung_up(rows["customer"])
+    assert added["launch_agg_rows_in"] > 4 * added["launch_agg_capacity"]
 
 
 def test_an_estimate_too_small_is_retried_to_the_exact_answer(
@@ -237,7 +276,7 @@ def test_explain_analyze_runs_at_the_capacities_and_has_no_retry(
 def test_the_launch_counts_its_scans_rows_and_their_rungs(served, stmt):
     cell, rows, _, out = served
     added = out[stmt][1]
-    tables = _statement(cell, stmt)[1].TABLES
+    tables = SCANS.get(stmt) or _statement(cell, stmt)[1].TABLES
     assert added["scan_rows"] == sum(rows[t] for t in tables)
     assert added["scan_capacity_rows"] == sum(row_rung_up(rows[t])
                                               for t in tables)

@@ -484,6 +484,33 @@ def test_join_expand_total_exact_past_2_16():
     np.testing.assert_array_equal(counts, np.full(np_, nb))
 
 
+@pytest.mark.parametrize("cap", [64, 890, 4096])
+def test_join_expand_slots_equal_numpy_under_and_over_the_capacity(cap):
+    """An expansion's pair buffer against a numpy expansion (ISSUE 34:
+    Q13's join is the first the benchmark runs): every slot under the
+    capacity names the probe row and the build row numpy gives it,
+    whether the pairs fit (4096), nearly fit (890 for 899) or overflow
+    many times over (64); the total stays the exact count, which is
+    what the overflow check and the retry's size read."""
+    rng = np.random.default_rng(34)
+    bk = rng.integers(0, 40, 300).astype(np.int64)
+    pk = rng.integers(0, 60, 200).astype(np.int64)
+    psel = rng.random(200) < 0.8
+    pi, bi, osel, matched, total = K.join_expand(
+        [jnp.asarray(bk)], jnp.ones(300, dtype=bool), [jnp.asarray(pk)],
+        jnp.asarray(psel), cap)
+    order = np.argsort(bk, kind="stable")
+    want = [(i, int(b)) for i in range(200) if psel[i]
+            for b in order[bk[order] == pk[i]]]
+    assert int(total) == len(want) == 899
+    assert int(np.asarray(osel).sum()) == min(cap, len(want))
+    got = list(zip(np.asarray(pi)[np.asarray(osel)].tolist(),
+                   np.asarray(bi)[np.asarray(osel)].tolist()))
+    assert got == want[:cap]
+    np.testing.assert_array_equal(
+        np.asarray(matched), psel & np.isin(pk, bk))
+
+
 def test_join_lookup_presorted_parity():
     """join_lookup fed a HOST-precomputed index (the join-index cache's
     numpy mirror) must be bit-identical to the in-program argsort path —

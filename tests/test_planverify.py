@@ -368,6 +368,21 @@ def test_a_join_capacity_out_of_place_is_a_finding(single_session, qname,
         [f.render() for f in findings]
 
 
+@pytest.mark.parametrize("qname", ["q13", "q18"])
+def test_an_aggregate_at_its_proven_ceiling_verifies(single_session, qname):
+    """ISSUE 34: an aggregate held under its child's capacity by a proof
+    (Q13's ``GROUP BY c_custkey`` at customer's rows, Q18's ``GROUP BY
+    l_orderkey`` at the key's span) verifies clean; one above its
+    compacted child's capacity stays PR 33's finding (the ``q11`` case
+    above, whose plan carries its ceilings too)."""
+    plan = _stamped(single_session, QUERIES[qname])
+    held = [a for a in _nodes(plan, N.PAgg)
+            if a.group_keys and a.capacity < N.capacity_of(a.child)]
+    assert held and all(a.capacity >= a._cap_ceiling for a in held), \
+        plan.explain()
+    assert verify_plan(plan, single_session) == []
+
+
 def test_every_capacity_walk_follows_the_joins_own(single_session):
     """One derivation: the binder's, the memory estimate's, the tiled
     planner's and the verifier's row rule give a compacted join the
