@@ -4,7 +4,7 @@ and prints, for every program the statement launched, a hash of its
 lowered module text and the ``jax.result_info`` keys it carries.
 
     python tests/program_identity_worker.py <n_segments> \
-        <q15v|q3|q12|q13|q1|q6> \
+        <q15v|q3|q12|q13|q18|q1|q6> \
         [--no-origin] [--tiled] [--store [--warm]]
 
 ``--no-origin`` binds every literal without its origin (``expr.Literal``'s
@@ -58,7 +58,7 @@ Q15V = ("select l_suppkey as supplier_no, "
         "and l_shipdate < date '1996-04-01' "
         "group by l_suppkey order by supplier_no")
 STATEMENTS = {"q15v": Q15V, **{q: QUERIES[q] for q in
-                               ("q3", "q12", "q13", "q1", "q6")}}
+                               ("q3", "q12", "q13", "q18", "q1", "q6")}}
 
 if "--no-origin" in sys.argv[3:]:
     from cloudberry_tpu.plan import binder

@@ -511,6 +511,108 @@ def test_join_expand_slots_equal_numpy_under_and_over_the_capacity(cap):
         np.asarray(matched), psel & np.isin(pk, bk))
 
 
+def _slot_map_case(name):
+    """(build keys, probe keys, probe selection, capacity) of one shape
+    the slot map has to get right; every build row is selected, so the
+    host-built index (a selected prefix) fits each."""
+    rng = np.random.default_rng(35)
+    bk = rng.integers(10, 30, 120).astype(np.int64)
+    pk = rng.integers(10, 30, 90).astype(np.int64)
+    psel = rng.random(90) < 0.85
+    total = int(sum((bk == k).sum() for k in pk[psel]))
+
+    def hit(n):
+        return rng.integers(10, 30, n).astype(np.int64)
+
+    def miss(n):
+        return rng.integers(40, 50, n).astype(np.int64)
+
+    if name == "capacity-is-the-total":
+        return bk, pk, psel, total
+    if name == "capacity-one-under-the-total":
+        return bk, pk, psel, total - 1
+    if name == "leading-rows-without-a-match":
+        return bk, np.concatenate([miss(40), hit(30)]), np.ones(70, bool), 400
+    if name == "trailing-rows-without-a-match":
+        return bk, np.concatenate([hit(30), miss(40)]), np.ones(70, bool), 400
+    if name == "long-inner-runs-without-a-match":
+        pk = np.concatenate([hit(3), miss(50), hit(2), miss(70), hit(4)])
+        psel = np.ones(pk.shape[0], bool)
+        psel[60:90] = False         # unselected rows inside a run
+        return bk, pk, psel, 128
+    if name == "no-probe-row-selected":
+        return bk, pk, np.zeros(90, bool), 64
+    if name == "one-probe-row-owns-every-slot":
+        pk = miss(33)
+        pk[17] = 7
+        return np.full(150, 7, np.int64), pk, np.ones(33, bool), 150
+    if name == "fan-out-past-2-16-clipped-at-the-capacity":
+        # 300 x 300 pairs: every offset past the fourth row's saturates
+        return (np.zeros(300, np.int64), np.zeros(300, np.int64),
+                np.ones(300, bool), 1000)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("presorted", [False, True],
+                         ids=["in-program-sort", "host-built-index"])
+@pytest.mark.parametrize("bits", [64, 32])
+@pytest.mark.parametrize("case", [
+    "capacity-is-the-total", "capacity-one-under-the-total",
+    "leading-rows-without-a-match", "trailing-rows-without-a-match",
+    "long-inner-runs-without-a-match", "no-probe-row-selected",
+    "one-probe-row-owns-every-slot",
+    "fan-out-past-2-16-clipped-at-the-capacity"])
+def test_join_expand_slot_map_equals_numpy(case, bits, presorted):
+    """The slot map of an expansion (ISSUE 35: one int32 word, the
+    offsets clipped to the capacity): every selected slot names the
+    probe row and the build row a numpy expansion gives it, the total
+    is the exact int64 count and ``matched`` the per-probe any-match,
+    wherever rows without pairs sit and wherever the capacity cuts."""
+    from cloudberry_tpu.exec.joinindex import _np_index
+
+    bk, pk, psel, cap = _slot_map_case(case)
+    nb = bk.shape[0]
+    bsel = jnp.ones(nb, dtype=bool)
+    if presorted:
+        jix = _np_index([bk], nb, nb, bits)
+        ranges = [(jnp.asarray(jix["lo0"]), jnp.asarray(jix["span0"]))]
+        got = K.join_expand_sorted(
+            jnp.asarray(jix["order"]), jnp.asarray(jix["skeys"]), ranges,
+            [jnp.asarray(pk)], jnp.asarray(psel), cap, bits=bits)
+    else:
+        got = K.join_expand([jnp.asarray(bk)], bsel, [jnp.asarray(pk)],
+                            jnp.asarray(psel), cap, bits=bits)
+    pi, bi, osel, matched, total = (np.asarray(a) for a in got)
+    order = np.argsort(bk, kind="stable")
+    want = [(i, int(b)) for i in range(pk.shape[0]) if psel[i]
+            for b in order[bk[order] == pk[i]]]
+    assert total.dtype == np.int64 and int(total) == len(want)
+    assert pi.dtype == bi.dtype == np.int32
+    n = min(cap, len(want))
+    np.testing.assert_array_equal(osel, np.arange(cap) < n)
+    assert list(zip(pi[:n].tolist(), bi[:n].tolist())) == want[:n]
+    # what a masked slot holds is never read, but it is gathered with:
+    assert pi.min() >= 0 and pi.max() < pk.shape[0]
+    assert bi.min() >= 0 and bi.max() < nb
+    np.testing.assert_array_equal(matched, psel & np.isin(pk, bk))
+
+
+@pytest.mark.parametrize("cap,n_build", [(1 << 31, 1024), (1024, 1 << 31)])
+def test_join_expand_refuses_positions_past_int32(cap, n_build):
+    """The slot map's positions are int32 words: a pair buffer or a
+    build of 2^31 rows is refused while the program is traced (abstract
+    shapes: nothing is allocated), never run on a wrapped position."""
+    u64 = jax.ShapeDtypeStruct((), jnp.uint64)
+    with pytest.raises(ValueError, match="expansion join"):
+        jax.eval_shape(
+            lambda order, kb, lo, span, pk, ps: K.join_expand_sorted(
+                order, kb, [(lo, span)], [pk], ps, cap),
+            jax.ShapeDtypeStruct((n_build,), jnp.int32),
+            jax.ShapeDtypeStruct((n_build,), jnp.uint64), u64, u64,
+            jax.ShapeDtypeStruct((512,), jnp.int64),
+            jax.ShapeDtypeStruct((512,), jnp.bool_))
+
+
 def test_join_lookup_presorted_parity():
     """join_lookup fed a HOST-precomputed index (the join-index cache's
     numpy mirror) must be bit-identical to the in-program argsort path —
