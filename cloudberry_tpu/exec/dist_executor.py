@@ -29,6 +29,7 @@ from cloudberry_tpu.columnar.batch import ColumnBatch
 from cloudberry_tpu.exec import executor as X
 from cloudberry_tpu.exec import kernels as K
 from cloudberry_tpu.exec.expr_compile import compile_expr
+from cloudberry_tpu.obs import programs as PG
 from cloudberry_tpu.parallel.mesh import SEG_AXIS, segment_mesh
 from cloudberry_tpu.plan import nodes as N
 from cloudberry_tpu.utils import hashing
@@ -130,16 +131,19 @@ def compile_distributed(plan: N.PlanNode, session, param_keys=None,
         # reduce checks to replicated scalars (any segment tripped) so
         # every HOST can read them — per-seg shards are not addressable
         # across processes on a multi-host mesh
-        checks = {
-            k: low.tx.psum(jnp.asarray(v).astype(jnp.int32), SEG_AXIS) > 0
-            for k, v in low.checks.items()}
+        with jax.named_scope("checks"):
+            checks = {
+                k: low.tx.psum(jnp.asarray(v).astype(jnp.int32),
+                               SEG_AXIS) > 0
+                for k, v in low.checks.items()}
         # motion stats (already pmax-reduced, replicated): the observed
         # per-destination bucket demand each redistribute actually saw —
         # the capacity-ladder promotion reads these host-side
         return out, sel[None], checks, dict(low.stats)
 
-    fn = jax.jit(_shard_map(seg_fn, mesh, (in_specs,),
-                            _out_specs_like(plan)))
+    fn = PG.jit(_shard_map(seg_fn, mesh, (in_specs,),
+                           _out_specs_like(plan)),
+                X.node_titles(plan), "distributed")
     # what one launch hands the program and puts on its motions' wires
     # follows from the shapes the program is compiled for: reckoned here,
     # once, for execute_distributed's counters
@@ -559,10 +563,7 @@ class DistLowerer(X.Lowerer):
 
     def motion(self, node: N.PMotion):
         cols, sel = self.lower_shared(node.child)
-        # a profile tells a Motion's operations (bucketing, pack, the
-        # collective, unpack) from the rest by this scope in their names
-        with jax.named_scope(f"motion:{node.kind}"):
-            return self._motion(node, cols, sel)
+        return self._motion(node, cols, sel)
 
     def _motion(self, node: N.PMotion, cols, sel):
         if node.pre_compact:

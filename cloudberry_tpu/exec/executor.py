@@ -25,6 +25,7 @@ import numpy as np
 from cloudberry_tpu.columnar.batch import ColumnBatch
 from cloudberry_tpu.exec import kernels as K
 from cloudberry_tpu.exec.expr_compile import compile_expr
+from cloudberry_tpu.obs import programs as PG
 from cloudberry_tpu.plan import expr as ex
 from cloudberry_tpu.plan import nodes as N
 from cloudberry_tpu.types import DType, Field, Schema
@@ -114,6 +115,7 @@ def compile_plan(plan: N.PlanNode, session,
     count_compile(session)
 
     dicts = stamp_dict_tables(plan)
+    titles = node_titles(plan)
 
     def run(tables):
         low = Lowerer(tables, platform=platform,
@@ -124,18 +126,22 @@ def compile_plan(plan: N.PlanNode, session,
             return out, sel, low.checks, low.node_counts
         return out, sel, low.checks
 
-    def jit(fn):
-        return _WithDictTables(jax.jit(fn), dicts) if dicts \
-            else jax.jit(fn)
+    def jit(fn, what):
+        # registered where it is built (obs/programs.py): the statement,
+        # the plan's node titles, each trace's abstract inputs
+        jitted = PG.jit(fn, titles, what)
+        return _WithDictTables(jitted, dicts) if dicts else jitted
 
     raw = run if not dicts else \
         (lambda tables: run({**tables, DICT_TABLES: dicts}))
     if instrument:
-        return Executable(plan, jit(run), table_names, store_scans,
-                          raw, instrumented=True)
-    return Executable(plan, jit(run), table_names, store_scans, raw,
+        return Executable(plan, jit(run, "instrumented"), table_names,
+                          store_scans, raw, instrumented=True)
+    return Executable(plan, jit(run, "one-shot"), table_names, store_scans,
+                      raw,
                       packed_fn=jit(
-                          lambda tables: pack_answer(*run(tables))),
+                          lambda tables: pack_answer(*run(tables)),
+                          "one-shot packed"),
                       join_shapes=join_shapes(plan),
                       agg_shapes=agg_shapes(plan))
 
@@ -435,27 +441,31 @@ def pack_answer(cols, sel, checks) -> PackedAnswer:
     with no padding."""
     import jax.lax as lax
 
-    leaves = [jnp.asarray(x) for x in (
-        *(_check_flag(bad) for bad in checks.values()), sel,
-        *cols.values())]
+    # what a program does outside any plan node carries a scope too
+    # (obs/programs.py UNNUMBERED): the check flags' reduction, the
+    # packing
+    with jax.named_scope("checks"):
+        flags = [_check_flag(bad) for bad in checks.values()]
+    leaves = [jnp.asarray(x) for x in (*flags, sel, *cols.values())]
     # 0: the byte buffer, 1: the float64 buffer, 2: beside them
     kinds = [2 if x.nbytes > _PACK_LEAF_MAX
              else int(x.dtype == jnp.float64) for x in leaves]
     bufs, place = [], {}  # place[leaf] = (buffer, offset in its items)
-    for k in (0, 1):
-        mine = sorted((i for i, kind in enumerate(kinds) if kind == k),
-                      key=lambda i: -leaves[i].dtype.itemsize)
-        parts, at = [], 0
-        for i in mine:
-            x = leaves[i]
-            if k == 0:
-                x = x.astype(jnp.uint8) if x.dtype == jnp.bool_ \
-                    else lax.bitcast_convert_type(x, jnp.uint8)
-            place[i] = (len(bufs), at)
-            parts.append(x.reshape(-1))
-            at += parts[-1].size
-        if parts:
-            bufs.append(jnp.concatenate(parts))
+    with jax.named_scope("answer"):
+        for k in (0, 1):
+            mine = sorted((i for i, kind in enumerate(kinds) if kind == k),
+                          key=lambda i: -leaves[i].dtype.itemsize)
+            parts, at = [], 0
+            for i in mine:
+                x = leaves[i]
+                if k == 0:
+                    x = x.astype(jnp.uint8) if x.dtype == jnp.bool_ \
+                        else lax.bitcast_convert_type(x, jnp.uint8)
+                place[i] = (len(bufs), at)
+                parts.append(x.reshape(-1))
+                at += parts[-1].size
+            if parts:
+                bufs.append(jnp.concatenate(parts))
     for i, kind in enumerate(kinds):
         if kind == 2:
             place[i] = (len(bufs), -1)
@@ -890,6 +900,48 @@ def scans_of(plan: N.PlanNode):
 # ------------------------------------------------------------- plan lowering
 
 
+def number_nodes(ordinals: dict, plan: N.PlanNode) -> None:
+    """Give every node under ``plan`` that has none the next ordinal, in
+    document order: the one numbering (``Lowerer.ref``)."""
+    for n in all_nodes(plan):
+        ordinals.setdefault(id(n), len(ordinals))
+
+
+def node_titles(*roots: N.PlanNode) -> dict:
+    """{ordinal: ``node.title()``} as a ``Lowerer`` numbers the nodes of
+    ``roots`` (its ``root`` first, then what it lowers beside it): what a
+    registered program (obs/programs.py) says of its plan, capacities
+    included, as EXPLAIN prints them."""
+    ordinals: dict = {}
+    for root in roots:
+        number_nodes(ordinals, root)
+    return {ordinals[id(n)]: n.title()
+            for root in roots for n in all_nodes(root)}
+
+
+def node_kind(node: N.PlanNode) -> str:
+    """What a plan node is called in the scope its operations carry
+    (``Lowerer.lower``): THE place that names a node for a profile. A
+    closed vocabulary, which obs/programs.py ``NODE_CLASSES`` sorts into
+    operator classes: scan, filter, project, join:lookup, join:expand
+    (by the shape ``Lowerer._join`` takes), agg, sort, limit,
+    motion:<kind>, window, share, rfilter, concat."""
+    if isinstance(node, N.PJoin):
+        return "join:expand" if node.expands else "join:lookup"
+    if isinstance(node, N.PMotion):
+        return f"motion:{node.kind}"
+    kind = _NODE_KINDS.get(type(node))
+    if kind is None:
+        raise ExecError(f"cannot execute node {type(node).__name__}")
+    return kind
+
+
+_NODE_KINDS = {N.PScan: "scan", N.PFilter: "filter", N.PProject: "project",
+               N.PAgg: "agg", N.PSort: "sort", N.PLimit: "limit",
+               N.PWindow: "window", N.PShare: "share",
+               N.PRuntimeFilter: "rfilter", N.PConcat: "concat"}
+
+
 class Lowerer:
     """Traces a plan into jax ops. Subclassed by the distributed executor,
     which overrides scan (per-segment inputs) and motion (collectives).
@@ -947,8 +999,7 @@ class Lowerer:
         self.dense_strategy = "segment" if platform == "cpu" else "reduce"
 
     def _number(self, plan: N.PlanNode) -> None:
-        for n in all_nodes(plan):
-            self._ordinals.setdefault(id(n), len(self._ordinals))
+        number_nodes(self._ordinals, plan)
 
     def ref(self, node: N.PlanNode) -> int:
         """The node's ordinal: its name in check and stats keys. A node
@@ -969,10 +1020,16 @@ class Lowerer:
             return hit
         if not self._ordinals:
             self._number(node)
-        cols, sel = self.scan_tile(node) if node is self.stream \
-            else self.lower_node(node)
-        if self.count_rows:
-            self.record_rows(node, jnp.sum(sel.astype(jnp.int64)))
+        # every operation the node emits carries the node's scope,
+        # ``n<ordinal>:<kind>``, in its name (trace-time metadata, which
+        # obs/programs.py reads back from the compiled module); children
+        # lower inside it, so the innermost ``n<ordinal>:`` of a name is
+        # the node the operation belongs to
+        with jax.named_scope(f"n{self.ref(node)}:{node_kind(node)}"):
+            cols, sel = self.scan_tile(node) if node is self.stream \
+                else self.lower_node(node)
+            if self.count_rows:
+                self.record_rows(node, jnp.sum(sel.astype(jnp.int64)))
         return cols, sel
 
     def record_rows(self, node: N.PlanNode, n) -> None:
@@ -1176,12 +1233,7 @@ class Lowerer:
         # subtree — it must trace once
         bcols, bsel = self.lower_shared(node.build)
         pcols, psel = self.lower(node.probe)
-        # a profile tells a join's own operations (key packing, the
-        # search or the expansion, the payload gathers) from its inputs'
-        # and the rest by this scope in their names, by the shape taken
-        with jax.named_scope(
-                "join:expand" if node.expands else "join:lookup"):
-            return self._join(node, bcols, bsel, pcols, psel)
+        return self._join(node, bcols, bsel, pcols, psel)
 
     def _compact_rows(self, node: N.PJoin, what: str, cols, sel,
                       capacity: int):

@@ -165,6 +165,12 @@ class StatementLog:
                 "_t0": time.monotonic()}
         return sid
 
+    def sql_of(self, sid: int) -> str:
+        """The text of an active statement ("" once it finished)."""
+        with self._lock:
+            entry = self._active.get(sid)
+        return entry["sql"] if entry is not None else ""
+
     # ------------------------------------------------ statement lifecycle
     # The active registry doubles as the cancellation directory (the
     # pg_stat_activity + pg_cancel_backend pair): a session attaches its

@@ -43,6 +43,7 @@ from cloudberry_tpu.exec import kernels as K
 from cloudberry_tpu.exec import scanpipe as SP
 from cloudberry_tpu.exec import tilepipe as TP
 from cloudberry_tpu.exec.resource import estimate_plan_memory
+from cloudberry_tpu.obs import programs as PG
 from cloudberry_tpu.plan import expr as ex
 from cloudberry_tpu.plan import nodes as N
 from cloudberry_tpu.utils.faultinject import fault_point
@@ -971,27 +972,30 @@ class TiledExecutable(AdaptiveTiledMixin):
             pcols, psel = low.lower(shape.partial_plan)
             checks = dict(low.checks)
             acc_cols, acc_sel = acc
-            if group_names:
-                key_cols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
-                            for n in group_names}
+            # the tile's partial folded into the carry: no plan node's
+            # (obs/programs.py UNNUMBERED)
+            with jax.named_scope("tile:merge"):
+                if group_names:
+                    key_cols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
+                                for n in group_names}
+                    agg_vals = {s.out_name: jnp.concatenate(
+                        [acc_cols[s.out_name], pcols[s.out_name]])
+                        for s in specs}
+                    sel = jnp.concatenate([acc_sel, psel])
+                    # the one-shot executor's grouped aggregation: tiled
+                    # and one-shot results cannot diverge
+                    ok, oa, osel, n_groups = K.group_aggregate(
+                        key_cols, agg_vals, specs, sel, g_cap)
+                    checks["tile merge overflow: more groups than capacity "
+                           f"{g_cap}; raise the aggregation capacity"] = \
+                        n_groups > g_cap
+                    return ({**ok, **oa}, osel), checks
                 agg_vals = {s.out_name: jnp.concatenate(
                     [acc_cols[s.out_name], pcols[s.out_name]])
                     for s in specs}
                 sel = jnp.concatenate([acc_sel, psel])
-                # the one-shot executor's grouped aggregation: tiled
-                # and one-shot results cannot diverge
-                ok, oa, osel, n_groups = K.group_aggregate(
-                    key_cols, agg_vals, specs, sel, g_cap)
-                checks["tile merge overflow: more groups than capacity "
-                       f"{g_cap}; raise the aggregation capacity"] = \
-                    n_groups > g_cap
-                return ({**ok, **oa}, osel), checks
-            agg_vals = {s.out_name: jnp.concatenate(
-                [acc_cols[s.out_name], pcols[s.out_name]])
-                for s in specs}
-            sel = jnp.concatenate([acc_sel, psel])
-            out = K.global_aggregate(agg_vals, specs, sel)
-            return (out, jnp.ones((1,), dtype=jnp.bool_)), checks
+                out = K.global_aggregate(agg_vals, specs, sel)
+                return (out, jnp.ones((1,), dtype=jnp.bool_)), checks
 
         def finalize_fn(acc):
             acc_cols, acc_sel = acc
@@ -1005,10 +1009,12 @@ class TiledExecutable(AdaptiveTiledMixin):
         # a statement-level program set built: the engine's compile
         # counter moves here as it does in compile_plan
         X.count_compile(self.session)
-        self._compiled = (jax.jit(prelude_fn),
-                          jax.jit(step_fn, donate_argnums=TP.step_donation(
-                              self._platform)),
-                          jax.jit(finalize_fn))
+        titles = X.node_titles(shape.partial_plan, shape.root)
+        self._compiled = (
+            PG.jit(prelude_fn, titles, "tiled prelude"),
+            PG.jit(step_fn, titles, "tiled step",
+                   donate_argnums=TP.step_donation(self._platform)),
+            PG.jit(finalize_fn, titles, "tiled finalize"))
         return self._compiled
 
     def _init_acc(self):
@@ -1194,9 +1200,10 @@ class TopNTiledExecutable(TiledExecutable):
             pcols, psel = low.lower(shape.partial_plan)
             checks = dict(low.checks)
             acc_cols, acc_sel = acc
-            ccols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
-                     for n in names}
-            csel = jnp.concatenate([acc_sel, psel])
+            with jax.named_scope("tile:merge"):
+                ccols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
+                         for n in names}
+                csel = jnp.concatenate([acc_sel, psel])
             low2 = X.Lowerer({}, platform=plat, root=shape.partial_plan,
                              replace={id(mleaf): (ccols, csel)})
             scols, ssel = low2.lower(msort)
@@ -1215,10 +1222,15 @@ class TopNTiledExecutable(TiledExecutable):
         # a statement-level program set built: the engine's compile
         # counter moves here as it does in compile_plan
         X.count_compile(self.session)
-        self._compiled = (jax.jit(prelude_fn),
-                          jax.jit(step_fn, donate_argnums=TP.step_donation(
-                              self._platform)),
-                          jax.jit(finalize_fn))
+        self._compiled = (
+            PG.jit(prelude_fn, X.node_titles(shape.partial_plan),
+                   "tiled prelude"),
+            PG.jit(step_fn, X.node_titles(shape.partial_plan, msort),
+                   "tiled top-N step",
+                   donate_argnums=TP.step_donation(self._platform)),
+            PG.jit(finalize_fn,
+                   X.node_titles(shape.partial_plan, shape.root),
+                   "tiled finalize"))
         return self._compiled
 
 
@@ -1294,7 +1306,9 @@ class SortTiledExecutable(TiledExecutable):
         # a statement-level program set built: the engine's compile
         # counter moves here as it does in compile_plan
         X.count_compile(self.session)
-        self._compiled = (jax.jit(prelude_fn), jax.jit(step_fn))
+        titles = X.node_titles(shape.partial_plan)
+        self._compiled = (PG.jit(prelude_fn, titles, "tiled prelude"),
+                          PG.jit(step_fn, titles, "tiled sort step"))
         return self._compiled
 
     def _stream_sorted(self):
@@ -1427,7 +1441,9 @@ class WindowTiledExecutable(SortTiledExecutable):
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, osel, low.checks
 
-        self._chunk_compiled = jax.jit(run_chunk)
+        self._chunk_compiled = PG.jit(
+            run_chunk, X.node_titles(shape.partial_plan, shape.root),
+            "tiled window chunk")
         return self._chunk_compiled
 
     def _run_once(self) -> ColumnBatch:

@@ -54,6 +54,7 @@ from cloudberry_tpu.exec.resource import estimate_plan_memory
 from cloudberry_tpu.exec.tiled import (_MAX_TILE, _MIN_TILE, _acc_width,
                                        _expr_dict, _merge_bytes, _out_cap,
                                        _raise_tile_checks, AdaptiveTiledMixin)
+from cloudberry_tpu.obs import programs as PG
 from cloudberry_tpu.parallel.mesh import SEG_AXIS
 from cloudberry_tpu.parallel.topology import \
     topology_token as _topology_token
@@ -485,8 +486,10 @@ def _add_seg(tree):
 
 def _reduce_checks(checks: dict) -> dict:
     """Replicated any-segment-tripped scalars — readable on every host."""
-    return {k: jax.lax.psum(jnp.asarray(v).astype(jnp.int32), SEG_AXIS) > 0
-            for k, v in checks.items()}
+    with jax.named_scope("checks"):
+        return {k: jax.lax.psum(jnp.asarray(v).astype(jnp.int32),
+                                SEG_AXIS) > 0
+                for k, v in checks.items()}
 
 
 def _motion_stats(low, motions, nseg: int):
@@ -589,8 +592,9 @@ class DistTiledExecutable(AdaptiveTiledMixin):
             outs = [_add_seg(low.lower_shared(b)) for b in shape.builds]
             return outs, _reduce_checks(low.checks)
 
-        prelude_fn = jax.jit(_shard_map(
-            prelude_seg, mesh, (res_specs,), (P(SEG_AXIS), P())))
+        prelude_fn = PG.jit(_shard_map(
+            prelude_seg, mesh, (res_specs,), (P(SEG_AXIS), P())),
+            X.node_titles(shape.partial_plan), "tiled dist prelude")
 
         step_fn = self._make_step(mesh, lowerer, res_specs)
 
@@ -603,9 +607,11 @@ class DistTiledExecutable(AdaptiveTiledMixin):
             out = {f.name: cols[f.name][None] for f in shape.root.fields}
             return out, sel[None], _reduce_checks(low.checks)
 
-        finalize_fn = jax.jit(_shard_map(
+        finalize_fn = PG.jit(_shard_map(
             finalize_seg, mesh, (P(SEG_AXIS),),
-            (P(SEG_AXIS), P(SEG_AXIS), P())))
+            (P(SEG_AXIS), P(SEG_AXIS), P())),
+            X.node_titles(shape.partial_plan, shape.root),
+            "tiled dist finalize")
 
         # a statement-level program set built: the engine's compile
         # counter moves here as it does in compile_plan
@@ -643,41 +649,45 @@ class DistTiledExecutable(AdaptiveTiledMixin):
             srows = _motion_stats(low, stat_motions, nseg)
             acc_cols, acc_sel = _strip_seg(tuple(acc))
             g_cap = shape.g_cap
-            if group_names:
-                key_cols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
-                            for n in group_names}
+            # the tile's partial folded into the carry: no plan node's
+            # (obs/programs.py UNNUMBERED)
+            with jax.named_scope("tile:merge"):
+                if group_names:
+                    key_cols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
+                                for n in group_names}
+                    agg_vals = {s.out_name: jnp.concatenate(
+                        [acc_cols[s.out_name], pcols[s.out_name]])
+                        for s in specs}
+                    sel = jnp.concatenate([acc_sel, psel])
+                    # the one-shot executor's grouped aggregation
+                    ok, oa, osel, n_groups = K.group_aggregate(
+                        key_cols, agg_vals, specs, sel, g_cap)
+                    checks["tile merge overflow: more groups than capacity "
+                           f"{g_cap}; raise the aggregation capacity"] = \
+                        n_groups > g_cap
+                    return _add_seg(({**ok, **oa}, osel)), \
+                        _reduce_checks(checks), srows
                 agg_vals = {s.out_name: jnp.concatenate(
                     [acc_cols[s.out_name], pcols[s.out_name]])
                     for s in specs}
                 sel = jnp.concatenate([acc_sel, psel])
-                # the one-shot executor's grouped aggregation
-                ok, oa, osel, n_groups = K.group_aggregate(
-                    key_cols, agg_vals, specs, sel, g_cap)
-                checks["tile merge overflow: more groups than capacity "
-                       f"{g_cap}; raise the aggregation capacity"] = \
-                    n_groups > g_cap
-                return _add_seg(({**ok, **oa}, osel)), \
+                out = K.global_aggregate(agg_vals, specs, sel)
+                return _add_seg((out, jnp.ones((1,), dtype=jnp.bool_))), \
                     _reduce_checks(checks), srows
-            agg_vals = {s.out_name: jnp.concatenate(
-                [acc_cols[s.out_name], pcols[s.out_name]])
-                for s in specs}
-            sel = jnp.concatenate([acc_sel, psel])
-            out = K.global_aggregate(agg_vals, specs, sel)
-            return _add_seg((out, jnp.ones((1,), dtype=jnp.bool_))), \
-                _reduce_checks(checks), srows
 
-        return self._jit_step(step_seg, mesh, res_specs)
+        return self._jit_step(step_seg, mesh, res_specs,
+                              X.node_titles(shape.partial_plan))
 
-    def _jit_step(self, step_seg, mesh, res_specs):
+    def _jit_step(self, step_seg, mesh, res_specs, titles):
         step_in = (res_specs, P(SEG_AXIS), P(SEG_AXIS), P(SEG_AXIS),
                    P(SEG_AXIS))
         donate = TP.step_donation(jax.default_backend())
         # third output: per-motion (required-bucket, per-destination
         # rows) telemetry pairs — psum/pmax replicated, so P() like the
         # checks; the skew sentinel consumes them host-side
-        return jax.jit(_shard_map(step_seg, mesh, step_in,
-                                  (P(SEG_AXIS), P(), P())),
-                       donate_argnums=donate)
+        return PG.jit(_shard_map(step_seg, mesh, step_in,
+                                 (P(SEG_AXIS), P(), P())),
+                      titles, "tiled dist step", donate_argnums=donate)
 
     def _refinalize(self) -> None:
         """Size the merge boundary for the accumulator: a segment's acc has
@@ -903,9 +913,10 @@ class DistTopNTiledExecutable(DistTiledExecutable):
             checks = dict(low.checks)
             srows = _motion_stats(low, stat_motions, nseg)
             acc_cols, acc_sel = _strip_seg(tuple(acc))
-            ccols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
-                     for n in names}
-            csel = jnp.concatenate([acc_sel, psel])
+            with jax.named_scope("tile:merge"):
+                ccols = {n: jnp.concatenate([acc_cols[n], pcols[n]])
+                         for n in names}
+                csel = jnp.concatenate([acc_sel, psel])
             low2 = lowerer({}, root=shape.partial_plan,
                            replace={id(mleaf): (ccols, csel)})
             scols, ssel = low2.lower(msort)
@@ -913,7 +924,8 @@ class DistTopNTiledExecutable(DistTiledExecutable):
             return _add_seg(({n: scols[n][:m] for n in names},
                              ssel[:m])), _reduce_checks(checks), srows
 
-        return self._jit_step(step_seg, mesh, res_specs)
+        return self._jit_step(step_seg, mesh, res_specs,
+                              X.node_titles(shape.partial_plan, msort))
 
 
 class DistSortTiledExecutable(DistTiledExecutable):
@@ -948,8 +960,9 @@ class DistSortTiledExecutable(DistTiledExecutable):
             outs = [_add_seg(low.lower_shared(b)) for b in shape.builds]
             return outs, _reduce_checks(low.checks)
 
-        prelude_fn = jax.jit(_shard_map(
-            prelude_seg, mesh, (res_specs,), (P(SEG_AXIS), P())))
+        prelude_fn = PG.jit(_shard_map(
+            prelude_seg, mesh, (res_specs,), (P(SEG_AXIS), P())),
+            X.node_titles(shape.partial_plan), "tiled dist prelude")
 
         sort = shape.sortnode
         kchild = sort.child
@@ -973,10 +986,11 @@ class DistSortTiledExecutable(DistTiledExecutable):
             out = {nm: X._as_column(pcols[nm], n) for nm in names}
             return _add_seg((out, psel, keys)), _reduce_checks(low.checks)
 
-        step_fn = jax.jit(_shard_map(
+        step_fn = PG.jit(_shard_map(
             step_seg, mesh,
             (res_specs, P(SEG_AXIS), P(SEG_AXIS), P(SEG_AXIS)),
-            (P(SEG_AXIS), P())))
+            (P(SEG_AXIS), P())),
+            X.node_titles(shape.partial_plan), "tiled dist sort step")
         # a statement-level program set built: the engine's compile
         # counter moves here as it does in compile_plan
         X.count_compile(self.session)
@@ -1117,7 +1131,9 @@ class DistWindowTiledExecutable(DistSortTiledExecutable):
             out = {f.name: cols[f.name] for f in shape.root.fields}
             return out, osel, low.checks
 
-        self._chunk_compiled = jax.jit(run_chunk)
+        self._chunk_compiled = PG.jit(
+            run_chunk, X.node_titles(shape.partial_plan, shape.root),
+            "tiled dist window chunk")
         return self._chunk_compiled
 
     def _run_once(self) -> ColumnBatch:

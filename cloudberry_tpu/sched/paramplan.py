@@ -679,7 +679,10 @@ class GenericPlan:
             # join indexes ride once per batch, like the tables
             axes.update({k: None for k in self.jix_keys})
             axes["$params"] = 0
-        fn = jax.jit(jax.vmap(self.exe.raw_fn, in_axes=(axes,)))
+        from cloudberry_tpu.obs import programs as PG
+
+        fn = PG.jit(jax.vmap(self.exe.raw_fn, in_axes=(axes,)),
+                    X.node_titles(self.exe.plan), f"stacked x{rung}")
         with self._rung_lock:
             self._rungs[rung] = fn
         return fn

@@ -45,8 +45,8 @@ def _int_arg(kind: str, arg, default: int) -> int:
 def describe(session, kind: str, arg=None):
     """One metadata answer. Kinds: tables | columns | stats | views |
     matviews | sequences | info | activity | sched | tenants |
-    metrics | statements | trace | progress | flight | topology |
-    ingest | compaction | summary.
+    metrics | statements | trace | programs | progress | flight |
+    topology | ingest | compaction | summary.
 
     (graftlint's ``obs-meta-verbs`` rule pins this docstring list to the
     implemented kinds BOTH ways — document new verbs here.)"""
@@ -202,6 +202,14 @@ def describe(session, kind: str, arg=None):
 
         traces = session.stmt_log.traces(_int_arg(kind, arg, 8))
         return {"traces": traces, "chrome": chrome_trace(traces)}
+    if kind == "programs":
+        # the programs this process built and still holds
+        # (obs/programs.py): statement, plan node titles by ordinal,
+        # traces, and whether the map from compiled instruction to node
+        # was built; arg bounds how many entries ship, newest first
+        from cloudberry_tpu.obs import programs
+
+        return programs.snapshot(_int_arg(kind, arg, 64))
     if kind == "activity":
         # pg_stat_activity role: running + recent statements across every
         # backend of this server (one shared StatementLog)
