@@ -60,10 +60,11 @@ class Executable:
     # the one-shot launch's program (run_executable): ``fn`` ending in
     # pack_answer, so the host gets the whole answer in one wait
     packed_fn: Callable = None  # type: ignore[assignment]
-    # (lookups, expansions, compacted lookups) among the plan's joins
+    # (lookups, expansions, compacted lookups, direct lookups) among the
+    # plan's joins
     # (join_shapes), fixed when the plan is lowered and counted on every
     # launch
-    join_shapes: tuple = (0, 0, 0)
+    join_shapes: tuple = (0, 0, 0, 0)
     # (rows in, capacity) summed over the plan's grouped aggregates
     # (agg_shapes), fixed and counted likewise
     agg_shapes: tuple = (0, 0)
@@ -694,28 +695,32 @@ def find_expansion_node(plan: N.PlanNode, message: str):
 
 
 def join_shapes(plan: N.PlanNode) -> tuple:
-    """(sorted-build lookups, pair expansions, lookups at capacities of
-    their own) among ``plan``'s joins, by the shape ``Lowerer.join``
-    takes for each (``PJoin.expands``, plan/joincap.py)."""
+    """(lookups, pair expansions, lookups at capacities of their own,
+    lookups through a direct-address table) among ``plan``'s joins, by
+    the shape ``Lowerer.join`` takes for each (``PJoin.expands``,
+    ``PJoin.direct_lookup``, plan/joincap.py)."""
     joins = _dedupe_nodes(nd for nd in all_nodes(plan)
                           if isinstance(nd, N.PJoin))
     expand = sum(nd.expands for nd in joins)
     probe_rows = [(nd, N.capacity_of(nd.probe)) for nd in joins
                   if nd.compacts]
     compacted = sum(nd.out_rows(rows) < rows for nd, rows in probe_rows)
-    return len(joins) - expand, expand, compacted
+    direct = sum(nd.direct_lookup for nd in joins)
+    return len(joins) - expand, expand, compacted, direct
 
 
 def count_join_shapes(log, shapes: tuple) -> None:
     """One launch's joins on the engine's counters: ``launch_joins_lookup``
     and ``launch_joins_expand`` say which join the planner chose in the
     programs that ran, ``launch_joins_compacted`` how many of the lookups
-    ran at a capacity of their own (a program that joins nothing bumps
-    none)."""
+    ran at a capacity of their own, ``launch_joins_direct`` how many
+    found their build rows in a direct-address table (a program that
+    joins nothing bumps none)."""
     if log is None:
         return
     for name, n in zip(("launch_joins_lookup", "launch_joins_expand",
-                        "launch_joins_compacted"), shapes):
+                        "launch_joins_compacted", "launch_joins_direct"),
+                       shapes):
         if n:
             log.bump(name, n)
 
@@ -1280,10 +1285,21 @@ class Lowerer:
             return self._join_expand(node, bcols, bsel, bselm, bkeys,
                                      pcols, psel, pselm, pkeys)
 
-        order, kb_sorted, ranges = self._join_index(node) \
-            or K.build_sort(bkeys, bselm, node.pack_bits)
-        pos, matched = K.join_probe_sorted(kb_sorted, ranges, pkeys, pselm,
-                                           bits=node.pack_bits)
+        direct = node.direct_lookup
+        if direct:
+            # a proven small key span: the build row is one gather from
+            # a direct-address table, no sort and no search
+            pos, matched, has_dup, past = K.join_lookup_direct(
+                bkeys, bselm, pkeys, pselm, node.direct_span)
+            self.checks[
+                f"join build key past its proven span {node.direct_span} "
+                f"{self.label(node)}"] = past
+        else:
+            order, kb_sorted, ranges = self._join_index(node) \
+                or K.build_sort(bkeys, bselm, node.pack_bits)
+            pos, matched = K.join_probe_sorted(kb_sorted, ranges, pkeys,
+                                               pselm, bits=node.pack_bits)
+            has_dup = K.dup_check(kb_sorted, node.pack_bits)
         if node.out_rows(psel.shape[0]) < psel.shape[0]:
             # sparse matches: the probe's columns and the positions found
             # are compacted to the matched rows, so the build rows and
@@ -1294,15 +1310,14 @@ class Lowerer:
                 node.out_capacity)
             pos = out.pop("$joinpos")
             pcols, psel = out, matched
-        idx = order[pos].astype(jnp.int32)
-        has_dup = K.dup_check(kb_sorted, node.pack_bits)
+        idx = pos if direct else order[pos].astype(jnp.int32)
         payload = K.gather_payload(
             {n: bcols[n] for n in node.build_payload}, idx, matched)
         if node.kind in ("inner", "left"):
             # semi/anti only test membership; inner/left rely on the
             # planner's uniqueness proof — verify it at runtime: the
             # lookup checks the build side itself (adjacent-equal on its
-            # sorted keys)
+            # sorted keys, or a direct table read back at its keys)
             self.checks[
                 f"join build side has duplicate keys {self.label(node)} but "
                 "the planner assumed a unique (PK) build side"] = has_dup

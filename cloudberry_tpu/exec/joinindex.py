@@ -92,7 +92,9 @@ def _np_index(cols: list[np.ndarray], n_rows: int, capacity: int,
 def annotate_join_index(plan: N.PlanNode, session) -> None:
     """Stamp every eligible PJoin with its JoinIndexSpec (``_jix``); the
     input-assembly chokepoints then feed the cached index and the join
-    lowering skips the build-side argsort."""
+    lowering skips the build-side argsort. A lookup through a
+    direct-address table (``PJoin.direct_lookup``) sorts nothing and
+    reads no index: none is shipped for it."""
     if session.config.join_filter.index_cache <= 0:
         return
     from cloudberry_tpu.exec import executor as X
@@ -100,7 +102,8 @@ def annotate_join_index(plan: N.PlanNode, session) -> None:
     nseg = session.config.n_segments
     direct = getattr(plan, "_direct_segment", None) is not None
     for node in X.all_nodes(plan):
-        if isinstance(node, N.PJoin) and not hasattr(node, "_jix"):
+        if isinstance(node, N.PJoin) and not hasattr(node, "_jix") \
+                and not node.direct_lookup:
             spec = _build_spec(node, session, nseg, direct)
             if spec is not None:
                 node._jix = spec

@@ -4,9 +4,14 @@ Design discipline (SURVEY.md §7.3): XLA requires static shapes, so
 - filters AND into the selection mask (no compaction);
 - group-by is sort-based: lexsort → boundary flags → segment reductions.
   Exact (no hash collisions), and sort/scan map well onto the VPU;
-- joins are "sorted-build lookup": sort the unique (PK) side, binary-search
-  probes with ``searchsorted``, gather payloads. This covers every PK–FK join
-  shape in TPC-H; a many-to-many expansion kernel is planned separately.
+- a unique-build (PK–FK) join finds each probe row's build row in one of
+  two forms, and the planner picks (``PJoin.direct_lookup``): where the
+  build keys' span is proven and no larger than the largest array the
+  join already holds (its build, or the rows that reach its search), one
+  gather from a DIRECT-ADDRESS table of one int32 a key value
+  (``join_lookup_direct``); else the "sorted-build lookup": sort the
+  build, binary-search probes with ``searchsorted``. Payloads are then
+  gathered. A many-to-many join expands pairs (``join_expand``).
 
 These replace the reference's per-tuple executor nodes: nodeAgg.c,
 nodeHash.c/nodeHashjoin.c, nodeSort.c, nodeLimit.c — pointer-chasing hash
@@ -657,6 +662,41 @@ def join_probe_sorted(
     # empty-build case (kb_sorted all sentinel) correctly match nothing.
     matched = (kb_sorted[pos_c] == kp) & probe_sel & (kp != big)
     return pos_c, matched
+
+
+def join_lookup_direct(
+    build_key: Sequence[jnp.ndarray],
+    build_sel: jnp.ndarray,
+    probe_key: Sequence[jnp.ndarray],
+    probe_sel: jnp.ndarray,
+    span: int,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The unique-build lookup through a direct-address table, no sort
+    and no search: the build keys packed with the build's own ranges lie
+    below ``span`` by the planner's proof (``PJoin.direct_span``), so a
+    table of ``span`` int32 holds each key's build row, -1 where none,
+    and a probe row's build row is one gather from it. Returns
+    (build_row int32[cap_p], matched bool[cap_p], has_dup, past_span):
+    ``has_dup`` where two selected build rows share a key (the table
+    holds one of them, whichever write the device kept: read back, the
+    other differs), ``past_span`` where a selected build key packs to
+    ``span`` or above (the proof is broken: the answer is not to be
+    used)."""
+    ranges = key_ranges(list(build_key), build_sel)
+    kb = pack_with_ranges(list(build_key), ranges)
+    kp = pack_with_ranges(list(probe_key), ranges)
+    top = jnp.uint64(span)
+    inside = build_sel & (kb < top)
+    past_span = (build_sel & ~inside).any()
+    # (a row outside the table is written at ``span``: dropped)
+    slot = jnp.where(inside, kb, top).astype(jnp.int32)
+    rows = jax.lax.iota(jnp.int32, build_sel.shape[0])
+    table = jnp.full(span, -1, jnp.int32).at[slot].set(rows, mode="drop")
+    has_dup = (inside & (table[jnp.minimum(slot, span - 1)] != rows)).any()
+    # (an out-of-range probe packs to the all-ones sentinel: no entry)
+    hit = kp < top
+    row = jnp.where(hit, table[jnp.where(hit, kp, 0).astype(jnp.int32)], -1)
+    return jnp.maximum(row, 0), (row >= 0) & probe_sel, has_dup, past_span
 
 
 def join_lookup(

@@ -97,6 +97,8 @@ def estimate_plan_memory(plan: N.PlanNode) -> MemoryEstimate:
     def rec(node: N.PlanNode):
         nonlocal total
         b = N.capacity_of(node) * width(node)
+        if isinstance(node, N.PJoin) and node.direct_lookup:
+            b += 4 * node.direct_span   # its int32 direct-address table
         per_node.append((node.title(), b))
         total += b
         for c in node.children():

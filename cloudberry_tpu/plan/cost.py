@@ -196,7 +196,8 @@ def col_origin(plan: N.PlanNode, name: str, unions: bool = True,
 def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
     """Prove 32-bit packed join keys from build-side column statistics,
     and one packed word (32 or 64 bits) for a grouped aggregate's keys
-    and for a sort's.
+    and for a sort's; stamp each lookup join's proven key span
+    (``PJoin.direct_span``, plan/joincap.py).
 
     The kernels pack key tuples into one order-preserving integer using the
     BUILD side's runtime ranges (kernels.pack_with_ranges); probe values
@@ -205,6 +206,7 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
     stats-proven spans fits 32 bits (minus the sentinel), every in-range
     pack does too — and the sort/search/collective lanes halve. TPC-H keys
     stay 32-bit provable through SF100 (orderkey max 6e9·0.1 < 2^31)."""
+    from cloudberry_tpu.plan.joincap import direct_span
     from cloudberry_tpu.types import DType
 
     # value-space spans only translate to pack-space for types whose
@@ -260,6 +262,8 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
     def walk(n: N.PlanNode):
         if isinstance(n, (N.PJoin, N.PRuntimeFilter)):
             n.pack_bits = bits_of(n.build, n.build_keys)
+        if isinstance(n, N.PJoin):
+            n.direct_span = direct_span(n, catalog)
         if isinstance(n, N.PAgg) and n.group_keys:
             n.pack_bits = word_bits(n.child, [e for _, e in n.group_keys])
         if isinstance(n, N.PSort):

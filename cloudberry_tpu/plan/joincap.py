@@ -185,12 +185,14 @@ def _matches_estimate(join: N.PJoin, catalog) -> float:
 _INTEGERS = (DType.INT32, DType.INT64, DType.DATE)
 
 
-def _key_span(scan: N.PScan, phys: str, catalog):
+def _key_span(scan: N.PScan, phys: str, catalog, cold: bool = False):
     """How many values the scan's integer column can hold (max - min + 1),
     from a range that cannot be narrower than the rows the scan reads,
     else None. A store scan: the zone maps of the micro-partitions it is
     bound to, each written with its file. A table in RAM: the range
-    ``Table.set_data`` recomputes with every change of the data."""
+    ``Table.set_data`` recomputes with every change of the data; with
+    ``cold``, a table not yet read in: the range its manifest gives over
+    every partition of the version the plan is keyed on."""
     from cloudberry_tpu.catalog.catalog import unversioned
 
     parts = getattr(scan, "_store_parts", None)
@@ -205,7 +207,7 @@ def _key_span(scan: N.PScan, phys: str, catalog):
             t = catalog.table(scan.table_name)
         except KeyError:
             return None
-        if t.cold or unversioned(t):
+        if (t.cold and not cold) or unversioned(t):
             return None
         mm = t.stats.min_max.get(phys)
         if mm is None:
@@ -215,6 +217,31 @@ def _key_span(scan: N.PScan, phys: str, catalog):
     if not (-2 ** 53 < lo <= hi < 2 ** 53):
         return None
     return int(hi) - int(lo) + 1
+
+
+def direct_span(join: N.PJoin, catalog) -> int:
+    """How many values a lookup join's packed build keys can take, by
+    proof: the product of the keys' spans (``_key_span``; a cold table's
+    by its manifest, so that a backend that has not read it in yet
+    proves what one that has does), every key a plain integer column
+    that traces back to a scan. The kernel packs the keys with the
+    build's own ranges at run time, each no wider than its proven span,
+    so every packed key lies below the product. 0 where the join
+    expands or a key has no proof."""
+    from cloudberry_tpu.plan.cost import col_origin
+
+    if join.expands:
+        return 0
+    prod = 1
+    for k in join.build_keys:
+        if not isinstance(k, ex.ColumnRef) or k.dtype.base not in _INTEGERS:
+            return 0
+        src = col_origin(join.build, k.name, unions=False)
+        span = src and _key_span(src[0], src[1], catalog, cold=True)
+        if not span:
+            return 0
+        prod *= span
+    return prod
 
 
 def _group_ceiling(agg: N.PAgg, catalog):

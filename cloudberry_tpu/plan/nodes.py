@@ -218,6 +218,10 @@ class PJoin(PlanNode):
     # in-range pack fits u32 (cost.annotate_pack_bits) — TPU sorts and
     # searches run ~2× faster on 32-bit lanes
     pack_bits: int = 64
+    # a lookup's build keys pack below this many values, by proof
+    # (plan/joincap.py direct_span); 0 = no proof. ``direct_lookup``
+    # says whether the lowering engages a direct-address table for it
+    direct_span: int = 0
 
     def children(self):
         return [self.build, self.probe]
@@ -235,6 +239,20 @@ class PJoin(PlanNode):
         full and anti joins keep unmatched rows, residual joins and
         expansions size a pair buffer: those keep the probe's capacity."""
         return not self.expands and self.kind in ("inner", "semi")
+
+    @property
+    def direct_lookup(self) -> bool:
+        """Whether the lookup finds its build row with one gather from a
+        table of ``direct_span`` words (``kernels.join_lookup_direct``)
+        rather than a binary search through the sorted build keys: where
+        the span is proven and the table is no larger than the largest
+        array the join holds already, its build or the rows that reach
+        its search."""
+        if self.expands or not 0 < self.direct_span < 1 << 31:
+            return False
+        return self.direct_span <= max(
+            capacity_of(self.build),
+            self.search_rows(capacity_of(self.probe)))
 
     def search_rows(self, probe_cap: int) -> int:
         """Rows the search runs at, given the probe's capacity."""

@@ -350,7 +350,8 @@ class _Walker:
                     node.match_name, node.probe_match_name,
                     node.unique_build, node.out_capacity,
                     node.probe_capacity, node.null_aware,
-                    node.pack_bits, jix.key if jix is not None else None,
+                    node.pack_bits, node.direct_span,
+                    jix.key if jix is not None else None,
                     bk, pk,
                     self._site(node, "residual", False),
                     self._site(node, "build_key_valid", False),

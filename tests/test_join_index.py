@@ -17,6 +17,10 @@ from cloudberry_tpu.plan import nodes as N
 
 Q = ("select grp, count(*) as n, sum(p) as sp from fact, dim "
      "where grp = d group by grp order by grp")
+# dim's keys lie this far apart: a span no array of the join comes near,
+# so it keeps the sorted search the index serves (a dense key finds its
+# row in a direct-address table instead: PJoin.direct_lookup)
+STRIDE = 1000
 
 
 def _mk(nseg=1, **ov):
@@ -24,9 +28,10 @@ def _mk(nseg=1, **ov):
     s.sql("create table fact (k bigint, grp bigint, v bigint) "
           "distributed by (k)")
     s.sql("create table dim (d bigint, p bigint) distributed by (d)")
-    rows = ",".join(f"({i}, {i % 500}, {i % 7})" for i in range(2000))
+    rows = ",".join(f"({i}, {i % 500 * STRIDE}, {i % 7})"
+                    for i in range(2000))
     s.sql(f"insert into fact values {rows}")
-    rows = ",".join(f"({i}, {i * 3})" for i in range(500))
+    rows = ",".join(f"({i * STRIDE}, {i * 3})" for i in range(500))
     s.sql(f"insert into dim values {rows}")
     return s
 
@@ -56,13 +61,13 @@ def test_invalidate_on_write():
     s = _mk(1)
     s.sql(Q)
     b0 = s.stmt_log.counter("join_index_builds")
-    s.sql("insert into dim values (500, 9999)")
-    s.sql("insert into fact values (99999, 500, 1)")
+    s.sql(f"insert into dim values ({500 * STRIDE}, 9999)")
+    s.sql(f"insert into fact values (99999, {500 * STRIDE}, 1)")
     out = s.sql(Q).to_pandas()
     # the write bumped the table version → fresh index, fresh results
     assert s.stmt_log.counter("join_index_builds") > b0
     assert len(out) == 501
-    assert out[out.grp == 500].sp.tolist() == [9999]
+    assert out[out.grp == 500 * STRIDE].sp.tolist() == [9999]
 
 
 def test_dist_shard_mode_parity():

@@ -8,7 +8,11 @@ Q13's outer join on ``o_custkey`` an expansion; the launch counts the
 rows its scans hold and the rows they are padded to. ISSUE 34: Q18
 (``reference/q18.py``; three lookups, one of them a semi-join whose probe
 is sized by key containment) compiles ONE program cold, and Q13's
-aggregates emit under the capacity their rows arrive at."""
+aggregates emit under the capacity their rows arrive at. ISSUE 37: a
+lookup whose build keys have a proven small span finds its build row in
+a direct-address table (``PJoin.direct_lookup``): every join kind equals
+the sorted search row for row, a broken proof is an error, and a span
+past the rule keeps the search."""
 
 from __future__ import annotations
 
@@ -62,13 +66,15 @@ DRAWS = {"q3": {"segment": 1, "day": 15},
          "q18": {"quantity": 250}}
 # lookups, expansions, lookups at a capacity of their own (ISSUE 33: Q3's
 # two joins match few of their probe rows, Q12's filter keeps few; Q18's
-# semi-join keeps the lines of a few large orders)
-JOINS = {"q3": (2, 0, 2), "q12": (1, 0, 1), "q13": (0, 1, 0),
-         "q18": (3, 0, 1)}
+# semi-join keeps the lines of a few large orders), lookups through a
+# direct-address table (ISSUE 37: the generator's keys are dense)
+JOINS = {"q3": (2, 0, 2, 2), "q12": (1, 0, 1, 1), "q13": (0, 1, 0, 0),
+         "q18": (3, 0, 1, 3)}
 # the tables a statement scans, one entry a scan
 SCANS = {"q18": ("customer", "orders", "lineitem", "lineitem")}
 COUNTERS = ("launch_joins_lookup", "launch_joins_expand",
-            "launch_joins_compacted", "join_compact_retries", "scan_rows",
+            "launch_joins_compacted", "launch_joins_direct",
+            "join_compact_retries", "scan_rows",
             "scan_capacity_rows", "launch_packed", "compiles",
             "launch_agg_rows_in", "launch_agg_capacity")
 
@@ -152,7 +158,8 @@ def test_a_repeat_send_launches_the_joins_and_compiles_nothing(served,
     _, _, _, out = served
     added = out[stmt][1]
     assert (added["launch_joins_lookup"], added["launch_joins_expand"],
-            added["launch_joins_compacted"]) == JOINS[stmt]
+            added["launch_joins_compacted"],
+            added["launch_joins_direct"]) == JOINS[stmt]
     assert added["launch_packed"] == 1 and added["compiles"] == 0
 
 
@@ -300,3 +307,151 @@ def test_q3s_dates_plan_to_one_set_of_capacities(loaded):
                                                  "day": day})))
         seen.add(tuple(re.findall(r"Join inner \[out (\d+)\]", plan)))
     assert len(seen) == 1 and len(next(iter(seen))) == 2, seen
+
+
+# ---------------------------------------------- direct-address lookups
+
+
+def _lookup_session(stride: int = 1):
+    """A session at one segment over tables in RAM: ``b`` keyed by 300
+    dense keys 100..399 (times ``stride``), ``b2`` by the pairs of 100
+    keys and 3 sub-keys, ``bn`` by every other key of ``b`` and one
+    NULL, ``p`` 1,000 probe rows whose keys run past both ends of
+    ``b``'s and hit both, some NULL."""
+    import numpy as np
+
+    s = cb.Session(Config(n_segments=1))
+    for t, cols in (("b", "k bigint, v bigint"),
+                    ("b2", "k bigint, k2 bigint, v bigint"),
+                    ("bn", "k bigint, v bigint"),
+                    ("p", "k bigint, k2 bigint, w bigint")):
+        s.sql(f"create table {t} ({cols}) distributed by (v)"
+              if t != "p" else f"create table {t} ({cols}) "
+              "distributed by (w)")
+    k = np.arange(100, 400, dtype=np.int64)
+    s.catalog.table("b").set_data({"k": k * stride, "v": k * 2})
+    s.catalog.table("b2").set_data({"k": 100 + np.arange(300) // 3,
+                                    "k2": np.arange(300) % 3, "v": k})
+    kn = k[::2].copy()
+    s.catalog.table("bn").set_data({"k": kn * stride, "v": kn},
+                                   validity={"k": kn != 110})
+    rng = np.random.default_rng(37)
+    pk = rng.integers(50, 450, 1000)
+    pk[:2] = (100, 399)
+    s.catalog.table("p").set_data(
+        {"k": pk * stride, "k2": rng.integers(0, 3, 1000),
+         "w": np.arange(1000)}, validity={"k": np.arange(1000) % 17 != 5})
+    return s
+
+
+LOOKUPS = {
+    "inner": "select p.w, b.v from p join b on p.k = b.k",
+    "left": "select p.w, b.v from p left join b on p.k = b.k",
+    "semi": "select w from p where k in (select k from bn)",
+    "anti": "select w from p where not exists "
+            "(select 1 from bn where bn.k = p.k)",
+    "null_aware_anti": "select w from p where k not in (select k from bn)",
+    "not_in": "select w from p where k not in (select k from b)",
+    "two_keys": "select p.w, b2.v from p join b2 "
+                "on p.k = b2.k and p.k2 = b2.k2",
+    "empty_build": "select p.w, b.v from p join b on p.k = b.k "
+                   "where b.v < 0",
+}
+
+
+def _rows(s, sql) -> list:
+    return sorted(map(tuple, s.sql(sql).to_pandas().astype(
+        object).where(lambda f: f.notna(), None).values.tolist()),
+        key=repr)
+
+
+@pytest.mark.parametrize("kind", sorted(LOOKUPS))
+def test_a_direct_lookup_equals_the_sorted_search(kind, monkeypatch):
+    """Each join kind through a direct-address table and through the
+    sorted search (``direct_lookup`` held off, as a span past the rule
+    would): the same rows. NULL keys on either side match nothing, a
+    NULL in a NOT IN's subquery empties it, probes outside the build's
+    keys and at both ends of its span find what the search finds."""
+    from cloudberry_tpu.plan import nodes as N
+
+    direct = _lookup_session()
+    got = _rows(direct, LOOKUPS[kind])
+    assert direct.stmt_log.counter("launch_joins_direct") >= 1
+    monkeypatch.setattr(N.PJoin, "direct_lookup",
+                        property(lambda self: False))
+    search = _lookup_session()
+    assert got == _rows(search, LOOKUPS[kind])
+    assert search.stmt_log.counter("launch_joins_direct") == 0
+    if kind in ("null_aware_anti", "empty_build"):
+        assert got == []
+    else:
+        assert len(got) > 50
+
+
+def _direct_plan(s, sql):
+    from cloudberry_tpu.exec.executor import all_nodes
+    from cloudberry_tpu.plan import nodes as N
+    from cloudberry_tpu.plan.planner import plan_statement
+    from cloudberry_tpu.sql.parser import parse_sql
+
+    plan = plan_statement(parse_sql(sql), s, {}).plan
+    joins = [n for n in all_nodes(plan) if isinstance(n, N.PJoin)]
+    return plan, joins
+
+
+@pytest.mark.parametrize("fault", ["duplicate_keys", "key_past_the_span"])
+def test_a_direct_table_that_cannot_hold_the_build_is_an_error(fault):
+    """Planned while ``b.k`` was unique over 100..399, run after the data
+    changed in place: a repeated key trips the lookup's duplicate check
+    (the table read back at the keys), a key past the proven span the
+    guard. Neither returns an answer."""
+    import numpy as np
+
+    from cloudberry_tpu.exec import executor as X
+
+    s = _lookup_session()
+    plan, joins = _direct_plan(s, LOOKUPS["inner"])
+    assert [j.direct_lookup for j in joins] == [True]
+    assert joins[0].direct_span == 300
+    t = s.catalog.table("b")
+    data = {c: np.asarray(v).copy() for c, v in t.data.items()}
+    data["k"][1] = data["k"][0] if fault == "duplicate_keys" else 400
+    t.set_data(data, t.dicts)
+    want = X.DuplicateBuildKeyError if fault == "duplicate_keys" \
+        else X.ExecError
+    match = "duplicate keys" if fault == "duplicate_keys" \
+        else "past its proven span 300"
+    with pytest.raises(want, match=match):
+        X.execute(plan, s)
+
+
+@pytest.mark.parametrize("stride", [1, 1000])
+def test_a_span_past_the_rule_keeps_the_search(stride):
+    """Keys 1,000 apart span 299,001 values for 300 build rows and 1,000
+    probe rows: the table would outgrow every array of the join, so the
+    sorted search stays (its ``while``), ``launch_joins_direct`` stays 0
+    and the answer is the same. Dense keys lower with no loop at all, and
+    the plan's memory estimate holds their table."""
+    import jax
+
+    from cloudberry_tpu.exec import executor as X
+    from cloudberry_tpu.exec.resource import estimate_plan_memory
+
+    s = _lookup_session(stride)
+    sql = "select count(*) as n, sum(b.v) as sv from p join b on p.k = b.k"
+    plan, joins = _direct_plan(s, sql)
+    assert [j.direct_lookup for j in joins] == [stride == 1]
+    # admission counts the table's int32 words where it is built
+    held = estimate_plan_memory(plan).peak_bytes
+    span, joins[0].direct_span = joins[0].direct_span, 0
+    assert held - estimate_plan_memory(plan).peak_bytes == \
+        (4 * span if stride == 1 else 0)
+    joins[0].direct_span = span
+    exe = X.compile_plan(plan, s)
+    text = jax.jit(exe.raw_fn).lower(X.prepare_inputs(exe, s)).as_text()
+    assert ("while" in text) == (stride != 1)
+    got = s.sql(sql).to_pandas()
+    assert s.stmt_log.counter("launch_joins_direct") == (stride == 1)
+    ref = _lookup_session(1).sql(sql).to_pandas()
+    assert got.values.tolist() == ref.values.tolist()
+    assert int(got["n"][0]) > 500

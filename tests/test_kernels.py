@@ -178,48 +178,101 @@ def test_global_aggregate():
     assert float(out["mx"][0]) == 3.0
 
 
+def _lookup_case(name: str):
+    """(build key columns, build selection, probe key columns, probe
+    selection, a proven span of the build's packed keys) of one unique
+    build: the shapes a lookup join meets. A NULL key is an unselected
+    row (``Lowerer._join`` masks it out of either side)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    if name == "pk_fk":
+        bk = [np.array([10, 20, 30, 40, 0, 0, 0, 0], dtype=np.int64)]
+        pk = [np.array([20, 20, 99, 40, 10, 30, 30, 7] + [0] * 8,
+                       dtype=np.int64)]
+        return bk, _sel(4, 8), pk, _sel(8, 16), 31
+    if name == "two_keys":
+        bk = [np.array([1, 1, 2, 2], dtype=np.int64),
+              np.array([1, 2, 1, 2], dtype=np.int64)]
+        pk = [np.array([1, 2, 2, 3], dtype=np.int64),
+              np.array([2, 1, 9, 1], dtype=np.int64)]
+        return bk, jnp.ones(4, dtype=bool), pk, jnp.ones(4, dtype=bool), 4
+    if name == "empty_build":
+        k = [np.array([1, 2, 3, 4], dtype=np.int64)]
+        return k, jnp.zeros(4, dtype=bool), k, jnp.ones(4, dtype=bool), 4
+    bk = [rng.permutation(1000)[:300].astype(np.int64) + 5000]
+    bsel = rng.random(300) < 0.9
+    pk = [rng.integers(4900, 6100, 2000).astype(np.int64)]
+    psel = rng.random(2000) < 0.8
+    span = int(bk[0].max() - bk[0].min() + 1)
+    if name == "null_keys":
+        # NULL keys hold 0 and leave the selection on both sides
+        bk[0][:40], bsel[:40] = 0, False
+        pk[0][:100], psel[:100] = 0, False
+        span = int(bk[0][40:].max() - bk[0][40:].min() + 1)
+    elif name == "span_ends":
+        # the proven span's two ends are keys, and probes hit both
+        lo, hi = bk[0].min(), bk[0].max()
+        bsel[bk[0] == lo] = bsel[bk[0] == hi] = True
+        pk[0][:4], psel[:4] = [lo, hi, lo - 1, hi + 1], True
+    elif name == "selection_narrower_than_proof":
+        # the build's rows at run time span less than the proof
+        bsel &= (bk[0] > 5300) & (bk[0] < 5700)
+        two = rng.integers(0, 40, 300).astype(np.int64)
+        return [bk[0], two], jnp.asarray(bsel), \
+            [pk[0], rng.integers(0, 40, 2000).astype(np.int64)], \
+            jnp.asarray(psel), span * 40
+    return bk, jnp.asarray(bsel), pk, jnp.asarray(psel), span
+
+
+LOOKUP_CASES = ["pk_fk", "two_keys", "empty_build", "out_of_range",
+                "null_keys", "span_ends", "selection_narrower_than_proof"]
+
+
 @pytest.mark.parametrize("jit", [False, True])
-def test_join_lookup_pk_fk(jit):
-    cap_b, cap_p = 8, 16
-    bkey = np.array([10, 20, 30, 40, 0, 0, 0, 0], dtype=np.int64)
-    bsel = _sel(4, cap_b)
-    pkey = np.array([20, 20, 99, 40, 10, 30, 30, 7] + [0] * 8, dtype=np.int64)
-    psel = _sel(8, cap_p)
-
-    fn = K.join_lookup
+@pytest.mark.parametrize("case", LOOKUP_CASES)
+def test_join_lookup_forms_find_the_build_row_of_every_key(case, jit):
+    """The sorted search (``join_lookup``) and the direct-address table
+    (``join_lookup_direct``) against numpy, and against each other row
+    for row: the same matches, the same build row for each."""
+    bk, bsel, pk, psel, span = _lookup_case(case)
+    bk = [jnp.asarray(k) for k in bk]
+    pk = [jnp.asarray(k) for k in pk]
+    search, direct = K.join_lookup, \
+        lambda *a: K.join_lookup_direct(*a, span)
     if jit:
-        fn = jax.jit(fn)
-    idx, matched, has_dup = fn([jnp.asarray(bkey)], bsel, [jnp.asarray(pkey)], psel)
-    assert not bool(has_dup)
-    m = np.asarray(matched)
-    np.testing.assert_array_equal(
-        m[:8], [True, True, False, True, True, True, True, False])
-    picked = np.asarray(idx)[m]
-    np.testing.assert_array_equal(bkey[picked], np.asarray(pkey[:8])[m[:8]])
+        search, direct = jax.jit(search), jax.jit(direct)
+    row0, m0, dup0 = search(bk, bsel, pk, psel)
+    row1, m1, dup1, past = direct(bk, bsel, pk, psel)
+    m0, m1 = np.asarray(m0), np.asarray(m1)
+    b = np.stack([np.asarray(k) for k in bk], 1)[np.asarray(bsel)]
+    p = np.stack([np.asarray(k) for k in pk], 1)
+    want = np.array([tuple(r) in set(map(tuple, b)) for r in p]) \
+        & np.asarray(psel)
+    np.testing.assert_array_equal(m0, want)
+    np.testing.assert_array_equal(m1, want)
+    np.testing.assert_array_equal(np.asarray(row1)[m1],
+                                  np.asarray(row0)[m0])
+    got = np.stack([np.asarray(k) for k in bk], 1)[np.asarray(row1)[m1]]
+    np.testing.assert_array_equal(got, p[m1])
+    assert not bool(dup0) and not bool(dup1) and not bool(past)
+    if case == "empty_build":
+        assert not m1.any()
 
 
-def test_join_lookup_multikey():
-    bk1 = np.array([1, 1, 2, 2], dtype=np.int64)
-    bk2 = np.array([1, 2, 1, 2], dtype=np.int64)
-    bsel = jnp.ones(4, dtype=bool)
-    pk1 = np.array([1, 2, 2, 3], dtype=np.int64)
-    pk2 = np.array([2, 1, 9, 1], dtype=np.int64)
-    psel = jnp.ones(4, dtype=bool)
-    idx, matched, _ = K.join_lookup(
-        [jnp.asarray(bk1), jnp.asarray(bk2)], bsel,
-        [jnp.asarray(pk1), jnp.asarray(pk2)], psel)
-    np.testing.assert_array_equal(np.asarray(matched), [True, True, False, False])
-    got = np.asarray(idx)[np.asarray(matched)]
-    np.testing.assert_array_equal(bk1[got], [1, 2])
-    np.testing.assert_array_equal(bk2[got], [2, 1])
-
-
-def test_join_empty_build():
-    bsel = jnp.zeros(4, dtype=bool)
-    psel = jnp.ones(4, dtype=bool)
-    k = jnp.asarray(np.array([1, 2, 3, 4], dtype=np.int64))
-    _, matched, _ = K.join_lookup([k], bsel, [k], psel)
-    assert not bool(np.asarray(matched).any())
+@pytest.mark.parametrize("fault", ["duplicate_keys", "key_past_the_span"])
+def test_join_lookup_direct_reports_what_its_table_cannot_hold(fault):
+    """Two selected build rows with one key: the table keeps one of
+    them and the other reads back another row (the sorted search's
+    adjacent-equal test says the same). A key that packs past the span
+    the planner proved: the guard fires."""
+    bk = jnp.asarray(np.array([7, 3, 9, 3, 5], dtype=np.int64))
+    bsel = jnp.asarray([True, True, True, fault == "duplicate_keys", True])
+    pk = [jnp.asarray(np.arange(12, dtype=np.int64))]
+    span = 7 if fault == "duplicate_keys" else 6    # keys 3..9: 7 values
+    _, _, dup, past = K.join_lookup_direct([bk], bsel, pk,
+                                           jnp.ones(12, bool), span)
+    _, _, dup0 = K.join_lookup([bk], bsel, pk, jnp.ones(12, bool))
+    assert bool(dup) == bool(dup0) == (fault == "duplicate_keys")
+    assert bool(past) == (fault == "key_past_the_span")
 
 
 def test_limit_mask():

@@ -299,11 +299,31 @@ def _sparse_compaction_q12(sh):
                             sh((n,), jnp.bool_))
 
 
+def _direct_lookup_q3(sh):
+    """K.join_lookup_direct at the width SF1's Q3 runs it (ISSUE 37):
+    the 524,288 orders its customer join keeps scattered into a table of
+    o_orderkey's 1,500,000 values, lineitem's 6,029,312 probe rows read
+    from it, one int64 payload gathered: no sort, no search."""
+    from cloudberry_tpu.exec import kernels as K
+
+    nb, n = 524_288, 6_029_312
+
+    def f(bk, bsel, pk, psel, pay):
+        idx, matched, has_dup, past = K.join_lookup_direct(
+            [bk], bsel, [pk], psel, 1_500_000)
+        return K.gather_payload({"p": pay}, idx, matched), has_dup, past
+    return jax.jit(f).lower(sh((nb,), jnp.int64), sh((nb,), jnp.bool_),
+                            sh((n,), jnp.int64), sh((n,), jnp.bool_),
+                            sh((nb,), jnp.int64))
+
+
 @pytest.mark.parametrize("lower", [_mid_cardinality_agg,
                                    _small_build_probe_join, _dense_agg_q1,
-                                   _sparse_compaction_q12],
+                                   _sparse_compaction_q12,
+                                   _direct_lookup_q3],
                          ids=["group_aggregate_2e16", "join_lookup_1024",
-                              "dense_agg_q1", "compact_sparse_q12"])
+                              "dense_agg_q1", "compact_sparse_q12",
+                              "join_lookup_direct_q3"])
 def test_xla_formulations_of_the_kernel_shapes_compile_for_tpu(one_chip,
                                                                lower):
     def sh(shape, dtype):

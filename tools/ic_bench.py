@@ -380,13 +380,18 @@ def bench_join_filter(nseg: int, rows: int, dim_rows: int, skew: float,
 
     rng = np.random.default_rng(17)
     # fact keys: skew fraction lands on ONE hot key OUTSIDE the dim
-    # domain (dim covers [0, dim_rows), fact spans 10x that), the rest
+    # domain (dim covers dim_rows keys, fact spans 10x them), the rest
     # uniform — so the filter both drops ~90% of the uniform probes AND
     # deletes the hot bucket that sized the unfiltered capacity rung:
     # semijoin reduction doubles as skew relief, the MPP classic
-    ks = rng.integers(0, dim_rows * 10, rows)
+    # (every key a multiple of ``stride``: a span no array of the join comes
+    # near, so the repeated statement keeps the sorted search that the
+    # join-index cache serves, where dense keys would find their rows in
+    # a direct-address table: PJoin.direct_lookup)
+    stride = 1000
+    ks = rng.integers(0, dim_rows * 10, rows) * stride
     hot = rng.random(rows) < skew
-    grp = np.where(hot, dim_rows * 5, ks)
+    grp = np.where(hot, dim_rows * 5 * stride, ks)
 
     def mk(enabled: bool):
         cfg = Config(n_segments=nseg).with_overrides(**{
@@ -402,7 +407,8 @@ def bench_join_filter(nseg: int, rows: int, dim_rows: int, skew: float,
         vals = ",".join(f"({i}, {int(g)}, {i % 97})"
                         for i, g in enumerate(grp))
         s.sql(f"insert into fact values {vals}")
-        vals = ",".join(f"({i}, {i * 2})" for i in range(dim_rows))
+        vals = ",".join(f"({i * stride}, {i * 2})"
+                        for i in range(dim_rows))
         s.sql(f"insert into dim values {vals}")
         return s
 
