@@ -65,9 +65,9 @@ class Executable:
     # (join_shapes), fixed when the plan is lowered and counted on every
     # launch
     join_shapes: tuple = (0, 0, 0, 0)
-    # (rows in, capacity) summed over the plan's grouped aggregates
-    # (agg_shapes), fixed and counted likewise
-    agg_shapes: tuple = (0, 0)
+    # (rows in, capacity, keys carried, sort words) summed over the
+    # plan's grouped aggregates (agg_shapes), fixed and counted likewise
+    agg_shapes: tuple = (0, 0, 0, 0)
 
 
 def execute(plan: N.PlanNode, session) -> ColumnBatch:
@@ -144,7 +144,7 @@ def compile_plan(plan: N.PlanNode, session,
                           lambda tables: pack_answer(*run(tables)),
                           "one-shot packed"),
                       join_shapes=join_shapes(plan),
-                      agg_shapes=agg_shapes(plan))
+                      agg_shapes=agg_shapes(plan, platform))
 
 
 # A string predicate is decided once over its column's dictionary, on the
@@ -725,25 +725,74 @@ def count_join_shapes(log, shapes: tuple) -> None:
             log.bump(name, n)
 
 
-def agg_shapes(plan: N.PlanNode) -> tuple:
-    """(the capacity their rows arrive at, their own capacity) summed
-    over ``plan``'s grouped aggregates: how far below its input the
-    planner could hold an aggregate's output, and with it whatever runs
-    above (plan/joincap.py, the proven ceilings)."""
+def agg_shapes(plan: N.PlanNode, platform: str) -> tuple:
+    """(the capacity their rows arrive at, their own capacity, the keys
+    they carry rather than sort, the words their grouping sorts compare)
+    summed over ``plan``'s grouped aggregates: how far below its input
+    the planner could hold an aggregate's output, and with it whatever
+    runs above (plan/joincap.py, the proven ceilings), and what the
+    compiler's sorts read (plan/fdep.py, ``sort_words``)."""
     aggs = _dedupe_nodes(nd for nd in all_nodes(plan)
                          if isinstance(nd, N.PAgg) and nd.group_keys)
     return (sum(N.capacity_of(nd.child) for nd in aggs),
-            sum(nd.capacity for nd in aggs))
+            sum(nd.capacity for nd in aggs),
+            sum(len(nd.carried) for nd in aggs),
+            sum(sort_words(nd, dense_strategy(platform)) for nd in aggs))
+
+
+def sort_words(agg: N.PAgg, strategy: str) -> int:
+    """The u32 words a grouped aggregate's sort compares (its rows'
+    positions, which every sort carries last, not counted): none on the
+    perfect-hash path, one for a 32-bit packed word, two for a 64-bit
+    one, else each sorted key's own (``kernels.sort_key_word``)."""
+    if dense_domain(agg, strategy) is not None:
+        return 0
+    if agg.pack_bits:
+        return agg.pack_bits // 32
+    return sum(1 if e.dtype.np_dtype.itemsize <= 4 else 2
+               for k, e in agg.group_keys if k not in agg.carried)
 
 
 def count_agg_shapes(log, shapes: tuple) -> None:
     """One launch's grouped aggregates on the engine's counters:
-    ``launch_agg_rows_in`` and ``launch_agg_capacity`` (a program that
-    groups nothing bumps neither)."""
-    rows_in, capacity = shapes
+    ``launch_agg_rows_in`` and ``launch_agg_capacity``,
+    ``launch_agg_keys_carried`` and ``launch_agg_sort_words`` (a program
+    that groups nothing bumps none)."""
+    rows_in, capacity, carried, words = shapes
     if log is not None and rows_in:
         log.bump("launch_agg_rows_in", rows_in)
         log.bump("launch_agg_capacity", capacity)
+        for name, n in (("launch_agg_keys_carried", carried),
+                        ("launch_agg_sort_words", words)):
+            if n:
+                log.bump(name, n)
+
+
+def dense_strategy(platform: str) -> str:
+    """How a perfect-hash aggregate lowers: scatter (segment ops) lowers
+    well on CPU; TPU serializes large scatters, so it gets unrolled
+    masked reductions instead."""
+    return "segment" if platform == "cpu" else "reduce"
+
+
+def dense_domain(node: N.PAgg, strategy: str):
+    """The sizes of a grouped aggregate's key domains where it takes the
+    perfect-hash path (``Lowerer._dense_agg``): every key a dictionary-
+    coded string, the product of their domains no larger than the
+    capacity nor the unroll's cap ('reduce' unrolls one masked reduction
+    per cell — cap it hard or XLA program size / compile time explodes;
+    'segment', a CPU scatter, scales to larger domains). Else None."""
+    sizes = []
+    for name, _ in node.group_keys:
+        f = node.field(name)
+        if f.type.base != DType.STRING or f.sdict is None \
+                or len(f.sdict) == 0:
+            return None
+        sizes.append(len(f.sdict))
+    max_cells = 4096 if strategy == "segment" else 64
+    if math.prod(sizes) > min(node.capacity, max_cells):
+        return None
+    return sizes
 
 
 def count_scan_rows(log, tables: dict) -> None:
@@ -997,11 +1046,9 @@ class Lowerer:
         self._subcache: dict[int, jnp.ndarray] = {}
         # shared-subplan (PShare) results, keyed by child object identity
         self._sharecache: dict[int, tuple] = {}
-        # scatter (segment ops) lower well on CPU; TPU serializes large
-        # scatters, so it gets unrolled masked reductions instead
         platform = platform or jax.default_backend()
         self.platform = platform
-        self.dense_strategy = "segment" if platform == "cpu" else "reduce"
+        self.dense_strategy = dense_strategy(platform)
 
     def _number(self, plan: N.PlanNode) -> None:
         number_nodes(self._ordinals, plan)
@@ -1759,7 +1806,7 @@ class Lowerer:
                     for name, e in node.group_keys}
         out_keys, out_aggs, out_sel, n_groups = K.group_aggregate(
             key_cols, agg_values, agg_specs, sel, node.capacity,
-            pack_bits=node.pack_bits)
+            pack_bits=node.pack_bits, carried=node.carried)
         self.checks[
             f"aggregation overflow: more groups than capacity "
             f"{node.capacity} {self.label(node)}"] = n_groups > node.capacity
@@ -1772,22 +1819,10 @@ class Lowerer:
         """Perfect-hash aggregation when ALL group keys are dictionary-coded
         strings with a small static domain (nodeAgg's hashed strategy with a
         compile-time-perfect hash) — skips the sort entirely."""
-        sizes = []
-        for name, e in node.group_keys:
-            f = node.field(name)
-            if f.type.base != DType.STRING or f.sdict is None \
-                    or len(f.sdict) == 0:
-                return None
-            sizes.append(len(f.sdict))
-        prod = 1
-        for s in sizes:
-            prod *= s
-        # 'reduce' unrolls one masked reduction per cell — cap the unroll
-        # hard or XLA program size / compile time explodes; 'segment' (CPU
-        # scatter) scales to larger domains
-        max_cells = 4096 if self.dense_strategy == "segment" else 64
-        if prod > min(node.capacity, max_cells):
+        sizes = dense_domain(node, self.dense_strategy)
+        if sizes is None:
             return None
+        prod = math.prod(sizes)
 
         strides = []
         acc = 1

@@ -314,10 +314,10 @@ class GroupLayout:
     """Sorted-group scaffolding of the sort-based aggregation: the sort,
     boundary detection and start compaction."""
 
-    names: list
+    names: list              # the keys sorted (not the carried ones)
     perm: jnp.ndarray        # sort permutation (selected rows first)
     s_sel: jnp.ndarray       # selection in sorted order
-    s_keys: Columns          # key columns in sorted order
+    s_keys: Columns          # sorted key columns in sorted order
     new_grp: jnp.ndarray     # group-start flags over sorted selected rows
     n_groups: jnp.ndarray
     n_sel: jnp.ndarray
@@ -328,11 +328,14 @@ class GroupLayout:
 
 
 def group_layout(key_cols: Columns, sel: jnp.ndarray,
-                 out_capacity: int, pack_bits: int = 0) -> GroupLayout:
+                 out_capacity: int, pack_bits: int = 0,
+                 carried: Sequence[str] = ()) -> GroupLayout:
     """``pack_bits`` 32 or 64: the planner's proof (PAgg.pack_bits) that
-    the keys of the selected rows pack into one word that wide; the sort
-    then compares that word."""
-    names = list(key_cols)
+    the sorted keys of the selected rows pack into one word that wide;
+    the sort then compares that word. ``carried``: keys the sorted ones
+    determine (PAgg.carried, plan/fdep.py), never sorted, each gathered
+    at its group's first row."""
+    names = [n for n in key_cols if n not in carried]
     key_list = [key_cols[n] for n in names]
     if pack_bits:
         packed = pack_keys(key_list, sel)
@@ -364,9 +367,10 @@ def group_layout(key_cols: Columns, sel: jnp.ndarray,
     ends = jnp.where(valid, ends, 0)
 
     out_keys: Columns = {}
-    for n in names:
-        out_keys[n] = jnp.where(valid, s_keys[n][starts],
-                                jnp.zeros((), dtype=s_keys[n].dtype))
+    first = perm[starts] if carried else None
+    for n in key_cols:
+        col = key_cols[n][first] if n in carried else s_keys[n][starts]
+        out_keys[n] = jnp.where(valid, col, jnp.zeros((), dtype=col.dtype))
     return GroupLayout(names, perm, s_sel, s_keys, new_grp, n_groups,
                        n_sel, starts, ends, valid, out_keys)
 
@@ -378,22 +382,24 @@ def group_aggregate(
     sel: jnp.ndarray,
     out_capacity: int,
     pack_bits: int = 0,
+    carried: Sequence[str] = (),
 ) -> tuple[Columns, Columns, jnp.ndarray, jnp.ndarray]:
     """Sort-based grouped aggregation (nodeAgg.c analog).
 
     Returns (out_key_cols, out_agg_cols, out_sel, n_groups); groups are
-    emitted in ascending key order (a free ORDER BY for the common agg→sort
-    pattern). ``n_groups`` is the TRUE group count — the executor must check
-    it against out_capacity after the run: groups beyond capacity are clipped
-    into the last slot, so n_groups > out_capacity means wrong results and is
-    an error, never silent (the capacity-flow-control discipline of
+    emitted in ascending order of the sorted keys (``carried`` keys are
+    determined by them, ``group_layout``). ``n_groups`` is the TRUE group
+    count — the executor must check it against out_capacity after the
+    run: groups beyond capacity are clipped into the last slot, so
+    n_groups > out_capacity means wrong results and is an error, never
+    silent (the capacity-flow-control discipline of
     ic_udpifc.c:3018 applied to shapes).
 
     Scatter-free segmented reduction (TPU serializes big scatters): every
     per-group aggregate is a cumulative-sum DIFFERENCE between consecutive
     group boundaries — pure sort/scan/gather, the VPU formulation.
     """
-    lay = group_layout(key_cols, sel, out_capacity, pack_bits)
+    lay = group_layout(key_cols, sel, out_capacity, pack_bits, carried)
     names, key_list = lay.names, [key_cols[n] for n in lay.names]
     perm, s_sel = lay.perm, lay.s_sel
     n_groups, n_sel = lay.n_groups, lay.n_sel

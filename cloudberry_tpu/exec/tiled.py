@@ -174,7 +174,8 @@ def _plan_by_mode(shape: "_TileShape", session):
     # per-tile partial aggregation over the spine (mode/fields mirror the
     # distributed two-stage construction, plan/distribute.py:532)
     partial = N.PAgg(shape.agg.child, shape.agg.group_keys, partial_aggs,
-                     capacity=shape.agg.capacity, mode="partial")
+                     capacity=shape.agg.capacity, mode="partial",
+                     carried=shape.agg.carried)
     partial.fields = [
         N.PlanField(n, e.dtype, _expr_dict(shape.agg.child, e))
         for n, e in shape.agg.group_keys
@@ -985,7 +986,8 @@ class TiledExecutable(AdaptiveTiledMixin):
                     # the one-shot executor's grouped aggregation: tiled
                     # and one-shot results cannot diverge
                     ok, oa, osel, n_groups = K.group_aggregate(
-                        key_cols, agg_vals, specs, sel, g_cap)
+                        key_cols, agg_vals, specs, sel, g_cap,
+                        carried=shape.agg.carried)
                     checks["tile merge overflow: more groups than capacity "
                            f"{g_cap}; raise the aggregation capacity"] = \
                         n_groups > g_cap

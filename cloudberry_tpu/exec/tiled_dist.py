@@ -236,7 +236,8 @@ def _analyze_dist(plan: N.PlanNode, session) -> Optional[_DistTileShape]:
     from cloudberry_tpu.exec.tiled import _AccLeaf
 
     partial = N.PAgg(agg.child, agg.group_keys, partial_aggs,
-                     capacity=agg.capacity, mode="partial")
+                     capacity=agg.capacity, mode="partial",
+                     carried=agg.carried)
     partial.fields = [
         N.PlanField(n, e.dtype, _expr_dict(agg.child, e))
         for n, e in agg.group_keys
@@ -661,7 +662,8 @@ class DistTiledExecutable(AdaptiveTiledMixin):
                     sel = jnp.concatenate([acc_sel, psel])
                     # the one-shot executor's grouped aggregation
                     ok, oa, osel, n_groups = K.group_aggregate(
-                        key_cols, agg_vals, specs, sel, g_cap)
+                        key_cols, agg_vals, specs, sel, g_cap,
+                        carried=shape.partial_plan.carried)
                     checks["tile merge overflow: more groups than capacity "
                            f"{g_cap}; raise the aggregation capacity"] = \
                         n_groups > g_cap

@@ -197,7 +197,9 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
     """Prove 32-bit packed join keys from build-side column statistics,
     and one packed word (32 or 64 bits) for a grouped aggregate's keys
     and for a sort's; stamp each lookup join's proven key span
-    (``PJoin.direct_span``, plan/joincap.py).
+    (``PJoin.direct_span``, plan/joincap.py) and each grouped
+    aggregate's keys that the others determine (``PAgg.carried``,
+    plan/fdep.py), which its word leaves out.
 
     The kernels pack key tuples into one order-preserving integer using the
     BUILD side's runtime ranges (kernels.pack_with_ranges); probe values
@@ -206,6 +208,7 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
     stats-proven spans fits 32 bits (minus the sentinel), every in-range
     pack does too — and the sort/search/collective lanes halve. TPC-H keys
     stay 32-bit provable through SF100 (orderkey max 6e9·0.1 < 2^31)."""
+    from cloudberry_tpu.plan.fdep import carried_keys
     from cloudberry_tpu.plan.joincap import direct_span
     from cloudberry_tpu.types import DType
 
@@ -265,7 +268,9 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
         if isinstance(n, N.PJoin):
             n.direct_span = direct_span(n, catalog)
         if isinstance(n, N.PAgg) and n.group_keys:
-            n.pack_bits = word_bits(n.child, [e for _, e in n.group_keys])
+            n.carried = carried_keys(n, catalog)
+            n.pack_bits = word_bits(n.child, [e for k, e in n.group_keys
+                                              if k not in n.carried])
         if isinstance(n, N.PSort):
             # (a string sorts by its collation rank, not by the code the
             # statistics are of)

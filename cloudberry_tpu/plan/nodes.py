@@ -295,14 +295,20 @@ class PAgg(PlanNode):
     # (cost.annotate_pack_bits), so the grouping sort compares one key and
     # not a tuple: the TPU compiler's time for a sort grows steeply with
     # the words its comparator reads (kernels.py, "sorts"). 0: not proven.
+    # Packs the sorted keys only (``carried`` are not sorted).
     pack_bits: int = 0
+    # output names of the group keys the others determine by proof
+    # (plan/fdep.py, stamped by cost.annotate_pack_bits): not sorted,
+    # each taken at its group's first row
+    carried: tuple = ()
 
     def children(self):
         return [self.child]
 
     def title(self):
         kind = "GroupAgg" if self.group_keys else "Agg"
-        return f"{kind} {self.mode} [{self.capacity}]"
+        carry = f" carry {len(self.carried)}" if self.carried else ""
+        return f"{kind} {self.mode} [{self.capacity}]{carry}"
 
 
 @dataclass

@@ -365,8 +365,9 @@ class _Walker:
                  self.esig(c.arg, False)[0],
                  self.esig(c.filter, False)[0])
                 for name, c in node.aggs)
-            return (t, node.mode, node.capacity, node.pack_bits, keys, aggs,
-                    self._fieldsig(node), self.nsig(node.child))
+            return (t, node.mode, node.capacity, node.pack_bits,
+                    node.carried, keys, aggs, self._fieldsig(node),
+                    self.nsig(node.child))
         if isinstance(node, N.PSort):
             keys = tuple((self.esig(e, False)[0], asc)
                          for e, asc in node.keys)

@@ -250,6 +250,16 @@ QUERIES = {
         "with c as (select k, g from f group by k, g) "
         "select x.g as a, y.g as b, count(*) as n from c x, c y "
         "where x.k = y.k group by x.g, y.g"),
+    # Q3's grouping: the probe's key and a column of the one build row it
+    # finds (carried, plan/fdep.py)
+    "q3_grouping": "select f.k as k, d.h as h, sum(f.v) as s from f, d "
+                   "where f.k = d.k group by f.k, d.h",
+    # Q18's: a unique key and its row's column, the carried one first,
+    # over the rows a semi-join on an aggregate keeps
+    "q18_grouping": (
+        "select d.k as k, d.h as h, count(*) as n from d, f "
+        "where d.k = f.k and d.k in (select k from f group by k "
+        "having sum(v) > 40) group by d.h, d.k"),
 }
 
 
@@ -283,6 +293,14 @@ def _expected(name: str, f: dict, d: dict) -> list:
             "g", dropna=False).size()
         return [((None if g != g else int(g)), int(n))
                 for g, n in out.items()]
+    if name == "q3_grouping":
+        out = F.merge(D, on="k").groupby(["k", "h"])["v"].sum()
+        return sorted((int(k), int(h), int(s)) for (k, h), s in out.items())
+    if name == "q18_grouping":
+        heavy = F.groupby("k")["v"].sum().loc[lambda x: x > 40].index
+        out = D[D["k"].isin(heavy)].merge(F, on="k").groupby(
+            ["k", "h"]).size()
+        return sorted((int(k), int(h), int(n)) for (k, h), n in out.items())
     if name == "cte_shared_aggregate":
         F = F[["k", "g"]].drop_duplicates()
     out = F.merge(F, on="k").groupby(["g_x", "g_y"]).size()
@@ -297,7 +315,8 @@ def _nulls_last(row: tuple) -> tuple:
 def test_no_ceiling_is_under_the_groups_that_come(shape):
     """Seeded random tables, every query shape: no "aggregation overflow",
     every answer a pandas group-by's, every stamped capacity at least
-    the groups that came and at most its child's."""
+    the groups that came and at most its child's. Q3's and Q18's
+    groupings carry the key their other key determines."""
     for seed in (1, 2):
         f, d = _tables(shape, seed)
         s = cb.Session(Config(n_segments=1))
@@ -317,6 +336,8 @@ def test_no_ceiling_is_under_the_groups_that_come(shape):
                     N.capacity_of(agg.child), 1), (shape, name)
             outer = _aggs(s, q)[0]
             assert outer.capacity >= len(rows), (shape, seed, name)
+            if name in ("q3_grouping", "q18_grouping"):
+                assert outer.carried == ("d.h",), (shape, seed, name)
 
 
 def test_an_append_widens_the_range_before_the_next_plan():
