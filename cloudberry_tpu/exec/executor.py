@@ -60,11 +60,10 @@ class Executable:
     # the one-shot launch's program (run_executable): ``fn`` ending in
     # pack_answer, so the host gets the whole answer in one wait
     packed_fn: Callable = None  # type: ignore[assignment]
-    # (lookups, expansions, compacted lookups, direct lookups) among the
-    # plan's joins
-    # (join_shapes), fixed when the plan is lowered and counted on every
-    # launch
-    join_shapes: tuple = (0, 0, 0, 0)
+    # (lookups, expansions, compacted lookups, direct lookups, semi-joins
+    # on a scan) among the plan's joins (join_shapes), fixed when the
+    # plan is lowered and counted on every launch
+    join_shapes: tuple = (0, 0, 0, 0, 0)
     # (rows in, capacity, keys carried, sort words) summed over the
     # plan's grouped aggregates (agg_shapes), fixed and counted likewise
     agg_shapes: tuple = (0, 0, 0, 0)
@@ -696,9 +695,11 @@ def find_expansion_node(plan: N.PlanNode, message: str):
 
 def join_shapes(plan: N.PlanNode) -> tuple:
     """(lookups, pair expansions, lookups at capacities of their own,
-    lookups through a direct-address table) among ``plan``'s joins, by
-    the shape ``Lowerer.join`` takes for each (``PJoin.expands``,
-    ``PJoin.direct_lookup``, plan/joincap.py)."""
+    lookups through a direct-address table, semi-joins whose probe holds
+    no join) among ``plan``'s joins, by the shape ``Lowerer.join`` takes
+    for each (``PJoin.expands``, ``PJoin.direct_lookup``,
+    plan/joincap.py) and by where the binder placed a semi-join
+    (``Binder._place_in_subquery``)."""
     joins = _dedupe_nodes(nd for nd in all_nodes(plan)
                           if isinstance(nd, N.PJoin))
     expand = sum(nd.expands for nd in joins)
@@ -706,7 +707,9 @@ def join_shapes(plan: N.PlanNode) -> tuple:
                   if nd.compacts]
     compacted = sum(nd.out_rows(rows) < rows for nd, rows in probe_rows)
     direct = sum(nd.direct_lookup for nd in joins)
-    return len(joins) - expand, expand, compacted, direct
+    on_scan = sum(nd.kind == "semi" and not any(
+        isinstance(c, N.PJoin) for c in all_nodes(nd.probe)) for nd in joins)
+    return len(joins) - expand, expand, compacted, direct, on_scan
 
 
 def count_join_shapes(log, shapes: tuple) -> None:
@@ -714,12 +717,14 @@ def count_join_shapes(log, shapes: tuple) -> None:
     and ``launch_joins_expand`` say which join the planner chose in the
     programs that ran, ``launch_joins_compacted`` how many of the lookups
     ran at a capacity of their own, ``launch_joins_direct`` how many
-    found their build rows in a direct-address table (a program that
-    joins nothing bumps none)."""
+    found their build rows in a direct-address table,
+    ``launch_joins_semi_on_scan`` how many semi-joins filter a table
+    before any join (a program that joins nothing bumps none)."""
     if log is None:
         return
     for name, n in zip(("launch_joins_lookup", "launch_joins_expand",
-                        "launch_joins_compacted", "launch_joins_direct"),
+                        "launch_joins_compacted", "launch_joins_direct",
+                        "launch_joins_semi_on_scan"),
                        shapes):
         if n:
             log.bump(name, n)

@@ -113,8 +113,10 @@ def _unique_sets(plan: N.PlanNode, catalog: Catalog) -> list[frozenset[str]]:
     """Column sets guaranteed unique in a plan's output (PK propagation):
     scans expose unique base columns, joins preserve the PROBE side's
     uniqueness (each probe row matches ≤1 build row), aggs are unique on
-    their group keys."""
-    cached = getattr(plan, "_unique_sets", None)
+    their group keys. Kept on the node under the slot of the statistics
+    it came from (a view of the catalog names its own: plan/joincap.py)."""
+    slot = getattr(catalog, "unique_slot", "_unique_sets")
+    cached = getattr(plan, slot, None)
     if cached is not None:
         return cached
     out: list[frozenset[str]] = []
@@ -144,7 +146,7 @@ def _unique_sets(plan: N.PlanNode, catalog: Catalog) -> list[frozenset[str]]:
         for s in _unique_sets(plan.child, catalog):
             if all(c in renames for c in s):
                 out.append(frozenset(renames[c] for c in s))
-    plan._unique_sets = out
+    setattr(plan, slot, out)
     return out
 
 
@@ -423,20 +425,14 @@ class Binder:
                     residual.extend(preds)
                     continue
                 p = plans[alias]
-                old = p
                 for pred in preds:
                     p = self._filter(p, self.bind_scalar(pred, scope))
-                plans[alias] = p
-                # rebind EVERY entry (and plan) that shared the old
-                # object: an explicit JOIN's aliases all point at one
-                # merged plan, and a stale sibling would make suffix
-                # resolution see two distinct sources for one column
-                for e in scope.entries:
-                    if e.alias == alias or e.plan is old:
-                        e.plan = p
-                for a2, pv in list(plans.items()):
-                    if pv is old:
-                        plans[a2] = p
+                _replace_plan(plans, scope, alias, p)
+            for pred in list(subq_preds):
+                placed = self._place_in_subquery(pred, plans, scope)
+                if placed is not None:
+                    _replace_plan(plans, scope, *placed)
+                    subq_preds.remove(pred)
             plan = self._join_tree(plans, edges, scope,
                                    groupby=sel.group_by)
             for pred in residual:
@@ -1960,6 +1956,41 @@ class Binder:
     # The cdbsubselect.c analog: EXISTS/IN/scalar subqueries in WHERE become
     # semi/anti/inner joins against a (possibly grouped) subplan.
 
+    def _place_in_subquery(self, pred: ast.ExprNode, plans: dict,
+                           scope: Scope):
+        """(alias, plan) where ``pred`` is ``x IN (subquery)`` whose outer
+        columns (x and the outer side of every correlation pair) all
+        belong to ONE FROM item of a join: the semi-join filters that item
+        before the join tree, σ_{x∈S}(R ⋈ T) = σ_{x∈S}(R) ⋈ T. Else None,
+        and the predicate is applied above the join tree as before: NOT
+        IN (a null-aware anti-join) and EXISTS (which may carry a
+        residual) are never moved, nor a predicate over two items, nor
+        one on an item an explicit JOIN merged with others (that plan is
+        a join already, possibly outer: nothing to go below)."""
+        if not isinstance(pred, ast.InSubquery) or pred.negated \
+                or len({id(p) for p in plans.values()}) < 2 \
+                or _contains_subquery(pred.expr):
+            return None
+        split = self._split_correlation(pred.select, scope)
+        _, corr, _, residual = split
+        if residual:
+            return None
+        try:
+            aliases = scope.aliases_of(pred.expr)
+            for o, _ in corr:
+                aliases |= scope.aliases_of(o)
+        except BindError:
+            return None
+        if len(aliases) != 1:
+            return None
+        (alias,) = aliases
+        home = plans.get(alias)
+        if home is None or any(e.plan is home and e.alias != alias
+                               for e in scope.entries):
+            return None
+        return alias, self._apply_in_subquery(pred, home, scope, False,
+                                              split)
+
     def _apply_subquery_pred(self, pred: ast.ExprNode, plan: N.PlanNode,
                              scope: Scope) -> N.PlanNode:
         negated = False
@@ -2181,9 +2212,11 @@ class Binder:
         return j
 
     def _apply_in_subquery(self, node: ast.InSubquery, plan: N.PlanNode,
-                           scope: Scope, negated: bool) -> N.PlanNode:
+                           scope: Scope, negated: bool,
+                           split=None) -> N.PlanNode:
         sub = node.select
-        inner, corr, inner_conjs, residual = self._split_correlation(sub, scope)
+        inner, corr, inner_conjs, residual = \
+            split or self._split_correlation(sub, scope)
         if residual:
             raise BindError("IN subquery with non-equi correlation "
                             "not supported yet")
@@ -2811,6 +2844,21 @@ def _rebind_scope(scope: Scope, alias: str, plan: N.PlanNode) -> None:
     for e in scope.entries:
         if e.alias == alias:
             e.plan = plan
+
+
+def _replace_plan(plans: dict, scope: Scope, alias: str,
+                  new: N.PlanNode) -> None:
+    """FROM item ``alias`` is now ``new``: rebind EVERY entry (and plan)
+    that shared its old plan. An explicit JOIN's aliases all point at one
+    merged plan, and a stale sibling would make suffix resolution see two
+    distinct sources for one column."""
+    old = plans[alias]
+    for e in scope.entries:
+        if e.alias == alias or e.plan is old:
+            e.plan = new
+    for a2, pv in list(plans.items()):
+        if pv is old:
+            plans[a2] = new
 
 
 def alias_set_of(groups) -> set:

@@ -99,6 +99,11 @@ def _estimate_join(node: N.PJoin, catalog) -> float:
     if node.kind == "full":
         return max(inner, p) + max(b - inner, 0.0)
     if node.kind == "semi":
+        from cloudberry_tpu.plan.binder import _build_is_unique
+
+        if _build_is_unique(node.probe, node.probe_keys, catalog):
+            # each build key meets at most one probe row
+            return min(p, b)
         # fraction of probe rows with a partner
         if nd_p:
             return p * min(1.0, (nd_b or b) / nd_p)

@@ -88,17 +88,20 @@ FLOOR = 1024    # ... and not under FLOOR rows
 
 class _Persisted:
     """The catalog's statistics that do not depend on where a table's
-    rows are: its row count, its columns' ranges and histograms, and the
-    distinct counts of a table that lives in RAM only. A STORED table
+    rows are: its row count, its columns' ranges and histograms, its
+    manifest's uniqueness flags, and the distinct counts of a table that
+    lives in RAM only. A STORED table
     that a backend has loaded counts distinct values on demand
     (``Table.ndv``) and one it has not cannot; sized from those, a cold
     backend and a warm one would lower two programs for one statement
     over one store (two compiles of minutes each at SF1), so a stored
-    table's capacities see none. ``est_slot``: such estimates are kept
-    on the nodes beside the planner's own (``cost.estimate_rows``), not
-    in their place."""
+    table's capacities see none. ``est_slot``, ``unique_slot``: such
+    estimates and uniqueness are kept on the nodes beside the planner's
+    own (``cost.estimate_rows``, ``binder._unique_sets``), not in their
+    place."""
 
     est_slot = "_est_rows_persisted"
+    unique_slot = "_unique_sets_persisted"
 
     def __init__(self, catalog):
         self._catalog = catalog
@@ -116,6 +119,24 @@ class _PersistedTable:
     def ndv(self, col: str):
         t = self._table
         return t.ndv(col) if t.backing is None else None
+
+    def is_unique(self, col: str) -> bool:
+        """A stored table's column is unique where its manifest can say
+        so (``TableStore._unique_flags``: integer kinds, the flags kept
+        current on every append), whether its rows are loaded or not."""
+        t = self._table
+        arr = t.data.get(col)
+        if t.backing is not None and (arr is None
+                                      or arr.dtype.kind not in "iu"):
+            return False
+        return t.is_unique(col)
+
+    def is_unique_cols(self, cols: tuple) -> bool:
+        """As ``is_unique``; a stored table's manifest flags single
+        columns only (a cold table's rule, ``Table.is_unique_cols``)."""
+        if self._table.backing is None:
+            return self._table.is_unique_cols(cols)
+        return any(self.is_unique(c) for c in cols)
 
 
 def _post_order(plan: N.PlanNode) -> list:

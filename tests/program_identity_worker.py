@@ -24,7 +24,12 @@ appends (``tools/tpchgen.stream_load_tpch``, as the benchmark's loader
 and ``chip_smoke.py`` do) and serves the statement from a fresh session,
 whose tables are COLD: the planner knows of them what the manifests say
 (ISSUE 31). ``--warm`` loads them into RAM before the statement is
-planned. The line also carries the joins the launches counted, by shape.
+planned. The line also carries the joins the launches counted, by shape,
+and the semi-joins among them that filter a scan before any join.
+
+Run in two checkouts, it is the check that a change leaves a statement's
+programs as they were: equal ``hashes`` and ``result_info`` for the same
+arguments.
 """
 
 from __future__ import annotations
@@ -115,6 +120,8 @@ print(json.dumps({"rows": rows, "programs": len(programs),
                   "names": [name for name, _ in programs],
                   "joins": [s.stmt_log.counter("launch_joins_lookup"),
                             s.stmt_log.counter("launch_joins_expand")],
+                  "semi_on_scan": s.stmt_log.counter(
+                      "launch_joins_semi_on_scan"),
                   "hashes": [hashlib.sha256(t.encode()).hexdigest()
                              for _, t in programs],
                   "result_info": info}))

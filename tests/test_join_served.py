@@ -69,7 +69,7 @@ DRAWS = {"q3": {"segment": 1, "day": 15},
 # semi-join keeps the lines of a few large orders), lookups through a
 # direct-address table (ISSUE 37: the generator's keys are dense)
 JOINS = {"q3": (2, 0, 2, 2), "q12": (1, 0, 1, 1), "q13": (0, 1, 0, 0),
-         "q18": (3, 0, 1, 3)}
+         "q18": (3, 0, 3, 3)}
 # the tables a statement scans, one entry a scan
 SCANS = {"q18": ("customer", "orders", "lineitem", "lineitem")}
 COUNTERS = ("launch_joins_lookup", "launch_joins_expand",
