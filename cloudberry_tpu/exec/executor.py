@@ -1004,15 +1004,17 @@ _NODE_KINDS = {N.PScan: "scan", N.PFilter: "filter", N.PProject: "project",
 class Lowerer:
     """Traces a plan into jax ops. Subclassed by the distributed executor,
     which overrides scan (per-segment inputs) and motion (collectives).
-    Three pieces of state vary what a lowering does, for both classes:
+    Four pieces of state vary what a lowering does, for both classes:
     ``replace`` (nodes already computed), ``stream``/``tile_n`` (the scan
-    a tiled step feeds a tile at a time) and ``count_rows`` (EXPLAIN
-    ANALYZE's per-node row counts)."""
+    a tiled step feeds a tile at a time), ``direct_tables`` (joins whose
+    build was streamed into a direct-address table before the step) and
+    ``count_rows`` (EXPLAIN ANALYZE's per-node row counts)."""
 
     def __init__(self, tables, platform: str | None = None, params=None,
                  root=None, replace: dict | None = None,
                  stream: N.PScan | None = None, tile_n=None,
-                 count_rows: bool = False):
+                 count_rows: bool = False,
+                 direct_tables: dict | None = None):
         self.tables = tables
         # id(node) -> ordinal under ``root`` (numbered_nodes): how check
         # and stats keys name a node. Without a ``root`` the first plan
@@ -1037,6 +1039,10 @@ class Lowerer:
         # reads its table
         self.stream = stream
         self.tile_n = tile_n
+        # id(join) -> (box, (present, payload)): the join reads a table
+        # built before this program (exec/tiled.py build stream) and its
+        # build subtree is never traced
+        self.direct_tables = direct_tables or {}
         # EXPLAIN ANALYZE: every lowered node's selected-row count goes
         # through record_rows
         self.count_rows = count_rows
@@ -1286,6 +1292,10 @@ class Lowerer:
         return order, skeys, ranges
 
     def join(self, node: N.PJoin):
+        table = self.direct_tables.get(id(node))
+        if table is not None:
+            pcols, psel = self.lower(node.probe)
+            return self._join_table(node, *table, pcols, psel)
         # lower_shared: a runtime filter may reference the same build
         # subtree — it must trace once
         bcols, bsel = self.lower_shared(node.build)
@@ -1373,6 +1383,17 @@ class Lowerer:
             self.checks[
                 f"join build side has duplicate keys {self.label(node)} but "
                 "the planner assumed a unique (PK) build side"] = has_dup
+        return self._lookup_out(
+            node, pcols, psel, pkv, matched, payload,
+            None if bkv is None
+            else lambda: self.global_any(bsel & ~bkv))
+
+    def _lookup_out(self, node: N.PJoin, pcols, psel, pkv, matched,
+                    payload, build_null=None):
+        """A lookup join's output: the probe's columns, the matched build
+        rows' ``payload``, and the rows its kind keeps. ``build_null``:
+        whether any build key is NULL (a NOT IN's test), None where no
+        build key can be."""
         cols = {**pcols, **payload}
         if node.match_name:
             cols[node.match_name] = matched
@@ -1388,11 +1409,26 @@ class Lowerer:
                 # segments (the NULL row may live on another shard)
                 if pkv is not None:
                     sel = sel & pkv
-                if bkv is not None:
-                    sel = sel & ~self.global_any(bsel & ~bkv)
+                if build_null is not None:
+                    sel = sel & ~build_null()
         else:
             raise ExecError(f"join kind {node.kind}")
         return cols, sel
+
+    def _join_table(self, node: N.PJoin, box, table, pcols, psel):
+        """A lookup against a direct-address table built before this
+        program (``kernels.direct_table_insert``, a tile at a time): one
+        gather a probe row, no build to lower, sort or search. The
+        planner streams only builds no NULL key of which a NOT IN reads
+        (exec/tiled.py ``_build_stream``)."""
+        present, payload = table
+        pkeys = [self.expr(k, pcols) for k in node.probe_keys]
+        pkv = self.expr(node.probe_key_valid, pcols) \
+            if node.probe_key_valid is not None else None
+        pselm = psel & pkv if pkv is not None else psel
+        slot, matched = K.direct_table_lookup(present, pkeys, pselm, box)
+        return self._lookup_out(node, pcols, psel, pkv, matched,
+                                K.gather_payload(payload, slot, matched))
 
     def window(self, node: N.PWindow):
         """Windows over sorted partitions — scatter-free: boundary flags,

@@ -605,16 +605,16 @@ def _pipeline_once(plan, session, query):
     packed = wire_packed(session)
     try:
         est = check_admission(plan, session)
-    except ResourceError:
+    except ResourceError as err:
         # over-budget plans take the tiled (out-of-core) path like any
         # statement would; per-node counts are not separable there, but
         # the tiled report (per-tile time histogram, checkpoint/resume
         # counters) rides the rendered output instead
-        from cloudberry_tpu.exec.tiled import plan_tiled
+        from cloudberry_tpu.exec.tiled import declined, plan_tiled
 
         texe = plan_tiled(plan, session)
         if texe is None:
-            raise
+            raise declined(err, plan) from None
         from cloudberry_tpu.obs import capacity as OC
 
         texe.refresh_bufpool_charge()

@@ -20,6 +20,7 @@ tables have no TPU analog, sort+segment ops are the native formulation.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -703,6 +704,77 @@ def join_lookup_direct(
     hit = kp < top
     row = jnp.where(hit, table[jnp.where(hit, kp, 0).astype(jnp.int32)], -1)
     return jnp.maximum(row, 0), (row >= 0) & probe_sel, has_dup, past_span
+
+
+def direct_slots(key: Sequence[jnp.ndarray], box: Sequence[tuple[int, int]]
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Each row's slot in a direct-address table laid over a PROVEN key
+    box (``box``: one ``(least value, span)`` a key, plan/joincap.py
+    ``direct_box``), and whether its keys lie inside the box. Unlike
+    ``join_lookup_direct``, which packs with the build's own ranges in
+    one program, the box is fixed at plan time: a table built across
+    many programs, a tile at a time, packs every tile alike, and so do
+    the probes that read it. Returns (slot int32, inside bool); a slot
+    outside the box is the table's size (a dropped write)."""
+    slot = jnp.zeros(key[0].shape, jnp.int64)
+    inside = jnp.ones(key[0].shape, jnp.bool_)
+    for k, (lo, span) in zip(key, box):
+        off = k.astype(jnp.int64) - lo
+        inside = inside & (off >= 0) & (off < span)
+        slot = slot * span + off
+    size = math.prod(span for _, span in box)
+    return jnp.where(inside, slot, size).astype(jnp.int32), inside
+
+
+def direct_table_insert(
+    present: jnp.ndarray,
+    payload: Columns,
+    build_key: Sequence[jnp.ndarray],
+    build_sel: jnp.ndarray,
+    box: Sequence[tuple[int, int]],
+    rows: Columns,
+    unique: bool,
+) -> tuple[jnp.ndarray, Columns, jnp.ndarray, jnp.ndarray]:
+    """The build half of ``join_lookup_direct``, one tile at a time: the
+    selected rows' keys mark their slots in ``present`` (bool[size]) and
+    their ``rows`` (payload columns) are written at those slots of
+    ``payload``. Returns (present, payload, has_dup, past_span):
+    ``past_span`` where a selected key lies outside the proven box (the
+    proof is broken: the table is not to be used); with ``unique`` (an
+    inner or left join's build), ``has_dup`` where a selected key's slot
+    was taken by an earlier tile or by another row of this one (the
+    table then holds one of them, whichever write the device kept, and
+    the answer is not to be used). A semi or anti join's build records
+    presence only; its repeated keys are expected."""
+    slot, inside = direct_slots(build_key, box)
+    inside = inside & build_sel
+    past_span = (build_sel & ~inside).any()
+    size = present.shape[0]
+    slot = jnp.where(inside, slot, size)
+    has_dup = jnp.asarray(False)
+    if unique:
+        at = jnp.minimum(slot, size - 1)
+        ids = jax.lax.iota(jnp.int32, slot.shape[0])
+        owner = jnp.full(size, -1, jnp.int32).at[slot].set(ids, mode="drop")
+        has_dup = (inside & (present[at] | (owner[at] != ids))).any()
+    present = present.at[slot].set(True, mode="drop")
+    payload = {n: t.at[slot].set(rows[n], mode="drop")
+               for n, t in payload.items()}
+    return present, payload, has_dup, past_span
+
+
+def direct_table_lookup(
+    present: jnp.ndarray,
+    probe_key: Sequence[jnp.ndarray],
+    probe_sel: jnp.ndarray,
+    box: Sequence[tuple[int, int]],
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """A probe against a table ``direct_table_insert`` built: one gather.
+    Returns (slot int32, matched bool): ``payload[slot]`` is the matched
+    build row's; a probe key outside the box matches nothing."""
+    slot, inside = direct_slots(probe_key, box)
+    slot = jnp.where(inside, slot, 0)
+    return slot, inside & probe_sel & present[slot]
 
 
 def join_lookup(

@@ -77,16 +77,21 @@ class MemoryEstimate:
     per_node: list[tuple[str, int]]
 
 
-def estimate_plan_memory(plan: N.PlanNode) -> MemoryEstimate:
+def estimate_plan_memory(plan: N.PlanNode,
+                         tables: dict | None = None) -> MemoryEstimate:
     """Upper-bound device bytes per segment for one query.
 
     Node capacities are already per-segment after the distribution pass
     (scan capacities are shard capacities, motion capacities are receive
     buffers), so summing capacity × Σ column widths (+ masks) directly gives
     the per-segment bound. An over-estimate (XLA frees fused intermediates)
-    but shape-exact — the point is a hard admission bound, not a profile."""
+    but shape-exact — the point is a hard admission bound, not a profile.
+    ``tables``: id(join) -> bytes of the direct-address table its build
+    was streamed into (exec/tiled.py); such a join counts the table and
+    not its build subtree, which no program of the step holds."""
     per_node: list[tuple[str, int]] = []
     total = 0
+    tables = tables or {}
 
     def width(node: N.PlanNode) -> int:
         w = 1  # selection mask
@@ -97,11 +102,15 @@ def estimate_plan_memory(plan: N.PlanNode) -> MemoryEstimate:
     def rec(node: N.PlanNode):
         nonlocal total
         b = N.capacity_of(node) * width(node)
-        if isinstance(node, N.PJoin) and node.direct_lookup:
+        streamed = tables.get(id(node))
+        if streamed is not None:
+            b += streamed
+        elif isinstance(node, N.PJoin) and node.direct_lookup:
             b += 4 * node.direct_span   # its int32 direct-address table
         per_node.append((node.title(), b))
         total += b
-        for c in node.children():
+        for c in ([node.probe] if streamed is not None
+                  else node.children()):
             rec(c)
 
     rec(plan)

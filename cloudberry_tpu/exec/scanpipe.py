@@ -98,10 +98,14 @@ class ScanPipeline:
     consumer-thread-only slot and never crosses threads."""
 
     def __init__(self, gen, depth: int = 2, device_stage: bool = False,
-                 stats: Optional[ScanStats] = None):
+                 stats: Optional[ScanStats] = None,
+                 span_args: Optional[dict] = None):
         from cloudberry_tpu.lifecycle import current_handle
 
         self._gen = gen
+        # what the consumer's feed-wait and h2d spans carry besides
+        # (a tiled build stream's ``phase``)
+        self._span_args = span_args or {}
         self.depth = max(int(depth), 1)
         self._device_stage = bool(device_stage)
         self.scan_stats = stats
@@ -238,7 +242,8 @@ class ScanPipeline:
                 if wait is None:
                     # first look found the queue empty: open the stage
                     # (outside the leaf lock), then look again and wait
-                    wait = OT.stage("feed-wait", "launch_seconds")
+                    wait = OT.stage("feed-wait", "launch_seconds",
+                                    **self._span_args)
                     wait.__enter__()
                 check_cancel()
         finally:
@@ -257,7 +262,7 @@ class ScanPipeline:
         tile, n = item
         with OT.stage("h2d", "launch_seconds", bytes=sum(
                 int(getattr(v, "nbytes", 0)) for v in tile.values()
-                if not isinstance(v, jax.Array))):
+                if not isinstance(v, jax.Array)), **self._span_args):
             return ({k: jax.device_put(v) for k, v in tile.items()}, n)
 
     # ------------------------------------------------------------ teardown
@@ -329,19 +334,20 @@ class PlainFeed:
 
 def maybe_pipeline(gen, config, device_stage: bool = False,
                    stats: Optional[ScanStats] = None,
-                   min_depth: int = 1):
+                   min_depth: int = 1, span_args: Optional[dict] = None):
     """Wrap a tile generator in the prefetch pipeline when
     ``config.scan_pipeline`` enables it; a PlainFeed otherwise (the
     synchronous path, unchanged semantics). ``min_depth`` lets the
     windowed tile dispatcher (exec/tilepipe.py) deepen the prefetch
     queue to its in-flight window so the feed never becomes the
     bottleneck behind a W-deep device queue; it never turns the
-    pipeline ON when the config disabled it."""
+    pipeline ON when the config disabled it. ``span_args``: what the
+    feed's spans carry besides."""
     sp = getattr(config, "scan_pipeline", None)
     if sp is not None and sp.enabled and sp.prefetch_tiles >= 1:
         return ScanPipeline(gen, depth=max(sp.prefetch_tiles, min_depth),
                             device_stage=device_stage and sp.device_buffer,
-                            stats=stats)
+                            stats=stats, span_args=span_args)
     return PlainFeed(gen, stats=stats)
 
 

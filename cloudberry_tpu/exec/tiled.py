@@ -13,6 +13,14 @@ it as a STREAM OF FIXED-SHAPE TILES —
   ever sees one tile of ``tile_rows`` rows;
 - every spine join's build subtree is computed ONCE by a prelude program
   and its (bounded, estimated-and-admitted) result arrays stay resident;
+- a spine join whose build does not fit the budget, but whose build keys
+  have a proven span (plan/joincap.py ``direct_box``), is a BUILD STREAM
+  (``_BuildStream``): before the probe stream starts, the build's own
+  scan is streamed in tiles through the same feed, and each tile's rows
+  are scattered into a direct-address table over that span
+  (``kernels.direct_table_insert``) — the batch-file discipline of
+  nodeHash.c with the table, not the build, resident; the probe steps
+  read it with one gather (``Lowerer._join_table``);
 - one jitted STEP program runs per tile: spine joins/filters/projections,
   a partial aggregation, and a merge into a fixed-capacity accumulator
   (the combine-function discipline of the distributed two-stage agg,
@@ -23,12 +31,14 @@ it as a STREAM OF FIXED-SHAPE TILES —
 
 Per-tile capacities keep the engine's checked-overflow discipline: a tile
 that overflows its expansion-join or group buffers raises, never truncates.
-Peak device memory is the admitted estimate: resident builds + one tile's
-working set + the accumulator — independent of the streamed table's size.
+Peak device memory is the admitted estimate: resident builds + streamed
+builds' tables + one tile's working set + the accumulator — independent
+of the streamed tables' sizes.
 """
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -94,19 +104,71 @@ class _TileShape:
     winnode: Optional[N.PWindow] = None  # window mode: BOTTOM of the stack
     wintop: Optional[N.PWindow] = None   # window mode: TOP of the stack
     n_ckeys: int = 0                  # window mode: chunk-key count
+    # spine joins whose builds stream into direct tables (``_fit``)
+    streams: list = field(default_factory=list)
+    stmt: N.PlanNode = None           # type: ignore[assignment]
+    decline: str = ""                 # why the budget cannot hold it
+
+
+@dataclass
+class _BuildStream:
+    """A spine join whose build streams into a direct-address table over
+    its keys' proven box before the probe stream starts: ``shape`` is the
+    build's own spine down to the scan it streams (its own builds
+    resident), ``box`` one ``(least value, span)`` a key, ``payload`` the
+    build columns an inner or left join carries, with their dtypes."""
+
+    join: N.PJoin
+    shape: _TileShape
+    box: list
+    payload: list
+    tile_rows: int = 0
+    whole_rows: int = 0     # the build scan's capacity before its tiles
+
+    def untile(self) -> None:
+        """The build scan back at its whole capacity (a declined plan's
+        build is computed whole by whichever program runs it)."""
+        self.shape.stream.capacity = self.whole_rows
+
+    @property
+    def slots(self) -> int:
+        return math.prod(span for _, span in self.box)
+
+    @property
+    def unique(self) -> bool:
+        """An inner or left join's build: one row a key, checked."""
+        return self.join.kind in ("inner", "left")
+
+    @property
+    def table_bytes(self) -> int:
+        return self.slots * (1 + sum(np.dtype(dt).itemsize
+                                     for _, dt in self.payload))
+
+    def step_bytes(self) -> int:
+        """One build tile's working set beside the tables: the build's
+        spine at its tile and its resident builds, and the scatter's
+        int32 owner table where duplicates are checked."""
+        own = estimate_plan_memory(self.join.build).peak_bytes
+        return own + (4 * self.slots if self.unique else 0)
+
+    def empty_table(self):
+        return (jnp.zeros((self.slots,), jnp.bool_),
+                {n: jnp.zeros((self.slots,), dt) for n, dt in self.payload})
 
 
 def plan_tiled(plan: N.PlanNode, session) -> Optional["TiledExecutable"]:
     """Try to re-plan an admission-rejected statement for tiled execution.
-    Returns None when the plan shape or the budget cannot support it."""
+    Returns None when the plan shape or the budget cannot support it, and
+    notes why on the plan (``declined``)."""
+    plan._tile_declined = None
     if not session.config.resource.enable_spill:
-        return None
+        return _decline(plan, "spill is off (resource.enable_spill)")
     if session.config.n_segments > 1:
         from cloudberry_tpu.exec.tiled_dist import plan_tiled_dist
 
         return plan_tiled_dist(plan, session)
     if getattr(plan, "_direct_segment", None) is not None:
-        return None
+        return _decline(plan, "a statement dispatched to one segment")
     from cloudberry_tpu.plan.pointlookup import unbind_point_lookups
 
     # the tile stream and resident loads key inputs by TABLE NAME: a
@@ -123,7 +185,8 @@ def plan_tiled(plan: N.PlanNode, session) -> Optional["TiledExecutable"]:
                                                strip_join_index)
     shape = _analyze(plan)
     if shape is None:
-        return None
+        return _decline(plan, "no streamable shape")
+    shape.stmt = plan
     # whole-run growth marks (session._run_with_growth) are meaningless at
     # tile scale and would poison the per-tile floor — the tiled adaptive
     # loop re-learns spine buffer sizes itself. Build-side joins keep
@@ -136,7 +199,25 @@ def plan_tiled(plan: N.PlanNode, session) -> Optional["TiledExecutable"]:
     t = _plan_by_mode(shape, session)
     if t is None:
         restore_join_index(stash)
+        _decline(plan, shape.decline or "no streamable shape")
     return t
+
+
+def _decline(plan: N.PlanNode, why: str) -> None:
+    plan._tile_declined = why
+
+
+def declined(err: Exception, plan: N.PlanNode) -> Exception:
+    """The admission error ``err`` of a plan ``plan_tiled`` declined,
+    saying why it declined: the join whose build the budget cannot hold
+    (its ordinal in the plan, its estimate against the budget, and what
+    keeps it from streaming), or the shape it cannot stream."""
+    why = getattr(plan, "_tile_declined", None)
+    if not why:
+        return err
+    from cloudberry_tpu.exec.resource import ResourceError
+
+    return ResourceError(f"{err}; tiled execution declined: {why}")
 
 
 def _plan_by_mode(shape: "_TileShape", session):
@@ -155,7 +236,8 @@ def _plan_by_mode(shape: "_TileShape", session):
     try:
         partial_aggs, final_aggs, finalize = _split_aggs(shape.agg.aggs)
     except ValueError:
-        return None  # an aggregate with no partial/merge decomposition
+        shape.decline = "an aggregate with no partial and merge form"
+        return None
     shape.finalize = finalize
     shape.merge_specs = [K.AggSpec(call.func, name)
                          for name, call in final_aggs]
@@ -183,7 +265,7 @@ def _plan_by_mode(shape: "_TileShape", session):
     shape.partial_plan = partial
 
     budget = session.config.resource.query_mem_bytes
-    tile_rows = _choose_tile(shape, budget)
+    tile_rows = _fit(shape, session, budget)
     if tile_rows is None:
         return None
 
@@ -208,7 +290,7 @@ def _plan_topn(shape: _TileShape, session) -> Optional["TopNTiledExecutable"]:
     sort = shape.sortnode
     shape.partial_plan = sort.child
     budget = session.config.resource.query_mem_bytes
-    tile_rows = _choose_tile(shape, budget)
+    tile_rows = _fit(shape, session, budget)
     if tile_rows is None:
         return None  # LIMIT too large for a resident accumulator
 
@@ -313,7 +395,7 @@ def _plan_sort(shape: _TileShape,
         return None
     shape.partial_plan = shape.sortnode.child
     budget = session.config.resource.query_mem_bytes
-    tile_rows = _choose_tile(shape, budget)
+    tile_rows = _fit(shape, session, budget)
     if tile_rows is None:
         return None
     shape.root = shape.post[0] if shape.post else shape.sortnode
@@ -339,6 +421,7 @@ def _plan_window(shape: _TileShape,
         common = {k: v for k, v in common.items() if k in here}
         node = node.child
     if not common:
+        shape.decline = "window specs with no common partition key"
         return None
     ckeys = list(common.values())
     srt = N.PSort(bottom.child, [(ck, True) for ck in ckeys])
@@ -347,7 +430,7 @@ def _plan_window(shape: _TileShape,
     shape.n_ckeys = len(ckeys)
     shape.partial_plan = bottom.child
     budget = session.config.resource.query_mem_bytes
-    tile_rows = _choose_tile(shape, budget)
+    tile_rows = _fit(shape, session, budget)
     if tile_rows is None:
         return None
     shape.root = shape.post[0] if shape.post else shape.wintop
@@ -437,10 +520,29 @@ def _analyze(plan: N.PlanNode) -> Optional[_TileShape]:
         post = chain[:chain.index(sortnode)]
         spine_top = sortnode.child
 
+    walk = _spine_walk(spine_top)
+    if walk is None:
+        return None
+    shape = _TileShape(agg, post, *walk)
+    if winnode is not None:
+        shape.mode = "window"
+        shape.winnode = winnode
+        shape.wintop = wintop
+    elif agg is None:
+        shape.mode = "topn" if m else "sort"
+        shape.sortnode = sortnode
+        shape.g_cap = m
+    return shape
+
+
+def _spine_walk(top: N.PlanNode):
+    """(spine, builds, scan, scan rows) of a filter/projection/join chain
+    whose probe path ends at a scan (the scan a tile stream reads), or
+    None. The probe side of a statement's stream and a build stream's
+    own (``_build_stream``) are found by this one walk."""
     spine: list[N.PlanNode] = []
     builds: list[N.PlanNode] = []
-    cur = spine_top
-    # graftlint: ignore[seam-loop] bounded plan-tree descent (one step per node, no blocking calls) — terminates with the tree, never a tile/retry loop
+    cur = top
     while True:
         if isinstance(cur, (N.PFilter, N.PProject)):
             spine.append(cur)
@@ -458,17 +560,7 @@ def _analyze(plan: N.PlanNode) -> Optional[_TileShape]:
             cur = cur.probe
         elif isinstance(cur, N.PScan) and cur.table_name != "$dual":
             rows = cur.num_rows if cur.num_rows >= 0 else cur.capacity
-            shape = _TileShape(agg, post, spine, cur, builds,
-                               stream_rows=max(rows, 1))
-            if winnode is not None:
-                shape.mode = "window"
-                shape.winnode = winnode
-                shape.wintop = wintop
-            elif agg is None:
-                shape.mode = "topn" if m else "sort"
-                shape.sortnode = sortnode
-                shape.g_cap = m
-            return shape
+            return spine, cur, builds, max(rows, 1)
         else:
             return None
 
@@ -527,11 +619,115 @@ def _choose_tile(shape: _TileShape, budget: int) -> Optional[int]:
     t = _MAX_TILE
     while t >= _MIN_TILE:
         _retile(shape, t)
-        est = estimate_plan_memory(shape.partial_plan).peak_bytes
-        if est + _merge_bytes(shape) <= budget:
+        if _step_bytes(shape) <= budget:
             return t
         t >>= 1
     return None
+
+
+def _step_bytes(shape: _TileShape) -> int:
+    """One probe step's estimate: the partial plan at its tile, the
+    streamed builds' tables in their subtrees' place, the merge."""
+    tables = {id(bs.join): bs.table_bytes for bs in shape.streams}
+    return estimate_plan_memory(shape.partial_plan, tables).peak_bytes \
+        + _merge_bytes(shape)
+
+
+def _build_phase_bytes(shape: _TileShape) -> int:
+    """The build streams' peak: every table, and the largest build
+    tile's working set beside them."""
+    if not shape.streams:
+        return 0
+    return sum(bs.table_bytes for bs in shape.streams) \
+        + max(bs.step_bytes() for bs in shape.streams)
+
+
+def _fit(shape: _TileShape, session, budget: int) -> Optional[int]:
+    """``_choose_tile``, and where no tile fits, the spine joins' builds
+    streamed into direct tables (``_build_stream``), the largest
+    resident build first, until one does. None, with ``shape.decline``
+    saying why, where none does."""
+    t = _choose_tile(shape, budget)
+    if t is not None:
+        return t
+    mib = 1 << 20
+    joins = sorted((n for n in shape.spine if isinstance(n, N.PJoin)),
+                   key=lambda j: -estimate_plan_memory(j.build).peak_bytes)
+    why = []
+    for j in joins:
+        need = estimate_plan_memory(j.build).peak_bytes
+        bs, cause = _build_stream(j, shape, session, budget)
+        if bs is None:
+            ordinal = next(i for i, n in enumerate(
+                X.numbered_nodes(shape.stmt)) if n is j)
+            why.append(f"join (node {ordinal}: {j.title()}) build "
+                       f"estimate {need / mib:.1f} MiB against the budget "
+                       f"{budget / mib:.1f} MiB: {cause}")
+            continue
+        shape.streams.append(bs)
+        t = _choose_tile(shape, budget)
+        if t is not None:
+            return t
+    t = _MIN_TILE
+    _retile(shape, t)
+    why.append(f"one {t}-row tile's step is estimated at "
+               f"{_step_bytes(shape) / mib:.1f} MiB against the budget "
+               f"{budget / mib:.1f} MiB")
+    for bs in shape.streams:
+        bs.untile()
+    shape.streams.clear()
+    shape.decline = "; ".join(why)
+    return None
+
+
+def _build_stream(join: N.PJoin, shape: _TileShape, session,
+                  budget: int):
+    """(a ``_BuildStream`` for ``join``, "") where its build can stream
+    into a direct table, else (None, why): a lookup that holds no pair
+    buffer (an inner or left join with a unique build, a semi or anti
+    join with no residual), no NOT IN whose build keys may be NULL (its
+    answer reads every build key), every build key a plain integer
+    column with a proven span (plan/joincap.py ``direct_box``), a table
+    of int32 slots, a build that is itself a spine of lookups ending at
+    a scan (``_spine_walk``), and its table, the tables streamed before
+    it and one build tile within the budget (the largest such tile)."""
+    from cloudberry_tpu.plan.joincap import direct_box
+
+    if join.expands:
+        return None, "the join expands pairs"
+    if join.kind == "anti" and join.null_aware \
+            and join.build_key_valid is not None:
+        return None, "a NOT IN whose build keys may be NULL"
+    box = direct_box(join, session.catalog)
+    if box is None:
+        return None, "no proven key span"
+    slots = math.prod(span for _, span in box)
+    if slots >= 1 << 31:
+        return None, f"its key span {slots} passes a table's int32 slots"
+    walk = _spine_walk(join.build)
+    if walk is None or any(isinstance(n, N.PJoin) and n.expands
+                           for n in walk[0]):
+        return None, "its build has no streamable shape"
+    carried = join.build_payload if join.kind in ("inner", "left") else ()
+    if not set(carried) <= set(join.build.names):
+        return None, "its payload is not columns of its build"
+    bshape = _TileShape(None, [], *walk, mode="build")
+    bshape.partial_plan = join.build
+    payload = [(n, np.dtype(join.build.field(n).type.np_dtype))
+               for n in carried]
+    bs = _BuildStream(join, bshape, box, payload,
+                      whole_rows=bshape.stream.capacity)
+    held = sum(b.table_bytes for b in shape.streams) + bs.table_bytes
+    t = _MAX_TILE
+    while t >= _MIN_TILE:
+        _retile(bshape, t)
+        if held + bs.step_bytes() <= budget:
+            bs.tile_rows = t
+            return bs, ""
+        t >>= 1
+    bs.untile()
+    return None, (f"its table of {bs.table_bytes / (1 << 20):.1f} MiB and "
+                  "one build tile do not fit")
 
 
 # --------------------------------------------------------------- execution
@@ -546,11 +742,12 @@ class _TileTimer:
     EXPLAIN ANALYZE's tiled trailer. Bounded by construction: one
     fixed-size histogram, and spans ride the trace's own cap."""
 
-    def __init__(self, session):
+    def __init__(self, session, **span_args):
         from cloudberry_tpu.obs.metrics import _Hist
 
         self._log = getattr(session, "stmt_log", None)
         self._h = _Hist()
+        self._args = span_args   # what each span carries besides its tile
 
     def step(self, idx: int):
         import contextlib
@@ -560,7 +757,7 @@ class _TileTimer:
         @contextlib.contextmanager
         def _cm():
             st = OT.stage("tile-step", "launch_seconds", log=self._log,
-                          tile=idx)
+                          tile=idx, **self._args)
             try:
                 with st:
                     yield
@@ -798,9 +995,14 @@ class AdaptiveTiledMixin:
             check_cancel()
             try:
                 batch = self._run_once()
-                X.count_join_shapes(
-                    getattr(self.session, "stmt_log", None),
-                    X.join_shapes(self._whole_plan()))
+                log = getattr(self.session, "stmt_log", None)
+                X.count_join_shapes(log, X.join_shapes(self._whole_plan()))
+                streams = getattr(self.shape, "streams", ())
+                if streams and log is not None:
+                    log.bump("tile_build_streams", len(streams))
+                    log.bump("tile_build_rows", self._build_rows)
+                    log.bump("tile_build_table_bytes",
+                             sum(bs.table_bytes for bs in streams))
                 return batch
             except X.ExecError as e:
                 msg = str(e)
@@ -901,19 +1103,20 @@ class TiledExecutable(AdaptiveTiledMixin):
         import threading
 
         self._run_lock = threading.Lock()
+        self._bprogs: dict = {}     # id(build stream) -> its programs
+        self._build_rows = 0        # rows the last run's build streams read
         self._refresh_report()
 
     def _refresh_report(self) -> None:
         shape = self.shape
         _retile(shape, self.tile_rows)
-        est = estimate_plan_memory(shape.partial_plan).peak_bytes
-        merge_bytes = _merge_bytes(shape)
         self.report = {
             "tiled": True,
             "stream_table": shape.stream.table_name,
             "tile_rows": self.tile_rows,
             "acc_capacity": shape.g_cap,
-            "est_step_bytes": est + merge_bytes,
+            "est_step_bytes": max(_step_bytes(shape),
+                                  _build_phase_bytes(shape)),
             # scan-pipeline staging charge (exec/scanpipe.py) plus the
             # dispatch window's extra in-flight tiles (exec/tilepipe.py)
             # — obs/capacity.record_tiled adds both to the statement's
@@ -930,14 +1133,23 @@ class TiledExecutable(AdaptiveTiledMixin):
                 self.session, shape.stream.table_name),
             "budget_bytes": self.budget,
         }
+        _stamp_build_streams(self.report, shape)
 
     # ------------------------------------------------------------ programs
 
-    def _resident_inputs(self) -> dict:
+    def _resident_inputs(self, bs: Optional[_BuildStream] = None) -> dict:
         """All step inputs except the tile: whole (non-stream) tables and
-        pruned store reads — exactly prepare_inputs minus the stream."""
-        scans = [s for s in X.scans_of(self._whole_plan())
-                 if s is not self.shape.stream]
+        pruned store reads — exactly prepare_inputs minus the stream. The
+        probe phase reads no scan of a streamed build; a build stream
+        ``bs`` reads its build's, but the one it streams."""
+        if bs is None:
+            away = {id(s) for b in self.shape.streams
+                    for s in X.scans_of(b.join.build)}
+            top, stream = self._whole_plan(), self.shape.stream
+        else:
+            away, top, stream = set(), bs.join.build, bs.shape.stream
+        scans = [s for s in X.scans_of(top)
+                 if s is not stream and id(s) not in away]
         store_scans = [s for s in scans if hasattr(s, "_store_parts")]
         names = sorted({s.table_name for s in scans
                         if not hasattr(s, "_store_parts")})
@@ -948,28 +1160,114 @@ class TiledExecutable(AdaptiveTiledMixin):
         # chain/finalize reference only aggregate outputs
         return self.shape.partial_plan
 
+    # -------------------------------------------------------- build streams
+
+    def _build_tables(self) -> list:
+        """Phase one of a statement with build streams: each streamed
+        build's table, in ``shape.streams`` order, under one
+        ``build-stream`` span. It takes no checkpoint: a fault here
+        restarts the statement, and a resumed probe stream streams its
+        builds again first (the tables are a function of the data)."""
+        from cloudberry_tpu.obs import trace as OT
+
+        if self._compiled is None:      # first run, or a retry re-planned
+            self._bprogs = {}
+        self._build_rows = 0
+        if not self.shape.streams:
+            return []
+        with OT.stage("build-stream", "launch_seconds"):
+            return [self._stream_build(bs) for bs in self.shape.streams]
+
+    def _build_programs(self, bs: _BuildStream):
+        """(prelude, step) of one build stream: the prelude computes the
+        build's own resident builds once; the step runs the build's spine
+        over one tile of its scan and scatters the selected rows into the
+        table it carries (donated), checked as the one-shot lookup is."""
+        shape = self.shape
+        plat = self._platform
+        join = bs.join
+        prelude_fn = _prelude_fn(bs.shape, plat, root=shape.partial_plan)
+
+        def step_fn(resident, prelude, tile, tile_n, table):
+            low = _step_lowerer(bs.shape, plat, resident, prelude, tile,
+                                tile_n, root=shape.partial_plan)
+            cols, sel = low.lower(join.build)
+            present, payload = table
+            with jax.named_scope(f"n{low.ref(join)}:{X.node_kind(join)}"):
+                keys = [low.expr(k, cols) for k in join.build_keys]
+                if join.build_key_valid is not None:
+                    sel = sel & low.expr(join.build_key_valid, cols)
+                rows = {n: X._as_column(cols[n], sel.shape[0])
+                        for n in payload}
+                for n, t in payload.items():
+                    if rows[n].dtype != t.dtype:
+                        raise X.ExecError(
+                            f"build stream of {low.label(join)}: column {n} "
+                            f"is {rows[n].dtype}, its table {t.dtype}")
+                present, payload, has_dup, past = K.direct_table_insert(
+                    present, payload, keys, sel, bs.box, rows, bs.unique)
+            checks = dict(low.checks)
+            checks[f"join build key past its proven span {bs.slots} "
+                   f"{low.label(join)}"] = past
+            if bs.unique:
+                checks[f"join build side has duplicate keys "
+                       f"{low.label(join)} but the planner assumed a unique "
+                       "(PK) build side"] = has_dup
+            return (present, payload), checks
+
+        # one program set of the statement's, as _compile counts its own
+        X.count_compile(self.session)
+        titles = X.node_titles(shape.partial_plan)
+        return (PG.jit(prelude_fn, titles, "tiled build prelude"),
+                PG.jit(step_fn, titles, "tiled build step",
+                       donate_argnums=TP.step_donation(self._platform)))
+
+    def _stream_build(self, bs: _BuildStream):
+        """Stream one build's scan through the tile feed (reader thread,
+        buffer pool, dispatch window) into its table; the table."""
+        progs = self._bprogs.get(id(bs))
+        if progs is None:
+            progs = self._bprogs[id(bs)] = self._build_programs(bs)
+        prelude_fn, step_fn = progs
+        resident = self._resident_inputs(bs)
+        prelude, pchecks = prelude_fn(resident)
+        X.raise_checks(pchecks)
+        table = bs.empty_table()
+        timer = _TileTimer(self.session, phase="build")
+        pipe = TP.TilePipe(self.session, TP.effective_window(
+            self.session.config, self._platform))
+        feed = _tile_feed(bs.shape.stream, self.session, bs.tile_rows,
+                          min_depth=pipe.window,
+                          span_args={"phase": "build"})
+        n = 0
+        try:
+            for tile, tile_n in feed:
+                fault_point("tile_step")
+                with timer.step(n):
+                    table, checks = step_fn(
+                        resident, prelude, tile,
+                        jnp.asarray(tile_n, dtype=jnp.int32), table)
+                    pipe.submit(n, checks)
+                n += 1
+                self._build_rows += int(tile_n)
+            pipe.drain_all()
+        finally:
+            SP.close_feed(feed)
+        return table
+
     def _compile(self):
         if self._compiled is not None:
             return self._compiled
         shape = self.shape
         plat = self._platform
-
-        def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, root=shape.partial_plan)
-            outs = [low.lower_shared(b) for b in shape.builds]
-            return outs, low.checks
-
+        prelude_fn = _prelude_fn(shape, plat)
         group_names = [n for n, _ in shape.agg.group_keys]
         specs = shape.merge_specs
         g_cap = shape.g_cap
 
         def step_fn(resident, prelude, tile, tile_n, acc):
-            tables = dict(resident)
-            tables["$tile"] = tile
-            replace = {id(b): prelude[i]
-                       for i, b in enumerate(shape.builds)}
-            low = X.Lowerer(tables, platform=plat, replace=replace,
-                            stream=shape.stream, tile_n=tile_n)
+            low = _step_lowerer(shape, plat, resident, prelude, tile,
+                                tile_n)
             pcols, psel = low.lower(shape.partial_plan)
             checks = dict(low.checks)
             acc_cols, acc_sel = acc
@@ -1061,13 +1359,15 @@ class TiledExecutable(AdaptiveTiledMixin):
         from cloudberry_tpu.obs import trace as OT
 
         # children of ``launch`` on the statement thread (obs/trace.py):
-        # prelude | (feed-wait | h2d | tile-step ⊃ drain-stall)* |
-        # finalize
+        # [build-stream] | prelude | (feed-wait | h2d | tile-step ⊃
+        # drain-stall)* | finalize
+        tables = self._build_tables()
         with OT.stage("prelude", "launch_seconds"):
             prelude_fn, step_fn, finalize_fn = self._compile()
             resident = self._resident_inputs()
             prelude, pchecks = prelude_fn(resident)
             X.raise_checks(pchecks)
+            prelude = _with_tables(self.shape, prelude, tables)
 
             # mid-statement recovery (exec/recovery.py): resume from the
             # last K-tile checkpoint instead of replaying the whole
@@ -1186,19 +1486,11 @@ class TopNTiledExecutable(TiledExecutable):
         m = shape.g_cap
         mleaf, msort = shape.finalize["mleaf"], shape.finalize["msort"]
         names = [f.name for f in shape.partial_plan.fields]
-
-        def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, root=shape.partial_plan)
-            outs = [low.lower_shared(b) for b in shape.builds]
-            return outs, low.checks
+        prelude_fn = _prelude_fn(shape, plat)
 
         def step_fn(resident, prelude, tile, tile_n, acc):
-            tables = dict(resident)
-            tables["$tile"] = tile
-            replace = {id(b): prelude[i]
-                       for i, b in enumerate(shape.builds)}
-            low = X.Lowerer(tables, platform=plat, replace=replace,
-                            stream=shape.stream, tile_n=tile_n)
+            low = _step_lowerer(shape, plat, resident, prelude, tile,
+                                tile_n)
             pcols, psel = low.lower(shape.partial_plan)
             checks = dict(low.checks)
             acc_cols, acc_sel = acc
@@ -1257,14 +1549,14 @@ class SortTiledExecutable(TiledExecutable):
     def _refresh_report(self) -> None:
         shape = self.shape
         _retile(shape, self.tile_rows)
-        est = estimate_plan_memory(shape.partial_plan).peak_bytes
         self.report = {
             "tiled": True,
             "mode": "sort",
             "stream_table": shape.stream.table_name,
             "tile_rows": self.tile_rows,
             "acc_capacity": 0,
-            "est_step_bytes": est + _merge_bytes(shape),
+            "est_step_bytes": max(_step_bytes(shape),
+                                  _build_phase_bytes(shape)),
             "est_pipeline_bytes": SP.queue_charge_bytes(
                 shape.stream, self.tile_rows, self.session.config)
             + TP.window_charge_bytes(
@@ -1274,6 +1566,7 @@ class SortTiledExecutable(TiledExecutable):
                 self.session, shape.stream.table_name),
             "budget_bytes": self.budget,
         }
+        _stamp_build_streams(self.report, shape)
 
     def _compile(self):
         if self._compiled is not None:
@@ -1282,19 +1575,11 @@ class SortTiledExecutable(TiledExecutable):
         plat = self._platform
         sort = shape.sortnode
         names = [f.name for f in sort.child.fields]
-
-        def prelude_fn(tables):
-            low = X.Lowerer(tables, platform=plat, root=shape.partial_plan)
-            outs = [low.lower_shared(b) for b in shape.builds]
-            return outs, low.checks
+        prelude_fn = _prelude_fn(shape, plat)
 
         def step_fn(resident, prelude, tile, tile_n):
-            tables = dict(resident)
-            tables["$tile"] = tile
-            replace = {id(b): prelude[i]
-                       for i, b in enumerate(shape.builds)}
-            low = X.Lowerer(tables, platform=plat, replace=replace,
-                            stream=shape.stream, tile_n=tile_n)
+            low = _step_lowerer(shape, plat, resident, prelude, tile,
+                                tile_n)
             pcols, psel = low.lower(shape.partial_plan)
             n = psel.shape[0]
             keys = []
@@ -1319,11 +1604,13 @@ class SortTiledExecutable(TiledExecutable):
         recovery ctx) as host arrays."""
         from cloudberry_tpu.exec import recovery as R
 
+        tables = self._build_tables()
         prelude_fn, step_fn = self._compile()
         shape = self.shape
         resident = self._resident_inputs()
         prelude, pchecks = prelude_fn(resident)
         X.raise_checks(pchecks)
+        prelude = _with_tables(shape, prelude, tables)
 
         ctx = R.begin(self, dist=False)
         names = [f.name for f in shape.sortnode.child.fields]
@@ -1524,6 +1811,62 @@ def window_chunk_pass(run, root, names, cols, karr, npk, cap):
     return final, n_chunks
 
 
+def _prelude_fn(shape: _TileShape, plat: str,
+                root: Optional[N.PlanNode] = None):
+    """The prelude program of a tile stream: every spine join's build
+    computed once, but a streamed build's (its table is built before).
+    ``root``: the plan its nodes are numbered in (``shape``'s partial
+    plan, or the statement's where ``shape`` is a build's own)."""
+    streamed = {id(bs.join.build) for bs in shape.streams}
+    root = root or shape.partial_plan
+
+    def prelude_fn(tables):
+        low = X.Lowerer(tables, platform=plat, root=root)
+        outs = [None if id(b) in streamed else low.lower_shared(b)
+                for b in shape.builds]
+        return outs, low.checks
+    return prelude_fn
+
+
+def _step_lowerer(shape: _TileShape, plat: str, resident, prelude, tile,
+                  tile_n, root: Optional[N.PlanNode] = None) -> X.Lowerer:
+    """The lowerer of one step over ``tile`` of ``shape``'s stream:
+    ``prelude`` (one entry a build) stands for each build computed once,
+    or for a streamed build's table, which its join reads."""
+    tables = dict(resident)
+    tables["$tile"] = tile
+    streamed = {id(bs.join.build): bs for bs in shape.streams}
+    replace, direct = {}, {}
+    for b, out in zip(shape.builds, prelude):
+        bs = streamed.get(id(b))
+        if bs is None:
+            replace[id(b)] = out
+        else:
+            direct[id(bs.join)] = (bs.box, out)
+    return X.Lowerer(tables, platform=plat, root=root, replace=replace,
+                     stream=shape.stream, tile_n=tile_n,
+                     direct_tables=direct)
+
+
+def _with_tables(shape: _TileShape, prelude: list, tables: list) -> list:
+    """The prelude's outputs with each streamed build's table in its
+    place (the prelude computes none of those)."""
+    if not shape.streams:
+        return prelude
+    by_build = {id(bs.join.build): t for bs, t in zip(shape.streams, tables)}
+    return [by_build.get(id(b), out) for b, out in zip(shape.builds, prelude)]
+
+
+def _stamp_build_streams(report: dict, shape: _TileShape) -> None:
+    """The run report's account of the build streams: for each, the
+    table it streams, its tile, and its table's bytes."""
+    if shape.streams:
+        report["build_streams"] = [
+            {"table": bs.shape.stream.table_name, "tile_rows": bs.tile_rows,
+             "slots": bs.slots, "table_bytes": bs.table_bytes}
+            for bs in shape.streams]
+
+
 def _leaf_of(root: N.PlanNode) -> N.PlanNode:
     cur = root
     while not isinstance(cur, _AccLeaf):
@@ -1541,7 +1884,11 @@ def _raise_tile_checks(checks: dict, tile_idx: int) -> None:
     check_cancel()
     for msg, bad in checks.items():
         if bool(np.asarray(bad).any()):
-            raise X.ExecError(f"[tile {tile_idx}] {msg}")
+            # (the class the one-shot lowering's check raises, as
+            # X.raise_checks gives it: a semantic error, never retried)
+            cls = X.DuplicateBuildKeyError if "duplicate keys" in msg \
+                else X.ExecError
+            raise cls(f"[tile {tile_idx}] {msg}")
 
 
 def _expr_dict(plan: N.PlanNode, e: ex.Expr):
@@ -1570,7 +1917,8 @@ def _empty_tile(scan: N.PScan, tile_rows: int) -> dict:
 
 
 def _tile_feed(scan: N.PScan, session, tile_rows: int,
-               skip_rows: int = 0, min_depth: int = 1):
+               skip_rows: int = 0, min_depth: int = 1,
+               span_args: Optional[dict] = None):
     """The single-node tile feed: (tile dict of padded arrays, n_valid)
     items, wrapped in the asynchronous scan pipeline when
     ``config.scan_pipeline`` enables it (exec/scanpipe.py — prefetch +
@@ -1596,7 +1944,8 @@ def _tile_feed(scan: N.PScan, session, tile_rows: int,
     # tiles in flight — a prefetch queue shallower than W would starve
     # the window it exists to feed
     return SP.maybe_pipeline(gen, session.config, device_stage=True,
-                             stats=stats, min_depth=min_depth)
+                             stats=stats, min_depth=min_depth,
+                             span_args=span_args)
 
 
 def _ram_tiles(scan: N.PScan, session, tile_rows: int,

@@ -208,9 +208,16 @@ _INTEGERS = (DType.INT32, DType.INT64, DType.DATE)
 
 def _key_span(scan: N.PScan, phys: str, catalog, cold: bool = False):
     """How many values the scan's integer column can hold (max - min + 1),
-    from a range that cannot be narrower than the rows the scan reads,
-    else None. A store scan: the zone maps of the micro-partitions it is
-    bound to, each written with its file. A table in RAM: the range
+    else None (``_key_range``)."""
+    rng = _key_range(scan, phys, catalog, cold)
+    return rng and rng[1] - rng[0] + 1
+
+
+def _key_range(scan: N.PScan, phys: str, catalog, cold: bool = False):
+    """The least and the greatest value the scan's integer column can
+    hold, from a range that cannot be narrower than the rows the scan
+    reads, else None. A store scan: the zone maps of the micro-partitions
+    it is bound to, each written with its file. A table in RAM: the range
     ``Table.set_data`` recomputes with every change of the data; with
     ``cold``, a table not yet read in: the range its manifest gives over
     every partition of the version the plan is keyed on."""
@@ -237,32 +244,41 @@ def _key_span(scan: N.PScan, phys: str, catalog, cold: bool = False):
     # (a RAM table's range is kept in float64: exact under 2**53)
     if not (-2 ** 53 < lo <= hi < 2 ** 53):
         return None
-    return int(hi) - int(lo) + 1
+    return int(lo), int(hi)
+
+
+def direct_box(join: N.PJoin, catalog):
+    """The proven range of each of a lookup join's build keys, as
+    ``(least value, span)`` pairs (``_key_range``; a cold table's by its
+    manifest, so that a backend that has not read it in yet proves what
+    one that has does), every key a plain integer column that traces
+    back to a scan; None where the join expands or a key has no proof.
+    A table laid over the box at ``key - least`` holds every build row
+    the scan can read (exec/tiled.py's build stream)."""
+    from cloudberry_tpu.plan.cost import col_origin
+
+    if join.expands:
+        return None
+    box = []
+    for k in join.build_keys:
+        if not isinstance(k, ex.ColumnRef) or k.dtype.base not in _INTEGERS:
+            return None
+        src = col_origin(join.build, k.name, unions=False)
+        rng = src and _key_range(src[0], src[1], catalog, cold=True)
+        if not rng:
+            return None
+        box.append((rng[0], rng[1] - rng[0] + 1))
+    return box
 
 
 def direct_span(join: N.PJoin, catalog) -> int:
     """How many values a lookup join's packed build keys can take, by
-    proof: the product of the keys' spans (``_key_span``; a cold table's
-    by its manifest, so that a backend that has not read it in yet
-    proves what one that has does), every key a plain integer column
-    that traces back to a scan. The kernel packs the keys with the
-    build's own ranges at run time, each no wider than its proven span,
-    so every packed key lies below the product. 0 where the join
-    expands or a key has no proof."""
-    from cloudberry_tpu.plan.cost import col_origin
-
-    if join.expands:
-        return 0
-    prod = 1
-    for k in join.build_keys:
-        if not isinstance(k, ex.ColumnRef) or k.dtype.base not in _INTEGERS:
-            return 0
-        src = col_origin(join.build, k.name, unions=False)
-        span = src and _key_span(src[0], src[1], catalog, cold=True)
-        if not span:
-            return 0
-        prod *= span
-    return prod
+    proof: the product of the keys' spans (``direct_box``). The kernel
+    packs the keys with the build's own ranges at run time, each no
+    wider than its proven span, so every packed key lies below the
+    product. 0 where the join expands or a key has no proof."""
+    box = direct_box(join, catalog)
+    return 0 if box is None else math.prod(span for _, span in box)
 
 
 def _group_ceiling(agg: N.PAgg, catalog):

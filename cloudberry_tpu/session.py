@@ -668,10 +668,9 @@ class Session:
             # analog, exec/tiled.py) first
             try:
                 est = check_admission(result.plan, self)
-            except ResourceError:
-                texe = self._plan_tiled_fallback(stmt, params, result.plan)
-                if texe is None:
-                    raise
+            except ResourceError as e:
+                texe = self._plan_tiled_fallback(stmt, params, result.plan,
+                                                 e)
                 # a cached executable's report predates the pool's
                 # current residency — re-stamp before charging the
                 # capacity plane
@@ -715,9 +714,11 @@ class Session:
         with self._slot(cost):
             return self._obs_launch(runner)
 
-    def _plan_tiled_fallback(self, stmt, params, plan):
-        """The tiled executable for an over-budget plan, or None."""
-        from cloudberry_tpu.exec.tiled import plan_tiled
+    def _plan_tiled_fallback(self, stmt, params, plan, err):
+        """The tiled executable for an over-budget plan; where there is
+        none, the admission error ``err`` saying why the tiled executor
+        declined (``tiled.declined``)."""
+        from cloudberry_tpu.exec.tiled import declined, plan_tiled
         from cloudberry_tpu.plan.planner import plan_statement
 
         texe = plan_tiled(plan, self)
@@ -739,10 +740,13 @@ class Session:
             result2 = plan_statement(stmt, clone, params)
             self._verify_plan(result2.plan, "greedy-replan")
             texe = plan_tiled(result2.plan, clone)
-            if texe is not None:
-                # the clone only existed to plan greedy: runs must
-                # report (last_tiled_report) to the REAL session
-                texe.session = self
+            if texe is None:
+                raise declined(err, result2.plan) from None
+            # the clone only existed to plan greedy: runs must
+            # report (last_tiled_report) to the REAL session
+            texe.session = self
+        if texe is None:
+            raise declined(err, plan) from None
         return texe
 
     def _slot(self, cost: int):
@@ -848,13 +852,13 @@ class Session:
                     self._vmem.grow(stmt_id, est.peak_bytes)  # …red-zone ok
                 except RunawayError:
                     raise  # red-zone termination, never a spill case
-                except ResourceError:
-                    from cloudberry_tpu.exec.tiled import plan_tiled
+                except ResourceError as err:
+                    from cloudberry_tpu.exec.tiled import declined, plan_tiled
 
                     joincap.drop(plan)  # a tile is its own capacity
                     texe = plan_tiled(plan, self)  # …or the plan spills
                     if texe is None:
-                        raise
+                        raise declined(err, plan) from None
                     return self._run_cached_tiled(ckey, texe, cfg_plan)
 
     def _check_topology_race(self, cfg_plan) -> None:

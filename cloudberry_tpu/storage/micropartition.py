@@ -73,6 +73,12 @@ MAGIC_ENC = b"CBMPENC1"  # TDE-encrypted container (utils/tde.py)
 # the cap because correctness never depends on a hit.
 _VERIFIED_CAP = 65536
 _verified: dict[tuple, bool] = {}
+# parsed footers of plaintext partition files, by (path, size, mtime):
+# a file is written once, and its footer's JSON (every column's stats,
+# bloom filter and string dictionary) costs tens of ms to parse at 2^20
+# rows — a tile feed reads the same partitions on every statement
+_FOOTERS_CAP = 64
+_footers: dict[tuple, dict] = {}
 
 
 def _compress(raw: bytes, codec: str) -> bytes:
@@ -255,6 +261,20 @@ def read_footer(path: str, cipher=None) -> dict:
     return json.loads(buf[-(len(MAGIC) + 4 + flen):-(len(MAGIC) + 4)])
 
 
+def _parsed_footer(path: str) -> dict:
+    """``read_footer`` of a plaintext file, parsed once a file identity
+    (``_footers``); callers read it and never change it."""
+    st = os.stat(path)
+    ident = (path, st.st_size, st.st_mtime_ns)
+    footer = _footers.get(ident)
+    if footer is None:
+        footer = read_footer(path)
+        if len(_footers) >= _FOOTERS_CAP:
+            _footers.clear()
+        _footers[ident] = footer
+    return footer
+
+
 def read_columns(path: str, names: Iterable[str] | None = None,
                  footer: dict | None = None,
                  cipher=None, pool=None,
@@ -283,7 +303,7 @@ def read_columns(path: str, names: Iterable[str] | None = None,
                 buf[-(len(MAGIC) + 4 + flen):-(len(MAGIC) + 4)])
     else:
         # plaintext: seek-based column projection (no whole-file read)
-        footer = footer or read_footer(path)
+        footer = footer or _parsed_footer(path)
         fh = open(path, "rb")
 
         def read_blob(enc, fh=fh):

@@ -36,6 +36,11 @@ from cloudberry_tpu.storage import iofault
 from cloudberry_tpu.storage import micropartition as mp
 from cloudberry_tpu.types import DType, Schema
 
+# TableStore._partition_meta's parsed (schema, nullable columns), by
+# (store root, table, version, manifest file inode, size, mtime)
+_PARTITION_METAS_CAP = 256
+_partition_metas: dict[tuple, tuple] = {}
+
 
 @dataclass
 class PartitionEntry:
@@ -231,6 +236,34 @@ class TableStore:
                                "storage.encryption_key configured")
             raw = self.cipher.decrypt(raw[8:])
         return json.loads(raw)
+
+    def _partition_meta(self, table: str, version: Optional[int]):
+        """(schema, nullable columns) of the manifest a partition read
+        follows, parsed once a manifest file (its path, inode, size and
+        mtime: a version is written once, and a table dropped and made
+        again writes its ``v1`` anew): the whole manifest, its string
+        dictionaries included, costs ~0.1 s to parse at SF1, and a tile
+        feed reads each partition of a table on its own."""
+        if version is None:
+            version = self.pinned.get(table)
+        v = self.current_version(table) if version is None else version
+        key = None
+        if v:
+            st = os.stat(os.path.join(self._mdir(table), f"v{v}.json"))
+            key = (self.root, table, v, st.st_ino, st.st_size,
+                   st.st_mtime_ns)
+            hit = _partition_metas.get(key)
+            if hit is not None:
+                return hit
+        man = self.read_manifest(table, v)
+        meta = (Schema(tuple(mp._field_from_json(j)
+                             for j in man["schema"])),
+                frozenset(man.get("nullable", [])))
+        if key is not None:
+            if len(_partition_metas) >= _PARTITION_METAS_CAP:
+                _partition_metas.clear()
+            _partition_metas[key] = meta
+        return meta
 
     def _commit(self, table: str, manifest: dict) -> int:
         """Atomically publish a new snapshot (single-coordinator commit).
@@ -637,9 +670,7 @@ class TableStore:
         from cloudberry_tpu.utils.faultinject import fault_point
 
         fault_point("store_read_partition")
-        man = self.read_manifest(table, version)
-        schema = Schema(tuple(mp._field_from_json(j) for j in man["schema"]))
-        nullable = set(man.get("nullable", []))
+        schema, nullable = self._partition_meta(table, version)
         tdir = os.path.join(self.root, table)
         names = list(columns) if columns is not None else list(schema.names)
         want = names + [f"$nn:{c}" for c in names if c in nullable]

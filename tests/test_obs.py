@@ -488,6 +488,20 @@ def test_served_statement_spans_where_the_work_happens(
     with Server(config=cfg) as srv, Client(srv.host, srv.port) as c:
         log = srv.session.stmt_log
         _served_trace(log, c, _STAGED_Q.format(5))      # cold: compiles
+        if path == "tiled":
+            # ``feed-wait`` is the consumer blocked on an empty feed: hold
+            # each tile on the reader thread, so that the feed is the
+            # slower side however fast a warm partition read is
+            from cloudberry_tpu.exec import tiled as T
+
+            store_tiles = T._store_tiles
+
+            def slow_tiles(*args, **kwargs):
+                for item in store_tiles(*args, **kwargs):
+                    time.sleep(0.05)
+                    yield item
+
+            monkeypatch.setattr(T, "_store_tiles", slow_tiles)
         before = _hist_counts(log)
         tr = _served_trace(log, c, _STAGED_Q.format(4))
         after = _hist_counts(log)

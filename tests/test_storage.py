@@ -150,3 +150,32 @@ def test_append_dict_must_extend(tmp_path, schema):
     cols, _, dd = store.scan("t")
     assert dd["s"].values[-1] == "dd"
     assert len(cols["k"]) == 100
+
+
+def test_partition_reads_parse_manifest_and_footer_once(tmp_path, schema,
+                                                        monkeypatch):
+    """A tile feed reads each partition on its own, statement after
+    statement: the manifest's schema and each file's footer are parsed
+    once a file, and a new version is parsed anew."""
+    import json
+
+    store = TableStore(str(tmp_path))
+    d1, dicts = _data(500)
+    store.append("t", d1, schema, dicts, rows_per_partition=200)
+    parts = store.read_manifest("t")["partitions"]
+    parsed = []
+    loads = json.loads
+    monkeypatch.setattr(json, "loads",
+                        lambda raw, **kw: parsed.append(1) or loads(raw))
+    for _ in range(3):
+        cols, _ = store.read_partitions("t", parts[:1], ["k", "v"])
+        np.testing.assert_array_equal(cols["k"], d1["k"][:200])
+    assert len(parsed) == 2           # the manifest and one footer
+    store.read_partitions("t", parts[1:2], ["k"])
+    assert len(parsed) == 3           # the second file's footer
+    d2, _ = _data(100, seed=1)
+    d2["s"] = dicts["s"].encode(np.asarray(["aa"] * 100))
+    store.append("t", d2, schema, dicts)
+    parsed.clear()
+    store.read_partitions("t", parts[:1], ["k"])
+    assert len(parsed) == 1           # version 2's manifest
