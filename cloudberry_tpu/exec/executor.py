@@ -64,9 +64,10 @@ class Executable:
     # on a scan) among the plan's joins (join_shapes), fixed when the
     # plan is lowered and counted on every launch
     join_shapes: tuple = (0, 0, 0, 0, 0)
-    # (rows in, capacity, keys carried, sort words) summed over the
-    # plan's grouped aggregates (agg_shapes), fixed and counted likewise
-    agg_shapes: tuple = (0, 0, 0, 0)
+    # (rows in, capacity, keys carried, sort words, direct tables) summed
+    # over the plan's grouped aggregates (agg_shapes), fixed and counted
+    # likewise
+    agg_shapes: tuple = (0, 0, 0, 0, 0)
 
 
 def execute(plan: N.PlanNode, session) -> ColumnBatch:
@@ -732,25 +733,29 @@ def count_join_shapes(log, shapes: tuple) -> None:
 
 def agg_shapes(plan: N.PlanNode, platform: str) -> tuple:
     """(the capacity their rows arrive at, their own capacity, the keys
-    they carry rather than sort, the words their grouping sorts compare)
-    summed over ``plan``'s grouped aggregates: how far below its input
-    the planner could hold an aggregate's output, and with it whatever
-    runs above (plan/joincap.py, the proven ceilings), and what the
-    compiler's sorts read (plan/fdep.py, ``sort_words``)."""
+    they carry rather than sort, the words their grouping sorts compare,
+    how many sum into a direct-address table) summed over ``plan``'s
+    grouped aggregates: how far below its input the planner could hold
+    an aggregate's output, and with it whatever runs above
+    (plan/joincap.py, the proven ceilings), what the compiler's sorts
+    read (plan/fdep.py, ``sort_words``), and how many sort nothing at
+    all (``PAgg.direct``)."""
     aggs = _dedupe_nodes(nd for nd in all_nodes(plan)
                          if isinstance(nd, N.PAgg) and nd.group_keys)
     return (sum(N.capacity_of(nd.child) for nd in aggs),
             sum(nd.capacity for nd in aggs),
             sum(len(nd.carried) for nd in aggs),
-            sum(sort_words(nd, dense_strategy(platform)) for nd in aggs))
+            sum(sort_words(nd, dense_strategy(platform)) for nd in aggs),
+            sum(nd.direct for nd in aggs))
 
 
 def sort_words(agg: N.PAgg, strategy: str) -> int:
     """The u32 words a grouped aggregate's sort compares (its rows'
     positions, which every sort carries last, not counted): none on the
-    perfect-hash path, one for a 32-bit packed word, two for a 64-bit
-    one, else each sorted key's own (``kernels.sort_key_word``)."""
-    if dense_domain(agg, strategy) is not None:
+    perfect-hash path or the direct-address one, one for a 32-bit
+    packed word, two for a 64-bit one, else each sorted key's own
+    (``kernels.sort_key_word``)."""
+    if agg.direct or dense_domain(agg, strategy) is not None:
         return 0
     if agg.pack_bits:
         return agg.pack_bits // 32
@@ -761,22 +766,27 @@ def sort_words(agg: N.PAgg, strategy: str) -> int:
 def count_agg_shapes(log, shapes: tuple) -> None:
     """One launch's grouped aggregates on the engine's counters:
     ``launch_agg_rows_in`` and ``launch_agg_capacity``,
-    ``launch_agg_keys_carried`` and ``launch_agg_sort_words`` (a program
-    that groups nothing bumps none)."""
-    rows_in, capacity, carried, words = shapes
+    ``launch_agg_keys_carried``, ``launch_agg_sort_words`` and
+    ``launch_agg_direct`` (a program that groups nothing bumps none)."""
+    rows_in, capacity, carried, words, direct = shapes
     if log is not None and rows_in:
         log.bump("launch_agg_rows_in", rows_in)
         log.bump("launch_agg_capacity", capacity)
         for name, n in (("launch_agg_keys_carried", carried),
-                        ("launch_agg_sort_words", words)):
+                        ("launch_agg_sort_words", words),
+                        ("launch_agg_direct", direct)):
             if n:
                 log.bump(name, n)
 
 
 def dense_strategy(platform: str) -> str:
-    """How a perfect-hash aggregate lowers: scatter (segment ops) lowers
-    well on CPU; TPU serializes large scatters, so it gets unrolled
-    masked reductions instead."""
+    """How a perfect-hash aggregate lowers: scatter (segment ops) on CPU;
+    unrolled masked reductions on TPU, where a domain of at most 64 cells
+    is a few fused sweeps. (A scatter is no serialized loop there: on a
+    TPU v5e the slot map's scatter-add took Q13's device time from
+    1,193.6 to 211.3 ms, 6M rows scattered into a 1.5M-slot table in
+    7.0 ms, and
+    ``kernels.group_aggregate_direct`` sums by scatter-adds alone.)"""
     return "segment" if platform == "cpu" else "reduce"
 
 
@@ -1845,6 +1855,21 @@ class Lowerer:
 
         key_cols = {name: self.expr(e, cols)
                     for name, e in node.group_keys}
+        if node.direct:
+            # a proven key box no wider than the rows: each row's group
+            # is its slot in a table over the box, no sort; groups never
+            # outnumber the slots, so no overflow check
+            out_keys, out_aggs, out_sel, past = K.group_aggregate_direct(
+                key_cols, agg_values, agg_specs, sel, node.direct_box,
+                node.capacity, carried=node.carried,
+                value_bits={n: (b, sg) for n, b, sg in node.sum_bits})
+            self.checks[
+                f"aggregation key past its proven span "
+                f"{math.prod(s for _, s in node.direct_box)} (or a summed "
+                f"value past its proven width) {self.label(node)}"] = past
+            for name, div in post_scale.items():
+                out_aggs[name] = out_aggs[name] / div
+            return {**out_keys, **out_aggs}, out_sel
         out_keys, out_aggs, out_sel, n_groups = K.group_aggregate(
             key_cols, agg_values, agg_specs, sel, node.capacity,
             pack_bits=node.pack_bits, carried=node.carried)

@@ -2,8 +2,12 @@
 
 Design discipline (SURVEY.md §7.3): XLA requires static shapes, so
 - filters AND into the selection mask (no compaction);
-- group-by is sort-based: lexsort → boundary flags → segment reductions.
-  Exact (no hash collisions), and sort/scan map well onto the VPU;
+- group-by takes one of two forms, and the planner picks (``PAgg.direct``):
+  where the keys' box is proven and holds no more slots than the rows
+  that arrive and the groups the node may emit, a scatter-add of each
+  row into its slot of a DIRECT-ADDRESS table over the box
+  (``group_aggregate_direct``); else sort-based: lexsort → boundary
+  flags → segment reductions. Both exact (no hash collisions);
 - a unique-build (PK–FK) join finds each probe row's build row in one of
   two forms, and the planner picks (``PJoin.direct_lookup``): where the
   build keys' span is proven and no larger than the largest array the
@@ -396,9 +400,13 @@ def group_aggregate(
     silent (the capacity-flow-control discipline of
     ic_udpifc.c:3018 applied to shapes).
 
-    Scatter-free segmented reduction (TPU serializes big scatters): every
-    per-group aggregate is a cumulative-sum DIFFERENCE between consecutive
-    group boundaries — pure sort/scan/gather, the VPU formulation.
+    Scatter-free segmented reduction: every per-group aggregate is a
+    cumulative-sum DIFFERENCE between consecutive group boundaries — pure
+    sort/scan/gather. Where the keys' box is proven and small enough the
+    planner takes ``group_aggregate_direct`` instead: no sort at all, and
+    a scatter is no serialized loop on TPU (on a v5e a slot map's
+    scatter-add took Q13's device time from 1,193.6 to 211.3 ms; 6M rows
+    scatter into a 1.5M-slot table in 7.0 ms).
     """
     lay = group_layout(key_cols, sel, out_capacity, pack_bits, carried)
     names, key_list = lay.names, [key_cols[n] for n in lay.names]
@@ -476,10 +484,12 @@ def group_aggregate_dense(
     strategy='reduce' (TPU): unrolled per-cell masked tree-reductions.
     strategy='segment' (CPU): scatter-based segment ops.
 
-    No sort and — crucially — no scatter: XLA lowers large scatters to a
-    serialized update loop on TPU (measured ~150ms per 1.8M-row segment_sum),
-    while an unrolled per-cell masked tree-reduction is a fused VPU sweep.
-    Exact for int64 (tree reduction of exact adds). Returns (agg columns
+    No sort: on TPU an unrolled per-cell masked tree-reduction, a fused
+    sweep a cell, which for a domain of at most 64 cells needs no table.
+    (An earlier reading of ~150 ms per 1.8M-row segment_sum on TPU does
+    not hold for today's scatters: on a v5e 6M rows scatter into a
+    1.5M-slot table in 7.0 ms.) Exact for int64 (tree
+    reduction of exact adds). Returns (agg columns
     indexed by cell id, occupancy mask); key reconstruction from cell id is
     the caller's job.
     """
@@ -549,6 +559,125 @@ def group_aggregate_dense(
         else:
             raise NotImplementedError(spec.func)
     return out, counts > 0
+
+
+def exact_table_sum(slot: jnp.ndarray, size: int, v: jnp.ndarray,
+                    counts: jnp.ndarray,
+                    value_bits: Optional[tuple[int, bool]] = None
+                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The exact int64 sum, a slot of a table of ``size``, of the integer
+    values ``v`` of the rows whose ``slot`` lies below ``size`` (the
+    others are dropped writes); ``counts``: the rows a slot holds. The
+    values are cut into words of b bits, b = 32 less the bits of the
+    row count, so no word's sum over every row can pass 2^32, and each
+    word is a u32 scatter-add; the words are recombined in int64 at the
+    table's size. Two's complement sums wrap alike, so the recombined
+    sum is the true one wherever that fits int64, negative values
+    included. ``value_bits``: (bits, signed), the width ``v`` fits by
+    proof, unsigned or two's complement; the words then cover ``v -
+    least`` only, least 0 or -2^(bits-1) (Q18's ``l_quantity``, 0..5,000
+    cents, 13 bits: two words of 9 at 6M rows, not eight), and ``counts
+    × least`` is added back. A width, not a range: a table whose values
+    move inside it keeps its program. Returns (the sums, a row's value
+    outside ``value_bits``: the proof is broken)."""
+    b = 32 - int(v.shape[0]).bit_length()
+    v = v.astype(jnp.int64)
+    width, least = 64, 0
+    if value_bits is not None:
+        width, signed = value_bits
+        least = -(1 << (width - 1)) if signed else 0
+    u = (v - least).view(jnp.uint64)
+    outside = (u >> jnp.uint64(width)) != 0 if width < 64 \
+        else jnp.zeros(v.shape, jnp.bool_)
+    total = jnp.zeros(size, jnp.uint64)
+    mask = jnp.uint64((1 << b) - 1)
+    for at in range(0, max(width, 1), b):
+        word = ((u >> jnp.uint64(at)) & mask).astype(jnp.uint32)
+        acc = jnp.zeros(size, jnp.uint32).at[slot].add(word, mode="drop")
+        total = total + (acc.astype(jnp.uint64) << jnp.uint64(at))
+    return total.view(jnp.int64) + counts * least, outside
+
+
+def group_aggregate_direct(
+    key_cols: Columns,
+    agg_values: dict[str, Optional[jnp.ndarray]],
+    aggs: Sequence[AggSpec],
+    sel: jnp.ndarray,
+    box: Sequence[tuple[int, int]],
+    out_capacity: int,
+    carried: Sequence[str] = (),
+    value_bits: Optional[dict] = None,
+) -> tuple[Columns, Columns, jnp.ndarray, jnp.ndarray]:
+    """Grouped aggregation into a direct-address table, no sort: the
+    keys that are not ``carried`` lie in a box proven at plan time
+    (``PAgg.direct_box``: one ``(least value, span)`` a key), so a
+    row's group is its slot in a table of the box's size
+    (``direct_slots``), and every aggregate is a scatter-add over the
+    slots: counts in int32, integer sums exactly (``exact_table_sum``;
+    ``value_bits``: an aggregate's proven argument width, by its output
+    name). A carried key is read at whichever row of its group
+    the table kept (the sorted keys determine it). The planner engages
+    it where the box holds no more slots than ``out_capacity`` (and the
+    rows), so every group has its slot and none can overflow.
+
+    Returns (out_key_cols, out_agg_cols, out_sel, past): groups in
+    ascending key order at their slots, NOT compacted to the front (the
+    occupied slots are ``out_sel``; pad beyond the box); ``past`` where
+    a selected row's keys lie outside the box or a summed value outside
+    its ``value_bits`` (the proof is broken: the answer is not to be
+    used)."""
+    names = [n for n in key_cols if n not in carried]
+    slot, inside = direct_slots([key_cols[n] for n in names], box)
+    size = math.prod(span for _, span in box)
+    past = (sel & ~inside).any()
+    slot = jnp.where(sel, slot, size)       # (outside: already ``size``)
+    counts = jnp.zeros(size, jnp.int32).at[slot].add(
+        1, mode="drop").astype(jnp.int64)
+    occupied = counts > 0
+    widths = value_bits or {}
+
+    out_aggs: Columns = {}
+    for spec in aggs:
+        v = agg_values.get(spec.out_name)
+        if spec.func == "count":
+            out = counts
+        elif spec.func == "count_nn":
+            out = jnp.zeros(size, jnp.int32).at[slot].add(
+                v.astype(jnp.int32), mode="drop").astype(jnp.int64)
+        elif spec.func in ("sum", "avg"):
+            total, outside = exact_table_sum(slot, size, v, counts,
+                                             widths.get(spec.out_name))
+            past = past | (sel & outside).any()
+            if spec.func == "avg":
+                out = total.astype(jnp.float64) / jnp.maximum(counts, 1)
+            else:
+                # (the sort path's sum keeps the argument's type)
+                out = total.astype(jnp.where(sel, v, 0).dtype)
+        else:
+            raise NotImplementedError(spec.func)
+        out_aggs[spec.out_name] = out
+
+    out_keys: Columns = {}
+    cell = jax.lax.iota(jnp.int64, size)
+    stride = size
+    for n, (lo, span) in zip(names, box):
+        stride //= span
+        k = lo + (cell // stride) % span
+        out_keys[n] = jnp.where(occupied, k, 0).astype(key_cols[n].dtype)
+    if carried:
+        rows = jax.lax.iota(jnp.int32, sel.shape[0])
+        first = jnp.zeros(size, jnp.int32).at[slot].set(rows, mode="drop")
+        for n in carried:
+            col = key_cols[n][first]
+            out_keys[n] = jnp.where(occupied, col,
+                                    jnp.zeros((), dtype=col.dtype))
+
+    def pad(c):
+        return jnp.pad(c, (0, out_capacity - size))
+
+    out_keys = {n: pad(out_keys[n]) for n in key_cols}
+    return (out_keys, {n: pad(c) for n, c in out_aggs.items()},
+            pad(occupied), past)
 
 
 def global_aggregate(

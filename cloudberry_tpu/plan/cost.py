@@ -202,9 +202,11 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
     """Prove 32-bit packed join keys from build-side column statistics,
     and one packed word (32 or 64 bits) for a grouped aggregate's keys
     and for a sort's; stamp each lookup join's proven key span
-    (``PJoin.direct_span``, plan/joincap.py) and each grouped
-    aggregate's keys that the others determine (``PAgg.carried``,
-    plan/fdep.py), which its word leaves out.
+    (``PJoin.direct_span``, plan/joincap.py), each grouped aggregate's
+    keys that the others determine (``PAgg.carried``, plan/fdep.py),
+    which its word leaves out, and the proven box of the others and the
+    widths of its sums (``PAgg.direct_box``, ``PAgg.sum_bits``,
+    plan/joincap.py).
 
     The kernels pack key tuples into one order-preserving integer using the
     BUILD side's runtime ranges (kernels.pack_with_ranges); probe values
@@ -214,7 +216,8 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
     pack does too — and the sort/search/collective lanes halve. TPC-H keys
     stay 32-bit provable through SF100 (orderkey max 6e9·0.1 < 2^31)."""
     from cloudberry_tpu.plan.fdep import carried_keys
-    from cloudberry_tpu.plan.joincap import direct_span
+    from cloudberry_tpu.plan.joincap import (direct_agg_box, direct_span,
+                                             sum_bits)
     from cloudberry_tpu.types import DType
 
     # value-space spans only translate to pack-space for types whose
@@ -276,6 +279,8 @@ def annotate_pack_bits(plan: N.PlanNode, catalog) -> None:
             n.carried = carried_keys(n, catalog)
             n.pack_bits = word_bits(n.child, [e for k, e in n.group_keys
                                               if k not in n.carried])
+            n.direct_box = direct_agg_box(n, catalog)
+            n.sum_bits = sum_bits(n, catalog) if n.direct_box else ()
         if isinstance(n, N.PSort):
             # (a string sorts by its collation rank, not by the code the
             # statistics are of)

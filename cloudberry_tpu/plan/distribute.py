@@ -761,7 +761,8 @@ class Distributor:
         # (the final stage groups the same columns' values: one proof)
         partial = N.PAgg(child, node.group_keys, partial_aggs,
                          capacity=min(node.capacity, cap), mode="partial",
-                         pack_bits=node.pack_bits, carried=node.carried)
+                         pack_bits=node.pack_bits, carried=node.carried,
+                         direct_box=node.direct_box)
         partial.fields = [N.PlanField(n, e.dtype, _f_dict(child, e))
                           for n, e in node.group_keys] + \
                          [N.PlanField(n, c.dtype, None)
@@ -805,7 +806,8 @@ class Distributor:
         final_keys = [(n, _field_ref(motion, n)) for n, _ in node.group_keys]
         final = N.PAgg(motion, final_keys, final_aggs,
                        capacity=min(node.capacity, mcap), mode="final",
-                       pack_bits=node.pack_bits, carried=node.carried)
+                       pack_bits=node.pack_bits, carried=node.carried,
+                       direct_box=node.direct_box)
         final.fields = [N.PlanField(n, e.dtype, _f_dict(motion, e))
                         for n, e in final_keys] + \
                        [N.PlanField(n, c.dtype, None) for n, c in final_aggs]

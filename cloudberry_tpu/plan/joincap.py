@@ -71,6 +71,12 @@ scans of one table do.
 One segment only: a distributed plan's capacities are per segment and
 its aggregates and Motions are sized by ``plan/distribute.py`` (ROADMAP
 S11).
+
+The same proofs lay tables over keys: a lookup join's build keys
+(``direct_box``, ``direct_span``) and a grouped aggregate's sorted keys
+(``direct_agg_box``, with ``sum_bits`` for its sums' arguments); the
+node decides whether the table is small enough to use
+(``PJoin.direct_lookup``, ``PAgg.direct``), at every segment count.
 """
 
 from __future__ import annotations
@@ -279,6 +285,95 @@ def direct_span(join: N.PJoin, catalog) -> int:
     product. 0 where the join expands or a key has no proof."""
     box = direct_box(join, catalog)
     return 0 if box is None else math.prod(span for _, span in box)
+
+
+# what ``kernels.group_aggregate_direct`` computes: counts and exact
+# integer sums (a float sum in scatter order would round otherwise than
+# the sort path's)
+_DIRECT_FUNCS = ("count", "count_nn", "sum", "avg")
+_EXACT = _INTEGERS + (DType.DECIMAL,)
+
+
+def direct_agg_box(agg: N.PAgg, catalog) -> tuple:
+    """The proven range of each of a grouped aggregate's keys that is not
+    carried, as ``(least value, span)`` pairs (``_key_range``, a cold
+    table's by its manifest, as ``direct_box``): every such key a plain
+    non-null integer or date column that traces back to a scan, and
+    every aggregate a count or an integer sum (``_DIRECT_FUNCS``). A
+    table laid over the box at ``key - least`` holds a slot for every
+    group the rows can form; ``PAgg.direct`` says whether it is small
+    enough to use. () where a key or a function has no such proof."""
+    from cloudberry_tpu.plan.cost import col_origin
+
+    for _, call in agg.aggs:
+        if call.func not in _DIRECT_FUNCS or (
+                call.arg is not None and call.func != "count"
+                and call.arg.dtype.base not in _EXACT):
+            return ()
+    box = []
+    for name, e in agg.group_keys:
+        if name in agg.carried:
+            continue
+        if not isinstance(e, ex.ColumnRef) or e.dtype.base not in _INTEGERS:
+            return ()
+        try:
+            if agg.child.field(e.name).null_mask is not None:
+                return ()
+        except KeyError:
+            return ()
+        src = col_origin(agg.child, e.name, unions=False)
+        rng = src and _key_range(src[0], src[1], catalog, cold=True)
+        if not rng:
+            return ()
+        box.append((rng[0], rng[1] - rng[0] + 1))
+    return tuple(box)
+
+
+def _value_range(child: N.PlanNode, e: ex.Expr, catalog):
+    """The least and the greatest value of an integer expression over
+    ``child``'s rows, by proof, else None: a scan's column by
+    ``_key_range``, an integer literal, a CASE whose every branch has
+    one (Q13's ``count(o_orderkey)`` arrives as CASE WHEN matched THEN 1
+    ELSE 0)."""
+    from cloudberry_tpu.plan.cost import col_origin
+
+    if isinstance(e, ex.ColumnRef):
+        src = col_origin(child, e.name, unions=False)
+        return src and _key_range(src[0], src[1], catalog, cold=True)
+    if isinstance(e, ex.Literal):
+        v = e.value
+        return (v, v) if isinstance(v, int) else None
+    if isinstance(e, ex.CaseWhen) and e.otherwise is not None:
+        ranges = [_value_range(child, v, catalog)
+                  for v in [v for _, v in e.whens] + [e.otherwise]]
+        if all(ranges):
+            return min(r[0] for r in ranges), max(r[1] for r in ranges)
+    return None
+
+
+def sum_bits(agg: N.PAgg, catalog) -> tuple:
+    """``(output name, bits, signed)`` of each sum or average of ``agg``
+    whose integer argument has a proven range (``_value_range``; 0 is
+    in it, a NULL arrives identity-filled): the width the argument fits,
+    unsigned, or two's complement where it can be negative.
+    ``kernels.exact_table_sum`` then sums that many bits, not 64 (Q18's
+    ``l_quantity``, 13 bits: two words of 9 at 6M rows, not eight; Q13's
+    match flag one). A width, not the range itself: values that move
+    inside it (an append) leave the program as it was."""
+    out = []
+    for name, call in agg.aggs:
+        if call.func not in ("sum", "avg"):
+            continue
+        rng = _value_range(agg.child, call.arg, catalog)
+        if not rng:
+            continue
+        lo, hi = min(rng[0], 0), max(rng[1], 0)
+        if lo == 0:
+            out.append((name, hi.bit_length(), False))
+        else:
+            out.append((name, max(hi.bit_length(),
+                                  (-lo - 1).bit_length()) + 1, True))
+    return tuple(out)
 
 
 def _group_ceiling(agg: N.PAgg, catalog):

@@ -13,6 +13,7 @@ XLA shape discipline.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field as dc_field
 from typing import Optional
 
@@ -301,14 +302,37 @@ class PAgg(PlanNode):
     # (plan/fdep.py, stamped by cost.annotate_pack_bits): not sorted,
     # each taken at its group's first row
     carried: tuple = ()
+    # one (least value, span) a key that is not carried, by proof
+    # (plan/joincap.py direct_agg_box, stamped by cost.annotate_pack_bits);
+    # () = no proof, or an aggregate function the direct path lacks.
+    # ``direct`` says whether the lowering engages a table laid over it
+    direct_box: tuple = ()
+    # (output name, bits, signed) of the sums whose argument has a
+    # proven width (plan/joincap.py sum_bits): the direct path sums that
+    # many bits of it only
+    sum_bits: tuple = ()
 
     def children(self):
         return [self.child]
 
+    @property
+    def direct(self) -> bool:
+        """Whether the grouped aggregate sums into a direct-address table
+        over its keys' proven box (``kernels.group_aggregate_direct``)
+        rather than sorting: where the box holds no more slots than the
+        aggregate's own capacity and its child's, the table is no larger
+        than arrays the node holds already, and its slots fit the output
+        without compaction."""
+        if not self.direct_box:
+            return False
+        return math.prod(span for _, span in self.direct_box) <= min(
+            self.capacity, capacity_of(self.child))
+
     def title(self):
         kind = "GroupAgg" if self.group_keys else "Agg"
         carry = f" carry {len(self.carried)}" if self.carried else ""
-        return f"{kind} {self.mode} [{self.capacity}]{carry}"
+        direct = " direct" if self.direct else ""
+        return f"{kind} {self.mode} [{self.capacity}]{carry}{direct}"
 
 
 @dataclass

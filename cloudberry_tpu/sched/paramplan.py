@@ -366,7 +366,9 @@ class _Walker:
                  self.esig(c.filter, False)[0])
                 for name, c in node.aggs)
             return (t, node.mode, node.capacity, node.pack_bits,
-                    node.carried, keys, aggs, self._fieldsig(node),
+                    node.carried, node.direct_box, node.sum_bits,
+                    keys, aggs,
+                    self._fieldsig(node),
                     self.nsig(node.child))
         if isinstance(node, N.PSort):
             keys = tuple((self.esig(e, False)[0], asc)

@@ -219,7 +219,8 @@ def test_a_distributed_module_carries_no_slow_formulation(stmt):
     """What the four-segment programs hand the compiler: every sort
     unstable, all of its operands keys, at most four of them (Q3's ORDER
     BY revenue desc, o_orderdate: two words, one, and the position); no
-    cumsum over rows as a reduce_window; no scatter of a shard's rows."""
+    cumsum over rows as a reduce_window; no scatter of a shard's rows
+    wider than one 32-bit word a row."""
     from cloudberry_tpu.exec import dist_executor as DX
     texts = []
     compile_distributed = DX.compile_distributed
@@ -252,16 +253,17 @@ def test_a_distributed_module_carries_no_slow_formulation(stmt):
         windows = re.findall(r"window_dimensions = array<i64: (\d+)>", text)
         assert all(int(w) <= 64 for w in windows), windows
         # a scatter sets one flag, counts rows into a motion's few
-        # buckets, or writes row NUMBERS into a lookup's direct-address
+        # buckets, writes row NUMBERS into a lookup's direct-address
         # table (one int32 word a row, 1-D: 2.3 s of compile at Q3's SF1
-        # widths, sandbox AOT, PR 37); none moves rows
+        # widths, compiled ahead of time for a v5e), or adds one u32 word
+        # of a sum a row into a grouped aggregate's table; none moves rows
         for m in re.finditer(r'"stablehlo\.scatter"', text):
             types = re.search(r"\}\) : \(([^)]*)\) ->",
                               text[m.start():m.start() + 4000]).group(1)
             operand, _, updates = [t.strip() for t in types.split(", ")]
             cells = int(np.prod([int(d) for d in re.findall(
                 r"(\d+)x", operand)] or [1]))
-            row_numbers = re.fullmatch(r"tensor<\d+xi32>", operand) \
-                and re.fullmatch(r"tensor<\d+xi32>", updates)
+            row_numbers = re.fullmatch(r"tensor<\d+xu?i32>", operand) \
+                and re.fullmatch(r"tensor<\d+xu?i32>", updates)
             assert updates.startswith("tensor<i") or cells <= 64 \
                 or row_numbers, types

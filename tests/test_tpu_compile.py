@@ -317,13 +317,32 @@ def _direct_lookup_q3(sh):
                             sh((nb,), jnp.int64))
 
 
+def _direct_agg_q18(sh):
+    """K.group_aggregate_direct at the width SF1's Q18 runs it:
+    lineitem's 6,029,312 rows summed into a table of l_orderkey's
+    1,500,000 values, ``sum(l_quantity)`` in two 9-bit words by its
+    proven 13 bits, a count, an average over 64 bits: no sort."""
+    from cloudberry_tpu.exec import kernels as K
+
+    n = 6_029_312
+    specs = [K.AggSpec("sum", "s"), K.AggSpec("count", "c"),
+             K.AggSpec("avg", "a")]
+
+    def f(k, v, sel):
+        return K.group_aggregate_direct(
+            {"k": k}, {"s": v, "c": None, "a": v}, specs, sel,
+            ((1, 1_500_000),), 1_507_328, value_bits={"s": (13, False)})
+    return jax.jit(f).lower(sh((n,), jnp.int64), sh((n,), jnp.int64),
+                            sh((n,), jnp.bool_))
+
+
 @pytest.mark.parametrize("lower", [_mid_cardinality_agg,
                                    _small_build_probe_join, _dense_agg_q1,
                                    _sparse_compaction_q12,
-                                   _direct_lookup_q3],
+                                   _direct_lookup_q3, _direct_agg_q18],
                          ids=["group_aggregate_2e16", "join_lookup_1024",
                               "dense_agg_q1", "compact_sparse_q12",
-                              "join_lookup_direct_q3"])
+                              "join_lookup_direct_q3", "direct_agg_q18"])
 def test_xla_formulations_of_the_kernel_shapes_compile_for_tpu(one_chip,
                                                                lower):
     def sh(shape, dtype):
