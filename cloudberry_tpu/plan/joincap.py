@@ -8,7 +8,11 @@ payload word, and every node above it inherits that capacity. Where the
 estimates say the probe's selected rows, or the join's matches, are a
 small share of the capacity they arrive at, this pass stamps a capacity
 of the join's own (``Lowerer._join`` compacts to it with
-``kernels.compact_sparse``):
+``kernels.compact_sparse``: the mask packed 32 rows a word, each
+non-empty word's rank scattered to its first slot and carried over the
+slots by a running maximum, so n / 32 updates plus a scan of the
+stamped capacity; a compaction still pays for the stamp, not the rows,
+though no longer a binary search a slot):
 
 - ``probe_capacity``: the probe's selected rows, before the search;
 - ``out_capacity``: the matched rows, after the match test and before

@@ -298,9 +298,14 @@ def test_compact_overflow_reported():
     assert int(n) == 4  # caller sees 4 > capacity 2 and errors
 
 
-def _mask(n: int, k: int, seed: int) -> np.ndarray:
-    """``k`` of ``n`` rows selected, at random places."""
+def _mask(n: int, k, seed: int) -> np.ndarray:
+    """``k`` of ``n`` rows selected, at random places; or, where ``k`` is
+    a tuple of (first, last) row ranges, those rows and no others."""
     sel = np.zeros(n, dtype=bool)
+    if isinstance(k, tuple):
+        for first, last in k:
+            sel[first:last + 1] = True
+        return sel
     sel[np.random.default_rng(seed).choice(n, k, replace=False)] = True
     return sel
 
@@ -313,15 +318,26 @@ def _mask(n: int, k: int, seed: int) -> np.ndarray:
     (5000, 93, 256),       # sparse
     (130, 7, 16),          # one block of the prefix sum and a bit
     (128, 128, 16),        # far over
+    pytest.param(160, ((32, 63), (70, 70)), 64, id="full-word"),
+    pytest.param(300_000, ((5, 5), (9_000, 9_001), (299_990, 299_990)), 8,
+                 id="long-empty-runs"),
+    pytest.param(256, ((32, 63), (64, 80)), 40, id="capacity-inside-word"),
+    pytest.param(3200, tuple((32 * w + w % 32,) * 2 for w in range(100)),
+                 16, id="more-words-than-slots"),
+    pytest.param(1001, ((0, 0), (995, 995), (1000, 1000)), 8,
+                 id="n-not-a-multiple-of-32"),
+    pytest.param(320, 40, 64, id="capacity-past-the-words"),
 ])
 @pytest.mark.parametrize("jit", [False, True])
 def test_compact_sparse_is_compact(jit, n, k, capacity):
-    """The sparse form (a prefix sum and ``capacity`` searches) against
-    ``K.compact`` (a sort of the whole input) and numpy: the selected
-    rows in their order, the mask of the slots they fill, and the TRUE
-    count, which the caller checks against the capacity."""
-    sel = _mask(n, k, seed=n + k)
-    rng = np.random.default_rng(k)
+    """The sparse form (a prefix sum, a scatter of the words' ranks and a
+    running maximum) against ``K.compact`` (a sort of the whole input)
+    and numpy: the selected rows in their order, the mask of the slots
+    they fill, and the TRUE count, which the caller checks against the
+    capacity."""
+    sel = _mask(n, k, seed=n + k if isinstance(k, int) else n)
+    picked = int(sel.sum())
+    rng = np.random.default_rng(picked)
     cols = {"pos": jnp.arange(n, dtype=jnp.int64),
             "val": jnp.asarray(rng.integers(-99, 99, n).astype(np.int32)),
             "flag": jnp.asarray(rng.random(n) < 0.5)}
@@ -329,8 +345,8 @@ def test_compact_sparse_is_compact(jit, n, k, capacity):
         else K.compact_sparse
     out, osel, count = fn(cols, jnp.asarray(sel), capacity)
     ref, rsel, rcount = K.compact(cols, jnp.asarray(sel), capacity)
-    assert int(count) == int(rcount) == k
-    kept = min(k, capacity)
+    assert int(count) == int(rcount) == picked
+    kept = min(picked, capacity)
     np.testing.assert_array_equal(np.asarray(osel),
                                   np.arange(capacity) < kept)
     np.testing.assert_array_equal(np.asarray(osel), np.asarray(rsel))
@@ -343,6 +359,22 @@ def test_compact_sparse_is_compact(jit, n, k, capacity):
                                       np.asarray(ref[name])[:kept])
         np.testing.assert_array_equal(np.asarray(out[name])[:kept],
                                       np.asarray(cols[name])[want])
+
+
+@pytest.mark.parametrize("n,capacity", [(6_029_312, 1_048_576),
+                                        (1_507_328, 4_096)])
+def test_compact_sparse_lowers_without_a_loop(n, capacity):
+    """No search is left in the compaction: its lowering holds no
+    ``while``, at the join cell's widest width and largevol's narrowest
+    (traced from shapes, nothing runs)."""
+    def f(key, mode, sel):
+        return K.compact_sparse({"k": key, "m": mode}, sel, capacity)
+    text = jax.jit(f).lower(
+        jax.ShapeDtypeStruct((n,), jnp.int64),
+        jax.ShapeDtypeStruct((n,), jnp.int32),
+        jax.ShapeDtypeStruct((n,), jnp.bool_)).as_text()
+    assert "stablehlo.scatter" in text
+    assert "while" not in text
 
 
 @pytest.mark.parametrize("ladder, pad", [("row_rung_up", 1 / 32),

@@ -107,6 +107,15 @@ def test_prefix_sum_is_cumsum(n, dtype):
     assert got.dtype == want.dtype and (got == want).all()
 
 
+@pytest.mark.parametrize("n", [1, 127, 128, 129, 16384, 70001])
+@pytest.mark.parametrize("dtype", ["int32", "uint64"])
+def test_running_max_is_cummax(n, dtype):
+    x = RNG.integers(0, 2**31 - 1, n).astype(dtype)
+    want = np.maximum.accumulate(x)
+    got = np.asarray(jax.jit(K.running_max)(jnp.asarray(x)))
+    assert got.dtype == want.dtype and (got == want).all()
+
+
 def test_prefix_sum_leaves_floats_to_cumsum():
     x = jnp.asarray(RNG.random(1000))
     assert (np.asarray(K.prefix_sum(x)) == np.asarray(jnp.cumsum(x))).all()

@@ -283,20 +283,30 @@ def _dense_agg_q1(sh):
                             sh((_N,), jnp.bool_))
 
 
-def _sparse_compaction_q12(sh):
-    """K.compact_sparse at the width SF1's Q12 runs it (ISSUE 33): the
-    lines its filter keeps, of lineitem's 6,029,312 rows of capacity, to
-    the 262,144 the planner stamps: the mask packed 32 rows a word,
-    262,144 searches through the words' running popcount, five popcount
-    halvings inside the word found, two columns gathered."""
+def _sparse_compaction(sh, n, cap):
     from cloudberry_tpu.exec import kernels as K
-
-    n, cap = 6_029_312, 262_144
 
     def f(key, mode, sel):
         return K.compact_sparse({"k": key, "m": mode}, sel, cap)
     return jax.jit(f).lower(sh((n,), jnp.int64), sh((n,), jnp.int32),
                             sh((n,), jnp.bool_))
+
+
+def _sparse_compaction_q12(sh):
+    """K.compact_sparse at the width SF1's Q12 runs it: the
+    lines its filter keeps, of lineitem's 6,029,312 rows of capacity, to
+    the 262,144 the planner stamps: the mask packed 32 rows a word, each
+    of the 188,416 words' index scattered to its first slot, a running
+    maximum over the 262,144 slots, five popcount halvings inside the
+    word found, two columns gathered."""
+    return _sparse_compaction(sh, 6_029_312, 262_144)
+
+
+def _sparse_compaction_q3(sh):
+    """K.compact_sparse at the width SF1's Q3 runs its lineitem ⋈ orders
+    match compaction: 6,029,312 rows to the 1,048,576 the planner
+    stamps, two columns gathered."""
+    return _sparse_compaction(sh, 6_029_312, 1_048_576)
 
 
 def _direct_lookup_q3(sh):
@@ -339,10 +349,12 @@ def _direct_agg_q18(sh):
 @pytest.mark.parametrize("lower", [_mid_cardinality_agg,
                                    _small_build_probe_join, _dense_agg_q1,
                                    _sparse_compaction_q12,
-                                   _direct_lookup_q3, _direct_agg_q18],
+                                   _direct_lookup_q3, _direct_agg_q18,
+                                   _sparse_compaction_q3],
                          ids=["group_aggregate_2e16", "join_lookup_1024",
                               "dense_agg_q1", "compact_sparse_q12",
-                              "join_lookup_direct_q3", "direct_agg_q18"])
+                              "join_lookup_direct_q3", "direct_agg_q18",
+                              "compact_sparse_q3"])
 def test_xla_formulations_of_the_kernel_shapes_compile_for_tpu(one_chip,
                                                                lower):
     def sh(shape, dtype):
