@@ -908,7 +908,7 @@ def grow_expansion(plan: N.PlanNode, message: str, factor: int = 4,
             nd.host_bucket_cap = K.rung_up(
                 max(nd.host_bucket_cap * 2, observed, 64))
             # no _min_* floor needed: nothing re-derives host_bucket_cap
-            # on a live plan (tiled _retile_dist re-derives bucket_cap
+            # on a live plan (tiled_plan.retile_mesh re-derives bucket_cap
             # only), so the promoted rung cannot be shrunk back
         return bool(hits)
     if "redistribute overflow" in message:

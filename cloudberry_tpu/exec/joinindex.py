@@ -25,7 +25,7 @@ Eligible joins (annotate_join_index, stamped post-distribution):
 Everything else falls back to the in-program argsort automatically: the
 join lowering looks the input up with ``.get`` and computes the sort when
 the key is absent (tiled/spill step programs assemble their own inputs
-and strip the annotations at intake — exec/tiled.py, exec/tiled_dist.py).
+and strip the annotations at intake — exec/tiled.py ``plan_tiled``).
 """
 
 from __future__ import annotations

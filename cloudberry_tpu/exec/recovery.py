@@ -5,9 +5,9 @@ The reference survives segment failure with FTS + mirror promotion
 (SURVEY §7.1): a statement's in-flight state lives on mirrored disks, so
 a dead segment's work is not lost. Mesh slots have no mirrors — segments
 are stateless over immutable storage — so the analog is CHECKPOINTED
-RE-EXECUTION: the tiled executors (exec/tiled.py, exec/tiled_dist.py)
-already cross a host boundary after every tile, and the state carried
-between tiles is small by construction (agg partials bounded by the
+RE-EXECUTION: the tiled executors (exec/tiled.py, in either segment
+layout) already cross a host boundary after every tile, and the state
+carried between tiles is small by construction (agg partials bounded by the
 accumulator capacity, top-N heaps bounded by the LIMIT, sort-merge runs
 already host-resident). Every K-th tile that carried state is
 snapshotted to a host-side, statement-scoped checkpoint; when a device
@@ -66,7 +66,7 @@ from cloudberry_tpu.utils.faultinject import fault_point
 class TileReplan(Exception):
     """Mid-statement adaptive replan request (NOT a failure).
 
-    Raised by the tiled-dist skew sentinel (exec/tiled.py SkewSentinel)
+    Raised by the mesh's skew sentinel (exec/tiled.py SkewSentinel)
     after it has (a) folded the cumulative per-destination motion rows
     into the feedback store as a partial sketch and (b) durably
     checkpointed the carried state via RecoveryCtx.force_snapshot. The
@@ -246,10 +246,7 @@ def plan_signature(exe) -> tuple:
                  for f in shape.partial_plan.fields),
            tuple(parts) if parts is not None else None)
     if shape.mode == "agg":
-        groups = getattr(shape, "group_names", None)
-        if groups is None:
-            groups = [n for n, _ in shape.agg.group_keys]
-        sig += (tuple(groups),
+        sig += (tuple(shape.group_names),
                 tuple((s.func, s.out_name) for s in shape.merge_specs))
     else:
         sig += (repr(shape.sortnode.keys) if shape.sortnode is not None
@@ -544,10 +541,7 @@ class RecoveryCtx:
     def _current_cap(self) -> int:
         shape = self.exe.shape
         if shape.mode == "agg":
-            groups = getattr(shape, "group_names", None)
-            if groups is None:
-                groups = [n for n, _ in shape.agg.group_keys]
-            return shape.g_cap if groups else 1
+            return shape.g_cap if shape.group_names else 1
         return shape.g_cap
 
     # --------------------------------------------------------- restoring
@@ -678,11 +672,11 @@ class RecoveryCtx:
 
     def force_snapshot(self, tiles_local: int, payload_fn) -> bool:
         """Snapshot NOW, ignoring the K-tile cadence — the mid-statement
-        adaptive replan (exec/tiled_dist.py) checkpoints the carried
-        state at the alarm tile so the replanned executable resumes from
-        exactly here instead of re-streaming. True when the checkpoint
-        was durably saved; an adaptation must not proceed on a failed
-        save (the replanned run would replay consumed tiles)."""
+        adaptive replan (exec/tiled.py SkewSentinel) checkpoints the
+        carried state at the alarm tile so the replanned executable
+        resumes from exactly here instead of re-streaming. True when the
+        checkpoint was durably saved; an adaptation must not proceed on a
+        failed save (the replanned run would replay consumed tiles)."""
         if self.sid is None or not self.cfg.enabled or self._ckpt_broken:
             return False
         total = self.tiles_base + tiles_local

@@ -1,8 +1,8 @@
 """Asynchronous tiled-scan pipeline — prefetch + parallel decode +
 device double-buffering.
 
-The tiled executors (exec/tiled.py, exec/tiled_dist.py) stream a table
-as fixed-shape tiles; before this module the feed was fully synchronous:
+The tiled executors (exec/tiled.py, on one segment and on the mesh)
+stream a table as fixed-shape tiles; before this module the feed was fully synchronous:
 read a micro-partition, decode every column, concatenate, pad, feed —
 all on the statement thread, device idle the whole time. JAX's async
 dispatch already overlaps *compute* for free; the win left on the table

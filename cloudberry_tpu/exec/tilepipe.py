@@ -1,7 +1,7 @@
 """Windowed in-flight tile dispatch — keep the accelerator queue full.
 
-The tiled streaming loops (exec/tiled.py, exec/tiled_dist.py) are the
-engine's out-of-core hot path, and before this module every tile
+The tiled streaming loops (exec/tiled.py, either segment layout) are
+the engine's out-of-core hot path, and before this module every tile
 round-tripped the device: ``step_fn`` launches, then
 ``_raise_tile_checks``/``sentinel.observe`` immediately force the tiny
 per-tile check/stat scalars to host, so the device queue drains to

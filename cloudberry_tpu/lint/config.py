@@ -95,7 +95,7 @@ KERNEL_MODULES = (
     "exec/executor.py",
     "exec/dist_executor.py",
     "exec/tiled.py",
-    "exec/tiled_dist.py",
+    "exec/tiled_plan.py",
     "exec/instrument.py",
 )
 
@@ -107,7 +107,7 @@ LIMB_FUNC_MARKERS = ("limb", "decimal")
 # modules whose unbounded tile/retry loops must contain a cancel seam
 SEAM_LOOP_MODULES = (
     "exec/tiled.py",
-    "exec/tiled_dist.py",
+    "exec/tiled_plan.py",
     "exec/recovery.py",
     "exec/scanpipe.py",
     "exec/tilepipe.py",

@@ -8,7 +8,7 @@ exactly the failure mode the verifier exists to close. Rules:
 
 - ``planprops-unruled``: a class deriving from PlanNode (anywhere in
   the package — plan/nodes.py or an executor-private leaf like
-  exec/tiled.py's _AccLeaf) with no ``@rule("<Class>")`` registration
+  exec/tiled_plan.py's _AccLeaf) with no ``@rule("<Class>")`` registration
   in plan/verify.py. Anchored at the class definition.
 - ``planprops-orphan-rule``: a ``@rule("<Name>")`` registration naming
   no existing PlanNode subclass — a stale row that would mask the

@@ -26,8 +26,9 @@ way they share compiled programs. Consumers:
   the histogram;
 - ``plan/distribute.py digest_filter_frac`` prices probe redistributes
   at the OBSERVED survivor fraction of the runtime filter;
-- ``exec/tiled_dist.py`` replans MID-STATEMENT through the PR-6
-  checkpoint store when per-tile motion stats cross the skew alarm.
+- ``exec/tiled.py SkewSentinel`` replans a tiled run on the mesh
+  MID-STATEMENT through the checkpoint store (exec/recovery.py) when
+  per-tile motion stats cross the skew alarm.
 
 Invalidation is by construction, not by protocol: every sketch carries
 the same content-stable tokens the shared cache tier keys on —

@@ -1156,7 +1156,7 @@ def _check_topn_merge(v: Verifier, m: N.PMotion, path: str) -> None:
 
 
 @rule("_AccLeaf", doc="the tiled finalize program's accumulator leaf "
-                      "(exec/tiled.py): pooled partial state, one "
+                      "(exec/tiled_plan.py): pooled partial state, one "
                       "place, no children")
 def _r_accleaf(v: Verifier, node, kids, path) -> Props:
     cap = getattr(node, "capacity", 0) or 1

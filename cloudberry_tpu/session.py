@@ -884,7 +884,7 @@ class Session:
         with OT.stage("bind", host=True):
             self._check_topology_race(cfg_plan)
             names = sorted({s.table_name
-                            for s in X.scans_of(texe._whole_plan())})
+                            for s in X.scans_of(texe.shape.partial_plan)})
             if not self._any_external(names):
                 report = texe.report
                 self._cache_statement(

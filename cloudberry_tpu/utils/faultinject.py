@@ -92,8 +92,8 @@ INVENTORY = frozenset({
     # seams — 'error' provokes mid-offer failures, 'skip' suppresses
     # admission / forces refusal-over-eviction
     "bufpool_admit", "bufpool_evict",
-    # feedback-driven re-optimization (plan/feedback.py,
-    # exec/tiled_dist.py): 'skip' on feedback_fold suppresses learning
+    # feedback-driven re-optimization (plan/feedback.py, exec/tiled.py
+    # SkewSentinel): 'skip' on feedback_fold suppresses learning
     # after a statement; 'skip' on tile_replan suppresses the
     # mid-statement adaptive replan even when the skew alarm fires
     "feedback_fold", "tile_replan",

@@ -38,6 +38,10 @@ def pytest_configure(config):
 # behavior tests while its perf-property searches move, and the spill
 # modules keep one representative of each recognized spine. Node-id
 # suffixes so fixture-parametrized products can be tiered individually.
+# One executable a mode serves both segment layouts (exec/tiled.py), so
+# each mode keeps a tier-1 case on the mesh: the dist8 join-group, global
+# and colocated aggregates and the top-N OFFSET case stay tier-1, beside
+# test_spill_dist.py's small sort and window cases.
 _SLOW_TIER = (
     "test_spill_dist.py::test_dist_merge_overflow_grows_accumulator",
     "test_spill_dist.py::test_dist_tiled_topn_matches_in_memory",
@@ -75,7 +79,6 @@ _SLOW_TIER = (
     "[dist8]",
     "test_spill_sort_window.py::test_external_sort_matches_in_memory"
     "[dist8]",
-    "test_spill_dist.py::test_dist_tiled_join_group_matches_in_memory",
     "test_cte.py::test_basic_cte[dist8]",
     "test_grouping_sets.py::test_cube[dist8]",
     "test_setop_all.py::test_running_extreme_null_never_beats_dtype_extreme"
@@ -90,26 +93,23 @@ _SLOW_TIER = (
     "test_distributed.py::test_tpch_distributed[q8]",
     # round 7 (PR 7 margin): the single-node kill matrix + degraded-dist
     # recovery tests stay tier-1 while the dist8 kill matrix moves; the
-    # dist topn OFFSET variant keeps its single-node twin
-    # (test_spill.py::test_tiled_topn_offset_and_desc) and the plain
-    # dist topn stays covered slow-tier; digest-parity q5 single rides
-    # the slow full sweep like q5 dist8 already does (q3/q10 both stay).
+    # plain dist topn stays covered slow-tier; digest-parity q5 single
+    # rides the slow full sweep like q5 dist8 already does (q3/q10 both
+    # stay).
     "test_recovery.py::test_tiled_dist_kill_matrix",
-    "test_spill_dist.py::test_dist_tiled_topn_offset",
     "test_join_filter.py::test_tpch_digest_parity_single[q5]",
     # round 8 (PR 8 margin — lint gate + witness fixtures + taxonomy
     # suite joined tier-1): more dist8/heavy variants whose cheaper
     # sibling stays — dist degraded-resume keeps the colocated-declines
     # dist8 case + the single-node resume matrix; the dist statement-
-    # cache/colocated-agg pair keep their single-node twins in
-    # test_spill.py; digest-parity q10-dist8 keeps q3-dist8 + the q10
+    # cache test keeps its single-node twin in test_spill.py;
+    # digest-parity q10-dist8 keeps q3-dist8 + the q10
     # single-seg subset; lead-offset/packed-redistribute/generic-q3
     # keep their single/seg1 twins; four more TPC-H dist8 queries keep
     # their test_tpch_query single-seg siblings (q2/q8 precedent); DS
     # q86/q60 keep their single-seg runs.
     "test_recovery.py::test_dist_degraded_resume",
     "test_spill_dist.py::test_dist_tiled_statement_cache_reuses_runner",
-    "test_spill_dist.py::test_dist_tiled_colocated_one_stage_agg",
     "test_join_filter.py::test_tpch_digest_parity_dist8[q10]",
     "test_window_longtail.py::test_lead_offset_and_default[dist8]",
     "test_packed_motion.py::test_packed_matches_percol_all_motion_kinds"
@@ -151,8 +151,7 @@ _SLOW_TIER = (
     # keep their single-seg runs, digest-parity q3-dist8 now rides the
     # slow full sweep like q5/q10 already do (the whole single-seg
     # digest subset minus q5 stays tier-1), packed-parity q3-seg8
-    # keeps q3-seg1, and the dist global agg keeps its single-node
-    # twin (test_spill.py::test_tiled_global_agg).
+    # keeps q3-seg1.
     "test_join_filter.py::test_tpch_digest_parity_dist8[q3]",
     "test_tpcds_round5.py::test_tpcds_round5[dist8-q43]",
     "test_tpcds_round5.py::test_tpcds_round5[dist8-q94]",
@@ -173,7 +172,6 @@ _SLOW_TIER = (
     "test_tpcds.py::test_tpcds_distributed[q55]",
     "test_tpcds.py::test_tpcds_distributed[q12]",
     "test_packed_motion.py::test_tpch_packed_parity_pinned[q3-seg8]",
-    "test_spill_dist.py::test_dist_tiled_global_agg",
     # round 20 (windowed tile-dispatch suite joins tier-1, ~75s): more
     # dist8 TPC-H/DS queries whose single-seg twins stay tier-1 (the
     # q2/q8 precedent continues), and the windowed suite's own heaviest

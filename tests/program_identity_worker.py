@@ -12,7 +12,7 @@ lowered module text and the ``jax.result_info`` keys it carries.
 origin reaches no program.
 
 ``--tiled`` holds the statement to a memory budget that sends it through
-``exec/tiled.py`` (``exec/tiled_dist.py`` on several segments) as the
+``exec/tiled.py`` (on the segment mesh on several segments) as the
 out-of-core cell's 125 MiB does at SF1
 (``resource.query_mem_bytes``: 4 MiB at this worker's SF 0.01 — 1.25 MiB,
 the cell's budget cut with the data, is under the least tile Q6 runs at),
@@ -51,7 +51,6 @@ from cloudberry_tpu.config import Config                 # noqa: E402
 from cloudberry_tpu.exec import dist_executor as DX      # noqa: E402
 from cloudberry_tpu.exec import executor as X            # noqa: E402
 from cloudberry_tpu.exec import tiled as T               # noqa: E402
-from cloudberry_tpu.exec import tiled_dist as TD         # noqa: E402
 from program_texts import (record_tiled_programs,        # noqa: E402
                            recording)
 from tools.tpch_queries import QUERIES                   # noqa: E402
@@ -94,7 +93,7 @@ X.compile_plan = compile_plan
 overrides = {}
 if "--tiled" in sys.argv[3:]:
     overrides["resource.query_mem_bytes"] = 4 << 20
-    record_tiled_programs((T, TD), programs)
+    record_tiled_programs((T,), programs)
 
 TABLES = ["lineitem", "orders", "customer"]
 if "--store" in sys.argv[3:]:

@@ -26,9 +26,9 @@ def recording(fn, sink: list, once: bool = False):
 
 
 def record_tiled_programs(modules, sink: list, set_attr=setattr) -> None:
-    """Every tiled executable class of ``modules`` (exec/tiled.py,
-    exec/tiled_dist.py) that builds programs in a ``_compile`` of its own
-    hands them out recording, at their first launch. ``set_attr``: a
+    """Every tiled executable class of ``modules`` (exec/tiled.py) that
+    builds programs in a ``_compile`` of its own hands them out
+    recording, at their first launch. ``set_attr``: a
     test's ``monkeypatch.setattr``."""
     def recording_compile(compile_):
         def _compile(self):

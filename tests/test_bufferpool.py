@@ -600,7 +600,7 @@ def test_scan_bench_hot_point_smoke(tmp_path):
 @pytest.mark.slow
 def test_tpch_tiled_dist_sf10_second_pass_hit_rates(tmp_path):
     """Carried evidence debt (ROADMAP round 15): FULL TPC-H — not just
-    the scan shape — through tiled_dist at SF10 in the slow tier, each
+    the scan shape — tiled on the mesh at SF10 in the slow tier, each
     query run twice in ONE session with first-scan admission
     (admit_min_scans=1) so the SECOND pass is served by the buffer
     pool, recording per-query second-pass hit rates as one JSON line

@@ -103,7 +103,7 @@ def test_planprops_rule_table_exhaustive_live():
     covers every PlanNode subclass actually importable from the
     package, both ways — so the static rule and the runtime table can
     never drift apart."""
-    from cloudberry_tpu.exec.tiled import _AccLeaf  # noqa: F401
+    from cloudberry_tpu.exec.tiled_plan import _AccLeaf  # noqa: F401
     from cloudberry_tpu.plan import nodes as N
     from cloudberry_tpu.plan.verify import RULES
 

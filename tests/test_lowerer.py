@@ -20,7 +20,7 @@ import cloudberry_tpu as cb
 from cloudberry_tpu.config import Config
 from cloudberry_tpu.exec import dist_executor as DX
 from cloudberry_tpu.exec import executor as X
-from cloudberry_tpu.exec import tiled_dist as TD
+from cloudberry_tpu.exec import tiled as T
 from cloudberry_tpu.parallel.mesh import SEG_AXIS
 from cloudberry_tpu.plan import nodes as N
 from program_texts import record_tiled_programs, recording
@@ -261,10 +261,10 @@ PROGRAMS = {
     "one_shot": (AGG, "sql", {}, "seg_fn"),
     "explain_analyze": (AGG, "explain_analyze", {}, "seg_fn"),
     "tiled_aggregate": (AGG, "sql", {"resource.query_mem_bytes": 1 << 20},
-                        "finalize_seg"),
+                        "finalize_fn"),
     "tiled_sort": (SORT, "sql", {"resource.query_mem_bytes": 1 << 20,
                                  "planner.broadcast_threshold": 0},
-                   "step_seg"),
+                   "step_fn"),
 }
 
 
@@ -278,7 +278,7 @@ def launched(monkeypatch):
         DX, "compile_distributed",
         lambda *a, **kw: recording(compile_distributed(*a, **kw), texts,
                                    once=True))
-    record_tiled_programs((TD,), texts, monkeypatch.setattr)
+    record_tiled_programs((T,), texts, monkeypatch.setattr)
     return texts
 
 

@@ -387,7 +387,7 @@ def test_every_capacity_walk_follows_the_joins_own(single_session):
     """One derivation: the binder's, the memory estimate's, the tiled
     planner's and the verifier's row rule give a compacted join the
     capacity its lowering emits."""
-    from cloudberry_tpu.exec import tiled
+    from cloudberry_tpu.exec import tiled_plan
     from cloudberry_tpu.plan import binder
 
     plan = _stamped(single_session, QUERIES["q12"])
@@ -395,7 +395,7 @@ def test_every_capacity_walk_follows_the_joins_own(single_session):
     want = j.probe_capacity
     assert 0 < want < N.capacity_of(j.probe)
     assert N.capacity_of(j) == binder._plan_capacity(j) \
-        == tiled._out_cap(j) == want
+        == tiled_plan._out_cap(j) == want
     from cloudberry_tpu.plan.verify import _join_rows
 
     assert _join_rows(j, N.capacity_of(j.build),

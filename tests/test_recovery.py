@@ -1,9 +1,9 @@
 """Mid-statement fault recovery (exec/recovery.py) — the chaos ladder.
 
 The contract under test: killing a device (faultinject
-``tile_device_lost``) at an ARBITRARY tile of a tiled or tiled_dist
-statement yields bit-identical results vs the uninterrupted run, with
-``tiles_replayed`` strictly less than the total tile count (resume from
+``tile_device_lost``) at an ARBITRARY tile of a tiled statement, on one
+segment or on the mesh, yields bit-identical results vs the
+uninterrupted run, with ``tiles_replayed`` strictly less than the total tile count (resume from
 the last K-tile checkpoint, not restart) — including the degraded case
 where the survivor mesh has fewer segments than the original plan.
 Plus the recovery/lifecycle interplay: an in-progress recovery counts

@@ -1,4 +1,5 @@
-"""Distributed tiled execution (exec/tiled_dist.py) — spill on the mesh.
+"""Distributed tiled execution (exec/tiled.py on the mesh layout) — spill
+on the mesh.
 
 The contract under test: an admission-rejected DISTRIBUTED statement (8
 segments) completes by streaming per-segment tiles through the plan's
@@ -166,6 +167,81 @@ def test_dist_tiled_topn_offset():
     assert exp.equals(got)
     rep = s.last_tiled_report
     assert rep["mode"] == "topn" and rep["acc_capacity"] == 15
+
+
+SORT_Q = ("SELECT g, v, fact.k AS k FROM fact JOIN dim ON fact.d = dim.d "
+          "WHERE v < 50 ORDER BY g, v DESC, fact.k, fact.d")
+WINDOW_Q = ("SELECT fact.d AS d, g, v, "
+            "rank() over (partition by fact.d order by v desc) AS r, "
+            "sum(v) over (partition by fact.d) AS sv "
+            "FROM fact JOIN dim ON fact.d = dim.d")
+
+
+def _by_all(df):
+    return df.sort_values(list(df.columns)).reset_index(drop=True)
+
+
+def test_dist_tiled_sort_matches_in_memory():
+    """An unbounded ORDER BY over a redistribute-join spine: every
+    segment's tiles go to host-RAM runs, one key sort merges them."""
+    big = _mk()
+    _load(big)
+    exp = big.sql(SORT_Q).to_pandas()
+    s = _mk(budget=10 << 20)
+    _load(s)
+    got = s.sql(SORT_Q).to_pandas()
+    assert exp.equals(got)
+    rep = s.last_tiled_report
+    assert rep["distributed"] and rep["mode"] == "sort"
+    assert rep["n_tiles"] > 1
+
+
+def test_dist_tiled_window_matches_in_memory():
+    """A window stack over a redistribute-join spine: the per-segment
+    external-sort stream, then whole-partition chunks on one device."""
+    big = _mk()
+    _load(big, n_fact=100_000)
+    exp = big.sql(WINDOW_Q).to_pandas()
+    s = _mk(budget=2 << 20)
+    _load(s, n_fact=100_000)
+    got = s.sql(WINDOW_Q).to_pandas()
+    assert _by_all(exp).equals(_by_all(got))
+    rep = s.last_tiled_report
+    assert rep["distributed"] and rep["mode"] == "window"
+    assert rep["n_tiles"] > 1 and rep["n_chunks"] >= 1
+
+
+@pytest.mark.parametrize("mode,q,budget", [
+    ("agg", JOIN_GROUP_Q, 2 << 20),
+    ("topn", "SELECT v, fact.k AS k FROM fact JOIN dim ON fact.d = dim.d "
+             "ORDER BY v DESC, fact.k DESC, fact.d DESC LIMIT 10 OFFSET 5",
+     4 << 20),
+    ("sort", SORT_Q, 6 << 20),
+    ("window", WINDOW_Q, 4 << 20),
+], ids=["agg", "topn", "sort", "window"])
+def test_each_mode_plans_one_executable_at_one_and_four_segments(
+        mode, q, budget, monkeypatch):
+    """One executable class a mode, whichever segment layout it runs
+    over: one segment and four plan the same class, on the one-segment
+    and the mesh layout, stream more than one tile, and answer alike."""
+    from cloudberry_tpu.exec import tiled as T
+
+    planned = []
+    plan_tiled = T.plan_tiled
+    monkeypatch.setattr(T, "plan_tiled", lambda plan, session: (
+        planned.append(plan_tiled(plan, session)) or planned[-1]))
+    got = []
+    for nseg in (1, 4):
+        s = _mk(budget=budget, n_segments=nseg)
+        _load(s, n_fact=100_000)
+        got.append(_by_all(s.sql(q).to_pandas()))
+        assert s.last_tiled_report["n_tiles"] > 1
+    one, four = planned
+    assert type(one) is type(four)
+    assert one.shape.mode == four.shape.mode == mode
+    assert (one.lay.nseg, one.lay.dist) == (1, False)
+    assert (four.lay.nseg, four.lay.dist) == (4, True)
+    assert got[0].equals(got[1])
 
 
 def test_tpch_q5_q9_tiled_distributed():

@@ -1,6 +1,6 @@
 """Spill shapes beyond agg/top-N: full external ORDER BY and windows
-(exec/tiled.py SortTiledExecutable / WindowTiledExecutable and their
-distributed twins) — the tuplesort.c spill-to-tape and nodeWindowAgg.c
+(exec/tiled.py SortTiledExecutable / WindowTiledExecutable, on one
+segment and on the mesh layout) — the tuplesort.c spill-to-tape and nodeWindowAgg.c
 disciplines with host RAM as the workfile.
 
 Contract: an admission-rejected unbounded-sort or windowed statement

@@ -1,5 +1,5 @@
 """A join build too large for the statement budget streams into a direct-
-address table (exec/tiled.py ``_BuildStream``): TPC-H Q3 and Q4 tiled
+address table (exec/tiled_plan.py ``BuildStream``): TPC-H Q3 and Q4 tiled
 under Cloudberry's default 125 MiB statement memory, cut with the data
 (SF 0.05 through the store, 125 MiB x 0.05), served over the wire and
 compared with the plain references and with the one-shot answer at the

@@ -172,11 +172,11 @@ def test_tiled_step_compiles_for_tpu_with_donation(sf1_lineitem, one_chip,
     s.config = sf1_lineitem.config.with_overrides(
         **{"resource.query_mem_bytes": 256 << 20})
     texe = tiled.plan_tiled(_plan(s, QUERIES["q6"]), s)
-    assert texe is not None and texe._platform == "tpu"
-    assert TP.step_donation(texe._platform) == (4,)
-    assert TP.effective_window(s.config, texe._platform) == 4
+    assert texe is not None and texe.lay.platform == "tpu"
+    assert TP.step_donation(texe.lay.platform) == (4,)
+    assert TP.effective_window(s.config, texe.lay.platform) == 4
     prelude_fn, step_fn, _ = texe._compile()
-    resident = texe._resident_inputs()
+    resident = texe.lay.resident(texe)
     feed = tiled._tile_feed(texe.shape.stream, s, texe.tile_rows)
     try:
         tile, _ = next(iter(feed))
