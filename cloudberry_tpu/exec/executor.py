@@ -149,35 +149,42 @@ def compile_plan(plan: N.PlanNode, session,
 
 # A string predicate is decided once over its column's dictionary, on the
 # host, and the program gathers the verdicts by code (ex.DictLookup). A
-# table longer than this rides into the program as an INPUT at the rung
-# above its length, not as a constant: a large dictionary's size and
-# contents follow from the data (Q13's LIKE over some 560,000 distinct
-# order comments), and a constant would make every load of the table a
-# program of its own. Shorter tables (a flag, a mode, a segment: under
-# the ladder's first step) stay constants.
+# table the statement's literals chose (ex.LookupLiterals: an IN list, a
+# LIKE pattern) rides into the program as an INPUT at every length: its
+# contents change from draw to draw, and a constant would make each draw
+# a program of its own (in a generic plan it is a $params binding,
+# sched/paramplan.py). Any other table longer than this rides as an input
+# too, at the rung above its length: a large dictionary's size and
+# contents follow from the data. Shorter ones (a rank or translation
+# table over a flag, a mode, a segment) stay constants.
 DICT_TABLES = "$dicts"
 _DICT_TABLE_MIN = 64
 
 
 def stamp_dict_tables(plan: N.PlanNode) -> dict:
-    """{input key: device array} of the plan's long dictionary tables,
-    each padded to its rung; every such ``DictLookup`` is stamped with
-    its key (``_table_input``), which ``compile_expr`` reads the table
-    by when the lowerer offers it. Keys follow the plan's document order,
-    so two plans of one shape name their tables alike."""
+    """{input key: device array} of the plan's input lookup tables (see
+    above), each padded to its rung; every such ``DictLookup`` is
+    stamped with its key (``_table_input``), which ``compile_expr``
+    reads the table by when the lowerer offers it. Keys follow the
+    plan's document order, so two plans of one shape name their tables
+    alike. A table a generic plan binds (``ex.TABLE_PARAM``) is left to
+    its ``$params``."""
     from cloudberry_tpu.plan.distribute import _node_exprs
 
     out = {}
     for node in numbered_nodes(plan):
         for e in _node_exprs(node):
             for sub in ex.walk(e):
-                if isinstance(sub, ex.DictLookup) \
-                        and len(sub.table) > _DICT_TABLE_MIN:
+                if not isinstance(sub, ex.DictLookup) or getattr(
+                        sub, "_table_input", "$").startswith(
+                            ex.TABLE_PARAM):
+                    continue
+                if sub.literals is not None \
+                        or len(sub.table) > _DICT_TABLE_MIN:
                     key = f"$dict{len(out)}"
                     object.__setattr__(sub, "_table_input", key)
-                    table = np.asarray(sub.table)
-                    out[key] = jnp.asarray(K.pad_rows(
-                        table, K.row_rung_up(len(table))))
+                    out[key] = jnp.asarray(K.pad_to_rung(
+                        np.asarray(sub.table)))
     return out
 
 
@@ -1034,8 +1041,9 @@ class Lowerer:
         if root is not None:
             self._number(root)
         # runtime literal bindings for a generic plan (sched/paramplan.py):
-        # "$prm<slot>" -> scalar array, injected next to the columns when
-        # an expression carries Param leaves
+        # "$prm<slot>" -> scalar array, "$dlt<i>" -> a literal-chosen
+        # lookup table, injected next to the columns when an expression
+        # carries Param leaves or gathers through such a table
         self.params = params
         # long dictionary tables riding as inputs (stamp_dict_tables):
         # injected next to the columns of an expression that gathers one
@@ -1241,8 +1249,10 @@ class Lowerer:
         Param leaves (generic plans) read their runtime binding from the
         program's "$params" input."""
         subs = [n for n in ex.walk(e) if isinstance(n, ex.SubqueryScalar)]
-        if self.params is not None \
-                and any(isinstance(n, ex.Param) for n in ex.walk(e)):
+        if self.params is not None and any(
+                isinstance(n, ex.Param)
+                or getattr(n, "_table_input", None) in self.params
+                for n in ex.walk(e)):
             cols = {**cols, **self.params}
         if self.dict_tables and any(
                 getattr(n, "_table_input", None) in self.dict_tables

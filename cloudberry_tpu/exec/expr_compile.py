@@ -96,9 +96,10 @@ def compile_expr(e: ex.Expr) -> Callable[[Columns], jnp.ndarray]:
 
     if isinstance(e, ex.DictLookup):
         f = compile_expr(e.column)
-        # a long table rides into the program as an input, padded to
-        # its rung (exec/executor.py stamp_dict_tables); where the
-        # lowerer offers none, the table is the constant it always was
+        # a long or literal-chosen table rides into the program as an
+        # input, padded to its rung (exec/executor.py stamp_dict_tables,
+        # or a generic plan's $params); where the lowerer offers none,
+        # the table is the node's own, a constant
         key = getattr(e, "_table_input", None)
 
         def lookup(cols):

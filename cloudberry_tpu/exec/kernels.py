@@ -1307,6 +1307,13 @@ def pad_rows(arr: np.ndarray, capacity: int) -> np.ndarray:
     return np.concatenate([arr, np.zeros((short,), dtype=arr.dtype)])
 
 
+def pad_to_rung(arr: np.ndarray) -> np.ndarray:
+    """A host array padded to the rung above its length: a dictionary
+    lookup table, whose length follows from the data, rides into a
+    program at a length on the ladder."""
+    return pad_rows(arr, row_rung_up(len(arr)))
+
+
 # --------------------------------------------------------------------------
 # misc
 # --------------------------------------------------------------------------

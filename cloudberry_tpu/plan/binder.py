@@ -1580,10 +1580,9 @@ class Binder:
             e = b(node.expr)
             if e.dtype.base == DType.STRING and all(
                     isinstance(it, ast.StringLit) for it in node.items):
-                sdict = _require_dict(e)
-                values = {it.value for it in node.items}
-                table = sdict.predicate_table(lambda v: v in values)
-                out: ex.Expr = ex.DictLookup(e, table)
+                out: ex.Expr = _literal_lookup(
+                    e, "in", [it.value for it in node.items],
+                    [it.pos for it in node.items])
                 v = _valid_of(e)
                 if v is not None:
                     out = _set_valid(ex.BinOp("and", out, v, T.BOOL), v)
@@ -1599,8 +1598,7 @@ class Binder:
 
         if isinstance(node, ast.Like):
             e = b(node.expr)
-            sdict = _require_dict(e)
-            out = ex.DictLookup(e, sdict.like_table(node.pattern))
+            out = _literal_lookup(e, "like", [node.pattern], [node.pos])
             v = _valid_of(e)
             if v is not None:
                 out = _set_valid(ex.BinOp("and", out, v, T.BOOL), v)
@@ -2530,7 +2528,9 @@ class Binder:
             if _is_null_literal(left) or _is_null_literal(right):
                 return _null_bool()  # cmp with NULL is NULL (never TRUE)
             v = _and_valid(_valid_of(left), _valid_of(right))
-            out = self._bind_comparison(op, left, right)
+            pos = next((n.pos for n in (node.left, node.right)
+                        if isinstance(n, ast.StringLit)), -1)
+            out = self._bind_comparison(op, left, right, pos)
             if v is not None:
                 out = ex.BinOp("and", out, v, T.BOOL)  # is-true normalize
             return _set_valid(out, v)
@@ -2578,7 +2578,11 @@ class Binder:
         return ex.BinOp(op, self._coerce(left, rtype),
                         self._coerce(right, rtype), rtype)
 
-    def _bind_comparison(self, op: str, left: ex.Expr, right: ex.Expr) -> ex.Expr:
+    def _bind_comparison(self, op: str, left: ex.Expr, right: ex.Expr,
+                         pos: int = -1) -> ex.Expr:
+        """``pos``: where a string literal compared stands in the
+        statement's text (an ordering comparison's lookup table is chosen
+        by it)."""
         lt, rt = left.dtype, right.dtype
         # string comparisons fold through the dictionary
         if lt.base == DType.STRING or rt.base == DType.STRING:
@@ -2597,9 +2601,7 @@ class Binder:
                     code = sdict.code_of(lit)
                     return ex.BinOp("<>", left,
                                     ex.Literal(code, T.STRING), T.BOOL)
-                table = sdict.predicate_table(
-                    lambda v: _str_cmp(op, v, lit))
-                return ex.DictLookup(left, table)
+                return _literal_lookup(left, op, [lit], [pos])
             if rt.base == DType.STRING:
                 ldict, rdict = _expr_dict(left), _expr_dict(right)
                 if ldict is None or rdict is None:
@@ -3510,6 +3512,30 @@ def replay_literal(origin: ex.LiteralOrigin, text: str
     except (ValueError, TypeError, OverflowError):
         return None
     return lit
+
+
+def lookup_table(kind: str, sdict: StringDictionary, texts) -> np.ndarray:
+    """The boolean table a string predicate's literals choose over
+    ``sdict``: ``in`` (any of ``texts``), ``like`` (the pattern
+    ``texts[0]``) or a comparison operator against ``texts[0]``. A
+    literal the dictionary lacks matches no code. The binder builds
+    every such table here, and the literal template of a generic plan
+    (sched/paramplan.py) builds it again from another text's literals."""
+    if kind == "in":
+        values = set(texts)
+        return sdict.predicate_table(lambda v: v in values)
+    if kind == "like":
+        return sdict.like_table(texts[0])
+    return sdict.predicate_table(lambda v: _str_cmp(kind, v, texts[0]))
+
+
+def _literal_lookup(e: ex.Expr, kind: str, texts, pos) -> ex.DictLookup:
+    """``e``'s codes gathered through the table its literals choose,
+    marked as a table of literals (``ex.LookupLiterals``): a parameter of
+    the program, not part of it."""
+    sdict = _require_dict(e)
+    return ex.DictLookup(e, lookup_table(kind, sdict, texts), T.BOOL,
+                         ex.LookupLiterals(kind, tuple(pos), sdict))
 
 
 def _common_type(ts: list[SqlType]) -> SqlType:

@@ -150,6 +150,27 @@ class CaseWhen(Expr):
         return tuple(out)
 
 
+# the $params key family of a generic plan's literal-chosen lookup tables
+# (sched/paramplan.py), as "$prm" is its literals'
+TABLE_PARAM = "$dlt"
+
+
+@dataclass(frozen=True)
+class LookupLiterals:
+    """Where a DictLookup's table was chosen by the statement's literals
+    (a string IN list, a LIKE pattern, an ordering comparison with a
+    string) and how to choose it again: ``kind`` is 'in', 'like' or the
+    comparison's operator, ``pos`` each literal token's offset in the
+    statement's text (-1: made by a rewrite, not read from the text) and
+    ``sdict`` the dictionary the table was decided over
+    (plan/binder.py ``lookup_table``). Such a table is a parameter of the
+    program, bound per send, never part of it; a table built from a
+    dictionary alone (a rank or translation table) has no such mark."""
+    kind: str
+    pos: tuple
+    sdict: Any = field(compare=False, repr=False)
+
+
 @dataclass(frozen=True, eq=False)
 class DictLookup(Expr):
     """Gather host-computed per-code table by a string column's codes.
@@ -159,6 +180,8 @@ class DictLookup(Expr):
     column: Expr
     table: np.ndarray = field(hash=False, compare=False)
     dtype: SqlType = BOOL
+    literals: Optional[LookupLiterals] = field(default=None, hash=False,
+                                               compare=False)
 
     def children(self):
         return (self.column,)
@@ -241,10 +264,12 @@ def rewrite(e: Expr, fn) -> Expr:
             rewrite(e.otherwise, fn) if e.otherwise is not None else None,
             e.dtype)
     if isinstance(e, DictLookup):
-        out = DictLookup(rewrite(e.column, fn), e.table, e.dtype)
-        d = getattr(e, "_out_dict", None)
-        if d is not None:
-            object.__setattr__(out, "_out_dict", d)
+        out = DictLookup(rewrite(e.column, fn), e.table, e.dtype,
+                         e.literals)
+        for attr in ("_out_dict", "_table_input"):
+            v = getattr(e, attr, None)
+            if v is not None:
+                object.__setattr__(out, attr, v)
         return out
     # leaves (ColumnRef, Literal, Param, IsValid, SubqueryScalar) pass
     return e

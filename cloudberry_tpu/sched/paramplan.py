@@ -26,10 +26,17 @@ signature (or the ``_no_stmt_cache`` gate) refuses the rebind and the
 statement keeps today's compile-per-text path.
 
 The signature deliberately captures everything the TRACE bakes in: node
-shapes and capacities, baked literal values outside param sites, DictLookup
-table contents (string-predicate lookup tables are literal-derived),
-dictionary identity for collation rank tables (guarded by table versions),
-and shared-subtree (PShare) topology.
+shapes and capacities, baked literal values outside param sites, the
+contents of DictLookup tables built from a dictionary alone (translation
+tables of a derived dictionary), dictionary identity for collation rank
+tables (guarded by table versions), and shared-subtree (PShare) topology.
+
+A lookup table the statement's literals chose (a string IN list, a LIKE
+pattern, an ordering comparison with a string: ``expr.LookupLiterals``)
+is a parameter like a literal: the signature holds its column, dtype,
+dictionary and padded length, and its contents ride per send as a
+``$dlt<i>`` entry of ``$params``, at every length. Two draws of one
+report with other modes or other words then share one program.
 """
 
 from __future__ import annotations
@@ -129,8 +136,8 @@ def _pyval(v) -> Any:
 
 def _param_scalar(e: ex.Literal) -> bool:
     """Literal eligible to travel as a device input: a numeric/bool/date
-    scalar (strings stay baked — their plan effect is DictLookup tables,
-    whose contents the signature hashes)."""
+    scalar (strings stay baked — their plan effect is a dictionary code,
+    a $prm slot of its own, or a DictLookup table, a $dlt binding)."""
     if isinstance(e.value, str):
         return False
     try:
@@ -156,6 +163,8 @@ class _Walker:
         # of a known origin that stay baked into the program
         self.slot_lits: list[Optional[ex.Literal]] = []
         self.baked: list[ex.Literal] = []
+        # (key, mark) of each literal-chosen lookup table ($dlt<i>)
+        self.tables: list[tuple[str, ex.LookupLiterals]] = []
         self.subplans = 0
         self._nrw = 0  # scan row-count parameter slots ($nrw<i>)
         self._memo: dict[int, int] = {}
@@ -242,6 +251,24 @@ class _Walker:
             new = ex.CaseWhen(tuple(news), on, e.dtype) if self.rewrite \
                 else e
             return ("W", _tsig(e.dtype), tuple(sigs), os_), new
+        if isinstance(e, ex.DictLookup) and e.literals is not None:
+            # the statement's literals chose the table: a parameter, bound
+            # per send at its rung; the signature keeps what the trace
+            # sees of it
+            from cloudberry_tpu.exec import kernels as K
+
+            s, n = self.esig(e.column, False)
+            key = f"{ex.TABLE_PARAM}{len(self.tables)}"
+            tab = K.pad_to_rung(np.asarray(e.table))
+            self.bindings[key] = tab
+            self.tables.append((key, e.literals))
+            tsig = ("DT", s, str(tab.dtype), len(tab),
+                    self._dictsig(e.literals.sdict))
+            if not self.rewrite:
+                return tsig, e
+            out = ex.DictLookup(n, e.table, e.dtype, e.literals)
+            object.__setattr__(out, "_table_input", key)
+            return tsig, out
         if isinstance(e, ex.DictLookup):
             s, n = self.esig(e.column, False)
             tab = np.asarray(e.table)
@@ -602,6 +629,13 @@ class GenericPlan:
         self._rungs: dict[int, Any] = {}
         self._rung_lock = __import__("threading").Lock()
 
+    def count_tables(self, session, sends: int = 1) -> None:
+        """Count the literal-chosen lookup tables ``sends`` launches bind
+        (``dict_params_bound``)."""
+        n = sum(1 for k in self.param_keys if k.startswith(ex.TABLE_PARAM))
+        if n:
+            session.stmt_log.bump("dict_params_bound", n * sends)
+
     def matches(self, session, sig, versions, ddlv) -> bool:
         return (self.sig == sig and self.config is session.config
                 and self.versions == versions and self.ddlv == ddlv)
@@ -638,6 +672,7 @@ class GenericPlan:
         from cloudberry_tpu.obs import trace as OT
 
         session.stmt_log.bump("param_binds")
+        self.count_tables(session)
         if self.kind == "dist":
             from cloudberry_tpu.exec import dist_executor as DX
 
@@ -710,6 +745,8 @@ class GenericPlan:
 #                          estimates from a predicate's selectivity)
 #   untracked_literal      a $prm slot, or a comparison that pruned a scan,
 #                          whose literal names no token of the text
+#   lookup_table           a literal-chosen lookup table ($dlt) one of whose
+#                          literals names no token of the text
 #   replay_mismatch        run again on the build's own text, the derivation
 #                          does not give the build's bindings and partitions
 #   arm_mismatch           a later send through the full path matched the
@@ -736,14 +773,16 @@ class LiteralTemplate:
     """One variant's bindings as a function of the statement's literal
     tokens. ``pinned`` tokens must read as the build's did (they are baked
     into the program, or nothing accounts for them); each ``$prm`` slot
-    replays its literal's origin on one of the others; a store scan's
-    partition list is decided again from the comparisons that pruned it
-    and must come out as the variant's. ``unseen`` holds the free tokens
-    no full-path send has yet shown under another text: the template
-    binds nothing until it is empty."""
+    replays its literal's origin on one of the others; each ``$dlt``
+    lookup table is chosen again over its dictionary from its literals'
+    tokens (``binder.lookup_table``); a store scan's partition list is
+    decided again from the comparisons that pruned it and must come out
+    as the variant's. ``unseen`` holds the free tokens no full-path send
+    has yet shown under another text: the template binds nothing until
+    it is empty."""
 
     def __init__(self, session, names, tokens, free, slots, fixed, scans,
-                 files):
+                 files, tables):
         import weakref
 
         from cloudberry_tpu.plan.feedback import feedback_gen
@@ -753,6 +792,8 @@ class LiteralTemplate:
                             if i not in free)
         self.slots = slots          # ((key, token index, origin, type), ...)
         self.fixed = fixed          # $nrw bindings: the table versions' own
+        # ((key, token indices, mark, bound length), ...) a $dlt table
+        self.tables = tables
         # per keyed scan: (table, ((col, op, origin, token index), ...),
         # the manifest's partitions to choose from), and the files the
         # variant reads of each
@@ -783,7 +824,8 @@ class LiteralTemplate:
         """(bindings, each keyed scan's partition files) as this text's
         literal tokens give them, or None when the text is not one the
         template speaks for."""
-        from cloudberry_tpu.plan.binder import replay_literal
+        from cloudberry_tpu.exec import kernels as K
+        from cloudberry_tpu.plan.binder import lookup_table, replay_literal
         from cloudberry_tpu.plan.scanprune import scan_bounds
 
         if len(tokens) != len(self.tokens):
@@ -800,6 +842,13 @@ class LiteralTemplate:
                 out[key] = np.asarray(lit.value, dtype=t.np_dtype)
             except (TypeError, ValueError, OverflowError):
                 return None
+        for key, idx, lits, n in self.tables:
+            # (the table-version guard holds: the dictionary is the build's)
+            tab = K.pad_to_rung(lookup_table(lits.kind, lits.sdict,
+                                             [tokens[i] for i in idx]))
+            if len(tab) != n:
+                return None
+            out[key] = tab
         files = []
         store = session.catalog.store
         for (table, cmps, candidates), own in zip(self.scans, self.files):
@@ -895,6 +944,12 @@ def _derive_template(session, gp: GenericPlan, plan, w: _Walker,
         if i is None:
             return "untracked_literal"
         slots.append((f"$prm{k}", i, lit.origin, w.slots[k]))
+    tables = []
+    for key, lits in w.tables:
+        idx = tuple(index.get(p) for p in lits.pos)
+        if None in idx:
+            return "lookup_table"
+        tables.append((key, idx, lits, len(w.bindings[key])))
     scans = []
     for s in w.keyed:
         cmps = []
@@ -905,11 +960,15 @@ def _derive_template(session, gp: GenericPlan, plan, w: _Walker,
             cmps.append((col, op, lit.origin, i))
         scans.append((s.table_name, tuple(cmps),
                       store.read_manifest(s.table_name)["partitions"]))
-    # a token is free to change only where $prm slots are all it feeds
-    free = {i for _, i, _, _ in slots} - {token_of(b) for b in w.baked}
+    # a token is free to change only where $prm slots and $dlt tables are
+    # all it feeds
+    free = ({i for _, i, _, _ in slots}
+            | {i for _, idx, _, _ in tables for i in idx}) \
+        - {token_of(b) for b in w.baked}
     fixed = {k: v for k, v in w.bindings.items() if k.startswith("$nrw")}
     t = LiteralTemplate(session, gp.names, tokens, free, tuple(slots), fixed,
-                        tuple(scans), tuple(_scan_files(s) for s in w.keyed))
+                        tuple(scans), tuple(_scan_files(s) for s in w.keyed),
+                        tuple(tables))
     got = t.bind(session, tokens)
     if got is None or not _same_bindings(got, w.bindings):
         return "replay_mismatch"
@@ -1245,6 +1304,7 @@ def run_batch(session, sqls: list[str]):
         # statement gets its own verdict and the grow-and-retry loop
         return None
     session.stmt_log.bump("batched_statements", k)
+    gp.count_tables(session, k)
     out = []
     host_cols = {name: np.asarray(v) for name, v in cols.items()}
     host_sel = np.asarray(sel)

@@ -49,6 +49,7 @@ class NumberLit(ExprNode):
 @dataclass
 class StringLit(ExprNode):
     value: str
+    pos: int = field(default=-1, compare=False, repr=False)  # as NumberLit
 
 
 @dataclass
@@ -112,6 +113,7 @@ class Like(ExprNode):
     expr: ExprNode
     pattern: str
     negated: bool = False
+    pos: int = field(default=-1, compare=False, repr=False)  # the pattern's
 
 
 @dataclass

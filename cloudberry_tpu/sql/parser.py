@@ -814,7 +814,7 @@ class Parser:
             pat = self.advance()
             if pat.kind != "string":
                 raise ParseError("LIKE pattern must be a string literal")
-            return ast.Like(e, pat.text, negated)
+            return ast.Like(e, pat.text, negated, pat.pos)
         if self.accept_kw("is"):
             neg = bool(self.accept_kw("not"))
             self.expect_kw("null")
@@ -858,7 +858,7 @@ class Parser:
             return ast.NumberLit(t.text, t.pos)
         if t.kind == "string":
             self.advance()
-            return ast.StringLit(t.text)
+            return ast.StringLit(t.text, t.pos)
         if self.at_op("("):
             self.advance()
             if self.at_kw("select"):

@@ -5,7 +5,7 @@ lowered module text and the ``jax.result_info`` keys it carries.
 
     python tests/program_identity_worker.py <n_segments> \
         <q15v|q3|q12|q13|q18|q1|q6> \
-        [--no-origin] [--tiled] [--store [--warm]]
+        [--no-origin] [--tiled] [--store [--warm]] [--draw <k>]
 
 ``--no-origin`` binds every literal without its origin (``expr.Literal``'s
 ``origin``, ISSUE 29), as the tree before it did: the witness that the
@@ -26,6 +26,11 @@ whose tables are COLD: the planner knows of them what the manifests say
 (ISSUE 31). ``--warm`` loads them into RAM before the statement is
 planned. The line also carries the joins the launches counted, by shape,
 and the semi-joins among them that filter a scan before any join.
+
+``--draw <k>`` sends Q12 or Q13 with the k-th of three literal sets that
+choose its lookup table (Q12's two shipping modes, Q13's two words; 0 is
+the validation values): two draws are the check that such a table is a
+parameter of the program, not a part of it.
 
 Run in two checkouts, it is the check that a change leaves a statement's
 programs as they were: equal ``hashes`` and ``result_info`` for the same
@@ -63,6 +68,19 @@ Q15V = ("select l_suppkey as supplier_no, "
         "group by l_suppkey order by supplier_no")
 STATEMENTS = {"q15v": Q15V, **{q: QUERIES[q] for q in
                                ("q3", "q12", "q13", "q18", "q1", "q6")}}
+
+# (the text's literal set, the sets ``--draw`` puts in its place)
+REDRAW = {"q12": ("'MAIL', 'SHIP'", ("'MAIL', 'SHIP'", "'REG AIR', 'FOB'",
+                                     "'TRUCK', 'RAIL'")),
+          "q13": ("%special%requests%", ("%special%requests%",
+                                         "%pending%deposits%",
+                                         "%express%packages%"))}
+if "--draw" in sys.argv[3:]:
+    stmt = sys.argv[2]
+    was, sets = REDRAW[stmt]
+    k = int(sys.argv[sys.argv.index("--draw") + 1])
+    assert was in STATEMENTS[stmt]
+    STATEMENTS[stmt] = STATEMENTS[stmt].replace(was, sets[k])
 
 if "--no-origin" in sys.argv[3:]:
     from cloudberry_tpu.plan import binder
